@@ -200,8 +200,8 @@ class JitInLoop(Rule):
     severity = "error"
     short = "jax.jit(...) constructed inside a for/while body"
     motivation = (
-        "recompilation churn: the round-2 evidence priced one compile at "
-        "2.67 s — per loop iteration, that is the whole hardware window"
+        "recompilation churn: one compile costs seconds, and a loop "
+        "pays it on every iteration"
     )
 
     def check(self, ctx: FileContext) -> Iterator[Finding]:

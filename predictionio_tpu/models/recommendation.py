@@ -855,8 +855,7 @@ class ALSAlgorithm(Algorithm):
                     k=k_pad, mode=self.params.streaming_top_k,
                 )
             # one fetch for both arrays: each device_get is a full host↔
-            # device round trip, which dominates per-batch latency on
-            # high-latency links (tunneled/remote devices)
+            # device round trip
             import jax
 
             scores, items = jax.device_get((scores, items))

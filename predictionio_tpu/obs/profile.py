@@ -26,8 +26,10 @@ layer (docs/observability.md#profiling):
   fences and roofline accounting: each phase records wall time, a
   fenced (``block_until_ready``) device-complete time, and optional
   FLOP/byte estimates from which MFU and HBM-bandwidth utilization are
-  computed against the v5e reference peaks (the ``bench.py`` numbers,
-  now shared). Disabled (``PIO_PROFILE`` unset), a phase is a no-op
+  computed against the peaks of the device that ran
+  (:data:`DEVICE_PEAKS`, keyed by ``device_kind``; a kind that is not in
+  the table gets achieved rates only). Disabled (``PIO_PROFILE`` unset),
+  a phase is a no-op
   context that never touches the clock or the device — hooks may stay
   in production paths.
 
@@ -63,17 +65,14 @@ __all__ = [
 #: always on — an int compare per dispatch.
 PROFILE_ENV = "PIO_PROFILE"
 
-#: Reference device peaks for roofline estimates. v5e: 197 TFLOP/s bf16
-#: MXU → ~half attainable for f32 solves; 819 GB/s HBM. The same
-#: constants bench.py has used since round 2 — one home now.
+#: Device peaks for roofline estimates, keyed by ``device_kind`` as JAX
+#: reports it. v5e (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s
+#: bf16 MXU → ~half attainable for f32 solves; 819 GB/s HBM. One home,
+#: shared by bench.py and ``pio profile``. A kind that is not here has
+#: no peaks: its roofline carries achieved rates and no utilization.
 DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
-    "tpu-v5e": {"flops_per_s_f32": 98.5e12, "hbm_bytes_per_s": 819e9},
+    "TPU v5 lite": {"flops_per_s_f32": 98.5e12, "hbm_bytes_per_s": 819e9},
 }
-
-#: The peaks roofline estimates are computed against when the caller
-#: does not name a device (estimates are then explicitly labelled as
-#: v5e-referenced, the convention bench.py set).
-REFERENCE_DEVICE = "tpu-v5e"
 
 #: compile-duration samples kept per function for replay-on-bind and
 #: reports; compiles are rare, so a small cap loses nothing real
@@ -92,19 +91,24 @@ def roofline(
     seconds: float,
     peaks: Optional[Dict[str, float]] = None,
 ) -> Dict[str, float]:
-    """FLOP/byte/time → achieved TFLOP/s, MFU and HBM-bandwidth
-    utilization against ``peaks`` (default: the v5e reference — callers
-    on other devices label the result accordingly, as bench.py does)."""
-    peaks = peaks if peaks is not None else DEVICE_PEAKS[REFERENCE_DEVICE]
-    if seconds <= 0.0:
-        return {"tflops_per_s": 0.0, "mfu": 0.0, "hbm_util": 0.0}
-    mfu = flops / seconds / peaks["flops_per_s_f32"]
-    hbm = hbm_bytes / seconds / peaks["hbm_bytes_per_s"]
-    return {
-        "tflops_per_s": flops / seconds / 1e12,
-        "mfu": mfu,
-        "hbm_util": hbm,
+    """FLOP/byte/time → achieved TFLOP/s and GB/s, plus ``mfu`` and
+    ``hbm_util`` against ``peaks``. ``peaks=None`` looks up the device
+    that ran (``jax.devices()[0].device_kind`` in :data:`DEVICE_PEAKS`);
+    for a kind that is not in the table the two utilization keys are
+    absent — never a share of another chip's peak."""
+    if peaks is None:
+        import jax
+
+        peaks = DEVICE_PEAKS.get(jax.devices()[0].device_kind)
+    per_s = 1.0 / seconds if seconds > 0.0 else 0.0
+    out = {
+        "tflops_per_s": flops * per_s / 1e12,
+        "hbm_gb_per_s": hbm_bytes * per_s / 1e9,
     }
+    if peaks is not None:
+        out["mfu"] = flops * per_s / peaks["flops_per_s_f32"]
+        out["hbm_util"] = hbm_bytes * per_s / peaks["hbm_bytes_per_s"]
+    return out
 
 
 class _InstrumentedJit:
@@ -527,7 +531,7 @@ class PhaseProfiler:
         with prof.phase("solve", flops=F, hbm_bytes=B) as ph:
             out = jitted(x)
             ph.fence(out)          # device-complete, not dispatch, time
-        prof.summary()["solve"]["mfu"]  # vs the v5e reference peaks
+        prof.summary()["solve"].get("mfu")  # when the device's peaks are known
 
     ``enabled=None`` reads ``PIO_PROFILE``; disabled, :meth:`phase`
     returns a shared no-op context that never calls the clock or the
@@ -594,9 +598,9 @@ class PhaseProfiler:
         )
 
     def summary(self) -> Dict[str, dict]:
-        """Per-phase totals + roofline estimates (vs the v5e reference
-        peaks unless the profiler was built with explicit ``peaks``) —
-        JSON-safe, the ``pio profile`` report's data."""
+        """Per-phase totals + roofline estimates (vs the peaks of the
+        device that ran unless the profiler was built with explicit
+        ``peaks``) — JSON-safe, the ``pio profile`` report's data."""
         with self._lock:
             phases = {
                 name: dict(st) for name, st in self._phases.items()
@@ -637,7 +641,7 @@ def render_profile_report(
         lines.append("")
         lines.append(
             f"{'phase':<24}{'count':>6}{'wall_s':>10}{'device_s':>10}"
-            f"{'tflops/s':>10}{'mfu(v5e)':>10}{'hbm_util':>10}"
+            f"{'tflops/s':>10}{'mfu':>10}{'hbm_util':>10}"
         )
         for name in sorted(phases):
             st = phases[name]
@@ -646,12 +650,15 @@ def render_profile_report(
                 f"{st.get('wall_s', 0.0):>10.3f}"
                 f"{st.get('device_s', st.get('wall_s', 0.0)):>10.3f}"
                 f"{st.get('tflops_per_s', 0.0):>10.3f}"
-                f"{st.get('mfu', 0.0):>10.4f}"
-                f"{st.get('hbm_util', 0.0):>10.4f}"
+                + "".join(
+                    f"{st[key]:>10.4f}" if key in st else f"{'-':>10}"
+                    for key in ("mfu", "hbm_util")
+                )
             )
         lines.append(
-            "  (mfu/hbm_util are roofline estimates vs the v5e reference "
-            "peaks; on other devices read them as relative, like bench.py)"
+            "  (mfu/hbm_util are roofline estimates vs the peaks of the "
+            "device that ran; '-' where its device_kind has no peaks in "
+            "DEVICE_PEAKS)"
         )
     if jit:
         lines.append("")

@@ -44,8 +44,8 @@ test mesh): factors at 1/2/4/8 shards match the single-device trainer
 within the PR-12 reassociation tolerances (rtol 1e-3 / atol 1e-4, holdout
 RMSE 1e-3) — sharding changes accumulation ORDER (per-shard index sorting
 happens in permuted id space), never the per-row math. The multi-host
-``jax.distributed`` drive is scripted for hardware day
-(docs/hardware_day.md#multi-host-train).
+``jax.distributed`` drive has not been run
+(docs/distributed_training.md).
 """
 
 from __future__ import annotations
@@ -53,6 +53,8 @@ from __future__ import annotations
 import dataclasses
 import functools
 import heapq
+import json
+import logging
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -61,7 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..parallel.collectives import shard_map
+from jax import shard_map
 from ..quant.ragged import ragged_gather
 from ..parallel.mesh import DATA_AXIS, MeshConfig, create_mesh
 from .als import (
@@ -88,6 +90,8 @@ __all__ = [
     "resolve_shards",
     "row_solve_flops",
 ]
+
+logger = logging.getLogger(__name__)
 
 #: Solve rows ride the mesh ``data`` axis — the same axis name the rest of
 #: the parallel plane uses, so a hybrid (DCN x ICI) mesh slots in directly.
@@ -539,7 +543,7 @@ def als_train_sharded(
     device resolve byte-identically). ``mesh`` (optional) supplies a
     prebuilt mesh whose :data:`SHARD_AXIS` size is the shard count —
     multi-host runs pass the ``hybrid_mesh`` built after
-    ``initialize_from_env()`` (docs/hardware_day.md#multi-host-train);
+    ``initialize_from_env()`` (docs/distributed_training.md);
     single-host runs build a mesh over the first ``shards`` devices.
 
     ``checkpoint`` (a :class:`~predictionio_tpu.ckpt.CheckpointStore`)
@@ -795,6 +799,13 @@ def als_train_sharded(
                 ck_prof["corruptSkipped"] = checkpoint.corrupt_skipped
                 ck_prof.setdefault("resumedFrom", None)
 
+    # where the trained tables' shards sit: one device each, or the mesh
+    # did not place what it was asked to (chip_smoke.py --chips 4 reads it)
+    logger.info("sharded ALS tables: %s", json.dumps({
+        "shards": n,
+        "user": [s.device.id for s in x.addressable_shards],
+        "item": [s.device.id for s in y.addressable_shards],
+    }))
     # permuted sharded layout → global row order (host-side unpermute)
     uf = np.asarray(x)[user_plan.flat_index(np.arange(n_users))]
     itf = np.asarray(y)[item_plan.flat_index(np.arange(n_items))]

@@ -33,14 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..parallel.collectives import shard_map  # jax-version compat shim
-
-try:  # pallas is TPU/GPU-oriented; keep the module importable anywhere
-    from jax.experimental import pallas as pl
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax import shard_map
+from jax.experimental import pallas as pl
 
 SEQ_AXIS = "seq"
 
@@ -214,10 +208,6 @@ def flash_attention_pallas(
     step in the revalidation queue). ``interpret=None`` auto-selects the
     interpreter off-TPU.
     """
-    if not _HAVE_PALLAS:
-        raise NotImplementedError(
-            "flash_attention_pallas requires pallas; use flash_attention"
-        )
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     lq, lk = q.shape[2], k.shape[2]

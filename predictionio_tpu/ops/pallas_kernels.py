@@ -14,9 +14,10 @@ Exclusion (seen/unavailable items — the e-commerce template's serving-time
 filters) is per-query index lists (``[B, E]``, -1 padded), matched against
 the block's global item indices, instead of a dense ``[B, N]`` mask.
 
-On non-TPU backends the kernel runs in interpret mode (tests), and
-:func:`top_k_streaming` transparently falls back to the XLA path when pallas
-is unavailable.
+On non-TPU backends the kernels run in interpret mode (tests). The dense XLA
+path (:func:`predictionio_tpu.ops.scoring.xla_topk_with_sentinels`) is a
+separate program that ``resolve_topk_path`` chooses openly; nothing here
+falls back to it.
 """
 
 from __future__ import annotations
@@ -30,13 +31,8 @@ import numpy as np
 
 _NEG_INF = float("-inf")  # plain scalar: jnp constants cannot be captured by kernels
 
-try:  # pallas is TPU/GPU-oriented; keep the module importable anywhere
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
 
 def _round_up(n: int, m: int) -> int:
@@ -74,10 +70,13 @@ def _topk_kernel(q_ref, items_ref, excl_ref, out_s_ref, out_i_ref, *,
         out_i_ref[:] = jnp.full_like(out_i_ref[:], -1)
 
     b = q_ref.shape[0]
+    # f32 scores, as on the dense path (ops/scoring.SCORE_PRECISION): the
+    # default rounds f32 inputs to bf16 passes on the chip
     scores = jax.lax.dot_general(
         q_ref[:], items_ref[:],
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # [B, T]
     gidx = j * block_items + jax.lax.broadcasted_iota(
         jnp.int32, (b, block_items), 1
@@ -154,7 +153,8 @@ def top_k_streaming(
     """Streaming top-k gather-dot: returns (scores ``[B, k]``, item indices
     ``[B, k]``) without materializing ``[B, N]`` scores in HBM.
 
-    Sentinel contract (all paths — kernel, interpret, XLA fallback): a slot
+    Sentinel contract (kernel and interpreter, shared with the dense XLA
+    path in ``ops/scoring.py``): a slot
     with fewer than ``k`` valid candidates (catalog smaller than ``k``, or
     exclusions masking the rest) holds score ``-inf`` and index ``-1``.
     Callers gathering items by index MUST treat ``-1`` as absent — negative
@@ -164,16 +164,6 @@ def top_k_streaming(
     (CPU tests). Queries/rank are padded to VPU/MXU tile boundaries; padding
     never appears in results (-inf / -1 masking).
     """
-    if not _HAVE_PALLAS:
-        # XLA fallback with the SAME contract: exclusions applied (dense
-        # mask), k clamped/padded to the catalog size, -inf slots carry
-        # the -1 sentinel. One home for that contract now that the fused
-        # serving entries (ops/scoring.py) share it.
-        from .scoring import xla_topk_with_sentinels
-
-        return xla_topk_with_sentinels(
-            query_vectors, item_factors, k, exclude_idx
-        )
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
@@ -287,23 +277,12 @@ def spd_solve_t(
     """Fused batched Cholesky solve in transposed layout.
 
     Requires ``n % 8 == 0`` and ``B % 128 == 0`` (callers pad; zero-padding
-    solves to exactly 0). Falls back to ``cho_solve`` when pallas is
-    unavailable. ``interpret=None`` auto-selects interpreter off-TPU.
+    solves to exactly 0). ``interpret=None`` auto-selects interpreter
+    off-TPU.
     """
     n, n2, bsz = a_t.shape
     if n != n2 or n % 8 != 0 or bsz % _SPD_BLK != 0:
         raise ValueError(f"spd_solve_t: bad shapes {a_t.shape}")
-    if not _HAVE_PALLAS:
-        a = jnp.moveaxis(a_t, -1, 0)  # [B, n, n]
-        # zero-padding guard: cho_factor of a zero matrix NaNs, so ridge
-        # the padded systems with I and zero their solutions afterwards —
-        # the kernel contract is "all-zero system ⇒ exactly-zero x"
-        # regardless of the rhs.
-        zero = jnp.trace(a, axis1=-2, axis2=-1) == 0
-        a = a + zero[:, None, None] * jnp.eye(n, dtype=a.dtype)
-        chol = jax.scipy.linalg.cho_factor(a, lower=True)
-        x = jax.scipy.linalg.cho_solve(chol, b_t.T)
-        return jnp.where(zero[None, :], 0.0, x.T)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return pl.pallas_call(
@@ -344,8 +323,7 @@ def spd_solve_t(
 # round 12 the kernel is the DEFAULT build wherever the pallas solver
 # resolves (ALSConfig.fused_gather=None; BENCH_FUSED_GATHER=0 /
 # fused_gather=False opt out) — the issue-rate question is still open
-# on silicon and sits FIRST on the hardware-day bisect checklist
-# (docs/hardware_day.md "Reclaiming the 3.29×").
+# on the chip (PERF.md, Open questions).
 #
 # Replaces the same MLlib hot loop as the solver above (reference:
 # ``examples/scala-parallel-recommendation/custom-prepartor/src/main/
@@ -355,16 +333,25 @@ def spd_solve_t(
 #: Max factor rows (DMAs) in flight per K-tile; VMEM tile is kt·r_pad·4 B.
 _FUSED_K_TILE = 512
 #: Max solve rows per grid step — bounds the [Bt, R, R] output block and
-#: the [Bt, K] index block in SMEM (Bt·K ≤ _FUSED_SMEM_IDX ints).
-_FUSED_B_TILE = 128
+#: the [Bt, K] index block in SMEM (Bt·K ≤ _FUSED_SMEM_IDX ints). R is
+#: lane-padded to 128 inside the kernel, so the output block is
+#: Bt·64 KiB and is double-buffered: 64 rows = 8 MiB, which with the
+#: gather tile and weight blocks stays inside Mosaic's 16 MiB of scoped
+#: VMEM (128 rows did not: refused for the chip at [4096, 128] buckets).
+_FUSED_B_TILE = 64
+#: Lanes of one SMEM ridge tile (one (8, _LANES) tile per grid step).
+_LANES = 128
 _FUSED_SMEM_IDX = 32768
 #: Widest K a single kernel call takes. Wider problems (the rare
 #: ultra-high-degree buckets) are split into K-slices summed in XLA.
 #: The per-call SMEM index block is [bt, k] with bt·k ≤ _FUSED_SMEM_IDX,
 #: so the real scalar-memory bound is _FUSED_SMEM_IDX·4 B = 128 KB
-#: regardless of this constant; the split's job is to keep a SINGLE
-#: row's index list (bt can't go below 1) within that same bound.
-_FUSED_K_SPLIT = 8192
+#: regardless of this constant; the split's job is to keep EIGHT rows'
+#: index lists within that same bound — a [bt, k] block whose bt is not
+#: a multiple of 8 (the sublane tiling) does not lower unless it is the
+#: whole array (found on the chip: a (4, 8192) block of a (32, 8192)
+#: array was refused).
+_FUSED_K_SPLIT = _FUSED_SMEM_IDX // 8
 
 
 def _gramian_kernel(idx_ref, w2_ref, rhs_ref, ridge_ref, y_ref, yty_ref,
@@ -389,7 +376,7 @@ def _gramian_kernel(idx_ref, w2_ref, rhs_ref, ridge_ref, y_ref, yty_ref,
         t = s % k_tiles
 
         def one(k, _):
-            # pio: lint-ok[mosaic-per-row-dma] the per-row gather IS this kernel's design; default-ON with the pallas solver since round 12 (explicit opt-out BENCH_FUSED_GATHER=0 / fused_gather=False), with the DMA-issue rate still first on the hardware-day A/B bisect list (docs/hardware_day.md)
+            # pio: lint-ok[mosaic-per-row-dma] the per-row gather IS this kernel's design; default-ON with the pallas solver since round 12 (explicit opt-out BENCH_FUSED_GATHER=0 / fused_gather=False), with the DMA-issue rate still unpriced on the chip (PERF.md, Open questions)
             dma = pltpu.make_async_copy(
                 y_ref.at[pl.ds(idx_ref[b, t * kt + k], 1), :],
                 gbuf.at[slot, pl.ds(k, 1), :],
@@ -433,7 +420,7 @@ def _gramian_kernel(idx_ref, w2_ref, rhs_ref, ridge_ref, y_ref, yty_ref,
 
         @pl.when(is_last_tile)
         def _():
-            a_ref[b] = a_acc + yty_ref[...] + ridge_ref[b] * eye
+            a_ref[b] = a_acc + yty_ref[...] + ridge_ref[0, b] * eye
             b_ref[b] = b_acc
 
         # reset the accumulators at each row boundary — a select, not a
@@ -465,7 +452,11 @@ def _gramian_fused_call(y, idx, w2, rhs, ridge, yty, bt, kt, interpret):
             pl.BlockSpec((bt, k), lambda i: (i, 0), memory_space=pltpu.SMEM),
             pl.BlockSpec((bt, k), lambda i: (i, 0)),
             pl.BlockSpec((bt, k), lambda i: (i, 0)),
-            pl.BlockSpec((bt,), lambda i: (i,), memory_space=pltpu.SMEM),
+            # each grid step's bt ridge values ride row 0 of an (8, 128)
+            # SMEM tile of their own (see gramian_fused)
+            pl.BlockSpec(
+                (8, _LANES), lambda i: (i, 0), memory_space=pltpu.SMEM
+            ),
             pl.BlockSpec(memory_space=pl.ANY),  # y stays in HBM
             pl.BlockSpec((r, r), lambda i: (0, 0)),
         ],
@@ -519,10 +510,6 @@ def gramian_fused(
     as the explicit einsum-build opt-out and narrow (K < rank) buckets
     auto-kept on the einsum path.
     """
-    if not _HAVE_PALLAS:
-        raise NotImplementedError(
-            "gramian_fused requires pallas; use the einsum path"
-        )
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n, r = y.shape
@@ -555,7 +542,9 @@ def gramian_fused(
     # zero-weighted extra slots).
     kt = min(_round_up(k, 128), _FUSED_K_TILE)
     k_pad = _round_up(k, kt)
-    bt = min(_FUSED_B_TILE, max(1, _FUSED_SMEM_IDX // k_pad))
+    # rows per grid step: as many as the SMEM index block allows, in
+    # whole sublane tiles of 8 (k_pad <= _FUSED_K_SPLIT leaves room for 8)
+    bt = min(_FUSED_B_TILE, _FUSED_SMEM_IDX // k_pad // 8 * 8)
     b_pad = _round_up(b, bt)
     idx = jnp.asarray(idx, jnp.int32)
     w2 = jnp.asarray(w2, jnp.float32)
@@ -585,9 +574,16 @@ def gramian_fused(
     elif r_pad != r:
         yty = jnp.pad(jnp.asarray(yty, jnp.float32),
                       ((0, r_pad - r), (0, r_pad - r)))
+    # one (8, 128) ridge tile per grid step, the step's bt values in its
+    # first row: a rank-1 (bt,) SMEM block was refused on the chip (a
+    # (64,) block of a (1024,) array), and a rank-1 array of 128-wide
+    # blocks by Mosaic's layout check; a whole tile is what both take
+    ridge = jnp.pad(
+        jnp.asarray(ridge, jnp.float32).reshape(b_pad // bt, 1, bt),
+        ((0, 0), (0, 7), (0, _LANES - bt)),
+    ).reshape(-1, _LANES)
     a, bvec = _gramian_fused_call(
-        y, idx, w2, rhs, jnp.asarray(ridge, jnp.float32), yty,
-        bt, kt, interpret,
+        y, idx, w2, rhs, ridge, yty, bt, kt, interpret,
     )
     return a[:b, :r, :r], bvec[:b, :r]
 

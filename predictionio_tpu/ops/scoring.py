@@ -76,9 +76,20 @@ def pad_pow2(n: int, lo: int = 1) -> int:
     return 1 << (n - 1).bit_length()
 
 
+#: Serving scores are f32 scores. On a TPU the default matmul precision
+#: rounds f32 inputs to bf16 passes: measured on the v5e (PR 22) that put
+#: served scores 7e-3 away from the f32 dot product and swapped
+#: neighbours up to 5e-3 apart in 14 of 32 top-10 lists — a ranking that
+#: differs from what the same model answers on any other backend.
+#: HIGHEST restores the f32 product; the score matmul is a small part of
+#: a dispatch. The streaming kernel names the same precision.
+SCORE_PRECISION = jax.lax.Precision.HIGHEST
+
+
 def _score_topk(query_vectors, item_factors, k, exclude_mask):
     scores = jnp.einsum(
-        "br,ir->bi", query_vectors, item_factors, preferred_element_type=jnp.float32
+        "br,ir->bi", query_vectors, item_factors,
+        preferred_element_type=jnp.float32, precision=SCORE_PRECISION,
     )
     if exclude_mask is not None:
         scores = jnp.where(exclude_mask, NEG_INF, scores)
@@ -131,7 +142,10 @@ def top_k_similar_items(
     norms = jnp.linalg.norm(item_factors, axis=1, keepdims=True)
     unit = item_factors / jnp.maximum(norms, 1e-12)
     q = unit[item_idx]  # [B, R]
-    scores = jnp.einsum("br,ir->bi", q, unit, preferred_element_type=jnp.float32)
+    scores = jnp.einsum(
+        "br,ir->bi", q, unit,
+        preferred_element_type=jnp.float32, precision=SCORE_PRECISION,
+    )
     if exclude_self:
         n_items = item_factors.shape[0]
         one_hot = jax.nn.one_hot(item_idx, n_items, dtype=jnp.bool_)
@@ -163,13 +177,11 @@ def xla_topk_with_sentinels(
     k: int,
     exclude_idx: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """The XLA fallback leg of the fused top-k: dense score + ``lax.top_k``
+    """The dense XLA leg of the fused top-k: dense score + ``lax.top_k``
     normalized to the streaming kernel's sentinel contract (-inf / -1 on
     invalid slots, k padded past the catalog size). Index-list exclusions
     (``[B, E]`` int32, -1 padded) densify to a one-hot mask here — the
-    dense path pays the [B, I] bytes anyway. Also the ``not _HAVE_PALLAS``
-    body of ``pallas_kernels.top_k_streaming`` (one home for the
-    contract)."""
+    dense path pays the [B, I] bytes anyway."""
     n_items = item_factors.shape[0]
     k_eff = min(k, n_items)
     mask = None

@@ -19,14 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.6: shard_map is a top-level API (check_vma kwarg)
-    from jax import shard_map
-except ImportError:  # older jax: experimental location, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(f, /, *, check_vma=True, **kwargs):
-        return _shard_map_compat(f, check_rep=check_vma, **kwargs)
-
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

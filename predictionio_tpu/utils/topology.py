@@ -1,8 +1,8 @@
 """Deviceless (compile-only) TPU topology access, with lockfile retry.
 
 ``jax.experimental.topologies.get_topology_desc`` loads libtpu, which
-holds a machine-wide lockfile during plugin init — a concurrent device
-probe, prewarm run, or test session makes the first attempt fail
+holds a machine-wide lockfile during plugin init — a concurrent
+prewarm run or test session makes the first attempt fail
 transiently. Every in-repo user (``tools/prewarm_cache``, the Mosaic
 AOT test modules) goes through this helper so they all share the retry
 (full-jittered via the shared :class:`RetryPolicy`: the contenders are

@@ -111,9 +111,9 @@ class ServerConfig:
     # dispatch. Worst-case added latency = batch_wait_ms; under load the
     # batch fills instantly and the wait never triggers.
     batching: bool = True
-    # 512 keeps the padded top-k program set small (pad_pow2) while letting
-    # a high-latency dispatch path (e.g. a remote-relay device) amortize
-    # the round trip over a large batch; device time grows sub-linearly.
+    # 512 keeps the padded top-k program set small (pad_pow2) while
+    # amortizing the host↔device round trip over a large batch; device
+    # time grows sub-linearly.
     # Memory envelope: scoring materializes a [batch, n_items] f32 matrix
     # PER IN-FLIGHT BATCH, so peak device memory scales with
     # batch_pipeline_depth × batch_max — at 10M items and depth 2,
@@ -127,8 +127,8 @@ class ServerConfig:
     batch_wait_ms: float = 1.0
     # In-flight batch pipelining: while one batch's results travel back
     # from the device, the next is already dispatched. Depth 2 hides one
-    # full host↔device round trip (the binding resource on a tunneled or
-    # remote-relay device); raise it when round_trip >> device_time. Peak
+    # full host↔device round trip; raise it when round_trip >>
+    # device_time. Peak
     # device memory scales with depth × the batch_max envelope above.
     batch_pipeline_depth: int = 2
     #: Remote error log: serving failures POST {message, query} here
@@ -1462,6 +1462,9 @@ class QueryServer(BackgroundHTTPServer):
                 "reload": self.reload_breaker.snapshot(),
             },
         }
+        from ..utils.platform import device_info
+
+        out["device"] = device_info()
         if self.config.shard_count > 1:
             out["shard"] = {
                 "index": self.config.shard_index,

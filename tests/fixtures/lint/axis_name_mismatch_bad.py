@@ -1,7 +1,7 @@
 """Family F fixture: collective names an axis the mesh does not bind."""
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

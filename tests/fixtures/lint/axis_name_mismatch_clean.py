@@ -3,7 +3,7 @@ replicated axis the specs never mention (legal, and the false positive
 the rule must not produce)."""
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -3,7 +3,7 @@ argument — a trace-time TypeError that only fires when the sharded path
 actually runs (the mesh-gated trainer's hardware-day failure mode)."""
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -3,7 +3,7 @@ positionally or as axis_name= — and an axis-less call OUTSIDE any mapped
 body is not this rule's business (the first unit test catches it)."""
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -5,7 +5,7 @@ deep (the per-file rule's documented ``*args/**kwargs calls pass``
 skip, now judged through the call graph)."""
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

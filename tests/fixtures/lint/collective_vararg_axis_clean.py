@@ -3,7 +3,7 @@ collectives' axis slots AND every mapped call site provably feeds one —
 an extra positional, or ``axis_name=`` riding the ``**kwargs``."""
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -1,6 +1,6 @@
 """Family F fixture: in_specs drifted from the mapped function arity."""
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

@@ -1,6 +1,6 @@
 """Clean twin: one spec per mapped operand."""
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

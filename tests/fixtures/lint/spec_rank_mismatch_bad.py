@@ -2,7 +2,7 @@
 rank-preserving collective body."""
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

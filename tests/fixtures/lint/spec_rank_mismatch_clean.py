@@ -1,7 +1,7 @@
 """Clean twin: specs agree on rank."""
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 

@@ -1,0 +1,209 @@
+"""The main path's kernels compiled for the chip that is described, not
+attached — at the widths ``chip_smoke.py`` runs (rank 50 padded to 56,
+138,493 users x 26,744 items, serving batch 32).
+
+Tier-1 (not slow): about two seconds per case, and the only tests in the
+default run that hand a kernel to the TPU compiler. ``interpret=False`` is
+passed explicitly — code that asks ``jax.default_backend()`` sees the CPU
+in a test. Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture and skipped from
+there, never at import: under pytest-xdist every worker imports this file,
+and only the worker that runs it may load the TPU library. The compiles
+happen in this process, with the persistent compilation cache off (an
+executable compiled for a described chip is written but cannot be read
+back without one, and would warn on the next run).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from predictionio_tpu.ops.pallas_kernels import (
+    gramian_fused,
+    spd_solve_t,
+    top_k_streaming,
+)
+
+N_USERS, N_ITEMS, RANK, RANK_PAD = 138_493, 26_744, 50, 56
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topology = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topology
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *avals, **static):
+    lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(
+        *avals, **static
+    )
+    compiled = lowered.compile()
+    assert compiled.memory_analysis().generated_code_size_in_bytes > 0
+    return compiled
+
+
+def test_spd_solve_rank50(one_chip):
+    compiled = _compile(
+        functools.partial(spd_solve_t, interpret=False),
+        _sds(one_chip, (RANK_PAD, RANK_PAD, 1024), jnp.float32),
+        _sds(one_chip, (RANK_PAD, 1024), jnp.float32),
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("b, k, table_dtype", [
+    # the smoke's own bucket blocks [rows, width]; each of the first three
+    # was refused for the chip before PR 22 (rank-1 SMEM ridge block;
+    # scoped VMEM at 128-row output blocks; a 4-row index block)
+    (1024, 512, jnp.float32),
+    (4096, 128, jnp.float32),
+    (32, 8192, jnp.float32),
+    (8, 32768, jnp.float32),
+    (4, 8192, jnp.bfloat16),
+])
+def test_gramian_fused_item_table(one_chip, b, k, table_dtype):
+    compiled = _compile(
+        functools.partial(gramian_fused, interpret=False),
+        _sds(one_chip, (N_ITEMS, RANK_PAD), table_dtype),
+        _sds(one_chip, (b, k), jnp.int32),
+        _sds(one_chip, (b, k), jnp.float32),
+        _sds(one_chip, (b, k), jnp.float32),
+        _sds(one_chip, (b,), jnp.float32),
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_als_half_step_with_its_kernels(one_chip, monkeypatch):
+    """One whole ALS half-step as ``pio train`` runs it on the chip:
+    pallas solver + fused Gramian over every bucket of a power-law
+    problem. The program picks interpret mode from
+    ``jax.default_backend()``, which is the CPU in a test — steered here,
+    or the compile would pass on interpreted kernels and say nothing
+    (how the fused kernel's faults stayed hidden until the chip)."""
+    from predictionio_tpu.ops import als
+    from predictionio_tpu.tools.prewarm_cache import _stage_avals
+
+    rng = np.random.default_rng(0)
+    n_u, n_i, nnz = 6_000, 1_500, 150_000
+    w = 1.0 / np.arange(1, n_u + 1) ** 0.8
+    users = rng.choice(n_u, size=nnz, p=w / w.sum())
+    items = rng.integers(0, n_i, nnz)
+    vals = rng.integers(1, 6, nnz).astype(np.float32)
+    by_item = als.bucketize(items, users, vals, n_i, n_u, pad_to_blocks=True)
+    assert max(b.width for b in by_item.buckets) >= 512  # wide buckets too
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _compile(
+        als._als_half,
+        _sds(one_chip, (n_u, RANK), jnp.float32),
+        _stage_avals(by_item, one_chip),
+        _sds(one_chip, (), jnp.float32),
+        _sds(one_chip, (), jnp.float32),
+        n_rows=n_i, rank=RANK, implicit=False, solve_mode="pallas",
+        mesh=None, gather_dtype="f32", fused_gather=True,
+    )
+    # a solver call and a fused-build call per bucket wide enough for it
+    assert compiled.as_text().count("tpu_custom_call") >= 4
+
+
+@pytest.mark.parametrize("n_excl", [0, 64])
+def test_top_k_streaming_catalog(one_chip, n_excl):
+    def fn(q, items, *excl):
+        return top_k_streaming(
+            q, items, 16, exclude_idx=excl[0] if excl else None,
+            interpret=False,
+        )
+
+    avals = [
+        _sds(one_chip, (64, RANK), jnp.float32),
+        _sds(one_chip, (N_ITEMS, RANK), jnp.float32),
+    ]
+    if n_excl:
+        avals.append(_sds(one_chip, (64, n_excl), jnp.int32))
+    compiled = _compile(fn, *avals)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_dense_serving_program(one_chip):
+    """What ``/queries.json`` dispatches for this catalog: the fused
+    score + top-k entry on its dense path (26,744 items at B=32 is under
+    the streaming bar), user-row gather included."""
+    from predictionio_tpu.ops.scoring import top_k_for_users_fused
+
+    compiled = _compile(
+        top_k_for_users_fused,
+        _sds(one_chip, (N_USERS, RANK), jnp.float32),
+        _sds(one_chip, (N_ITEMS, RANK), jnp.float32),
+        _sds(one_chip, (32,), jnp.int32),
+        k=16, mode="never",
+    )
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_sharded_half_step_2x2(topo):
+    """One ``pio train --shards 4`` half-step (solve the user side from
+    the item table) as one program across the 2x2 mesh: slabs and tables
+    sharded over the shard axis, the all-gather inside."""
+    from predictionio_tpu.ops import als_sharded
+    from predictionio_tpu.parallel.mesh import MeshConfig, create_mesh
+
+    shards, n_u, n_i, nnz = 4, 1_000, 300, 8_000
+    rng = np.random.default_rng(0)
+    users = rng.integers(0, n_u, nnz).astype(np.int32)
+    items = rng.integers(0, n_i, nnz).astype(np.int32)
+    vals = rng.integers(1, 6, nnz).astype(np.float32)
+    user_plan = als_sharded.plan_side(
+        np.bincount(users, minlength=n_u), shards, rank=RANK)
+    item_plan = als_sharded.plan_side(
+        np.bincount(items, minlength=n_i), shards, rank=RANK)
+    slabs, _ = als_sharded._build_side(
+        users, items, vals, user_plan, item_plan,
+        als_sharded.DEFAULT_BUCKET_WIDTHS, True,
+    )
+    mesh = create_mesh(
+        MeshConfig(((als_sharded.SHARD_AXIS, shards),)), topo.devices[:shards]
+    )
+    sharded = NamedSharding(mesh, P(als_sharded.SHARD_AXIS))
+    replicated = NamedSharding(mesh, P())
+    compiled = _compile(
+        als_sharded._half_sharded,
+        _sds(sharded, (shards * item_plan.cap, RANK), jnp.float32),
+        tuple(
+            tuple(_sds(sharded, a.shape, a.dtype) for a in slab)
+            for slab in slabs
+        ),
+        _sds(replicated, (), jnp.float32),
+        _sds(replicated, (), jnp.float32),
+        mesh=mesh, rank=RANK, implicit=False, gather_dtype="f32",
+        cap_x=user_plan.cap,
+    )
+    assert "all-gather" in compiled.as_text()
+    assert len(compiled.input_shardings[0][0].device_set) == shards

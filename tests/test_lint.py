@@ -1326,7 +1326,7 @@ class TestFlowCrossFile:
             "bodies.py":
                 "import jax\n\n\ndef gram(x):\n    return jax.lax.psum(x)\n",
             "train.py": (
-                "from jax.experimental.shard_map import shard_map\n\n"
+                "from jax import shard_map\n\n"
                 "from pkg import bodies\n\n\n"
                 "def fit(mesh, x):\n"
                 "    f = shard_map(bodies.gram, mesh=mesh,\n"

@@ -81,29 +81,27 @@ def test_user_gather_wrapper_agrees_with_xla_path():
     )
 
 
-def test_fallback_path_contract(monkeypatch):
-    """The no-pallas fallback must honor exclusions and k > catalog."""
-    import predictionio_tpu.ops.pallas_kernels as pk
+def test_dense_path_contract():
+    """The dense XLA path (what ``resolve_topk_path`` chooses below the
+    streaming bar) must honor exclusions and k > catalog."""
+    from predictionio_tpu.ops.scoring import xla_topk_with_sentinels
 
-    monkeypatch.setattr(pk, "_HAVE_PALLAS", False)
     rng = np.random.default_rng(4)
     q = rng.normal(size=(3, 8)).astype(np.float32)
     items = rng.normal(size=(20, 8)).astype(np.float32)
-    s0, i0 = pk.top_k_streaming(q, items, 2)
+    s0, i0 = xla_topk_with_sentinels(q, items, 2)
     excl = np.concatenate(
         [np.asarray(i0), np.full((3, 2), -1, np.int32)], axis=1
     ).astype(np.int32)
-    s, i = pk.top_k_streaming(q, items, 5, exclude_idx=jnp.asarray(excl))
+    s, i = xla_topk_with_sentinels(q, items, 5, exclude_idx=jnp.asarray(excl))
     for row in range(3):
         assert not set(np.asarray(i)[row]).intersection(set(np.asarray(i0)[row]))
-    s2, i2 = pk.top_k_streaming(q, items, 25)
+    s2, i2 = xla_topk_with_sentinels(q, items, 25)
     assert s2.shape == (3, 25)
     assert np.isneginf(np.asarray(s2)[:, 20:]).all()
 
 
-def test_fallback_sentinel_matches_kernel_when_exclusions_exhaust_catalog(
-    monkeypatch,
-):
+def test_dense_sentinel_matches_kernel_when_exclusions_exhaust_catalog():
     """Both paths must return -1 (never a real excluded id) in -inf slots —
     the divergence flagged in round-1 ADVICE: a caller gathering by index
     would map a real-but-excluded id to a live item."""
@@ -115,9 +113,11 @@ def test_fallback_sentinel_matches_kernel_when_exclusions_exhaust_catalog(
     # exclude ALL 5 items: fewer than k=3 valid candidates remain
     excl = np.tile(np.arange(5, dtype=np.int32), (2, 1))
 
+    from predictionio_tpu.ops.scoring import xla_topk_with_sentinels
+
     s_k, i_k = pk.top_k_streaming(q, items, 3, exclude_idx=jnp.asarray(excl))
-    monkeypatch.setattr(pk, "_HAVE_PALLAS", False)
-    s_f, i_f = pk.top_k_streaming(q, items, 3, exclude_idx=jnp.asarray(excl))
+    s_f, i_f = xla_topk_with_sentinels(
+        q, items, 3, exclude_idx=jnp.asarray(excl))
 
     for s, i in ((s_k, i_k), (s_f, i_f)):
         assert np.isneginf(np.asarray(s)).all()

@@ -37,6 +37,7 @@ from predictionio_tpu.obs import expo
 from predictionio_tpu.obs import perfledger
 from predictionio_tpu.obs.metrics import MetricsRegistry
 from predictionio_tpu.obs.profile import (
+    DEVICE_PEAKS,
     JitTelemetry,
     PhaseProfiler,
     render_profile_report,
@@ -45,6 +46,8 @@ from predictionio_tpu.obs.profile import (
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FIXTURES = os.path.join(REPO, "tests", "fixtures", "lint")
+#: four flat bench rounds + one failed round, in the driver's file shape
+HISTORY = os.path.join(REPO, "tests", "fixtures", "perf_history")
 
 
 class FakeJit:
@@ -217,7 +220,8 @@ class TestPhaseProfiler:
 
         fenced = []
         prof = PhaseProfiler(
-            enabled=True, clock=clock, fence=fenced.append
+            enabled=True, clock=clock, fence=fenced.append,
+            peaks=DEVICE_PEAKS["TPU v5 lite"],
         )
         with prof.phase("solve", flops=197e12, hbm_bytes=819e9) as ph:
             ph.fence("device-value")  # t0=1, fence read=2 → device 1s
@@ -232,6 +236,7 @@ class TestPhaseProfiler:
         assert st["mfu"] == pytest.approx(2.0)
         assert st["hbm_util"] == pytest.approx(1.0)
         assert st["tflops_per_s"] == pytest.approx(197.0)
+        assert st["hbm_gb_per_s"] == pytest.approx(819.0)
 
     def test_unfenced_phase_device_equals_wall(self):
         ticks = {"n": 0}
@@ -247,9 +252,29 @@ class TestPhaseProfiler:
         assert st["wall_s"] == st["device_s"] == pytest.approx(1.0)
 
     def test_roofline_zero_time(self):
-        assert roofline(1e12, 1e9, 0.0) == {
-            "tflops_per_s": 0.0, "mfu": 0.0, "hbm_util": 0.0,
+        assert roofline(
+            1e12, 1e9, 0.0, peaks=DEVICE_PEAKS["TPU v5 lite"]
+        ) == {
+            "tflops_per_s": 0.0, "hbm_gb_per_s": 0.0,
+            "mfu": 0.0, "hbm_util": 0.0,
         }
+
+    def test_roofline_unknown_device_kind_has_no_utilization(self):
+        # tests run on the CPU backend, whose device_kind has no peaks:
+        # achieved rates only — never a share of another chip's peak
+        import jax
+
+        assert jax.devices()[0].device_kind not in DEVICE_PEAKS
+        assert roofline(2e12, 4e9, 2.0) == {
+            "tflops_per_s": pytest.approx(1.0),
+            "hbm_gb_per_s": pytest.approx(2.0),
+        }
+        prof = PhaseProfiler(enabled=True, fence=lambda v: v)
+        with prof.phase("solve", flops=1.0, hbm_bytes=1.0):
+            pass
+        assert "mfu" not in prof.summary()["solve"]
+        text = render_profile_report("unit", phases=prof.summary())
+        assert "v5e" not in text and " -" in text
 
     def test_report_renders_all_sections(self):
         text = render_profile_report(
@@ -263,7 +288,7 @@ class TestPhaseProfiler:
                    "backend_compile_s": 4.0},
             device="TFRT_CPU_0",
         )
-        for token in ("train", "als_half", "retraces", "mfu(v5e)",
+        for token in ("train", "als_half", "retraces", "mfu",
                       "hits=1", "TFRT_CPU_0"):
             assert token in text, text
 
@@ -366,14 +391,14 @@ class TestPerfLedger:
         assert perfledger.load_ledger(str(tmp_path / "none.jsonl")) == []
 
     def test_checked_in_history_loads_and_is_flat(self):
-        history = perfledger.load_bench_history(REPO)
+        history = perfledger.load_bench_history(HISTORY)
         # r01 failed bring-up (parsed null) and contributes nothing
         assert len(history) >= 4
         assert all(r["schema"] == 1 for r in history)
         assert perfledger.detect_regressions(history) == []
 
     def test_injected_regression_is_flagged(self):
-        history = perfledger.load_bench_history(REPO)
+        history = perfledger.load_bench_history(HISTORY)
         prior = [r["value"] for r in history]
         baseline = sorted(prior)[len(prior) // 2]
         worse = _bench_like(round(baseline * 1.25, 3), source="injected")
@@ -469,7 +494,7 @@ class TestNoPriorReporting:
     explicitly, never let an ungated group read as "stable"."""
 
     def test_flipped_levers_reported_as_no_prior(self):
-        history = perfledger.load_bench_history(REPO)
+        history = perfledger.load_bench_history(HISTORY)
         flipped = _bench_like(5.0, source="flip", sort_gather=True)
         verdicts = perfledger.find_no_prior(history + [flipped])
         assert len(verdicts) == 1
@@ -482,7 +507,7 @@ class TestNoPriorReporting:
         assert perfledger.detect_regressions(history + [flipped]) == []
 
     def test_established_history_has_no_no_prior(self):
-        history = perfledger.load_bench_history(REPO)
+        history = perfledger.load_bench_history(HISTORY)
         assert perfledger.find_no_prior(history) == []
 
     def test_failed_runs_do_not_count_as_measurements(self):
@@ -528,6 +553,8 @@ class TestPerfCLI:
     def _main(self, argv):
         from predictionio_tpu.tools.console import main
 
+        if argv[0] == "perf" and "--history-dir" not in argv:
+            argv = argv + ["--history-dir", HISTORY]
         return main(argv)
 
     def test_perf_diff_clean_on_checked_in_history(self, capsys):
@@ -535,7 +562,7 @@ class TestPerfCLI:
         assert "no regressions" in capsys.readouterr().out
 
     def test_perf_diff_flags_injected_regression(self, tmp_path, capsys):
-        history = perfledger.load_bench_history(REPO)
+        history = perfledger.load_bench_history(HISTORY)
         baseline = sorted(r["value"] for r in history)[len(history) // 2]
         ledger = str(tmp_path / "ledger.jsonl")
         perfledger.append_record(
@@ -612,7 +639,7 @@ class TestPerfCLI:
             "phase", "wall_s", "device_s",  # per-phase wall/device time
             "bucketize", "train",
             "compiles", "retraces", "als_half",  # compile/retrace counts
-            "mfu(v5e)", "hbm_util",  # the roofline estimate
+            "mfu", "hbm_util",  # the roofline estimate
         ):
             assert token in out, out
         # the telemetry saw the two half-solves: one warmup compile,

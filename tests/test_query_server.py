@@ -161,6 +161,20 @@ def test_status_json_reports_resolved_topk_path(server):
         del srv.deployment.algorithms[0].topk_path
 
 
+def test_status_json_reports_the_device(server):
+    """/status.json names the device JAX gave the server process — a
+    deploy that came up on the wrong backend cannot look healthy."""
+    import jax
+
+    base, _, _, _ = server
+    doc = requests.get(f"{base}/status.json").json()
+    assert doc["device"] == {
+        "platform": "cpu",
+        "kind": jax.devices()[0].device_kind,
+        "count": len(jax.devices()),
+    }
+
+
 def test_reload_hot_swaps_to_latest(server):
     base, srv, registry, engine = server
     old_id = srv.deployment.instance.id

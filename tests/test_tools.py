@@ -283,6 +283,39 @@ def test_train_via_spawned_subprocess(registry, tmp_path):
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     inst = registry.get_metadata().engine_instance_get(out["engineInstanceId"])
     assert inst is not None and inst.status == "COMPLETED"
+    # the line names the device the child trained on, the levers as they
+    # resolved there, and the compile cache it used
+    assert out["device"]["platform"] == "cpu" and out["device"]["count"] >= 1
+    assert out["levers"]["als"] == {
+        "solve_mode": "chunked", "gather_dtype": "f32", "sort_gather": True,
+        "fused_gather": False, "shards": 1,
+    }
+    assert set(out["compileCache"]) == {"dir", "hits", "misses"}
+
+
+def test_result_line_resolves_levers_per_shard_count(registry, tmp_path):
+    """``run_workflow.result_line`` reads the instance's stored params:
+    one shard resolves like ``ALSConfig.resolve_levers()``, more than one
+    like the sharded trainer; an unknown instance has no levers."""
+    from predictionio_tpu.tools import run_workflow
+
+    _ingest_rates(registry, app_id=1)
+    target = tmp_path / "proj"
+    get_template("recommendation", str(target))
+    args = run_workflow.build_parser().parse_args(
+        ["--engine-dir", str(target)])
+    instance_id = run_workflow.run(args, registry)
+    line = run_workflow.result_line(instance_id, registry)
+    assert line["engineInstanceId"] == instance_id
+    assert line["device"]["platform"] == "cpu"
+    assert line["levers"]["als"]["shards"] == 1
+    assert line["levers"]["als"]["solve_mode"] == "chunked"
+    sharded = run_workflow.resolved_levers(registry, instance_id, shards=4)
+    assert sharded["als"] == {
+        "solve_mode": "chunked", "gather_dtype": "f32", "sort_gather": True,
+        "fused_gather": False, "shards": 4,
+    }
+    assert run_workflow.resolved_levers(registry, "no-such-instance") == {}
 
 
 def test_custom_engine_model_pickles_across_train_and_deploy(registry, tmp_path):
@@ -577,11 +610,11 @@ class TestRevalReport:
         assert "6200.1" in text and "21000.0" in text
         assert "unknown_extra" in text  # surfaced, not dropped
 
-    def test_fallback_marked_invalid(self, tmp_path):
+    def test_cpu_record_marked_invalid(self, tmp_path):
         from predictionio_tpu.tools.reval_report import load, report
 
         path = self._write(tmp_path, [
             {"step": "baseline_f32", "value": 12.0,
-             "fallback": "cpu-fallback", "rc": 0},
+             "platform": "cpu", "rc": 0},
         ], junk=False)
-        assert "FALLBACK — INVALID" in report(load(path))
+        assert "RAN ON cpu — INVALID" in report(load(path))
