@@ -33,6 +33,7 @@ from ..controller import (
     Params,
     Preparator,
 )
+from ..obs.trace import span
 from ..ops.als import ALSConfig, als_train_coo
 from ..ops.scoring import (
     pad_pow2,
@@ -302,6 +303,21 @@ class ALSModel:
             raise ValueError("ALS produced non-finite item factors")
 
 
+def _fetch_factors(factors) -> Tuple[np.ndarray, np.ndarray]:
+    """Both tables on the host. The wait is a span of its own: the
+    ``np.asarray`` would block there anyway, so nothing is fenced that
+    was not, and ``train.fetch`` times the copy alone."""
+    import jax
+
+    with span("train.wait_device"):
+        jax.block_until_ready((factors.user_factors, factors.item_factors))
+    with span("train.fetch"):
+        return (
+            np.asarray(factors.user_factors),
+            np.asarray(factors.item_factors),
+        )
+
+
 class ALSAlgorithm(Algorithm):
     """TPU ALS (reference ``ALSAlgorithm.scala:27-86``)."""
 
@@ -365,10 +381,23 @@ class ALSAlgorithm(Algorithm):
         self._quant_status = status
 
     def train(self, ctx, pd: PreparedData) -> ALSModel:
+        from ..ops.als_sharded import resolve_shards
+
         p = self.params
         # a config typo must fail the training run, not the first serving
         # query after deploy (use_streaming_topk raises on unknown modes)
         use_streaming_topk(p.streaming_top_k, 1, 1)
+        shards = resolve_shards(p.shards)
+        # the job's root span: under no server it starts a trace of its
+        # own, so one training job is one trace (docs/observability.md)
+        tags = {
+            "rank": p.rank, "iterations": p.num_iterations, "shards": shards,
+        }
+        with span("train", tags):
+            return self._train(ctx, pd, shards)
+
+    def _train(self, ctx, pd: PreparedData, shards: int) -> ALSModel:
+        p = self.params
         cfg = ALSConfig(
             rank=p.rank,
             iterations=p.num_iterations,
@@ -382,9 +411,8 @@ class ALSAlgorithm(Algorithm):
             fused_gather=p.fused_gather,
         )
         from ..ckpt import resolve_every, resolve_resume
-        from ..ops.als_sharded import als_train_sharded, resolve_shards
+        from ..ops.als_sharded import als_train_sharded
 
-        shards = resolve_shards(p.shards)
         # checkpoint cadence: params > workflow run (--checkpoint-every /
         # the continuous retrain config) > PIO_CKPT_EVERY > off; an
         # invalid value refuses here, at train time
@@ -427,10 +455,11 @@ class ALSAlgorithm(Algorithm):
                 checkpoint=store,
                 checkpoint_every=every if store is not None else 0,
             )
+            user_factors, item_factors = _fetch_factors(factors)
             model = ALSModel(
                 rank=p.rank,
-                user_factors=np.asarray(factors.user_factors),
-                item_factors=np.asarray(factors.item_factors),
+                user_factors=user_factors,
+                item_factors=item_factors,
                 user_map=pd.user_map,
                 item_map=pd.item_map,
             )
@@ -466,10 +495,11 @@ class ALSAlgorithm(Algorithm):
             checkpoint=checkpoint,
             checkpoint_every=every,
         )
+        user_factors, item_factors = _fetch_factors(factors)
         model = ALSModel(
             rank=p.rank,
-            user_factors=np.asarray(factors.user_factors),
-            item_factors=np.asarray(factors.item_factors),
+            user_factors=user_factors,
+            item_factors=item_factors,
             user_map=pd.user_map,
             item_map=pd.item_map,
         )
@@ -829,55 +859,61 @@ class ALSAlgorithm(Algorithm):
             # has not been gated yet — a query must never be served
             # from ungated codes
             self._attach_quant(model)
-            if self._quant is not None:
-                # quantized serving: scores from int8 codes + per-row
-                # scales (quant.top_k_quantized) — licensed by the
-                # exactness gate _attach_quant just ran/cached
-                from ..quant import top_k_quantized
+            # one span per batch each, on the profiler's clock too: the
+            # dispatch (which uploads the host tables), the fetch, and
+            # the Python result objects
+            with span("predict.dispatch", {"b": b_pad}):
+                if self._quant is not None:
+                    # quantized serving: scores from int8 codes + per-row
+                    # scales (quant.top_k_quantized) — licensed by the
+                    # exactness gate _attach_quant just ran/cached
+                    from ..quant import top_k_quantized
 
-                self._topk_path = "quant"
-                scores, items = top_k_quantized(
-                    model.user_factors, self._quant, padded_idx, k=k_pad
-                )
-            else:
-                # the fused score+select entry dispatches: Pallas
-                # streaming on TPU past the use_streaming_topk bar (the
-                # [B, I] score matrix never exists), XLA score +
-                # lax.top_k below it — record which path serves
-                # (resolve_topk_path is the ONE decision home the entry
-                # itself dispatches on, same (mode, b, n) inputs),
-                # surfaced at /status.json
-                self._topk_path = resolve_topk_path(
-                    self.params.streaming_top_k, b_pad, n_items
-                )
-                scores, items = top_k_for_users_fused(
-                    model.user_factors, model.item_factors, padded_idx,
-                    k=k_pad, mode=self.params.streaming_top_k,
-                )
+                    self._topk_path = "quant"
+                    scores, items = top_k_quantized(
+                        model.user_factors, self._quant, padded_idx, k=k_pad
+                    )
+                else:
+                    # the fused score+select entry dispatches: Pallas
+                    # streaming on TPU past the use_streaming_topk bar
+                    # (the [B, I] score matrix never exists), XLA score +
+                    # lax.top_k below it — record which path serves
+                    # (resolve_topk_path is the ONE decision home the
+                    # entry itself dispatches on, same (mode, b, n)
+                    # inputs), surfaced at /status.json
+                    self._topk_path = resolve_topk_path(
+                        self.params.streaming_top_k, b_pad, n_items
+                    )
+                    scores, items = top_k_for_users_fused(
+                        model.user_factors, model.item_factors, padded_idx,
+                        k=k_pad, mode=self.params.streaming_top_k,
+                    )
             # one fetch for both arrays: each device_get is a full host↔
             # device round trip
             import jax
 
-            scores, items = jax.device_get((scores, items))
-            # bulk ndarray→python conversion: one C call instead of
-            # 2×B×k scalar __float__/__int__ calls on the hot path
-            scores = scores[:b, :max_k].tolist()
-            items = items[:b, :max_k].tolist()
-            inv = model.item_map.inverse
-            for row, (i, q) in enumerate(known):
-                k = min(q.num, max_k)
-                s_row, i_row = scores[row], items[row]
-                out.append(
-                    (
-                        i,
-                        PredictedResult(
-                            item_scores=tuple(
-                                ItemScore(item=inv[i_row[j]], score=s_row[j])
-                                for j in range(k)
-                            )
-                        ),
+            with span("predict.fetch", {"b": b_pad}):
+                scores, items = jax.device_get((scores, items))
+            with span("predict.results", {"b": b_pad}):
+                # bulk ndarray→python conversion: one C call instead of
+                # 2×B×k scalar __float__/__int__ calls on the hot path
+                scores = scores[:b, :max_k].tolist()
+                items = items[:b, :max_k].tolist()
+                inv = model.item_map.inverse
+                for row, (i, q) in enumerate(known):
+                    k = min(q.num, max_k)
+                    s_row, i_row = scores[row], items[row]
+                    out.append(
+                        (
+                            i,
+                            PredictedResult(
+                                item_scores=tuple(
+                                    ItemScore(item=inv[i_row[j]], score=s_row[j])
+                                    for j in range(k)
+                                )
+                            ),
+                        )
                     )
-                )
         return out
 
     def query_class(self):

@@ -32,6 +32,19 @@ explicitly (``Tracer.span(..., parent=ctx)``).
 
 Clocks are injectable (``Tracer(clock=..., wall=...)``): every trace
 test runs with zero wall-clock sleeps.
+
+One span, two sinks. A span opened with :meth:`Tracer.span` or
+:meth:`Tracer.server_span` is also entered as a
+``jax.profiler.TraceAnnotation`` named ``pio.<name>`` (its tags as a
+`` k=v`` suffix), so that while a profiler session runs the same span
+lies in the profiler's trace, on the device trace's clock, beside the
+operations the chip ran under it; outside a session the annotation is a
+flag test. A process that never imported JAX has no profiler to write
+to and records to the store alone. Code that runs under no server (a
+training job) opens its spans with the module-level :func:`span`, which
+records into the ambient request's tracer or, with none, into
+:func:`default_tracer`: a root span there starts a new trace id, so one
+training job is one trace, as one request is.
 """
 
 from __future__ import annotations
@@ -40,6 +53,7 @@ import contextlib
 import contextvars
 import dataclasses
 import secrets
+import sys
 import threading
 import time
 from collections import deque
@@ -51,7 +65,9 @@ __all__ = [
     "SpanStore",
     "Tracer",
     "current_context",
+    "default_tracer",
     "new_trace_id",
+    "span",
 ]
 
 #: Wire header carrying the trace id. Value contract: an opaque token of
@@ -96,6 +112,21 @@ _ambient_span: contextvars.ContextVar = contextvars.ContextVar(
 def current_context() -> Optional[SpanContext]:
     """The span context of the request this thread is serving, if any."""
     return _ambient_span.get()
+
+
+def _annotation(name: str, tags: Optional[Dict[str, object]]):
+    """The profiler's half of a span: a ``TraceAnnotation`` named
+    ``pio.<name>`` with the tags as a suffix (``pio.als.stage
+    side=user``), or nothing to enter in a process that has not imported
+    JAX (no import is made here: ``obs/`` works without it, and a
+    process without JAX has no profiler session to write into)."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return contextlib.nullcontext()
+    label = "pio." + name
+    if tags:
+        label += "".join(f" {k}={v}" for k, v in tags.items())
+    return profiler.TraceAnnotation(label)
 
 
 class SpanStore:
@@ -178,7 +209,10 @@ class Tracer:
         t0 = self.clock()
         error: Optional[str] = None
         try:
-            yield ctx
+            # entered and left on this thread, as an annotation has to
+            # be: hand-timed ``record`` spans get none
+            with _annotation(name, tags):
+                yield ctx
         except BaseException as exc:
             error = type(exc).__name__
             raise
@@ -230,3 +264,25 @@ class Tracer:
         hand (cross-thread timing); pair with :meth:`record`."""
         trace_id = parent.trace_id if parent else new_trace_id()
         return SpanContext(trace_id, secrets.token_hex(4), self)
+
+
+_SINGLETON_LOCK = threading.Lock()
+_default: Optional[Tracer] = None
+
+
+def default_tracer() -> Tracer:
+    """The process's tracer for code that runs under no server (a
+    training job), beside ``obs.profile.default_telemetry``."""
+    global _default
+    with _SINGLETON_LOCK:
+        if _default is None:
+            _default = Tracer("process")
+        return _default
+
+
+def span(name: str, tags: Optional[Dict[str, object]] = None):
+    """A span under the ambient context, in that context's tracer; with
+    no ambient context, a root span in :func:`default_tracer`."""
+    ctx = current_context()
+    tracer = ctx.tracer if ctx is not None else default_tracer()
+    return tracer.span(name, tags=tags)

@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import span
+
 #: Default degree-bucket widths (powers of 4; rows pad to the nearest).
 DEFAULT_BUCKET_WIDTHS = (8, 32, 128, 512, 2048, 8192, 32768)
 
@@ -464,8 +466,10 @@ def _system_explicit(y, idx, val, mask, lam, rank):
     [B, K] with mask matching y's dtype.
     A_u = Gᵀ G + λ n_u I,  b_u = Gᵀ r_u   (G = masked gathered factors)
     """
-    g = y[idx] * mask[..., None]  # [B, K, R]
-    return _system_explicit_g(g, val, mask, lam, rank)
+    with jax.named_scope("als.gather"):
+        g = y[idx] * mask[..., None]  # [B, K, R]
+    with jax.named_scope("als.gramian"):
+        return _system_explicit_g(g, val, mask, lam, rank)
 
 
 def _system_implicit_g(g, yty, val, mask, lam, alpha, rank):
@@ -496,13 +500,16 @@ def _system_implicit(y, yty, idx, val, mask, lam, alpha, rank):
     ``ALS.scala`` implicit convention: confidence from magnitude, preference
     from sign — a negative rating is high-confidence "not preferred").
     """
-    g = y[idx] * mask[..., None]  # [B, K, R]
-    return _system_implicit_g(g, yty, val, mask, lam, alpha, rank)
+    with jax.named_scope("als.gather"):
+        g = y[idx] * mask[..., None]  # [B, K, R]
+    with jax.named_scope("als.gramian"):
+        return _system_implicit_g(g, yty, val, mask, lam, alpha, rank)
 
 
 def _cho_solve(a, b):
-    chol = jax.scipy.linalg.cho_factor(a, lower=True)
-    return jax.scipy.linalg.cho_solve(chol, b)
+    with jax.named_scope("als.solve"):
+        chol = jax.scipy.linalg.cho_factor(a, lower=True)
+        return jax.scipy.linalg.cho_solve(chol, b)
 
 
 @dataclasses.dataclass
@@ -695,34 +702,37 @@ def _fused_chunk_solve(
     """
     from .pallas_kernels import _SPD_BLK, gramian_fused, spd_solve_t
 
-    k = idx_blk.shape[-1]
-    maskf = (
-        jnp.arange(k, dtype=jnp.int32)[None, :] < counts_blk[:, None]
-    ).astype(jnp.float32)
-    if implicit:
-        c1 = (alpha * jnp.abs(val_blk)) * maskf
-        w2 = c1
-        rhs = (1.0 + c1) * ((val_blk > 0).astype(jnp.float32) * maskf)
-        yty_arg = yty_pad
-    else:
-        w2 = maskf
-        rhs = val_blk * maskf
-        yty_arg = None
-    ridge = lam * counts_blk.astype(jnp.float32)
-    a, bvec = gramian_fused(y_pad, idx_blk, w2, rhs, ridge, yty_arg)
-    # [B, R, R] → the solver's lane-batched [R, R, B] layout. This
-    # transpose is the one extra HBM round trip the fused path pays
-    # (B·R²·4 B — small next to the 2·B·K·R·4 B it removes for K ≳ R;
-    # the caller auto-gates on bucket width accordingly).
-    a_t = jnp.transpose(a, (1, 2, 0))
-    b_t = bvec.T
-    bsz = idx_blk.shape[0]
-    pad_b = -bsz % _SPD_BLK
-    if pad_b:
-        a_t = jnp.pad(a_t, ((0, 0), (0, 0), (0, pad_b)))
-        b_t = jnp.pad(b_t, ((0, 0), (0, pad_b)))
-    x_t = spd_solve_t(a_t, b_t)
-    return x_t[:rank, :bsz].T  # [B, rank]
+    # the kernel gathers inside itself: this path has no ``als.gather``
+    with jax.named_scope("als.gramian"):
+        k = idx_blk.shape[-1]
+        maskf = (
+            jnp.arange(k, dtype=jnp.int32)[None, :] < counts_blk[:, None]
+        ).astype(jnp.float32)
+        if implicit:
+            c1 = (alpha * jnp.abs(val_blk)) * maskf
+            w2 = c1
+            rhs = (1.0 + c1) * ((val_blk > 0).astype(jnp.float32) * maskf)
+            yty_arg = yty_pad
+        else:
+            w2 = maskf
+            rhs = val_blk * maskf
+            yty_arg = None
+        ridge = lam * counts_blk.astype(jnp.float32)
+        a, bvec = gramian_fused(y_pad, idx_blk, w2, rhs, ridge, yty_arg)
+    with jax.named_scope("als.solve"):
+        # [B, R, R] → the solver's lane-batched [R, R, B] layout. This
+        # transpose is the one extra HBM round trip the fused path pays
+        # (B·R²·4 B — small next to the 2·B·K·R·4 B it removes for K ≳ R;
+        # the caller auto-gates on bucket width accordingly).
+        a_t = jnp.transpose(a, (1, 2, 0))
+        b_t = bvec.T
+        bsz = idx_blk.shape[0]
+        pad_b = -bsz % _SPD_BLK
+        if pad_b:
+            a_t = jnp.pad(a_t, ((0, 0), (0, 0), (0, pad_b)))
+            b_t = jnp.pad(b_t, ((0, 0), (0, pad_b)))
+        x_t = spd_solve_t(a_t, b_t)
+        return x_t[:rank, :bsz].T  # [B, rank]
 
 
 def _solve_side_traced(
@@ -791,57 +801,62 @@ def _solve_side_traced(
             from .pallas_kernels import _SPD_BLK, spd_solve_t
 
             idx_blk, val_blk, counts_blk = c
-            mask = expand_mask(idx_blk, counts_blk)
-            g = y_pad[idx_blk] * mask[..., None]  # [B, K, n_pad]
-            if implicit:
-                maskf = mask.astype(jnp.float32)
-                c1 = (alpha * jnp.abs(val_blk)) * maskf
-                pref = (val_blk > 0).astype(jnp.float32) * maskf
-                a_t = yty_pad[:, :, None] + jnp.einsum(
-                    "bkr,bk,bks->rsb", g, c1.astype(g.dtype), g,
+            with jax.named_scope("als.gather"):
+                mask = expand_mask(idx_blk, counts_blk)
+                g = y_pad[idx_blk] * mask[..., None]  # [B, K, n_pad]
+            with jax.named_scope("als.gramian"):
+                if implicit:
+                    maskf = mask.astype(jnp.float32)
+                    c1 = (alpha * jnp.abs(val_blk)) * maskf
+                    pref = (val_blk > 0).astype(jnp.float32) * maskf
+                    a_t = yty_pad[:, :, None] + jnp.einsum(
+                        "bkr,bk,bks->rsb", g, c1.astype(g.dtype), g,
+                        preferred_element_type=jnp.float32,
+                    )
+                    rhs = (1.0 + c1) * pref
+                else:
+                    a_t = jnp.einsum(
+                        "bkr,bks->rsb", g, g,
+                        preferred_element_type=jnp.float32,
+                    )
+                    rhs = val_blk
+                n_u = counts_blk.astype(jnp.float32)  # == mask.sum(axis=1)
+                a_t = a_t + (lam * n_u)[None, None, :] * eye_t
+                b_t = jnp.einsum(
+                    "bkr,bk->rb", g, rhs.astype(g.dtype),
                     preferred_element_type=jnp.float32,
                 )
-                rhs = (1.0 + c1) * pref
-            else:
-                a_t = jnp.einsum(
-                    "bkr,bks->rsb", g, g,
-                    preferred_element_type=jnp.float32,
-                )
-                rhs = val_blk
-            n_u = counts_blk.astype(jnp.float32)  # == mask.sum(axis=1)
-            a_t = a_t + (lam * n_u)[None, None, :] * eye_t
-            b_t = jnp.einsum(
-                "bkr,bk->rb", g, rhs.astype(g.dtype),
-                preferred_element_type=jnp.float32,
-            )
             bsz = idx_blk.shape[0]
-            if mesh is None:
-                pad_b = -bsz % _SPD_BLK
-                if pad_b:
-                    a_t = jnp.pad(a_t, ((0, 0), (0, 0), (0, pad_b)))
-                    b_t = jnp.pad(b_t, ((0, 0), (0, pad_b)))
-                x_t = spd_solve_t(a_t, b_t)
-            else:
-                from jax.sharding import PartitionSpec as P
+            with jax.named_scope("als.solve"):
+                if mesh is None:
+                    pad_b = -bsz % _SPD_BLK
+                    if pad_b:
+                        a_t = jnp.pad(a_t, ((0, 0), (0, 0), (0, pad_b)))
+                        b_t = jnp.pad(b_t, ((0, 0), (0, pad_b)))
+                    x_t = spd_solve_t(a_t, b_t)
+                else:
+                    from jax.sharding import PartitionSpec as P
 
-                from jax import shard_map
-                from ..parallel.mesh import DATA_AXIS
+                    from jax import shard_map
+                    from ..parallel.mesh import DATA_AXIS
 
-                n_data = mesh.shape[DATA_AXIS]
-                # each device's local block must itself be a multiple of
-                # the kernel's lane block
-                pad_b = -bsz % (_SPD_BLK * n_data)
-                if pad_b:
-                    a_t = jnp.pad(a_t, ((0, 0), (0, 0), (0, pad_b)))
-                    b_t = jnp.pad(b_t, ((0, 0), (0, pad_b)))
-                x_t = shard_map(
-                    spd_solve_t,
-                    mesh=mesh,
-                    in_specs=(P(None, None, DATA_AXIS), P(None, DATA_AXIS)),
-                    out_specs=P(None, DATA_AXIS),
-                    check_vma=False,  # pallas body; replication is by spec
-                )(a_t, b_t)
-            return x_t[:rank, :bsz].T  # [B, rank]
+                    n_data = mesh.shape[DATA_AXIS]
+                    # each device's local block must itself be a multiple
+                    # of the kernel's lane block
+                    pad_b = -bsz % (_SPD_BLK * n_data)
+                    if pad_b:
+                        a_t = jnp.pad(a_t, ((0, 0), (0, 0), (0, pad_b)))
+                        b_t = jnp.pad(b_t, ((0, 0), (0, pad_b)))
+                    x_t = shard_map(
+                        spd_solve_t,
+                        mesh=mesh,
+                        in_specs=(
+                            P(None, None, DATA_AXIS), P(None, DATA_AXIS),
+                        ),
+                        out_specs=P(None, DATA_AXIS),
+                        check_vma=False,  # pallas body; replication by spec
+                    )(a_t, b_t)
+                return x_t[:rank, :bsz].T  # [B, rank]
 
         def solve_chunk_fused(c):
             idx_blk, val_blk, counts_blk = c
@@ -881,28 +896,56 @@ def _solve_side_traced(
             return x_blk[:bsz]
 
     for rows, idx, val, counts in buckets:
-        if idx.dtype != jnp.int32:
-            idx = idx.astype(jnp.int32)  # uint16 transfer packing
-        if solve_mode == "pallas":
-            # fused gather+Gramian only pays for itself when the removed
-            # [B, K, R] round trip outweighs its [B, R, R] transpose —
-            # i.e. width >= rank; narrow buckets keep the einsum build
-            fn = (
-                solve_chunk_fused
-                if fused_gather and idx.shape[-1] >= rank
-                else solve_chunk_pallas
-            )
-            solved = jax.lax.map(fn, (idx, val, counts))
-        elif solve_mode == "two_phase":
-            a, b = jax.lax.map(system, (idx, val, counts))
-            solved = _cho_solve(
-                a.reshape(-1, rank, rank), b.reshape(-1, rank)
-            )
-        else:
-            solved = jax.lax.map(lambda c: _cho_solve(*system(c)),
-                                 (idx, val, counts))
-        x = x.at[rows.reshape(-1)].set(solved.reshape(-1, rank), mode="drop")
+        with jax.named_scope(f"als.w{idx.shape[-1]}"):
+            if idx.dtype != jnp.int32:
+                idx = idx.astype(jnp.int32)  # uint16 transfer packing
+            if solve_mode == "pallas":
+                # fused gather+Gramian only pays for itself when the
+                # removed [B, K, R] round trip outweighs its [B, R, R]
+                # transpose — i.e. width >= rank; narrow buckets keep the
+                # einsum build
+                fn = (
+                    solve_chunk_fused
+                    if fused_gather and idx.shape[-1] >= rank
+                    else solve_chunk_pallas
+                )
+                solved = jax.lax.map(fn, (idx, val, counts))
+            elif solve_mode == "two_phase":
+                a, b = jax.lax.map(system, (idx, val, counts))
+                solved = _cho_solve(
+                    a.reshape(-1, rank, rank), b.reshape(-1, rank)
+                )
+            else:
+                solved = jax.lax.map(lambda c: _cho_solve(*system(c)),
+                                     (idx, val, counts))
+            with jax.named_scope("als.scatter"):
+                x = x.at[rows.reshape(-1)].set(
+                    solved.reshape(-1, rank), mode="drop"
+                )
     return x
+
+
+def _solve_side_scoped(
+    side, y, buckets, n_rows, rank, implicit, lam, alpha,
+    solve_mode, gather_dtype, mesh, fused_gather,
+):
+    """One side's solve under its device scope, ``als.user_side`` or
+    ``als.item_side`` by the side SOLVED (``y`` is the opposite table):
+    the implicit path's Gramian of ``y`` (``als.yty``), then every
+    bucket. Scopes are names in the program's metadata, read by the
+    benchmark's trace reduction; they compile to no operation."""
+    with jax.named_scope(f"als.{side}_side"):
+        yty = None
+        if implicit:
+            with jax.named_scope("als.yty"):
+                yty = jnp.einsum(
+                    "nr,ns->rs", y, y, preferred_element_type=jnp.float32
+                )
+        return _solve_side_traced(
+            y, buckets, n_rows, rank, implicit, lam, alpha, yty,
+            solve_mode=solve_mode, gather_dtype=gather_dtype, mesh=mesh,
+            fused_gather=fused_gather,
+        )
 
 
 def _als_iteration_body(
@@ -917,25 +960,13 @@ def _als_iteration_body(
     (A whole-run ``fori_loop`` fusion compiles pathologically on some
     backends; per-iteration fusion keeps dispatch count at
     ``iterations`` while staying cheap to compile.)"""
-    yty = (
-        jnp.einsum("nr,ns->rs", y, y, preferred_element_type=jnp.float32)
-        if implicit
-        else None
+    x = _solve_side_scoped(
+        "user", y, user_buckets, n_users, rank, implicit, lam, alpha,
+        solve_mode, gather_dtype, mesh, fused_gather,
     )
-    x = _solve_side_traced(
-        y, user_buckets, n_users, rank, implicit, lam, alpha, yty,
-        solve_mode=solve_mode, gather_dtype=gather_dtype, mesh=mesh,
-        fused_gather=fused_gather,
-    )
-    xtx = (
-        jnp.einsum("nr,ns->rs", x, x, preferred_element_type=jnp.float32)
-        if implicit
-        else None
-    )
-    y2 = _solve_side_traced(
-        x, item_buckets, n_items, rank, implicit, lam, alpha, xtx,
-        solve_mode=solve_mode, gather_dtype=gather_dtype, mesh=mesh,
-        fused_gather=fused_gather,
+    y2 = _solve_side_scoped(
+        "item", x, item_buckets, n_items, rank, implicit, lam, alpha,
+        solve_mode, gather_dtype, mesh, fused_gather,
     )
     return x, y2
 
@@ -943,7 +974,7 @@ def _als_iteration_body(
 def _als_half_body(
     y, buckets, lam, alpha,
     rank, implicit, n_rows, solve_mode="chunked",
-    gather_dtype="f32", mesh=None, fused_gather=False,
+    gather_dtype="f32", mesh=None, fused_gather=False, side="user",
 ):
     """One HALF iteration (solve one side from the opposite factors) as its
     own device program. The training loop uses this for the first executed
@@ -952,21 +983,15 @@ def _als_half_body(
     transfer overlaps the first solve instead of gating it — the staging
     overlap of VERDICT r3 item 4. Later iterations keep the fused
     whole-iteration program (one dispatch each)."""
-    yty = (
-        jnp.einsum("nr,ns->rs", y, y, preferred_element_type=jnp.float32)
-        if implicit
-        else None
-    )
-    return _solve_side_traced(
-        y, buckets, n_rows, rank, implicit, lam, alpha, yty,
-        solve_mode=solve_mode, gather_dtype=gather_dtype, mesh=mesh,
-        fused_gather=fused_gather,
+    return _solve_side_scoped(
+        side, y, buckets, n_rows, rank, implicit, lam, alpha,
+        solve_mode, gather_dtype, mesh, fused_gather,
     )
 
 
 _HALF_STATICS = (
     "rank", "implicit", "n_rows", "solve_mode",
-    "gather_dtype", "mesh", "fused_gather",
+    "gather_dtype", "mesh", "fused_gather", "side",
 )
 
 _als_half = functools.partial(
@@ -1120,12 +1145,16 @@ def als_train(
         )
     if sort_gather:
         # gather-locality pass (host, pre-staging); see sort_bucket_indices
-        by_user = sort_bucket_indices(by_user)
-        by_item = sort_bucket_indices(by_item)
+        with span("als.index_sort", {"side": "user"}):
+            by_user = sort_bucket_indices(by_user)
+        with span("als.index_sort", {"side": "item"}):
+            by_item = sort_bucket_indices(by_item)
     if isinstance(by_user, BucketedMatrix):
-        by_user = stage(by_user, row_sharding, row_multiple)
+        with span("als.stage", {"side": "user"}):
+            by_user = stage(by_user, row_sharding, row_multiple)
     if isinstance(by_item, BucketedMatrix):
-        by_item = stage(by_item, row_sharding, row_multiple)
+        with span("als.stage", {"side": "item"}):
+            by_item = stage(by_item, row_sharding, row_multiple)
     if profile is not None:
         profile["stage_s"] = _time.monotonic() - t_stage
         # RESOLVED lever flags — what this run actually executed, not
@@ -1153,10 +1182,11 @@ def als_train(
             ],
         }
         profile.setdefault("iteration_s", [])
-    y = init_factors(by_item.n_rows, rank, cfg.seed)  # item factors
-    if mesh is not None:
-        y = jax.device_put(y, tbl_spec)
-    ub, ib = _bucket_tensors(by_user), _bucket_tensors(by_item)
+    with span("als.init_factors"):
+        y = init_factors(by_item.n_rows, rank, cfg.seed)  # item factors
+        if mesh is not None:
+            y = jax.device_put(y, tbl_spec)
+        ub, ib = _bucket_tensors(by_user), _bucket_tensors(by_item)
     lam, alpha = jnp.float32(cfg.lambda_), jnp.float32(cfg.alpha)
     x = None
 
@@ -1218,6 +1248,9 @@ def als_train(
     from ..obs.profile import default_telemetry
 
     _telemetry = default_telemetry()
+    # the ``als.enqueue`` spans time the host's dispatch of a program,
+    # not the program: the host runs ahead of the device, and the
+    # program's own time is the device trace's (``jit__als_*`` modules)
     for i in range(start, cfg.iterations):
         t_iter = _time.monotonic()
         if i == start:
@@ -1225,24 +1258,31 @@ def als_train(
             # solve needs only the user-side buckets, so it starts as
             # soon as they land while the item-side transfer is still in
             # flight (same math — the fused body is these two calls)
-            x = _telemetry.call(
-                "als_half", half, y, ub, lam, alpha,
-                n_rows=by_user.n_rows, **common,
-            )
-            y = _telemetry.call(
-                "als_half", half, x, ib, lam, alpha,
-                n_rows=by_item.n_rows, **common,
-            )
+            with span("als.enqueue", {"program": "half_user", "i": i}):
+                x = _telemetry.call(
+                    "als_half", half, y, ub, lam, alpha,
+                    n_rows=by_user.n_rows, side="user", **common,
+                )
+            with span("als.enqueue", {"program": "half_item", "i": i}):
+                y = _telemetry.call(
+                    "als_half", half, x, ib, lam, alpha,
+                    n_rows=by_item.n_rows, side="item", **common,
+                )
         else:
-            x, y = _telemetry.call(
-                "als_iteration", iteration,
-                ub, ib, y, lam, alpha,
-                n_users=by_user.n_rows,
-                n_items=by_item.n_rows,
-                **common,
-            )
+            with span("als.enqueue", {"program": "iteration", "i": i}):
+                x, y = _telemetry.call(
+                    "als_iteration", iteration,
+                    ub, ib, y, lam, alpha,
+                    n_users=by_user.n_rows,
+                    n_items=by_item.n_rows,
+                    **common,
+                )
         if profile is not None:
-            jax.block_until_ready((x, y))
+            # the fence that ``profile`` has always made, now with a name:
+            # without it the host runs ahead and the whole wait is
+            # ``train.wait_device`` in ``ALSAlgorithm.train``
+            with span("als.wait_device", {"i": i}):
+                jax.block_until_ready((x, y))
             profile["iteration_s"].append(_time.monotonic() - t_iter)
         done = i + 1
         if (
@@ -1346,12 +1386,14 @@ def als_train_coo(
     checkpoint_every: int = 0,
 ) -> ALSFactors:
     """Convenience: COO triplets → bucketized both ways → train."""
-    by_user = bucketize(
-        users, items, ratings, n_users, n_items, pad_to_blocks=True
-    )
-    by_item = bucketize(
-        items, users, ratings, n_items, n_users, pad_to_blocks=True
-    )
+    with span("als.bucketize", {"side": "user"}):
+        by_user = bucketize(
+            users, items, ratings, n_users, n_items, pad_to_blocks=True
+        )
+    with span("als.bucketize", {"side": "item"}):
+        by_item = bucketize(
+            items, users, ratings, n_items, n_users, pad_to_blocks=True
+        )
     return als_train(
         by_user, by_item, cfg, mesh=mesh, factor_sharding=factor_sharding,
         checkpoint=checkpoint, checkpoint_every=checkpoint_every,

@@ -139,6 +139,7 @@ def _topk_streaming_call(query_vectors, item_factors, exclude_idx, k,
             jax.ShapeDtypeStruct((b, k), jnp.int32),
         ],
         interpret=interpret,
+        name="top_k_streaming",
     )(query_vectors, items, exclude_idx)
 
 
@@ -299,6 +300,7 @@ def spd_solve_t(
             pltpu.VMEM((n, _SPD_BLK), jnp.float32),
         ],
         interpret=interpret,
+        name="spd_solve_t",
     )(a_t, b_t)
 
 
@@ -473,6 +475,7 @@ def _gramian_fused_call(y, idx, w2, rhs, ridge, yty, bt, kt, interpret):
             pltpu.SemaphoreType.DMA((2,)),  # one per slot
         ],
         interpret=interpret,
+        name="gramian_fused",
     )(idx, w2, rhs, ridge, y, yty)
 
 

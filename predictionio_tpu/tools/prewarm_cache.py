@@ -201,9 +201,11 @@ def main(argv=None) -> int:
                       mesh=None, **kw)
         for prog, build in (
             (f"{name}/half_user", lambda: als._als_half.lower(
-                y_aval, ub, scalar, scalar, n_rows=n_users, **common)),
+                y_aval, ub, scalar, scalar, n_rows=n_users, side="user",
+                **common)),
             (f"{name}/half_item", lambda: als._als_half.lower(
-                x_aval, ib, scalar, scalar, n_rows=n_items, **common)),
+                x_aval, ib, scalar, scalar, n_rows=n_items, side="item",
+                **common)),
             (f"{name}/iteration", lambda: als._als_iteration.lower(
                 ub, ib, y_aval, scalar, scalar,
                 n_users=n_users, n_items=n_items, **common)),

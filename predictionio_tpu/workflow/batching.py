@@ -37,7 +37,7 @@ from concurrent.futures import Future
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 from ..obs.metrics import MetricsRegistry
-from ..obs.trace import Tracer, current_context
+from ..obs.trace import Tracer, current_context, default_tracer
 
 __all__ = ["MicroBatcher"]
 
@@ -68,7 +68,9 @@ class MicroBatcher:
     whose submitting thread carried a span context gets two child spans:
     ``batch.queue-wait`` (submit → dispatch) and ``batch.device`` (the
     processor call) — the queue-time-vs-device-time split that explains
-    a slow query. ``clock`` is injectable for sleep-free tests.
+    a slow query. Every executed batch is also one ``batch.execute`` span
+    (tags ``b``, ``flush``) in that tracer, or in the process's default
+    tracer without one. ``clock`` is injectable for sleep-free tests.
     """
 
     def __init__(
@@ -319,7 +321,15 @@ class MicroBatcher:
         # that read (the PR-8/9 batch-span flake).
         try:
             try:
-                results = self._process(items)
+                # one span per batch, entered and left on this executor
+                # thread, so it is in the profiler's trace too; with no
+                # ambient request it roots a trace of its own, and the
+                # model step's spans (``predict.*``) become its children
+                tracer = self._tracer or default_tracer()
+                with tracer.span(
+                    "batch.execute", {"b": len(items), "flush": reason}
+                ):
+                    results = self._process(items)
                 if len(results) != len(items):
                     raise RuntimeError(
                         f"batch processor returned {len(results)} results "

@@ -128,10 +128,20 @@ def test_als_half_step_with_its_kernels(one_chip, monkeypatch):
         _sds(one_chip, (), jnp.float32),
         _sds(one_chip, (), jnp.float32),
         n_rows=n_i, rank=RANK, implicit=False, solve_mode="pallas",
-        mesh=None, gather_dtype="f32", fused_gather=True,
+        mesh=None, gather_dtype="f32", fused_gather=True, side="item",
     )
     # a solver call and a fused-build call per bucket wide enough for it
-    assert compiled.as_text().count("tpu_custom_call") >= 4
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 4
+    # what the benchmark's trace reduction finds its events by
+    # (docs/observability.md): the kernels' names on their instructions,
+    # and the name stack side / bucket / phase in ``op_name``
+    assert "%gramian_fused" in text and "%spd_solve_t" in text
+    widest = max(b.width for b in by_item.buckets)
+    chunk = f"als.item_side/als.w{widest}/while/body/closed_call"
+    assert f"{chunk}/als.gramian/" in text
+    assert f"{chunk}/als.solve/spd_solve_t/pallas_call" in text
+    assert f"als.item_side/als.w{widest}/als.scatter/scatter" in text
 
 
 @pytest.mark.parametrize("n_excl", [0, 64])

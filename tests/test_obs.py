@@ -30,6 +30,7 @@ from __future__ import annotations
 import math
 import os
 import re
+import time
 
 import pytest
 import requests
@@ -293,6 +294,20 @@ class TestTracer:
 # 4. Server wiring (the acceptance layer)
 # ---------------------------------------------------------------------------
 
+def _spans_once_named(store, trace_id, name, timeout_s=5.0):
+    """A trace's spans once one named ``name`` is there. A server records
+    its admission span when the handler returns, AFTER the response has
+    gone out: a client that reads the store the moment it has its answer
+    can be ahead of that by a thread switch (seen once under six xdist
+    workers), so the read waits for the span, within a bound."""
+    deadline = time.monotonic() + timeout_s
+    while True:
+        spans = store.for_trace(trace_id)
+        if any(s["name"] == name for s in spans) or time.monotonic() > deadline:
+            return spans
+        time.sleep(0.005)
+
+
 #: every exposition line is a comment or `name[{labels}] value`
 _EXPO_LINE = re.compile(
     r"^(#.*|[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^}]*\})? \S+)$"
@@ -529,7 +544,9 @@ class TestTraceEndToEnd:
             assert r.headers[TRACE_HEADER] == tid
 
             # query-server side: admission span + the remote client span
-            qspans = server.tracer.store.for_trace(tid)
+            qspans = _spans_once_named(
+                server.tracer.store, tid, "POST /queries.json"
+            )
             names = {s["name"] for s in qspans}
             assert "POST /queries.json" in names
             assert "storage.GET" in names
@@ -538,7 +555,9 @@ class TestTraceEndToEnd:
             assert {"batch.queue-wait", "batch.device"} <= names
             # storage-server side: same trace id at admission, via the
             # X-PIO-Trace header the remote client forwarded
-            pspans = primary.tracer.store.for_trace(tid)
+            pspans = _spans_once_named(
+                primary.tracer.store, tid, "GET /events"
+            )
             assert any(s["name"] == "GET /events" for s in pspans)
             assert all(s["service"] == "storage-server" for s in pspans)
 
@@ -552,7 +571,9 @@ class TestTraceEndToEnd:
                 headers={TRACE_HEADER: tid2},
             )
             assert r.status_code == 200
-            rspans = replica.tracer.store.for_trace(tid2)
+            rspans = _spans_once_named(
+                replica.tracer.store, tid2, "GET /events"
+            )
             assert any(s["name"] == "GET /events" for s in rspans)
             assert all(s["service"] == "storage-replica" for s in rspans)
 
@@ -655,7 +676,10 @@ class TestTraceEndToEnd:
             assert r.status_code == 200
             server._feedback_pool.shutdown(wait=True)  # drain delivery
             es_names = {
-                s["name"] for s in es.tracer.store.for_trace(tid)
+                s["name"]
+                for s in _spans_once_named(
+                    es.tracer.store, tid, "POST /events.json"
+                )
             }
             assert "POST /events.json" in es_names
             q_names = {

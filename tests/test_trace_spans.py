@@ -1,0 +1,379 @@
+"""One span, two sinks (``obs/trace.py``, docs/observability.md): a
+program span lands in the ``SpanStore`` on the host clock and, while a
+profiler session runs, in the profiler's trace as ``pio.<name>``; the
+training path opens its own spans under one ``train`` root; the ALS
+programs carry stable device scopes and the Pallas kernels their names.
+"""
+
+from __future__ import annotations
+
+import glob
+import os
+import subprocess
+import sys
+from concurrent.futures import ThreadPoolExecutor
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from predictionio_tpu.obs.trace import (
+    Tracer,
+    current_context,
+    default_tracer,
+    span,
+)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _host_events(trace_dir, prefix="pio."):
+    """(name, start_ns, end_ns) of the host events named ``prefix``…"""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True
+    )
+    return [
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name.startswith(prefix)
+    ]
+
+
+class TestTwoSinks:
+    def test_span_under_a_profiler_session_is_in_both(self, tmp_path):
+        from jax.profiler import ProfileOptions
+
+        tracer = Tracer("svc")
+        options = ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            with tracer.span("outer", tags={"side": "user", "b": 64}) as o:
+                with tracer.span("inner") as i:
+                    jnp.ones(8).block_until_ready()
+            with tracer.server_span("GET /x"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+
+        events = {name: (s, e) for name, s, e in _host_events(str(tmp_path))}
+        assert set(events) == {
+            "pio.outer side=user b=64", "pio.inner", "pio.GET /x",
+        }
+        outer, inner = events["pio.outer side=user b=64"], events["pio.inner"]
+        assert outer[0] <= inner[0] and inner[1] <= outer[1]  # nested
+
+        stored = {s["name"]: s for s in tracer.store.dump()}
+        assert set(stored) == {"outer", "inner", "GET /x"}
+        assert stored["inner"]["parentId"] == stored["outer"]["spanId"]
+        assert stored["inner"]["traceId"] == stored["outer"]["traceId"]
+        assert (o.trace_id, i.trace_id) == (
+            stored["outer"]["traceId"], stored["inner"]["traceId"],
+        )
+        assert stored["outer"]["parentId"] is None
+        assert stored["outer"]["tags"] == {"side": "user", "b": 64}
+
+    def test_profiler_idle_store_still_records(self, tmp_path):
+        tracer = Tracer("svc")
+        with tracer.span("quiet", tags={"k": 1}):
+            pass
+        assert [s["name"] for s in tracer.store.dump()] == ["quiet"]
+        assert list(tmp_path.iterdir()) == []  # nothing written anywhere
+
+    def test_hand_timed_record_gets_no_annotation(self, tmp_path):
+        tracer = Tracer("svc")
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            ctx = tracer.child_context(None)
+            tracer.record("batch.device", ctx, None, 0.0, 0.5)
+        finally:
+            jax.profiler.stop_trace()
+        assert _host_events(str(tmp_path)) == []
+        assert tracer.store.dump()[0]["name"] == "batch.device"
+
+    def test_obs_trace_works_without_jax(self):
+        code = (
+            "import sys\n"
+            "sys.modules['jax'] = None  # any import of jax now raises\n"
+            "from predictionio_tpu.obs.trace import span, default_tracer\n"
+            "with span('solo', {'k': 'v'}):\n"
+            "    pass\n"
+            "(s,) = default_tracer().store.dump()\n"
+            "assert s['name'] == 'solo' and s['parentId'] is None, s\n"
+            "assert 'jax.profiler' not in sys.modules\n"
+        )
+        done = subprocess.run(
+            [sys.executable, "-c", code], cwd=REPO, capture_output=True,
+            text=True, timeout=120,
+        )
+        assert done.returncode == 0, done.stderr
+
+
+class TestDefaultTracer:
+    def test_one_per_process(self):
+        assert default_tracer() is default_tracer()
+
+    def test_rootless_span_roots_a_trace_in_it(self):
+        assert current_context() is None
+        with span("job") as root:
+            with span("phase") as child:
+                assert current_context() is child
+        assert child.trace_id == root.trace_id
+        assert child.tracer is default_tracer()
+        by_name = {
+            s["name"]: s for s in default_tracer().store.for_trace(root.trace_id)
+        }
+        assert by_name["job"]["parentId"] is None
+        assert by_name["phase"]["parentId"] == by_name["job"]["spanId"]
+
+    def test_span_joins_the_ambient_tracer(self):
+        server = Tracer("query")
+        with server.server_span("POST /queries.json") as req:
+            with span("predict.fetch"):
+                pass
+        names = [s["name"] for s in server.store.for_trace(req.trace_id)]
+        assert names == ["predict.fetch", "POST /queries.json"]
+
+
+def _toy_prepared(n_users=60, n_items=25, nnz=900, seed=0):
+    from predictionio_tpu.models.recommendation import PreparedData
+    from predictionio_tpu.storage import BiMap
+
+    rng = np.random.default_rng(seed)
+    users = rng.integers(0, n_users, nnz).astype(np.int32)
+    items = rng.integers(0, n_items, nnz).astype(np.int32)
+    pairs = np.unique(np.stack([users, items], 1), axis=0)
+    return PreparedData(
+        user_map=BiMap.string_int([f"u{i}" for i in range(n_users)]),
+        item_map=BiMap.string_int([f"i{i}" for i in range(n_items)]),
+        users=pairs[:, 0], items=pairs[:, 1],
+        ratings=rng.integers(1, 6, len(pairs)).astype(np.float32),
+    )
+
+
+class TestTrainingSpans:
+    def test_one_train_root_with_the_paths_spans(self):
+        from predictionio_tpu.models.recommendation import (
+            ALSAlgorithm,
+            ALSAlgorithmParams,
+        )
+
+        algo = ALSAlgorithm(
+            ALSAlgorithmParams(rank=4, num_iterations=3, seed=1)
+        )
+        model = algo.train(None, _toy_prepared())
+        assert model.user_factors.shape == (60, 4)
+        # the newest root is this job's (the store is a ring buffer that
+        # other tests of this process have written to)
+        root = [
+            s for s in default_tracer().store.dump() if s["name"] == "train"
+        ][-1]
+        assert root["parentId"] is None
+        assert root["tags"] == {"rank": 4, "iterations": 3, "shards": 1}
+        spans = default_tracer().store.for_trace(root["traceId"])
+        assert [s for s in spans if s["name"] == "train"] == [root]
+        children = [s for s in spans if s["parentId"] == root["spanId"]]
+        # below the children only what JitTelemetry already recorded: a
+        # ``jit.compile`` under the enqueue that compiled
+        enqueues = {s["spanId"] for s in children if s["name"] == "als.enqueue"}
+        for s in spans:
+            if s is not root and s not in children:
+                assert s["name"] == "jit.compile" and s["parentId"] in enqueues
+
+        def tagged(name, key):
+            return sorted(
+                s["tags"][key] for s in children if s["name"] == name
+            )
+
+        for name in ("als.bucketize", "als.index_sort", "als.stage"):
+            assert tagged(name, "side") == ["item", "user"], name
+        assert tagged("als.enqueue", "program") == [
+            "half_item", "half_user", "iteration", "iteration",
+        ]
+        assert tagged("als.enqueue", "i") == [0, 0, 1, 2]
+        counts = {}
+        for s in children:
+            counts[s["name"]] = counts.get(s["name"], 0) + 1
+        assert counts == {
+            "als.bucketize": 2, "als.index_sort": 2, "als.stage": 2,
+            "als.init_factors": 1, "als.enqueue": 4,
+            "train.wait_device": 1, "train.fetch": 1,
+        }
+        # children lie inside the root, in the order the work happens
+        ends = [s["startMs"] + s["durationMs"] for s in children]
+        assert max(ends) <= root["startMs"] + root["durationMs"] + 1.0
+        assert [s["name"] for s in children][-2:] == [
+            "train.wait_device", "train.fetch",
+        ]
+
+    def test_profile_fence_is_a_span_of_its_own(self):
+        """``als_train(profile=...)`` fences every iteration (the
+        benchmark's traced run passes it): that wait has a name, so the
+        children of the root still cover the job."""
+        from predictionio_tpu.ops import als
+
+        pd = _toy_prepared()
+        by_user = als.bucketize(pd.users, pd.items, pd.ratings, 60, 25)
+        by_item = als.bucketize(pd.items, pd.users, pd.ratings, 25, 60)
+        cfg = als.ALSConfig(rank=4, iterations=3, seed=1)
+        with span("train") as root:
+            als.als_train(by_user, by_item, cfg, profile={})
+        spans = default_tracer().store.for_trace(root.trace_id)
+        waits = [s for s in spans if s["name"] == "als.wait_device"]
+        assert [s["tags"]["i"] for s in waits] == [0, 1, 2]
+        # and never without ``profile``: nothing is fenced that was not
+        with span("train") as bare:
+            als.als_train(by_user, by_item, cfg)
+        names = {s["name"] for s in default_tracer().store.for_trace(bare.trace_id)}
+        assert "als.wait_device" not in names and "als.enqueue" in names
+
+    def test_serving_batch_spans(self):
+        from predictionio_tpu.models.recommendation import (
+            ALSAlgorithm,
+            ALSAlgorithmParams,
+            Query,
+        )
+        from predictionio_tpu.workflow.batching import MicroBatcher
+
+        algo = ALSAlgorithm(ALSAlgorithmParams(rank=4, num_iterations=2, seed=1))
+        model = algo.train(None, _toy_prepared())
+        tracer = Tracer("query")
+        batcher = MicroBatcher(
+            lambda qs: [r for _, r in algo.batch_predict(
+                model, list(enumerate(qs)))],
+            max_batch=4, max_wait_ms=20.0, tracer=tracer,
+        )
+        try:
+            with ThreadPoolExecutor(3) as pool:
+                results = list(pool.map(
+                    lambda i: batcher.submit(Query(user=f"u{i}", num=3), 60.0),
+                    range(3),
+                ))
+        finally:
+            batcher.close()
+        assert all(len(r.item_scores) == 3 for r in results)
+        spans = tracer.store.dump()
+        executes = [s for s in spans if s["name"] == "batch.execute"]
+        assert executes and sum(s["tags"]["b"] for s in executes) == 3
+        for ex in executes:
+            assert ex["parentId"] is None
+            kids = [s["name"] for s in spans if s["parentId"] == ex["spanId"]]
+            assert kids == [
+                "predict.dispatch", "predict.fetch", "predict.results",
+            ]
+        # one span per batch each, nothing per request
+        per_batch = [s for s in spans if s["name"] != "jit.compile"]
+        assert len(per_batch) == 4 * len(executes)
+
+
+SCOPES_PALLAS = (
+    "als.user_side", "als.item_side", "als.gather", "als.gramian",
+    "als.solve", "als.scatter",
+)
+
+
+def _staged(seed=0, n_u=400, n_i=60, nnz=6000):
+    from predictionio_tpu.ops import als
+
+    rng = np.random.default_rng(seed)
+    w = 1.0 / np.arange(1, n_u + 1) ** 0.9
+    users = rng.choice(n_u, size=nnz, p=w / w.sum()).astype(np.int32)
+    items = rng.integers(0, n_i, nnz).astype(np.int32)
+    vals = rng.integers(1, 6, nnz).astype(np.float32)
+    by_user = als.stage(als.bucketize(users, items, vals, n_u, n_i))
+    by_item = als.stage(als.bucketize(items, users, vals, n_i, n_u))
+    return als, by_user, by_item, n_u, n_i
+
+
+class TestDeviceScopes:
+    @pytest.mark.parametrize("implicit", [False, True])
+    def test_iteration_text_holds_every_scope(self, implicit):
+        als, by_user, by_item, n_u, n_i = _staged()
+        text = als._als_iteration.lower(
+            als._bucket_tensors(by_user), als._bucket_tensors(by_item),
+            jnp.zeros((n_i, 16)), jnp.float32(0.1), jnp.float32(1.0),
+            rank=16, implicit=implicit, n_users=n_u, n_items=n_i,
+            solve_mode="pallas", gather_dtype="f32", mesh=None,
+            fused_gather=True,
+        ).as_text(debug_info=True)
+        widths = {b.idx.shape[-1] for s in (by_user, by_item) for b in s.buckets}
+        assert len(widths) >= 3
+        wanted = SCOPES_PALLAS + tuple(f"als.w{w}" for w in widths)
+        if implicit:
+            wanted += ("als.yty",)
+        for scope in wanted:
+            assert scope in text, scope
+        assert ("als.yty" in text) == implicit
+        # the name stack reads side / bucket / phase; width 8 is under
+        # the rank, so that bucket gathers in XLA (``als.gather``), and
+        # the wider ones inside ``gramian_fused``
+        for side, staged in (("user", by_user), ("item", by_item)):
+            for bucket in staged.buckets:
+                stack = f"als.{side}_side/als.w{bucket.idx.shape[-1]}/"
+                assert stack in text, stack
+        assert "als.user_side/als.w8/als.scatter/scatter" in text
+        # (a chunk's phases are a called function in this text, so their
+        # stack is whole only in the compiled program:
+        # tests/test_chip_compile.py reads it there)
+
+    @pytest.mark.parametrize("side", ["user", "item"])
+    def test_half_text_names_the_side_it_solves(self, side):
+        als, by_user, by_item, n_u, n_i = _staged()
+        staged, n_rows, n_cols = (
+            (by_user, n_u, n_i) if side == "user" else (by_item, n_i, n_u)
+        )
+        text = als._als_half.lower(
+            jnp.zeros((n_cols, 8)), als._bucket_tensors(staged),
+            jnp.float32(0.1), jnp.float32(1.0),
+            rank=8, implicit=False, n_rows=n_rows, solve_mode="chunked",
+            gather_dtype="f32", mesh=None, fused_gather=False, side=side,
+        ).as_text(debug_info=True)
+        other = "item" if side == "user" else "user"
+        assert f"als.{side}_side" in text
+        assert f"als.{other}_side" not in text
+        # the XLA solve path: gather and Gramian in ``system``, the
+        # Cholesky under ``als.solve``
+        for scope in ("als.gather", "als.gramian", "als.solve", "als.scatter"):
+            assert scope in text, scope
+
+    def test_pallas_calls_carry_their_names(self):
+        from predictionio_tpu.ops.pallas_kernels import (
+            gramian_fused,
+            spd_solve_t,
+            top_k_streaming,
+        )
+
+        def kernel_names(fn, *args):
+            found = []
+
+            def walk(jaxpr):
+                for eqn in jaxpr.eqns:
+                    if eqn.primitive.name == "pallas_call":
+                        found.append(eqn.params["name"])
+                    for value in eqn.params.values():
+                        inner = getattr(value, "jaxpr", value)
+                        if hasattr(inner, "eqns"):
+                            walk(inner)
+
+            walk(jax.make_jaxpr(fn)(*args).jaxpr)
+            return found
+
+        f32 = jnp.float32
+        assert kernel_names(
+            spd_solve_t, jnp.zeros((8, 8, 128), f32), jnp.zeros((8, 128), f32)
+        ) == ["spd_solve_t"]
+        assert kernel_names(
+            lambda y, idx, w, r, ridge: gramian_fused(y, idx, w, r, ridge),
+            jnp.zeros((64, 128), f32), jnp.zeros((8, 8), jnp.int32),
+            jnp.zeros((8, 8), f32), jnp.zeros((8, 8), f32), jnp.zeros((8,), f32),
+        ) == ["gramian_fused"]
+        assert kernel_names(
+            lambda q, items: top_k_streaming(q, items, 4),
+            jnp.zeros((8, 8), f32), jnp.zeros((512, 8), f32),
+        ) == ["top_k_streaming"]
