@@ -282,7 +282,11 @@ def default_tracer() -> Tracer:
 
 def span(name: str, tags: Optional[Dict[str, object]] = None):
     """A span under the ambient context, in that context's tracer; with
-    no ambient context, a root span in :func:`default_tracer`."""
+    no ambient context, a root span in :func:`default_tracer`.
+
+    The store reads ``tags`` when the span ends: what the block adds to
+    the dict it passed (a count known only once the work is done) is
+    recorded with the span. The profiler's label is made on entry."""
     ctx = current_context()
     tracer = ctx.tracer if ctx is not None else default_tracer()
     return tracer.span(name, tags=tags)

@@ -39,13 +39,34 @@ import numpy as np
 
 from ..obs.trace import span
 
-#: Default degree-bucket widths (powers of 4; rows pad to the nearest).
-DEFAULT_BUCKET_WIDTHS = (8, 32, 128, 512, 2048, 8192, 32768)
+#: Default degree-bucket widths; a row pads to the nearest at or above its
+#: degree. Powers of 2 up to 32, powers of 4 from there. The ladder is fine
+#: only under the usual ranks: a narrow bucket gathers its rows in XLA at a
+#: fixed price per padded slot, and that is where most rows of a
+#: recommendation job are (one or two ratings each), so padding them to 8
+#: gathered four slots for every rating. From 128 up a finer rung buys
+#: nothing: ``gramian_fused`` pads a bucket's width to a multiple of 128.
+DEFAULT_BUCKET_WIDTHS = (1, 2, 4, 8, 16, 32, 128, 512, 2048, 8192, 32768)
+
+#: The rungs the device scopes group buckets by (the ladder before it
+#: grew its narrow widths): a bucket runs under ``als.w<rung>`` of the
+#: smallest rung at or above its width, so ``als.w8`` is every row of at
+#: most 8 ratings whatever it is padded to (docs/observability.md).
+_SCOPE_RUNGS = (8, 32, 128, 512, 2048, 8192, 32768)
+
+#: Most rows a device block may hold at any width: on the Pallas path a
+#: block's normal equations are ``[R, R, B]`` whatever the width (205 MB
+#: at rank 50), so under the rank it is they, not the gather, that bound
+#: the block.
+_MAX_BLOCK_ROWS = 16384
 
 #: Max rows per device block inside a bucket solve (bounds peak gather
 #: memory). Small buckets allocate LESS than a full block — see
 #: :func:`_alloc_block`: sentinel padding rows cost real device FLOPs.
-_BLOCK_ROWS = {8: 16384, 32: 8192, 128: 4096, 512: 1024, 2048: 256, 8192: 64, 32768: 16}
+_BLOCK_ROWS = {
+    1: 16384, 2: 16384, 4: 16384, 8: 16384, 16: 16384, 32: 8192,
+    128: 4096, 512: 1024, 2048: 256, 8192: 64, 32768: 16,
+}
 
 
 @dataclasses.dataclass
@@ -539,11 +560,17 @@ class StagedMatrix:
 
 
 def _block_rows_for(width: int) -> int:
-    for w, b in _BLOCK_ROWS.items():
-        if w == width:
-            return b
-    # unseen width: bound gather chunk to ~64M floats
-    return max(16, (1 << 26) // max(1, width * 64))
+    block = _BLOCK_ROWS.get(width)
+    if block is None:
+        # unseen width: bound gather chunk to ~64M floats
+        block = max(16, (1 << 26) // max(1, width * 64))
+    return min(block, _MAX_BLOCK_ROWS)
+
+
+def _scope_rung(width: int) -> int:
+    """The rung of :data:`_SCOPE_RUNGS` a bucket's device scope is named
+    after (its own width past the last rung)."""
+    return next((r for r in _SCOPE_RUNGS if r >= width), width)
 
 
 def stage(
@@ -896,7 +923,13 @@ def _solve_side_traced(
             return x_blk[:bsz]
 
     for rows, idx, val, counts in buckets:
-        with jax.named_scope(f"als.w{idx.shape[-1]}"):
+        width = idx.shape[-1]
+        # outer: the rung (what the listed per-bucket metrics read, and
+        # it must not move when the ladder does); inner: the padded width
+        with (
+            jax.named_scope(f"als.w{_scope_rung(width)}"),
+            jax.named_scope(f"als.k{width}"),
+        ):
             if idx.dtype != jnp.int32:
                 idx = idx.astype(jnp.int32)  # uint16 transfer packing
             if solve_mode == "pallas":
@@ -906,7 +939,7 @@ def _solve_side_traced(
                 # einsum build
                 fn = (
                     solve_chunk_fused
-                    if fused_gather and idx.shape[-1] >= rank
+                    if fused_gather and width >= rank
                     else solve_chunk_pallas
                 )
                 solved = jax.lax.map(fn, (idx, val, counts))
@@ -1386,14 +1419,21 @@ def als_train_coo(
     checkpoint_every: int = 0,
 ) -> ALSFactors:
     """Convenience: COO triplets → bucketized both ways → train."""
-    with span("als.bucketize", {"side": "user"}):
-        by_user = bucketize(
-            users, items, ratings, n_users, n_items, pad_to_blocks=True
-        )
-    with span("als.bucketize", {"side": "item"}):
-        by_item = bucketize(
-            items, users, ratings, n_items, n_users, pad_to_blocks=True
-        )
+
+    def bucketize_side(side, rows, cols, n_rows, n_cols):
+        tags = {"side": side}
+        with span("als.bucketize", tags):
+            out = bucketize(
+                rows, cols, ratings, n_rows, n_cols, pad_to_blocks=True
+            )
+            # the fill share of the job's padding: ratings held against
+            # slots gathered every iteration (rows of padding included)
+            tags["ratings"] = sum(int(b.counts.sum()) for b in out.buckets)
+            tags["slots"] = sum(b.idx.size for b in out.buckets)
+        return out
+
+    by_user = bucketize_side("user", users, items, n_users, n_items)
+    by_item = bucketize_side("item", items, users, n_items, n_users)
     return als_train(
         by_user, by_item, cfg, mesh=mesh, factor_sharding=factor_sharding,
         checkpoint=checkpoint, checkpoint_every=checkpoint_every,

@@ -43,6 +43,11 @@ def numpy_als_step(y, users, items, ratings, n_users, lam, rank):
     return x
 
 
+#: the default ladder before it grew rungs under the rank (PR 25): what a
+#: caller gets who passes it, and what the new default is held against
+OLD_LADDER = (8, 32, 128, 512, 2048, 8192, 32768)
+
+
 class TestBucketize:
     def test_roundtrip_contents(self):
         users, items, ratings = synthetic_ratings()
@@ -66,7 +71,11 @@ class TestBucketize:
         vals = np.ones(46, dtype=np.float32)
         bm = bucketize(users, items, vals, 3, 50)
         widths = sorted(b.width for b in bm.buckets)
-        assert widths == [8, 128]  # degrees 5,1 -> 8; degree 40 -> 128
+        # degree 1 -> 1, degree 5 -> 8, degree 40 -> 128 (nothing
+        # between 32 and 128: a finer rung there would be padded back)
+        assert widths == [1, 8, 128]
+        coarse = bucketize(users, items, vals, 3, 50, bucket_widths=OLD_LADDER)
+        assert sorted(b.width for b in coarse.buckets) == [8, 128]
 
     def test_empty_rows_absent(self):
         bm = bucketize(np.array([5]), np.array([0]), np.array([1.0]), 10, 1)
@@ -599,6 +608,107 @@ class TestAllocBlock:
         staged = stage(side)
         for b, s in zip(side.buckets, staged.buckets):
             assert int(np.prod(s.rows.shape)) == b.rows.shape[0]
+
+
+def _ladder_data(seed=11):
+    """Degrees in every rung of the ladder on both sides: most users
+    hold one to four ratings (as most rows of a recommendation job do),
+    a few hold up to 40; items follow a power law, so the tail's items
+    hold one or two."""
+    rng = np.random.default_rng(seed)
+    n_u, n_i = 500, 200
+    degrees = rng.choice(
+        [1, 1, 1, 2, 2, 2, 3, 4, 5, 8, 9, 16, 17, 32, 33, 40], size=n_u
+    )
+    w = 1.0 / np.arange(1, n_i + 1) ** 1.2
+    u = np.repeat(np.arange(n_u), degrees).astype(np.int32)
+    i = np.concatenate([
+        rng.choice(n_i, size=d, replace=False, p=w / w.sum()) for d in degrees
+    ]).astype(np.int32)
+    v = rng.integers(1, 6, len(u)).astype(np.float32)
+    return u, i, v, n_u, n_i
+
+
+class TestBucketLadder:
+    """The default ladder is fine under the rank (1, 2, 4, 8, 16, 32):
+    a padded slot adds an exact zero to the Gramian and to the right-hand
+    side, so the narrow rungs change what is gathered, not what is
+    solved; and a job with no narrow rows builds what it always built."""
+
+    @pytest.mark.parametrize("mode", ["chunked", "pallas"])
+    @pytest.mark.parametrize("implicit", [False, True])
+    def test_factors_equal_the_old_ladders(self, implicit, mode):
+        from predictionio_tpu.ops.als import (
+            ALSConfig, ALSFactors, als_train, bucketize, rmse,
+        )
+
+        u, i, v, n_u, n_i = _ladder_data()
+        cfg = ALSConfig(
+            rank=12, iterations=3, lambda_=0.05, implicit_prefs=implicit,
+            alpha=1.0, seed=2, solve_mode=mode,
+        )
+
+        def train(**ladder):
+            by_user = bucketize(u, i, v, n_u, n_i, pad_to_blocks=True, **ladder)
+            by_item = bucketize(i, u, v, n_i, n_u, pad_to_blocks=True, **ladder)
+            widths = {b.width for b in by_user.buckets + by_item.buckets}
+            f = als_train(by_user, by_item, cfg)
+            return widths, np.asarray(f.user_factors), np.asarray(f.item_factors)
+
+        widths, x_new, y_new = train()
+        assert {1, 2, 4, 8, 16, 32, 128} <= widths
+        widths_old, x_old, y_old = train(bucket_widths=OLD_LADDER)
+        assert widths_old == widths - {1, 2, 4, 16}
+        # the index sort's contract (TestSortGatherIndices): equal up to
+        # float reassociation
+        np.testing.assert_allclose(x_new, x_old, rtol=1e-3, atol=1e-4)
+        np.testing.assert_allclose(y_new, y_old, rtol=1e-3, atol=1e-4)
+        r_new = rmse(ALSFactors(x_new, y_new, rank=12), u, i, v)
+        r_old = rmse(ALSFactors(x_old, y_old, rank=12), u, i, v)
+        assert abs(r_new - r_old) < 1e-3
+
+    @pytest.mark.parametrize("pad_to_blocks", [False, True])
+    def test_rows_above_32_ratings_build_the_old_buckets(self, pad_to_blocks):
+        """Which buckets a job builds follows from its degrees: with
+        every row above 32 ratings (MovieLens-20M's users hold 20 or
+        more, the median 68) the finer ladder changes no array."""
+        from predictionio_tpu.ops.als import DEFAULT_BUCKET_WIDTHS
+
+        rng = np.random.default_rng(13)
+        n_u, n_i = 120, 900
+        degrees = rng.integers(33, 700, size=n_u)
+        u = np.repeat(np.arange(n_u), degrees).astype(np.int32)
+        i = np.concatenate(
+            [rng.choice(n_i, size=d, replace=False) for d in degrees]
+        ).astype(np.int32)
+        v = rng.normal(size=len(u)).astype(np.float32)
+        new = bucketize(u, i, v, n_u, n_i, pad_to_blocks=pad_to_blocks)
+        old = bucketize(
+            u, i, v, n_u, n_i, bucket_widths=OLD_LADDER,
+            pad_to_blocks=pad_to_blocks,
+        )
+        assert set(DEFAULT_BUCKET_WIDTHS) > set(OLD_LADDER)
+        assert [b.width for b in new.buckets] == [128, 512, 2048]
+        assert len(new.buckets) == len(old.buckets)
+        for a, b in zip(new.buckets, old.buckets):
+            for field in ("rows", "idx", "val", "counts"):
+                got, want = getattr(a, field), getattr(b, field)
+                assert got.dtype == want.dtype
+                np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("width", [1, 2, 3, 4, 8, 16, 24, 32, 64, 127])
+    def test_block_rows_bounded_under_the_rank(self, width):
+        """A block's normal equations are [R, R, B] whatever the width:
+        the rows of a block are bounded for a default width and for a
+        caller's own alike (the fall-through gave 524,288 at width 2)."""
+        from predictionio_tpu.ops.als import (
+            DEFAULT_BUCKET_WIDTHS, _alloc_block, _block_rows_for,
+        )
+
+        assert _block_rows_for(width) <= 16384
+        assert _alloc_block(width, 10_000_000) <= 16384
+        if width in DEFAULT_BUCKET_WIDTHS and width <= 16:
+            assert _block_rows_for(width) == 16384
 
 
 class TestHbmBytesModel:

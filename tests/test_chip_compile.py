@@ -135,13 +135,60 @@ def test_als_half_step_with_its_kernels(one_chip, monkeypatch):
     assert text.count("tpu_custom_call") >= 4
     # what the benchmark's trace reduction finds its events by
     # (docs/observability.md): the kernels' names on their instructions,
-    # and the name stack side / bucket / phase in ``op_name``
+    # and the name stack side / rung / own width / phase in ``op_name``
     assert "%gramian_fused" in text and "%spd_solve_t" in text
     widest = max(b.width for b in by_item.buckets)
-    chunk = f"als.item_side/als.w{widest}/while/body/closed_call"
+    bucket = f"als.item_side/als.w{widest}/als.k{widest}"
+    chunk = f"{bucket}/while/body/closed_call"
     assert f"{chunk}/als.gramian/" in text
     assert f"{chunk}/als.solve/spd_solve_t/pallas_call" in text
-    assert f"als.item_side/als.w{widest}/als.scatter/scatter" in text
+    assert f"{bucket}/als.scatter/scatter" in text
+
+
+@pytest.mark.parametrize("block, width, rung", [
+    # the blocks ``train-amazonbooks`` runs under the rank, [rows, width]:
+    # the ladder's narrow rungs beside the width 8 it always had
+    (16384, 1, 8), (16384, 2, 8), (16384, 4, 8), (16384, 8, 8),
+    (16384, 16, 32), (8192, 32, 32),
+])
+def test_narrow_bucket_half_step(one_chip, monkeypatch, block, width, rung):
+    """One bucket narrower than the rank, as the user side of the
+    benchmark's cell solves it: XLA's gather and einsum build, the
+    Pallas solver. The block is what ``_BLOCK_ROWS`` gives the width, and
+    what bounds the program is the block's normal equations ``[56, 56,
+    B]`` (205 MB at 16,384 rows), not the width."""
+    from predictionio_tpu.ops import als
+
+    assert als._block_rows_for(width) == block
+    chunks, n_users, n_items = 3, 8_026_324, 2_330_066  # the cell's tables
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _compile(
+        als._als_half,
+        _sds(one_chip, (n_items, RANK), jnp.float32),
+        ((
+            _sds(one_chip, (chunks, block), jnp.int32),
+            _sds(one_chip, (chunks, block, width), jnp.int32),
+            _sds(one_chip, (chunks, block, width), jnp.float32),
+            _sds(one_chip, (chunks, block), jnp.int32),
+        ),),
+        _sds(one_chip, (), jnp.float32),
+        _sds(one_chip, (), jnp.float32),
+        n_rows=n_users, rank=RANK, implicit=False, solve_mode="pallas",
+        mesh=None, gather_dtype="f32", fused_gather=True, side="user",
+    )
+    text = compiled.as_text()
+    assert "%spd_solve_t" in text and "%gramian_fused" not in text
+    # side / rung / own width / phase: ``als.w8`` holds every width up to 8
+    chunk = f"als.user_side/als.w{rung}/als.k{width}/while/body/closed_call"
+    assert f"{chunk}/als.gather/" in text
+    assert f"{chunk}/als.solve/spd_solve_t/pallas_call" in text
+    assert f"als.user_side/als.w{rung}/als.k{width}/als.scatter/scatter" in text
+    normal_equations = RANK_PAD * RANK_PAD * block * 4
+    padded_table = n_items * RANK_PAD * 4
+    assert (
+        compiled.memory_analysis().temp_size_in_bytes
+        < padded_table + 2 * normal_equations
+    )
 
 
 @pytest.mark.parametrize("n_excl", [0, 64])
@@ -187,7 +234,8 @@ def test_sharded_half_step_2x2(topo):
 
     shards, n_u, n_i, nnz = 4, 1_000, 300, 8_000
     rng = np.random.default_rng(0)
-    users = rng.integers(0, n_u, nnz).astype(np.int32)
+    w = 1.0 / np.arange(1, n_u + 1) ** 0.9  # most users hold 1 to 4
+    users = rng.choice(n_u, size=nnz, p=w / w.sum()).astype(np.int32)
     items = rng.integers(0, n_i, nnz).astype(np.int32)
     vals = rng.integers(1, 6, nnz).astype(np.float32)
     user_plan = als_sharded.plan_side(
@@ -198,6 +246,7 @@ def test_sharded_half_step_2x2(topo):
         users, items, vals, user_plan, item_plan,
         als_sharded.DEFAULT_BUCKET_WIDTHS, True,
     )
+    assert {1, 2, 4, 8, 16, 32, 128} <= {slab[1].shape[-1] for slab in slabs}
     mesh = create_mesh(
         MeshConfig(((als_sharded.SHARD_AXIS, shards),)), topo.devices[:shards]
     )

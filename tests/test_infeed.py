@@ -275,26 +275,35 @@ def test_native_scan_ratings_missing_property_raises(native_store):
 # -- native bucketize -----------------------------------------------------
 
 
-def test_native_bucketize_matches_numpy():
+@pytest.mark.parametrize("pad_to_blocks", [False, True])
+def test_native_bucketize_matches_numpy(pad_to_blocks):
     from predictionio_tpu.native import NativeBuildError
     from predictionio_tpu.ops.als import _bucketize_native, _bucketize_numpy
 
     rng = np.random.default_rng(7)
-    n_rows, n_cols, nnz = 800, 400, 30_000
-    w = 1.0 / np.arange(1, n_rows + 1) ** 0.8
+    n_rows, n_cols, nnz = 800, 400, 6_000
+    w = 1.0 / np.arange(1, n_rows + 1) ** 1.1
     rows = rng.choice(n_rows, size=nnz, p=w / w.sum()).astype(np.int32)
     cols = rng.integers(0, n_cols, nnz).astype(np.int32)
     vals = rng.normal(size=nnz).astype(np.float32)
-    ref = _bucketize_numpy(rows, cols, vals, n_rows, n_cols)
+    ref = _bucketize_numpy(
+        rows, cols, vals, n_rows, n_cols, pad_to_blocks=pad_to_blocks
+    )
+    # the default ladder, its narrowest rungs and a wide one included
+    assert {1, 2, 4, 8, 16, 32, 128, 512} <= {b.width for b in ref.buckets}
     try:
-        got = _bucketize_native(rows, cols, vals, n_rows, n_cols)
+        got = _bucketize_native(
+            rows, cols, vals, n_rows, n_cols, pad_to_blocks=pad_to_blocks
+        )
     except NativeBuildError as exc:
         pytest.skip(f"native bucketize unavailable: {exc}")
     assert len(ref.buckets) == len(got.buckets)
     for a, b in zip(ref.buckets, got.buckets):
+        assert a.idx.dtype == b.idx.dtype and a.idx.shape == b.idx.shape
         assert np.array_equal(a.rows, b.rows)
         assert np.array_equal(a.idx, b.idx)
         assert np.array_equal(a.val, b.val)
+        assert np.array_equal(a.counts, b.counts)
         assert np.array_equal(a.mask, b.mask)
 
 

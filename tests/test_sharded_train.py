@@ -16,7 +16,9 @@ import pytest
 
 from predictionio_tpu.ops.als import ALSConfig, ALSFactors, als_train_coo, rmse
 from predictionio_tpu.ops.als_sharded import (
+    DEFAULT_BUCKET_WIDTHS,
     SHARDS_ENV,
+    _padded_widths,
     als_train_sharded,
     assign_rows_balanced,
     plan_side,
@@ -91,6 +93,31 @@ class TestShardCountInvariance:
         got = rmse(ALSFactors(*sweep(shards), rank=_CFG.rank), u, i, v)
         assert abs(ref - got) < RMSE_TOL, (ref, got)
 
+    @pytest.mark.parametrize("implicit", [False, True])
+    def test_narrow_rungs_match_single_device(self, implicit):
+        """Rows of one to four ratings, which the shared recipe has none
+        of: each shard's slabs at widths 1, 2 and 4 (and their ragged
+        gather) solve what the single-device trainer solves."""
+        rng = np.random.default_rng(5)
+        n_u, n_i = 300, 60
+        degrees = rng.choice([1, 1, 2, 2, 3, 4, 9, 20], size=n_u)
+        u = np.repeat(np.arange(n_u), degrees).astype(np.int32)
+        i = np.concatenate(
+            [rng.choice(n_i, size=d, replace=False) for d in degrees]
+        ).astype(np.int32)
+        v = rng.integers(1, 6, len(u)).astype(np.float32)
+        cfg = ALSConfig(
+            rank=6, iterations=2, lambda_=0.1, implicit_prefs=implicit,
+            alpha=4.0, seed=2,
+        )
+        ref = als_train_coo(u, i, v, n_u, n_i, cfg)
+        got = als_train_sharded(u, i, v, n_u, n_i, cfg, shards=4)
+        for a, b in ((got.user_factors, ref.user_factors),
+                     (got.item_factors, ref.item_factors)):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-4
+            )
+
     def test_implicit_psum_gramian_matches_single_device(self):
         """Implicit mode builds YᵀY as a psum of per-shard Gramians —
         the collective path the explicit sweep never touches."""
@@ -106,12 +133,22 @@ class TestDensityBalancing:
     within a pinned imbalance bound, and the plan surfaces the evidence
     (``profile["shard_plan"]``) the hardware-day drive prints."""
 
-    def test_skewed_histogram_splits_within_bound(self):
-        # 8 heavy rows (pad to 2048), 60 medium (128), 600 light (32):
-        # a power-law histogram a naive row-count split would skew badly
+    @pytest.mark.parametrize("narrow", [False, True])
+    def test_skewed_histogram_splits_within_bound(self, narrow):
+        # 8 heavy rows (pad to 2048), 60 medium (128), 600 light (16):
+        # a power-law histogram a naive row-count split would skew badly;
+        # ``narrow`` adds what most rows of a real job are, one to three
+        # ratings each, on the ladder's rungs 1, 2 and 4
         degrees = np.concatenate([
             np.full(8, 1_500), np.full(60, 90), np.full(600, 10),
         ])
+        if narrow:
+            degrees = np.concatenate([
+                degrees, np.full(2_001, 1), np.full(1_503, 2), np.full(402, 3),
+            ])
+            assert set(_padded_widths(degrees, DEFAULT_BUCKET_WIDTHS)) == {
+                1, 2, 4, 16, 128, 2048,
+            }
         plan = plan_side(degrees, shards=4, rank=16)
         assert plan.flop_imbalance <= 1.15, plan.per_shard_flops
         # every shard got its fair share of the heavy class

@@ -193,6 +193,18 @@ class TestTrainingSpans:
 
         for name in ("als.bucketize", "als.index_sort", "als.stage"):
             assert tagged(name, "side") == ["item", "user"], name
+        # the fill share of the job's padding: every rating once on
+        # either side, over the slots the ladder padded them to
+        from predictionio_tpu.ops import als
+
+        pd = _toy_prepared()
+        assert tagged("als.bucketize", "ratings") == [len(pd.users)] * 2
+        assert tagged("als.bucketize", "slots") == sorted(
+            sum(b.idx.size for b in als.bucketize(
+                rows, cols, pd.ratings, n, m, pad_to_blocks=True).buckets)
+            for rows, cols, n, m in (
+                (pd.users, pd.items, 60, 25), (pd.items, pd.users, 25, 60))
+        )
         assert tagged("als.enqueue", "program") == [
             "half_item", "half_user", "iteration", "iteration",
         ]
@@ -303,21 +315,29 @@ class TestDeviceScopes:
             fused_gather=True,
         ).as_text(debug_info=True)
         widths = {b.idx.shape[-1] for s in (by_user, by_item) for b in s.buckets}
-        assert len(widths) >= 3
-        wanted = SCOPES_PALLAS + tuple(f"als.w{w}" for w in widths)
+        assert {1, 2, 4, 8, 16, 32, 128} <= widths
+        wanted = SCOPES_PALLAS + tuple(f"als.k{w}" for w in widths)
         if implicit:
             wanted += ("als.yty",)
         for scope in wanted:
             assert scope in text, scope
         assert ("als.yty" in text) == implicit
-        # the name stack reads side / bucket / phase; width 8 is under
-        # the rank, so that bucket gathers in XLA (``als.gather``), and
-        # the wider ones inside ``gramian_fused``
+        # the name stack reads side / rung / own width / phase. The rung
+        # is the bucket the rows sat in before the ladder grew its narrow
+        # widths, so ``als.w8`` (``w8_device_s``) holds every row of at
+        # most 8 ratings; no ``als.w`` scope is named after a narrow
+        # width, or the per-bucket table would count it twice. Widths
+        # under the rank gather in XLA (``als.gather``), the wider ones
+        # inside ``gramian_fused``
+        rungs = {1: 8, 2: 8, 4: 8, 8: 8, 16: 32, 32: 32}
         for side, staged in (("user", by_user), ("item", by_item)):
             for bucket in staged.buckets:
-                stack = f"als.{side}_side/als.w{bucket.idx.shape[-1]}/"
+                k = bucket.idx.shape[-1]
+                stack = f"als.{side}_side/als.w{rungs.get(k, k)}/als.k{k}/"
                 assert stack in text, stack
-        assert "als.user_side/als.w8/als.scatter/scatter" in text
+        for narrow in (1, 2, 4, 16):
+            assert f"als.w{narrow}/" not in text
+        assert "als.user_side/als.w8/als.k2/als.scatter/scatter" in text
         # (a chunk's phases are a called function in this text, so their
         # stack is whole only in the compiled program:
         # tests/test_chip_compile.py reads it there)
