@@ -11,14 +11,27 @@ axis: attention dispatches to ring or Ulysses sequence parallelism over the
 mesh ``seq`` axis for histories too long for one chip
 (:mod:`predictionio_tpu.ops.attention`).
 
-The transformer is deliberately framework-light (pure jax + optax pytrees,
-pre-LN blocks, tied input/output embeddings) so the model pytree persists
-through the standard model store like any other template's model.
+The backbone is a function of a configuration
+(:mod:`predictionio_tpu.models.seq_backbone`): the shipped preset is a small
+pre-LayerNorm transformer, and ``SeqRecAlgorithmParams.backbone`` names any
+other (a JSON file with the keys of a public model's ``config.json``, e.g.
+``conf/backbones/qwen3next-80b-a3b-ep16.json``: gated DeltaNet and gated
+attention layers with sparse experts, of which this chip holds a range).
+It stays framework-light (pure jax + optax pytrees), so the model pytree
+persists through the standard model store like any other template's model.
+
+Training runs on packed rows: ragged histories laid first-fit into rows of
+``seq_len + 1`` slots with the id of its history beside every slot, a
+background thread that keeps the next batch a step ahead on the device, and
+one donated jitted optimizer step.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
+import queue
+import threading
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -33,8 +46,10 @@ from ..controller import (
     Params,
     Preparator,
 )
-from ..ops.attention import attention
+from ..obs.trace import span
+from ..ops.scoring import top_k_for_vectors
 from ..storage import BiMap, EventFilter, get_registry
+from . import seq_backbone as bb
 
 
 # -- query / result ---------------------------------------------------------
@@ -81,13 +96,49 @@ class TrainingData:
 @dataclasses.dataclass
 class PreparedData:
     item_map: BiMap
-    windows: np.ndarray  # [W, seq_len + 1] int32, PAD = len(item_map)
+    #: packed rows [R, seq_len + 1] int32 (0 in the slots no history fills)
+    windows: np.ndarray
+    #: the id of the history in every slot, counted from 1 in each row; 0 =
+    #: padding. A slot's target is the next slot where the id is the same.
+    segments: np.ndarray
     user_recent: Dict[str, List[int]]  # tail of each user's history
     seq_len: int
 
     @property
-    def pad_id(self) -> int:
-        return len(self.item_map)
+    def fill(self) -> float:
+        """Real tokens over slots."""
+        return float((self.segments > 0).mean())
+
+
+def pack_first_fit(pieces: List[np.ndarray], slots: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Lay ``pieces`` (int arrays of at most ``slots`` ids), in the order
+    they arrive, each into the first row that still has room for it.
+    Returns rows and segment ids, [R, slots] int32 each."""
+    free: List[int] = []  # room left in each row
+    place: List[Tuple[int, int]] = []
+    # rows with room, kept as a list scanned from the front: first fit
+    open_rows: List[int] = []
+    for piece in pieces:
+        n = len(piece)
+        for at, r in enumerate(open_rows):
+            if free[r] >= n:
+                break
+        else:
+            r, at = len(free), len(open_rows)
+            free.append(slots)
+            open_rows.append(r)
+        place.append((r, slots - free[r]))
+        free[r] -= n
+        if free[r] < 2:  # no history of two ids fits any more
+            open_rows.pop(at)
+    rows = np.zeros((len(free), slots), np.int32)
+    segs = np.zeros((len(free), slots), np.int32)
+    count = [0] * len(free)
+    for piece, (r, start) in zip(pieces, place):
+        count[r] += 1
+        rows[r, start:start + len(piece)] = piece
+        segs[r, start:start + len(piece)] = count[r]
+    return rows, segs
 
 
 # -- DASE components --------------------------------------------------------
@@ -147,63 +198,73 @@ class SeqDataSource(DataSource):
 
 @dataclasses.dataclass(frozen=True)
 class SeqPreparatorParams(Params):
+    #: positions the model sees in one row; a row holds seq_len + 1 ids
     seq_len: int = 64
     #: slide stride when a history is longer than seq_len + 1
     window_stride: int = 32
 
 
 class SeqPreparator(Preparator):
-    """Item indexing + fixed-shape training windows (ragged histories become
-    left-padded ``[W, seq_len+1]`` blocks — the static-shape layout XLA
-    needs, same move as the ALS degree buckets)."""
+    """Item indexing + packed training rows: each history of two ids or
+    more goes whole into a row of ``seq_len + 1`` slots beside others
+    (first fit, in the order the histories arrive), with the id of its
+    history in every slot — the static-shape layout XLA needs without
+    spending a row on every short history. A history longer than a row
+    is cut into windows ``window_stride`` apart, the last one anchored on
+    its newest ids."""
 
     params_class = SeqPreparatorParams
 
     def __init__(self, params: SeqPreparatorParams = SeqPreparatorParams()):
         self.params = params
 
+    def pack(self, pieces: List[np.ndarray]) -> Tuple[np.ndarray, np.ndarray]:
+        """Indexed histories (each of at most ``seq_len + 1`` ids) -> packed
+        rows and their segment ids."""
+        with span("seqrec.pack", {"histories": len(pieces)}):
+            return pack_first_fit(pieces, self.params.seq_len + 1)
+
     def prepare(self, ctx, td: TrainingData) -> PreparedData:
         L = self.params.seq_len
         item_map = BiMap.string_int(
             [i for seq in td.sequences for i in seq]
         )
-        pad = len(item_map)
-        windows: List[np.ndarray] = []
+        pieces: List[np.ndarray] = []
         user_recent: Dict[str, List[int]] = {}
+        span_ = L + 1
         for uid, seq in zip(td.user_ids, td.sequences):
-            idx = [item_map[i] for i in seq]
-            user_recent[uid] = idx[-L:]
+            idx = np.fromiter((item_map[i] for i in seq), np.int32, len(seq))
+            user_recent[uid] = idx[-L:].tolist()
             if len(idx) < 2:
                 continue
-            span = L + 1
-            starts = list(range(0, max(1, len(idx) - span + 1),
+            starts = list(range(0, max(1, len(idx) - span_ + 1),
                                 self.params.window_stride))
             # anchor a final window on the newest interactions — a stride
             # that doesn't divide the history must not drop the tail
-            if len(idx) > span and starts[-1] != len(idx) - span:
-                starts.append(len(idx) - span)
-            for s in starts:
-                w = idx[s : s + span]
-                if len(w) < span:
-                    w = [pad] * (span - len(w)) + w
-                windows.append(np.asarray(w, dtype=np.int32))
-        if not windows:
+            if len(idx) > span_ and starts[-1] != len(idx) - span_:
+                starts.append(len(idx) - span_)
+            pieces.extend(idx[s: s + span_] for s in starts)
+        if not pieces:
             raise ValueError("No training windows (all histories length < 2)")
+        rows, segs = self.pack(pieces)
         return PreparedData(
-            item_map=item_map,
-            windows=np.stack(windows),
-            user_recent=user_recent,
-            seq_len=L,
+            item_map=item_map, windows=rows, segments=segs,
+            user_recent=user_recent, seq_len=L,
         )
 
 
-# -- transformer ------------------------------------------------------------
+# -- the model and its trainer ---------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class SeqRecAlgorithmParams(Params):
+    #: the backbone configuration: a JSON file (absolute, or relative to
+    #: the engine's directory) or the name of one under ``conf/backbones/``.
+    #: Empty = the shipped preset, sized by the three knobs below.
+    backbone: str = ""
     d_model: int = 64
     n_heads: int = 4
     n_layers: int = 2
     steps: int = 300
+    #: packed rows of seq_len positions in one optimizer step
     batch_size: int = 64
     learning_rate: float = 1e-3
     seed: int = 0
@@ -211,96 +272,36 @@ class SeqRecAlgorithmParams(Params):
     #: or "auto" (ring when the ctx mesh has a seq axis of size > 1)
     schedule: str = "flash"
     #: attention implementation on the single-device path: "xla"
-    #: (default) or "pallas" (fused flash kernel,
-    #: ops.attention.flash_attention_pallas; EXPERIMENTAL until
-    #: hardware-validated — flash_pallas step in the revalidation queue)
+    #: (default: blockwise in XLA, forward and backward) or "pallas" (the
+    #: fused forward kernel, ops.attention.flash_attention_pallas)
     flash_impl: str = "xla"
 
-
-def _init_params(
-    rng: np.random.Generator, vocab: int, p: SeqRecAlgorithmParams,
-    max_positions: int,
-):
-    d = p.d_model
-
-    def w(*shape, scale=None):
-        scale = scale if scale is not None else 1.0 / np.sqrt(shape[0])
-        return (rng.normal(size=shape) * scale).astype(np.float32)
-
-    layers = []
-    for _ in range(p.n_layers):
-        layers.append({
-            "ln1_g": np.ones(d, np.float32), "ln1_b": np.zeros(d, np.float32),
-            "qkv": w(d, 3 * d), "proj": w(d, d),
-            "ln2_g": np.ones(d, np.float32), "ln2_b": np.zeros(d, np.float32),
-            "mlp_in": w(d, 4 * d), "mlp_out": w(4 * d, d),
-        })
-    return {
-        "embed": w(vocab, d, scale=0.02),
-        # sized to the training context (pd.seq_len): no silent cap
-        "pos": w(max_positions, d, scale=0.02),
-        "layers": layers,
-        "lnf_g": np.ones(d, np.float32), "lnf_b": np.zeros(d, np.float32),
-    }
-
-
-def _layer_norm(x, g, b):
-    mu = x.mean(-1, keepdims=True)
-    var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + 1e-6) * g + b
-
-
-def forward(params, tokens, n_heads: int, mesh=None, schedule: str = "flash",
-            flash_impl: str = "xla"):
-    """Causal LM forward: tokens [B, L] int32 → logits [B, L, V]."""
-    b, l = tokens.shape
-    d = params["embed"].shape[1]
-    max_pos = params["pos"].shape[0]
-    if l > max_pos:
-        raise ValueError(
-            f"sequence length {l} exceeds the model's positional table "
-            f"({max_pos} positions — trained with a shorter seq_len)"
-        )
-    h = params["embed"][tokens] + params["pos"][:l][None]
-    dh = d // n_heads
-    for layer in params["layers"]:
-        x = _layer_norm(h, layer["ln1_g"], layer["ln1_b"])
-        qkv = x @ layer["qkv"]  # [B, L, 3D]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-
-        def heads(t):
-            return t.reshape(b, l, n_heads, dh).transpose(0, 2, 1, 3)
-
-        o = attention(
-            heads(q), heads(k), heads(v),
-            mesh=mesh if schedule in ("ring", "ulysses", "auto") else None,
-            causal=True,
-            schedule=schedule if schedule != "flash" else "auto",
-            impl=flash_impl,
-        )
-        o = o.transpose(0, 2, 1, 3).reshape(b, l, d)
-        h = h + o @ layer["proj"]
-        x = _layer_norm(h, layer["ln2_g"], layer["ln2_b"])
-        h = h + jax.nn.gelu(x @ layer["mlp_in"]) @ layer["mlp_out"]
-    h = _layer_norm(h, params["lnf_g"], params["lnf_b"])
-    return h @ params["embed"].T  # tied softmax
+    def backbone_config(self) -> bb.BackboneConfig:
+        if self.backbone:
+            return bb.BackboneConfig.load(self.backbone)
+        return bb.BackboneConfig.toy(self.d_model, self.n_heads, self.n_layers)
 
 
 @dataclasses.dataclass
 class SeqRecModel:
-    """Trained transformer + id maps + per-user recent histories."""
+    """Trained backbone + id maps + per-user recent histories."""
 
     params: dict  # numpy pytree
     item_map: BiMap
     user_recent: Dict[str, List[int]]
     seq_len: int
-    n_heads: int
+    config: bb.BackboneConfig
+    #: the loss of every optimizer step, in order
+    losses: Optional[np.ndarray] = None
+    #: what the job counted: slots filled, tokens per held expert, ...
+    stats: Optional[dict] = None
 
     def sanity_check(self):
-        flat, _ = jax.tree_util.tree_flatten(self.params)
-        for leaf in flat:
-            if not np.isfinite(np.asarray(leaf)).all():
-                raise ValueError("sequencerec produced non-finite weights")
+        # one reduction on the device: no host copy of every leaf
+        leaves = jax.tree_util.tree_leaves(self.device_params())
+        finite = jax.jit(lambda ls: jnp.all(jnp.stack([jnp.isfinite(x).all() for x in ls])))
+        if not bool(finite(leaves)):
+            raise ValueError("sequencerec produced non-finite weights")
 
     def device_params(self):
         """Device-resident weight pytree, uploaded once per model — serving
@@ -318,54 +319,161 @@ class SeqRecModel:
         return state
 
 
+def batch_order(n_rows: int, batch: int, steps: int, seed: int):
+    """Which packed rows each step of a job takes: seeded epochs without
+    replacement, one array of ``batch`` row numbers a step. A function of
+    its arguments alone, so whoever holds the rows can name a job's
+    batches without the trainer keeping them."""
+    rng = np.random.default_rng(seed)
+    order = np.empty(0, np.int64)
+    for _ in range(steps):
+        while len(order) < batch:
+            order = np.concatenate([order, rng.permutation(n_rows)])
+        take, order = order[:batch], order[batch:]
+        yield take
+
+
+class _Batches:
+    """The input pipeline: a thread that takes the rows of each step
+    (:func:`batch_order`), stacks them and puts them on the device while
+    the step before is still running. ``depth`` batches wait at most;
+    ``next()`` is the wait the trainer sees."""
+
+    def __init__(self, pd: PreparedData, batch: int, steps: int, seed: int, depth: int = 2):
+        self._queue: "queue.Queue" = queue.Queue(maxsize=depth)
+        self._stop = threading.Event()
+        self._thread = threading.Thread(
+            target=self._run, args=(pd, batch, steps, seed), daemon=True)
+        self._thread.start()
+
+    def close(self) -> None:
+        """Stop the thread (a trainer that failed mid-job leaves batches
+        undrawn) and wait for it."""
+        self._stop.set()
+        while self._thread.is_alive():
+            try:  # make room for a put that is waiting
+                self._queue.get_nowait()
+            except queue.Empty:
+                pass
+            self._thread.join(timeout=0.05)
+
+    def _run(self, pd, batch, steps, seed):
+        try:
+            for take in batch_order(pd.windows.shape[0], batch, steps, seed):
+                if self._stop.is_set():
+                    return
+                self._queue.put(jax.device_put((pd.windows[take], pd.segments[take])))
+        except BaseException as exc:  # the trainer re-raises it
+            self._queue.put(exc)
+
+    def next(self):
+        item = self._queue.get()
+        if isinstance(item, BaseException):
+            raise item
+        return item
+
+
+def make_loss_and_grad(cfg: bb.BackboneConfig, mesh=None, schedule: str = "auto",
+                       impl: str = "xla"):
+    """``(params, rows, segs) -> ((loss, (hidden, counters, ran)), grads)``
+    (``seq_backbone.loss_fn``): the function the optimizer step is built
+    from, jitted."""
+    return jax.jit(jax.value_and_grad(
+        lambda mp, rows, segs: bb.loss_fn(cfg, mp, rows, segs, mesh, schedule, impl),
+        has_aux=True))
+
+
+@functools.lru_cache(maxsize=8)
+def _programs(cfg: bb.BackboneConfig, learning_rate: float, mesh, schedule: str, impl: str):
+    """The jitted programs of a job, made once per configuration: a second
+    job of the same shape compiles nothing."""
+    import optax
+
+    opt = optax.adamw(learning_rate)
+    loss_and_grad = make_loss_and_grad(cfg, mesh, schedule, impl)
+
+    def step(mp, os_, rows, segs):
+        (loss, (_, counters, _)), grads = loss_and_grad(mp, rows, segs)
+        with jax.named_scope("seq.optimizer"):
+            updates, os_ = opt.update(grads, os_, mp)
+            mp = optax.apply_updates(mp, updates)
+        return mp, os_, loss, counters
+
+    return jax.jit(opt.init), jax.jit(step, donate_argnums=(0, 1)), loss_and_grad
+
+
 class SeqRecAlgorithm(Algorithm):
-    """Causal-transformer next-item trainer (optax AdamW)."""
+    """Next-item trainer over packed histories (optax AdamW)."""
 
     params_class = SeqRecAlgorithmParams
 
     def __init__(self, params: SeqRecAlgorithmParams = SeqRecAlgorithmParams()):
         self.params = params
 
+    def programs(self, cfg: bb.BackboneConfig):
+        """The jitted programs of this algorithm's single-device job, the
+        objects ``train`` runs and no copies: the optimizer's ``init``
+        (params -> state), the donated ``step`` ((params, state, rows,
+        segs) -> params, state, loss, counters) and the loss-and-gradient
+        function the step is built from (:func:`make_loss_and_grad`)."""
+        return _programs(cfg, self.params.learning_rate, None, "auto", self.params.flash_impl)
+
     def train(self, ctx, pd: PreparedData) -> SeqRecModel:
-        import optax
-
         p = self.params
-        vocab = len(pd.item_map) + 1  # + PAD
-        pad_id = pd.pad_id
-        rng = np.random.default_rng(p.seed)
-        model_params = jax.tree_util.tree_map(
-            jnp.asarray, _init_params(rng, vocab, p, max_positions=pd.seq_len)
-        )
+        cfg = p.backbone_config()
+        tags = {"backbone": p.backbone or "toy", "steps": p.steps,
+                "layers": cfg.num_hidden_layers}
+        # the job's root span: under no server it starts a trace of its own
+        with span("train", tags):
+            return self._train(ctx, pd, cfg)
+
+    def _train(self, ctx, pd: PreparedData, cfg: bb.BackboneConfig) -> SeqRecModel:
+        p = self.params
         mesh = ctx.mesh if (ctx is not None and p.schedule != "flash") else None
+        schedule = p.schedule if p.schedule != "flash" else "auto"
+        batch = min(p.batch_size, pd.windows.shape[0])
+        batches = _Batches(pd, batch, p.steps, p.seed)
+        try:
+            return self._run_steps(pd, cfg, batches, batch, mesh, schedule)
+        finally:
+            batches.close()
 
-        opt = optax.adamw(p.learning_rate)
-        opt_state = opt.init(model_params)
-
-        def loss_fn(mp, batch):
-            inp, tgt = batch[:, :-1], batch[:, 1:]
-            logits = forward(mp, inp, p.n_heads, mesh, p.schedule,
-                             flash_impl=p.flash_impl)
-            mask = (tgt != pad_id).astype(jnp.float32)
-            ll = optax.softmax_cross_entropy_with_integer_labels(logits, tgt)
-            return (ll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-
-        @jax.jit
-        def step(mp, os_, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(mp, batch)
-            updates, os_ = opt.update(grads, os_, mp)
-            return optax.apply_updates(mp, updates), os_, loss
-
-        n = pd.windows.shape[0]
+    def _run_steps(self, pd, cfg, batches, batch, mesh, schedule) -> SeqRecModel:
+        p = self.params
+        vocab = len(pd.item_map)
+        opt_init, step, _ = _programs(cfg, p.learning_rate, mesh, schedule, p.flash_impl)
+        with span("seqrec.init"):
+            model_params = bb.init_params(cfg, vocab, pd.seq_len, p.seed)
+            opt_state = opt_init(model_params)
+        losses, loads, counters, before = [], [], None, None
         for i in range(p.steps):
-            take = rng.integers(0, n, size=min(p.batch_size, n))
-            batch = jnp.asarray(pd.windows[take])
-            model_params, opt_state, loss = step(model_params, opt_state, batch)
+            with span("seqrec.input", {"i": i}):
+                rows, segs = batches.next()
+            with span("seqrec.step", {"i": i}):
+                model_params, opt_state, loss, counters = step(
+                    model_params, opt_state, rows, segs)
+                losses.append(loss)
+                if "expert_tokens" in counters:
+                    loads.append(counters["expert_tokens"])
+                # one step behind: the device already has step i when the
+                # host waits for step i - 1, so the span is a step long and
+                # the device is never left waiting for the host
+                if before is not None:
+                    jax.block_until_ready(before)
+                before = loss
+        with span("train.wait_device"):
+            jax.block_until_ready(model_params)
+        stats = {"fill": pd.fill, "steps": p.steps, "tokens_per_step": batch * pd.seq_len}
+        if counters:
+            stats.update(jax.tree_util.tree_map(np.asarray, counters))
+        with span("train.fetch"):
+            host_params = jax.tree_util.tree_map(np.asarray, model_params)
+            host_losses = np.asarray(jax.device_get(losses), np.float32)
+            if loads:  # [steps, periods, layers of a period, held experts]
+                stats["expert_tokens_by_step"] = np.stack(jax.device_get(loads))
         return SeqRecModel(
-            params=jax.tree_util.tree_map(np.asarray, model_params),
-            item_map=pd.item_map,
-            user_recent=pd.user_recent,
-            seq_len=pd.seq_len,
-            n_heads=p.n_heads,
+            params=host_params, item_map=pd.item_map, user_recent=pd.user_recent,
+            seq_len=pd.seq_len, config=cfg, losses=host_losses, stats=stats,
         )
 
     # -- serving ----------------------------------------------------------
@@ -385,22 +493,21 @@ class SeqRecAlgorithm(Algorithm):
         recent = self._tokens_for(model, query)
         if not recent:
             return PredictedResult(item_scores=())
-        pad_id = len(model.item_map)
-        # left-pad to the training context length: one compiled shape for
-        # every query (the serving-cache move the scoring kernels also make)
-        seq = [pad_id] * (model.seq_len - len(recent)) + list(recent)
-        tokens = jnp.asarray(np.asarray(seq, np.int32)[None, :], jnp.int32)
-        logits = forward(
-            model.device_params(), tokens, model.n_heads,
-            flash_impl=self.params.flash_impl,
-        )[0, -1]
-        # Next-item prediction keeps previously-seen items eligible (Markov
-        # semantics: the next state may be a revisit) — only PAD is masked.
-        # Top-k on device: no full-catalog sort on the serving hot path.
+        # the window is encoded anew for every query, left-padded to the
+        # training context length: one compiled shape for every query. The
+        # padding is a history of its own (id 0), which the recent items
+        # neither attend to nor inherit state from.
+        pad = model.seq_len - len(recent)
+        tokens = np.asarray([0] * pad + list(recent), np.int32)[None, :]
+        seg = np.asarray([0] * pad + [1] * len(recent), np.int32)[None, :]
         k = min(query.num, len(model.item_map))
-        scores = jax.nn.log_softmax(logits).at[pad_id].set(-jnp.inf)
-        top_s, top_i = jax.lax.top_k(scores, k)
-        top_s, top_i = jax.device_get((top_s, top_i))  # one round trip
+        top_s, top_i = _encode_and_select(
+            model.config, self.params.flash_impl, k,
+            model.device_params(), jnp.asarray(tokens), jnp.asarray(seg))
+        # Next-item prediction keeps previously-seen items eligible (Markov
+        # semantics: the next state may be a revisit). Scores are logits;
+        # score-and-select on the device, one round trip.
+        top_s, top_i = jax.device_get((top_s[0], top_i[0]))
         return PredictedResult(
             item_scores=tuple(
                 ItemScore(item=model.item_map.inverse[int(i)], score=float(s))
@@ -411,6 +518,13 @@ class SeqRecAlgorithm(Algorithm):
 
     def query_class(self):
         return Query
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _encode_and_select(cfg: bb.BackboneConfig, impl: str, k: int, params, tokens, seg):
+    hidden, *_ = bb.hidden_states(cfg, params, tokens, seg, impl=impl)
+    last = bb._norm(cfg, params["final_norm"], hidden[:, -1])
+    return top_k_for_vectors(last, bb.head_of(params), k)
 
 
 def engine_factory() -> Engine:

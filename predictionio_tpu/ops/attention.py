@@ -10,8 +10,11 @@ train over context windows sharded across the mesh ``seq`` axis.
 Three schedules, one math:
 
 - :func:`flash_attention` — single-device blockwise attention with an online
-  softmax (``lax.scan`` over KV blocks): O(block²) memory instead of O(L²),
-  XLA fuses the inner matmuls onto the MXU.
+  softmax over the (Q block, KV block) pairs that can hold a kept score:
+  O(block²) memory instead of O(L²) forward and backward (a flash-style
+  custom VJP), grouped key/value heads, a segment mask for packed rows.
+  :func:`flash_attention_pallas` is a fused forward kernel for equal head
+  counts; the XLA path is the default and the faster on the v5e.
 - :func:`ring_attention` — sequence parallelism over a mesh axis: every
   device keeps its Q chunk, KV chunks rotate around the ring via
   ``ppermute`` (ICI neighbor exchanges), partial results merge with the same
@@ -63,19 +66,22 @@ def _attend_block(q, k, v, m, l, o, mask, scale):
     return m_new, l_new, o_new
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, blk_q, blk_k, lk,
-                  causal, scale, n_kv):
+def _flash_kernel(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, *, blk_q, blk_k,
+                  lk, causal, scale, n_kv):
     """One (batch·head, Q-block) grid step: online softmax over KV blocks.
 
     Everything lives in VMEM: q block [blk_q, D], full K/V [Lk_pad, D]
     (fetched once per batch·head — the Q-block grid dim is innermost and
     their index map is constant in it), score tiles [blk_q, blk_k] that
     never touch HBM — the O(L²) score matrix is the thing this kernel
-    exists to not materialize.
+    exists to not materialize. ``sq_ref`` [blk_q, 1] and ``sk_ref``
+    [1, Lk_pad] hold the slots' history ids: a score between two histories
+    is masked.
     """
     i = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * scale  # [blk_q, D]
     d = q.shape[-1]
+    q_seg = sq_ref[0]  # [blk_q, 1]
     q_pos = i * blk_q + jax.lax.broadcasted_iota(
         jnp.int32, (blk_q, blk_k), 0
     )
@@ -91,13 +97,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, blk_q, blk_k, lk,
         k_pos = j * blk_k + jax.lax.broadcasted_iota(
             jnp.int32, (blk_q, blk_k), 1
         )
-        keep = k_pos < lk
+        # pio: lint-ok[mosaic-unaligned-lane-slice] blk_k is a static param the AST cannot resolve; on the chip the wrapper's blocks are 256 (a multiple of 128; smaller blocks run in interpret mode only), so j*blk_k offsets and blk_k sizes are lane-aligned (compiled in tests/test_chip_compile.py)
+        keep = (k_pos < lk) & (q_seg == sk_ref[0, :, pl.ds(j * blk_k, blk_k)])
         if causal:
             keep = keep & (q_pos >= k_pos)
         s = jnp.where(keep, s, _NEG_BIG)
         m_new = jnp.maximum(m, s.max(axis=1))
         corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[:, None])
+        # a tile may hold none of a row's history: its masked scores must
+        # add nothing while the row's running maximum is still the floor
+        p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
         l_new = l * corr + p.sum(axis=1)
         o_new = o * corr[:, None] + jax.lax.dot_general(
             p, vj, (((1,), (0,)), ((), ())),
@@ -121,7 +130,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, blk_q, blk_k, lk,
 @functools.partial(
     jax.jit, static_argnames=("causal", "blk_q", "blk_k", "interpret")
 )
-def _flash_pallas_call(q, k, v, causal, blk_q, blk_k, interpret):
+def _flash_pallas_call(q, k, v, seg_q, seg_k, causal, blk_q, blk_k, interpret):
     b, h, lq, d = q.shape
     lk = k.shape[2]
     lq_pad = -lq % blk_q
@@ -146,37 +155,51 @@ def _flash_pallas_call(q, k, v, causal, blk_q, blk_k, interpret):
             pl.BlockSpec((1, blk_q, d), lambda bhi, i: (bhi, i, 0)),
             pl.BlockSpec((1, lk + lk_pad, d), lambda bhi, i: (bhi, 0, 0)),
             pl.BlockSpec((1, lk + lk_pad, d), lambda bhi, i: (bhi, 0, 0)),
+            # pio: lint-ok[mosaic-blockspec-tiling] a block dim equal to the array's own dim (1) is allowed: one id a query row, broadcast along lanes in the kernel
+            pl.BlockSpec((1, blk_q, 1), lambda bhi, i: (bhi // h, i, 0)),
+            # pio: lint-ok[mosaic-blockspec-tiling] sublane dim 1 is the array's own dim: the row of key ids, broadcast along sublanes
+            pl.BlockSpec((1, 1, lk + lk_pad), lambda bhi, i: (bhi // h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, blk_q, d), lambda bhi, i: (bhi, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, lq + lq_pad, d), q.dtype),
         interpret=interpret,
-    )(qr, kr, vr)
+    )(qr, kr, vr,
+      jnp.pad(seg_q, ((0, 0), (0, lq_pad)), mode="edge")[:, :, None],
+      jnp.pad(seg_k, ((0, 0), (0, lk_pad)), mode="edge")[:, None, :])
     return out.reshape(b, h, lq + lq_pad, d)[:, :, :lq]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_pallas_diff(q, k, v, causal, blk_q, blk_k, interpret):
-    return _flash_pallas_call(q, k, v, causal, blk_q, blk_k, interpret)
+def _flash_pallas_run(q, k, v, seg, causal, blk_q, blk_k, interpret):
+    """``seg``: the rows' history ids [B, L] (Lq == Lk), or None."""
+    b, lq, lk = q.shape[0], q.shape[2], k.shape[2]
+    seg_q, seg_k = (jnp.zeros((b, n), jnp.int32) if seg is None else seg for n in (lq, lk))
+    return _flash_pallas_call(q, k, v, seg_q, seg_k, causal, blk_q, blk_k, interpret)
 
 
-def _flash_pallas_fwd(q, k, v, causal, blk_q, blk_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_pallas_diff(q, k, v, seg, causal, blk_q, blk_k, interpret):
+    return _flash_pallas_run(q, k, v, seg, causal, blk_q, blk_k, interpret)
+
+
+def _flash_pallas_fwd(q, k, v, seg, causal, blk_q, blk_k, interpret):
     # flash-style backward: save only q/k/v and recompute attention in
     # the VJP (the O(L²) score matrix is never a residual) — here the
-    # recompute runs through the XLA online-softmax path, whose autodiff
-    # is the reference math the kernel is equality-tested against
+    # recompute runs through the XLA path, whose own backward pass is the
+    # reference math the kernel is equality-tested against
     return (
-        _flash_pallas_call(q, k, v, causal, blk_q, blk_k, interpret),
-        (q, k, v),
+        _flash_pallas_run(q, k, v, seg, causal, blk_q, blk_k, interpret),
+        (q, k, v, seg),
     )
 
 
 def _flash_pallas_bwd(causal, blk_q, blk_k, interpret, res, g):
-    q, k, v = res
+    q, k, v, seg = res
     _, vjp = jax.vjp(
-        lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=causal),
+        lambda q_, k_, v_: flash_attention(
+            q_, k_, v_, causal=causal, segment_ids=seg),
         q, k, v,
     )
-    return vjp(g)
+    return vjp(g) + (None,)
 
 
 _flash_pallas_diff.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
@@ -190,79 +213,248 @@ def flash_attention_pallas(
     block_q: int = 256,
     block_k: int = 256,
     interpret: Optional[bool] = None,
+    segment_ids: Optional[jax.Array] = None,  # [B, L]: packed rows, Lq == Lk
 ) -> jax.Array:
     """Pallas flash attention: fused scores+softmax+PV per Q block, causal
-    upper-triangle KV blocks skipped entirely. K/V are VMEM-resident per
-    batch·head, so this single-device kernel targets L up to the VMEM
-    budget (~16k at D=64); beyond that, shard the sequence (ring/Ulysses
-    — which is the framework's long-context answer anyway).
+    upper-triangle KV blocks skipped entirely, a segment mask for packed
+    rows. K/V are VMEM-resident per batch·head, so this single-device
+    kernel targets L up to the VMEM budget (~16k at D=64); beyond that,
+    shard the sequence (ring/Ulysses — which is the framework's
+    long-context answer anyway). As many key/value heads as query heads:
+    grouped heads are the XLA path's.
 
     Differentiable: a custom VJP recomputes attention through the XLA
-    online-softmax path in the backward pass (flash-style — only q/k/v
-    are residuals, never the score matrix), so training through this
-    kernel is supported.
+    path in the backward pass (flash-style — only q/k/v are residuals,
+    never the score matrix), so training through this kernel is supported.
 
-    EXPERIMENTAL: selected via ``attention(..., impl="pallas")`` /
-    ``flash_impl`` in sequencerec params, XLA path remains the default
-    until the Mosaic lowering is hardware-validated (``flash_pallas``
-    step in the revalidation queue). ``interpret=None`` auto-selects the
-    interpreter off-TPU.
+    Selected via ``attention(..., impl="pallas")`` / ``flash_impl`` in the
+    sequencerec params. The XLA path is the default: on the v5e, at two
+    rows of 8,192 slots, it took 39.6 ms forward and backward against 51.7
+    (``PERF.md``, PR 26), so this kernel stays what it was plus the mask.
+    ``interpret=None`` auto-selects the interpreter off-TPU.
     """
+    if k.shape[1] != q.shape[1]:
+        raise ValueError(
+            f"the Pallas kernel takes one key/value head a query head, not {k.shape[1]} "
+            f"for {q.shape[1]}: grouped heads run on the XLA path (impl=\"xla\")")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     lq, lk = q.shape[2], k.shape[2]
+    seg = None if segment_ids is None else segment_ids.astype(jnp.int32)
     return _flash_pallas_diff(
-        q, k, v, causal, min(block_q, max(8, lq)), min(block_k, lk),
+        q, k, v, seg, causal, min(block_q, max(8, lq)), min(block_k, lk),
         interpret,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_k"))
+def _grouped_and_padded(q, k, v, segment_ids, bq: int, bk: int):
+    """What both single-device implementations take: q as [B, Hkv, G, Lq,
+    D] and k, v padded to whole blocks, and the rows' history ids (all one
+    history without ``segment_ids``), unpadded: the tiles mask by position."""
+    b, h, lq, d = q.shape
+    hkv, lk = k.shape[1], k.shape[2]
+    if h % hkv:
+        raise ValueError(f"{h} query heads do not divide over {hkv} key/value heads")
+    if segment_ids is None:
+        seg_q = jnp.zeros((b, lq), jnp.int32)
+        seg_k = jnp.zeros((b, lk), jnp.int32)
+    else:
+        seg_q = seg_k = segment_ids.astype(jnp.int32)
+    qg = q.reshape(b, hkv, h // hkv, lq, d)
+    pad_q, pad_k = -lq % bq, -lk % bk
+    if pad_q:
+        qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, pad_q), (0, 0)))
+    if pad_k:
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+    return qg, k, v, seg_q, seg_k
+
+
+def _block_pairs(lq: int, lk: int, bq: int, bk: int, causal: bool):
+    """Index arrays (i, j) of every Q block i and KV block j that can hold
+    a kept score: all of them, or under a causal mask those on or below the
+    diagonal."""
+    pairs = [
+        (i, j) for i in range(lq // bq) for j in range(lk // bk)
+        if not causal or j * bk <= (i + 1) * bq - 1
+    ]
+    return (jnp.asarray([p[0] for p in pairs], jnp.int32),
+            jnp.asarray([p[1] for p in pairs], jnp.int32))
+
+
+def _opaque(n: int):
+    """A trip count the compiler cannot read: a loop it can count it may
+    run as a scan that keeps every iteration's tiles."""
+    return jax.lax.optimization_barrier(jnp.int32(n))
+
+
+def _pair_meets(seg_q, seg_k, i, j, bq, bk):
+    """Whether any slot of Q block i can share a history with a slot of
+    KV block j: their id ranges meet. Where they do not, the whole tile
+    is masked and is skipped."""
+    sq = jax.lax.dynamic_slice_in_dim(seg_q, i * bq, bq, axis=1)
+    sk = jax.lax.dynamic_slice_in_dim(seg_k, j * bk, bk, axis=1)
+    return jnp.any((sq.max(1) >= sk.min(1)) & (sq.min(1) <= sk.max(1)))
+
+
+def _pair_tile(q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, after):
+    """Scores of Q block i against KV block j and the mask of those kept.
+    q [B, Hkv, G, Lq, D], k [B, Hkv, Lk, D], seg [B, L]. ``after`` is a
+    value of this iteration's carry: the barrier makes the tile wait for
+    it, or the compiler computes every pair's tile ahead of the loop and
+    keeps them all ([pairs, B, H, bq, bk] float32, gigabytes at 8k)."""
+    qi = jax.lax.dynamic_slice_in_dim(q, i * bq, bq, axis=3)
+    kj = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, axis=2)
+    qi, kj, after = jax.lax.optimization_barrier((qi, kj, after))
+    s = jnp.einsum("bkgqd,bkcd->bkgqc", qi, kj,
+                   preferred_element_type=jnp.float32) * scale
+    q_pos = i * bq + jnp.arange(bq)
+    k_pos = j * bk + jnp.arange(bk)
+    keep = jnp.broadcast_to(k_pos[None, :] < lk, (bq, bk))
+    if causal:
+        keep = keep & (q_pos[:, None] >= k_pos[None, :])
+    sq = jax.lax.dynamic_slice_in_dim(seg_q, i * bq, bq, axis=1)
+    sk = jax.lax.dynamic_slice_in_dim(seg_k, j * bk, bk, axis=1)
+    keep = keep[None] & (sq[:, :, None] == sk[:, None, :])  # [B, bq, bk]
+    return qi, kj, s, keep[:, None, None], after
+
+
+def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk):
+    """Online softmax over the block pairs; returns o (q's dtype) and the
+    log-sum-exp of every row [B, Hkv, G, Lq] (float32)."""
+    b, hkv, g, lq, d = q.shape
+    scale = 1.0 / np.sqrt(d)
+    ii, jj = _block_pairs(lq, k.shape[2], bq, bk, causal)
+
+    def body(t, carry):
+        i, j = ii[t], jj[t]
+
+        def attend(carry):
+            m, l, o = carry
+            mi = jax.lax.dynamic_slice_in_dim(m, i * bq, bq, axis=3)
+            qi, kj, s, keep, mi = _pair_tile(
+                q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, mi)
+            li = jax.lax.dynamic_slice_in_dim(l, i * bq, bq, axis=3)
+            oi = jax.lax.dynamic_slice_in_dim(o, i * bq, bq, axis=3)
+            vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=2)
+            sm = jnp.where(keep, s, _NEG_BIG)
+            m_new = jnp.maximum(mi, sm.max(-1))
+            corr = jnp.exp(mi - m_new)
+            p = jnp.where(keep, jnp.exp(sm - m_new[..., None]), 0.0)
+            l_new = li * corr + p.sum(-1)
+            o_new = oi * corr[..., None] + jnp.einsum(
+                "bkgqc,bkcd->bkgqd", p.astype(v.dtype), vj,
+                preferred_element_type=jnp.float32)
+            return (
+                jax.lax.dynamic_update_slice_in_dim(m, m_new, i * bq, axis=3),
+                jax.lax.dynamic_update_slice_in_dim(l, l_new, i * bq, axis=3),
+                jax.lax.dynamic_update_slice_in_dim(o, o_new, i * bq, axis=3),
+            )
+
+        meet = _pair_meets(seg_q, seg_k, i, j, bq, bk)
+        return jax.lax.cond(meet, attend, lambda c: c, carry)
+
+    m0 = jnp.full((b, hkv, g, lq), _NEG_BIG, jnp.float32)
+    l0 = jnp.zeros((b, hkv, g, lq), jnp.float32)
+    o0 = jnp.zeros((b, hkv, g, lq, d), jnp.float32)
+    m, l, o = jax.lax.fori_loop(0, _opaque(len(ii)), body, (m0, l0, o0))
+    l = jnp.maximum(l, 1e-30)
+    return (o / l[..., None]).astype(q.dtype), m + jnp.log(l)
+
+
+def _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk):
+    """The flash backward pass: scores are recomputed tile by tile from q,
+    k and the saved log-sum-exp; the score matrix is never a residual."""
+    b, hkv, g, lq, d = q.shape
+    scale = 1.0 / np.sqrt(d)
+    ii, jj = _block_pairs(lq, k.shape[2], bq, bk, causal)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+
+    def body(t, carry):
+        i, j = ii[t], jj[t]
+
+        def attend(carry):
+            dq, dk, dv = carry
+            dq_old = jax.lax.dynamic_slice_in_dim(dq, i * bq, bq, axis=3)
+            qi, kj, s, keep, dq_old = _pair_tile(
+                q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, dq_old)
+            vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=2)
+            doi = jax.lax.dynamic_slice_in_dim(do, i * bq, bq, axis=3)
+            lsei = jax.lax.dynamic_slice_in_dim(lse, i * bq, bq, axis=3)
+            di = jax.lax.dynamic_slice_in_dim(delta, i * bq, bq, axis=3)
+            p = jnp.where(keep, jnp.exp(s - lsei[..., None]), 0.0)
+            dvj = jnp.einsum("bkgqc,bkgqd->bkcd", p.astype(do.dtype), doi,
+                             preferred_element_type=jnp.float32)
+            dp = jnp.einsum("bkgqd,bkcd->bkgqc", doi, vj,
+                            preferred_element_type=jnp.float32)
+            ds = (p * (dp - di[..., None]) * scale).astype(q.dtype)
+            dqi = jnp.einsum("bkgqc,bkcd->bkgqd", ds, kj,
+                             preferred_element_type=jnp.float32)
+            dkj = jnp.einsum("bkgqc,bkgqd->bkcd", ds, qi,
+                             preferred_element_type=jnp.float32)
+
+            def add(buf, blk, at, axis):
+                old = jax.lax.dynamic_slice_in_dim(buf, at, blk.shape[axis], axis)
+                return jax.lax.dynamic_update_slice_in_dim(buf, old + blk, at, axis)
+
+            dq = jax.lax.dynamic_update_slice_in_dim(dq, dq_old + dqi, i * bq, 3)
+            return dq, add(dk, dkj, j * bk, 2), add(dv, dvj, j * bk, 2)
+
+        meet = _pair_meets(seg_q, seg_k, i, j, bq, bk)
+        return jax.lax.cond(meet, attend, lambda c: c, carry)
+
+    zeros = (jnp.zeros(q.shape, jnp.float32), jnp.zeros(k.shape, jnp.float32),
+             jnp.zeros(v.shape, jnp.float32))
+    dq, dk, dv = jax.lax.fori_loop(0, _opaque(len(ii)), body, zeros)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash(q, k, v, seg_q, seg_k, causal, bq, bk, lk):
+    return _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk)[0]
+
+
+def _flash_vjp_fwd(q, k, v, seg_q, seg_k, causal, bq, bk, lk):
+    o, lse = _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk)
+    return o, (q, k, v, seg_q, seg_k, o, lse)
+
+
+def _flash_vjp_bwd(causal, bq, bk, lk, res, do):
+    q, k, v, seg_q, seg_k, o, lse = res
+    dq, dk, dv = _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk)
+    return dq, dk, dv, None, None
+
+
+_flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "block_k", "block_q"))
 def flash_attention(
-    q: jax.Array,  # [B, H, L, D]
-    k: jax.Array,  # [B, H, L, D]
-    v: jax.Array,  # [B, H, L, D]
+    q: jax.Array,  # [B, H, Lq, D]
+    k: jax.Array,  # [B, Hkv, Lk, D], H a multiple of Hkv
+    v: jax.Array,  # [B, Hkv, Lk, D]
     causal: bool = True,
     block_k: int = 512,
+    segment_ids: Optional[jax.Array] = None,  # [B, L]: packed rows, Lq == Lk
+    block_q: Optional[int] = None,
 ) -> jax.Array:
-    """Blockwise attention with online softmax (single device)."""
+    """Blockwise attention with online softmax (single device).
+
+    Query heads share key/value heads in groups (``H / Hkv`` each). With
+    ``segment_ids`` a slot attends only to slots of its own id (histories
+    packed into one row); tiles whose id ranges do not meet are skipped,
+    as are, under ``causal``, the tiles above the diagonal. Differentiable:
+    the backward pass recomputes each tile from q, k and the rows'
+    log-sum-exp, so neither direction holds an [L, L] matrix."""
     b, h, lq, d = q.shape
     lk = k.shape[2]
-    scale = 1.0 / np.sqrt(d)
-    blk = min(block_k, lk)
-    n_blocks = (lk + blk - 1) // blk
-    pad = n_blocks * blk - lk
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
-
-    q_pos = jnp.arange(lq)
-    kb = k.reshape(b, h, n_blocks, blk, d).transpose(2, 0, 1, 3, 4)
-    vb = v.reshape(b, h, n_blocks, blk, d).transpose(2, 0, 1, 3, 4)
-
-    qf = q.astype(jnp.float32)
-
-    def step(carry, inputs):
-        m, l, o = carry
-        (j, kj, vj) = inputs
-        k_pos = j * blk + jnp.arange(blk)
-        valid = k_pos < lk  # padded keys masked out
-        if causal:
-            mask = (q_pos[:, None] >= k_pos[None, :]) & valid[None, :]
-        else:
-            mask = jnp.broadcast_to(valid[None, :], (lq, blk))
-        m, l, o = _attend_block(
-            qf, kj.astype(jnp.float32), vj, m, l, o, mask, scale
-        )
-        return (m, l, o), None
-
-    m0 = jnp.full((b, h, lq), _NEG_BIG, dtype=jnp.float32)
-    l0 = jnp.zeros((b, h, lq), dtype=jnp.float32)
-    o0 = jnp.zeros((b, h, lq, d), dtype=jnp.float32)
-    (m, l, o), _ = jax.lax.scan(
-        step, (m0, l0, o0), (jnp.arange(n_blocks), kb, vb)
-    )
-    return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+    bk = min(block_k, lk)
+    bq = min(block_q or block_k, lq)
+    qg, k, v, seg_q, seg_k = _grouped_and_padded(q, k, v, segment_ids, bq, bk)
+    seg_q = jnp.pad(seg_q, ((0, 0), (0, -lq % bq)), mode="edge")
+    seg_k = jnp.pad(seg_k, ((0, 0), (0, -lk % bk)), mode="edge")
+    o = _flash(qg, k, v, seg_q, seg_k, causal, bq, bk, lk)
+    return o[:, :, :, :lq].reshape(b, h, lq, d)
 
 
 def ring_attention(
@@ -272,8 +464,11 @@ def ring_attention(
     mesh: Mesh,
     axis: str = SEQ_AXIS,
     causal: bool = True,
+    segment_ids: Optional[jax.Array] = None,  # [B, L], sharded like L
 ) -> jax.Array:
-    """Sequence-parallel attention: KV chunks rotate around the mesh ring.
+    """Sequence-parallel attention: KV chunks rotate around the mesh ring
+    (with ``segment_ids`` their ids rotate with them, and a slot attends
+    only to slots of its own id).
 
     Inputs/outputs are length-sharded over ``axis`` (chunk i on device i,
     contiguous order). Each of the N ring steps attends the local Q chunk to
@@ -287,39 +482,45 @@ def ring_attention(
     chunk = l // n
     scale = 1.0 / np.sqrt(d)
 
-    def local(qc, kc, vc):
-        # qc/kc/vc: [B, H, chunk, D] local shards
+    if segment_ids is None:
+        segment_ids = jnp.zeros((b, l), jnp.int32)
+
+    def local(qc, kc, vc, sq):
+        # qc/kc/vc: [B, H, chunk, D] local shards; sq [B, chunk]
         my = jax.lax.axis_index(axis)
         q_pos = my * chunk + jnp.arange(chunk)
         qf = qc.astype(jnp.float32)
 
         def step(s, carry):
-            m, l_, o, kc_, vc_ = carry
+            m, l_, o, kc_, vc_, sk_ = carry
             src = (my - s) % n  # owner of the currently-visiting KV chunk
             k_pos = src * chunk + jnp.arange(chunk)
-            mask = (q_pos[:, None] >= k_pos[None, :]) if causal else None
+            mask = (sq[:, :, None] == sk_[:, None, :])[:, None]  # [B, 1, q, k]
+            if causal:
+                mask = mask & (q_pos[:, None] >= k_pos[None, :])
             m, l_, o = _attend_block(
                 qf, kc_.astype(jnp.float32), vc_, m, l_, o, mask, scale,
             )
             perm = [(i, (i + 1) % n) for i in range(n)]
             kc_ = jax.lax.ppermute(kc_, axis, perm)
             vc_ = jax.lax.ppermute(vc_, axis, perm)
-            return m, l_, o, kc_, vc_
+            sk_ = jax.lax.ppermute(sk_, axis, perm)
+            return m, l_, o, kc_, vc_, sk_
 
         m0 = jnp.full((b, h, chunk), _NEG_BIG, dtype=jnp.float32)
         l0 = jnp.zeros((b, h, chunk), dtype=jnp.float32)
         o0 = jnp.zeros((b, h, chunk, d), dtype=jnp.float32)
-        m, l_, o, _, _ = jax.lax.fori_loop(
-            0, n, step, (m0, l0, o0, kc, vc)
+        m, l_, o, _, _, _ = jax.lax.fori_loop(
+            0, n, step, (m0, l0, o0, kc, vc, sq)
         )
         return (o / jnp.maximum(l_, 1e-30)[..., None]).astype(qc.dtype)
 
     spec = P(None, None, axis, None)
     f = shard_map(
-        local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False,
+        local, mesh=mesh, in_specs=(spec, spec, spec, P(None, axis)),
+        out_specs=spec, check_vma=False,
     )
-    return jax.jit(f)(q, k, v)
+    return jax.jit(f)(q, k, v, segment_ids.astype(jnp.int32))
 
 
 def ulysses_attention(
@@ -329,6 +530,7 @@ def ulysses_attention(
     mesh: Mesh,
     axis: str = SEQ_AXIS,
     causal: bool = True,
+    segment_ids: Optional[jax.Array] = None,  # [B, L], sharded like L
 ) -> jax.Array:
     """All-to-all sequence parallelism (DeepSpeed-Ulysses schedule):
     reshard seq→heads, full-sequence attention per head subset, reshard
@@ -338,7 +540,10 @@ def ulysses_attention(
     assert h % n == 0, f"{h} heads not divisible by {n} devices"
     assert l % n == 0, f"sequence length {l} not divisible by {n} devices"
 
-    def local(qc, kc, vc):
+    if segment_ids is None:
+        segment_ids = jnp.zeros((b, l), jnp.int32)
+
+    def local(qc, kc, vc, sc):
         # [B, H, L/N, D] → all-to-all → [B, H/N, L, D]
         def a2a_in(x):
             return jax.lax.all_to_all(
@@ -351,40 +556,49 @@ def ulysses_attention(
             )
 
         qh, kh, vh = a2a_in(qc), a2a_in(kc), a2a_in(vc)
-        oh = flash_attention(qh, kh, vh, causal=causal)
+        seg = jax.lax.all_gather(sc, axis, axis=1, tiled=True)  # the whole row's ids
+        oh = flash_attention(qh, kh, vh, causal=causal, segment_ids=seg)
         return a2a_out(oh)
 
     spec = P(None, None, axis, None)
     f = shard_map(
-        local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False,
+        local, mesh=mesh, in_specs=(spec, spec, spec, P(None, axis)),
+        out_specs=spec, check_vma=False,
     )
-    return jax.jit(f)(q, k, v)
+    return jax.jit(f)(q, k, v, segment_ids.astype(jnp.int32))
 
 
 def attention(
-    q: jax.Array,
-    k: jax.Array,
+    q: jax.Array,  # [B, H, L, D]
+    k: jax.Array,  # [B, Hkv, L, D]
     v: jax.Array,
     mesh: Optional[Mesh] = None,
     axis: str = SEQ_AXIS,
     causal: bool = True,
     schedule: str = "auto",
     impl: str = "xla",
+    segment_ids: Optional[jax.Array] = None,
+    block: int = 512,
 ) -> jax.Array:
     """Dispatch: single-device flash when no mesh / 1-device axis; otherwise
     ring (default) or Ulysses (``schedule="ulysses"``, when heads divide).
-    ``impl="pallas"`` selects the fused single-device kernel
-    (:func:`flash_attention_pallas`; experimental, hardware-gated) —
-    sharded schedules keep the XLA inner step for now."""
+    ``impl="pallas"`` takes the fused forward kernel
+    (:func:`flash_attention_pallas`) on the single-device path; the
+    sharded schedules keep the XLA inner step, and repeat grouped
+    key/value heads to one per query head."""
     if impl not in ("xla", "pallas"):
         raise ValueError(f"unknown attention impl {impl!r}")
     if mesh is None or axis not in mesh.shape or mesh.shape[axis] == 1:
         if impl == "pallas":
-            return flash_attention_pallas(q, k, v, causal=causal)
-        return flash_attention(q, k, v, causal=causal)
+            return flash_attention_pallas(
+                q, k, v, causal=causal, segment_ids=segment_ids)
+        return flash_attention(
+            q, k, v, causal=causal, block_k=block, segment_ids=segment_ids)
+    if k.shape[1] != q.shape[1]:
+        k = jnp.repeat(k, q.shape[1] // k.shape[1], axis=1)
+        v = jnp.repeat(v, q.shape[1] // v.shape[1], axis=1)
     if schedule == "ulysses":
-        return ulysses_attention(q, k, v, mesh, axis, causal)
+        return ulysses_attention(q, k, v, mesh, axis, causal, segment_ids)
     if schedule not in ("auto", "ring"):
         raise ValueError(f"unknown attention schedule {schedule!r}")
-    return ring_attention(q, k, v, mesh, axis, causal)
+    return ring_attention(q, k, v, mesh, axis, causal, segment_ids)

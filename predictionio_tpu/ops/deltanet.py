@@ -1,0 +1,247 @@
+"""Gated DeltaNet: a linear-attention layer whose state is a matrix per
+head, rewritten by the delta rule and decayed by a gate.
+
+Per head, with state ``S`` [dk, dv], zero at the start of every history::
+
+    S <- alpha_t S;  u_t = beta_t (v_t - S^T k_t);  S <- S + k_t u_t^T;  o_t = S^T q_t
+
+:func:`gated_delta_rule` computes that in chunks of ``chunk`` tokens: inside
+a chunk the ``u_t`` solve a unit lower-triangular system (:func:`tri_inv`,
+blocked forward substitution), which every chunk does at once; only the
+state walks from chunk to chunk in a ``lax.scan``. The gradient is the same
+scan run backwards (autodiff of the chunked form; each step recomputes
+its own products, so a chunk keeps its incoming state and nothing else).
+
+Packed rows: ``seg`` gives each slot the id of its history (one contiguous
+run per id). A history's first token resets the state, which the chunked
+form does by masking the decay between slots of different histories; the
+short convolution reads zero where a tap would reach into the neighbour.
+
+Precision: gates ``alpha`` (as ``g = log alpha`` and its running sums) and
+the state are ``gate_dtype`` and ``state_dtype`` (float32); the triangular
+systems and the two products that read the state are float32 at
+``Precision.HIGHEST``; the other products take ``compute_dtype`` inputs
+(bfloat16 on the chip) and accumulate in float32.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+
+_HI = jax.lax.Precision.HIGHEST
+_BLOCK = 16  # diagonal blocks solved row by row; the rest by products
+
+
+def _tri_inv_impl(a):
+    """(I + a)^-1 for strictly lower-triangular ``a`` [..., C, C], float32.
+    Diagonal blocks of 16 by forward substitution (row i of the inverse is
+    e_i - sum_j a_ij row_j), block rows below them by products."""
+    c = a.shape[-1]
+    b = _BLOCK if c % _BLOCK == 0 else c
+    nb = c // b
+    lead = a.shape[:-2]
+    blocks = a.reshape(lead + (nb, b, nb, b))
+    diag = jnp.stack([blocks[..., n, :, n, :] for n in range(nb)], axis=-3)  # [..., nb, b, b]
+    t = jnp.broadcast_to(jnp.eye(b, dtype=a.dtype), diag.shape)
+    for i in range(1, b):
+        row = -jnp.einsum("...j,...jk->...k", diag[..., i, :], t, precision=_HI)
+        t = t.at[..., i, :].add(row)
+    if nb == 1:
+        return t.reshape(a.shape)
+    eye_nb = jnp.eye(nb, dtype=a.dtype)
+    # the inverse so far: block-diagonal; a without its diagonal blocks
+    full = jnp.einsum("...nij,nm->...nimj", t, eye_nb).reshape(a.shape)
+    off = (blocks * (1.0 - eye_nb)[:, None, :, None]).reshape(a.shape)
+    for n in range(1, nb):
+        rows = slice(n * b, (n + 1) * b)
+        below = jnp.einsum("...ij,...jk->...ik", off[..., rows, :], full, precision=_HI)
+        full = full.at[..., rows, :].add(
+            -jnp.einsum("...ij,...jk->...ik", t[..., n, :, :], below, precision=_HI))
+    return full
+
+
+@jax.custom_vjp
+def tri_inv(a):
+    return _tri_inv_impl(a)
+
+
+def _tri_inv_fwd(a):
+    t = _tri_inv_impl(a)
+    return t, t
+
+
+def _tri_inv_bwd(t, dt):
+    # d(I + a)^-1 = -T da T, so da = -T^T dT T^T, on the strict lower part
+    tt = jnp.swapaxes(t, -1, -2)
+    da = -jnp.einsum("...ij,...jk,...kl->...il", tt, dt, tt, precision=_HI)
+    c = t.shape[-1]
+    return (jnp.where(jnp.tril(jnp.ones((c, c), bool), -1), da, 0.0),)
+
+
+tri_inv.defvjp(_tri_inv_fwd, _tri_inv_bwd)
+
+
+def causal_conv(x, w, seg):
+    """Depthwise causal convolution, x [B, L, C], w [K, C] (tap K-1 is the
+    current slot), seg [B, L]: a tap in another history reads zero."""
+    taps, length = w.shape[0], x.shape[1]
+    out = x * w[taps - 1]
+    for back in range(1, taps):
+        shifted = jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :length]
+        same = jnp.pad(seg, ((0, 0), (back, 0)), constant_values=-1)[:, :length] == seg
+        out = out + jnp.where(same[..., None], shifted, 0) * w[taps - 1 - back]
+    return out
+
+
+@functools.partial(
+    jax.jit, static_argnames=("chunk", "compute_dtype", "state_dtype", "gate_dtype"))
+def gated_delta_rule(q, k, v, g, beta, seg, chunk: int = 64,
+                     compute_dtype=jnp.float32, state_dtype=jnp.float32,
+                     gate_dtype=jnp.float32):
+    """q, k [B, L, H, dk] (already normalised and scaled), v [B, L, H, dv],
+    g = log alpha and beta [B, L, H], seg [B, L] -> o [B, L, H, dv] float32."""
+    bsz, length, heads, dk = q.shape
+    dv = v.shape[-1]
+    pad = -length % chunk
+    if pad:  # slots of a history of their own, which write nothing
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in (q, k, v, g, beta))
+        seg = jnp.pad(seg, ((0, 0), (0, pad)), constant_values=-2)
+    n = (length + pad) // chunk
+
+    def chunks(a):  # [B, L, H, ...] -> [N, B, H, C, ...]: the scan's own layout
+        a = a.reshape((bsz, n, chunk, heads) + a.shape[3:])
+        return jnp.moveaxis(a, (1, 3), (0, 2))
+
+    f32 = jnp.float32
+    qc, kc, vc = chunks(q.astype(f32)), chunks(k.astype(f32)), chunks(v.astype(f32))
+    bc = chunks(beta.astype(f32))  # [N, B, H, C]
+    gc = jnp.cumsum(chunks(g.astype(gate_dtype)), axis=-1)  # inclusive, per chunk
+    sc = jnp.moveaxis(seg.reshape(bsz, n, chunk), 1, 0)  # [N, B, C]
+    same = (sc[..., :, None] == sc[..., None, :])[:, :, None]  # [N, B, 1, C, C]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    # decay from slot j to slot i of one history, i >= j; 0 elsewhere
+    diff = (gc[..., :, None] - gc[..., None, :]).astype(f32)
+    decay = jnp.exp(jnp.where(same & lower, diff, -jnp.inf))
+    prev_last = jnp.pad(sc[:-1, :, -1], ((1, 0), (0, 0)), constant_values=-3)  # [N, B]
+    carried = (sc == prev_last[..., None])[:, :, None].astype(f32)  # sees the incoming state
+    to_last = (sc == sc[..., -1:])[:, :, None].astype(f32)  # reaches the chunk's last slot
+    egc = jnp.exp(gc.astype(f32))
+    e_last = jnp.exp((gc[..., -1:] - gc).astype(f32))
+
+    kk = jnp.einsum("nbhid,nbhjd->nbhij", kc, kc, precision=_HI)
+    a = bc[..., None] * kk * decay * jnp.tril(jnp.ones((chunk, chunk), f32), -1)
+    t = tri_inv(a)
+    w = jnp.einsum("nbhij,nbhjd->nbhid", t, kc * (bc * egc * carried)[..., None], precision=_HI)
+    u = jnp.einsum("nbhij,nbhjd->nbhid", t, vc * bc[..., None], precision=_HI)
+    attn = jnp.einsum("nbhid,nbhjd->nbhij", qc, kc, precision=_HI) * decay
+    cd = compute_dtype
+    xs = (
+        w.astype(cd), u, (qc * (egc * carried)[..., None]).astype(cd),
+        (kc * (e_last * to_last)[..., None]).astype(cd), attn.astype(cd),
+        (egc[..., -1] * carried[..., -1]).astype(gate_dtype),
+    )
+
+    def with_state(a, s):
+        """[C, dk] rows against the [dk, dv] state. A float32 state is
+        read as float32 (``HIGHEST``: it is not rounded to feed the MXU, or
+        keeping it in float32 would buy nothing); a lower one as it is."""
+        if s.dtype == f32:
+            return jnp.einsum("bhck,bhkv->bhcv", a.astype(f32), s, precision=_HI)
+        return jnp.einsum("bhck,bhkv->bhcv", a, s.astype(cd), preferred_element_type=f32)
+
+    @jax.checkpoint
+    def step(s, x):
+        w_n, u_n, q_n, k_n, attn_n, keep = x
+        v_new = u_n - with_state(w_n, s)
+        o = with_state(q_n, s) + jnp.einsum(
+            "bhij,bhjv->bhiv", attn_n, v_new.astype(cd), preferred_element_type=f32)
+        s = s * keep[..., None, None].astype(state_dtype) + jnp.einsum(
+            "bhck,bhcv->bhkv", k_n, v_new.astype(cd), preferred_element_type=f32
+        ).astype(state_dtype)
+        return s, o
+
+    s0 = jnp.zeros((bsz, heads, dk, dv), state_dtype)
+    _, o = jax.lax.scan(step, s0, xs)  # [N, B, H, C, dv]
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(bsz, n * chunk, heads, dv)
+    return o[:, :length]
+
+
+def gated_deltanet(p: Dict, x, seg, *, key_heads: int, value_heads: int, key_dim: int,
+                   value_dim: int, eps: float, chunk: int = 64,
+                   compute_dtype=jnp.float32, state_dtype=jnp.float32,
+                   gate_dtype=jnp.float32):
+    """The mixer of a gated-DeltaNet layer: x [B, L, D] (normed) -> [B, L, D].
+    ``p``: ``w_qkvz`` [D, 2*Hk*dk + 2*Hv*dv], ``w_ba`` [D, 2*Hv], ``conv_w``
+    [K, 2*Hk*dk + Hv*dv], ``A_log`` and ``dt_bias`` [Hv], ``o_norm`` [dv],
+    ``w_out`` [Hv*dv, D].
+
+    The wide activations between the stages (the projections, q, k, v) are
+    kept in ``compute_dtype``, and each stage is recomputed from them in
+    the backward pass: at 16 k tokens the float32 intermediates of one
+    layer, all alive at once, would not leave room for the rest.
+
+    Also returns what the delta rule was given and what it gave, as this
+    call computed them (``q``, ``k``, ``v``, ``g``, ``beta``, ``o``, each
+    [B, L, Hv, ...]): a caller that wants to hold the scan that ran against
+    the recurrence reads them, and a program that does not use them does
+    not compute their copies."""
+    bsz, length, _ = x.shape
+    hk, hv, dk, dv = key_heads, value_heads, key_dim, value_dim
+    cd, f32 = compute_dtype, jnp.float32
+    n_qkv = 2 * hk * dk + hv * dv
+    with jax.named_scope("seq.deltanet.proj"):
+        qkvz = jnp.dot(x.astype(cd), p["w_qkvz"].astype(cd), preferred_element_type=f32).astype(cd)
+        # the gates' own inputs stay float32: alpha feeds an exponential
+        ba = jnp.dot(x.astype(f32), p["w_ba"], precision=_HI)
+
+    @jax.checkpoint
+    def prepare(qkvz, ba, seg, conv_w, a_log, dt_bias):
+        rows = qkvz.shape[0]
+        with jax.named_scope("seq.deltanet.conv"):
+            qkv = jax.nn.silu(causal_conv(qkvz[..., :n_qkv].astype(f32), conv_w, seg))
+            q = qkv[..., : hk * dk].reshape(rows, length, hk, dk)
+            k = qkv[..., hk * dk: 2 * hk * dk].reshape(rows, length, hk, dk)
+            v = qkv[..., 2 * hk * dk:].reshape(rows, length, hv, dv)
+
+            def l2(a):
+                return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+
+            q = jnp.repeat(l2(q) * dk ** -0.5, hv // hk, axis=2).astype(cd)
+            k = jnp.repeat(l2(k), hv // hk, axis=2).astype(cd)
+            beta = jax.nn.sigmoid(ba[..., :hv])
+            g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
+            return q, k, v.astype(cd), g, beta
+
+    rule = jax.checkpoint(functools.partial(
+        gated_delta_rule, chunk=chunk, compute_dtype=cd, state_dtype=state_dtype,
+        gate_dtype=gate_dtype))
+
+    @jax.checkpoint
+    def finish(o, qkvz, o_norm):
+        with jax.named_scope("seq.deltanet.norm"):
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps) * o_norm
+            z = qkvz[..., n_qkv:].astype(f32).reshape(o.shape)
+            return (o * jax.nn.silu(z)).reshape(o.shape[:2] + (hv * dv,)).astype(cd)
+
+    @jax.checkpoint
+    def one_row(row, conv_w, a_log, dt_bias, o_norm):
+        qkvz, ba, seg = (a[None] for a in row)
+        q, k, v, g, beta = prepare(qkvz, ba, seg, conv_w, a_log, dt_bias)
+        with jax.named_scope("seq.deltanet.scan"):
+            o = rule(q, k, v, g, beta, seg)
+        ran = {"q": q, "k": k, "v": v, "g": g, "beta": beta, "o": o}
+        return finish(o, qkvz, o_norm)[0], jax.tree_util.tree_map(lambda a: a[0], ran)
+
+    # between the projections one row at a time: the per-chunk matrices of
+    # a row (and, in the backward pass, their cotangents) are half of what
+    # two rows need, and at 16 k tokens that half is what fits
+    o, ran = jax.lax.map(
+        lambda row: one_row(row, p["conv_w"], p["A_log"], p["dt_bias"], p["o_norm"]),
+        (qkvz, ba, seg))
+    with jax.named_scope("seq.deltanet.proj"):
+        return jnp.dot(o, p["w_out"].astype(cd), preferred_element_type=f32), ran

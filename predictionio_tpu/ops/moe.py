@@ -1,0 +1,130 @@
+"""A sparse expert layer that is told which experts it holds.
+
+The router scores ALL ``n_experts`` in float32 and keeps each token's
+``top_k`` largest, renormalised over those ``top_k``. This share holds the
+contiguous range ``[first, first + count)``: it computes, for every token,
+the part of the result that its own experts give (a grouped matrix product
+over the assignments sorted by expert) plus the shared expert, which every
+share computes alike. What the absent experts would add is left out; no
+code stands in for the chips that hold them.
+
+No assignment is dropped. Shapes are static, so the sorted assignments are
+taken ``pass_rows`` at a time (default: twice this share's mean load), in
+as many passes as all of a step's assignments could need; a pass past the
+last held assignment is skipped, so the usual step runs one. ``dropped`` in
+the counters is the held assignments less the rows the passes that ran
+combined into the result (each pass counts the rows its own mask let
+through), so a pass that is skipped, short or cut wrongly shows there.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def swiglu(w: Dict, x, cd):
+    f32 = jnp.float32
+    xc = x.astype(cd)
+    gate = jnp.dot(xc, w["wg"].astype(cd), preferred_element_type=f32)
+    up = jnp.dot(xc, w["wu"].astype(cd), preferred_element_type=f32)
+    return jnp.dot((jax.nn.silu(gate) * up).astype(cd), w["wd"].astype(cd),
+                   preferred_element_type=f32)
+
+
+def route(x, router, top_k: int, norm_topk: bool = True):
+    """x [T, D], router [D, E] -> expert ids [T, k] and weights [T, k]
+    (float32; softmax over all E, the k largest, renormalised)."""
+    logits = jnp.dot(x.astype(jnp.float32), router, precision=_HI)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top, idx = jax.lax.top_k(probs, top_k)
+    if norm_topk:
+        top = top / top.sum(-1, keepdims=True)
+    return idx, top
+
+
+def _held_pass(x, experts: Dict, take, group_sizes, weights, top_k: int, cd):
+    """``take``: sorted assignments (token * k + slot) of one pass, the
+    first ``group_sizes.sum()`` of them held. The rows past those lie past
+    the last group, where the chip's grouped product writes NOTHING,
+    forward or backward: what it leaves there is whatever the buffer held.
+    So those rows are masked where they enter (their cotangent must not be
+    scattered onto a token) and where they leave. Returns the tokens' sums
+    and how many rows the mask let through."""
+    f32 = jnp.float32
+    tok = take // top_k
+    live = (jnp.arange(take.shape[0]) < group_sizes.sum())[:, None]
+    with jax.named_scope("seq.moe.dispatch"):
+        xs = jnp.where(live, x.astype(cd)[tok], 0)
+    with jax.named_scope("seq.moe.experts"):
+        gate = jax.lax.ragged_dot(xs, experts["wg"].astype(cd), group_sizes,
+                                  preferred_element_type=f32)
+        up = jax.lax.ragged_dot(xs, experts["wu"].astype(cd), group_sizes,
+                                preferred_element_type=f32)
+        hidden = jnp.where(live, jax.nn.silu(gate) * up, 0.0)
+        ys = jax.lax.ragged_dot(hidden.astype(cd), experts["wd"].astype(cd),
+                                group_sizes, preferred_element_type=f32)
+    with jax.named_scope("seq.moe.combine"):
+        ys = jnp.where(live, ys * weights[take][:, None], 0.0)
+        return jax.ops.segment_sum(ys, tok, num_segments=x.shape[0]), live.sum(dtype=jnp.int32)
+
+
+def _passes_for(all_rows: int, rows: int) -> int:
+    """Passes of ``rows`` sorted assignments that cover ``all_rows``."""
+    return -(-all_rows // rows)
+
+
+def expert_layer(p: Dict, x, *, first: int, top_k: int, norm_topk: bool = True,
+                 pass_rows: int = 0, compute_dtype=jnp.float32) -> Tuple[jax.Array, Dict]:
+    """x [T, D] (normed) -> y [T, D] float32 and the step's counters.
+    ``p``: ``router`` [D, E], ``shared_gate`` [D], ``shared`` and ``experts``
+    (``wg``, ``wu`` [.., D, F], ``wd`` [.., F, D]; experts with a leading
+    [count] axis: the experts ``first .. first + count - 1``)."""
+    x = jnp.asarray(x)
+    tokens = x.shape[0]
+    count = p["experts"]["wg"].shape[0]
+    n_experts = p["router"].shape[1]
+    cd = compute_dtype
+    all_rows = tokens * min(top_k, count)  # a token's k experts are distinct
+    rows = min(all_rows, pass_rows or max(8, 2 * tokens * top_k * count // n_experts))
+    passes = _passes_for(all_rows, rows)
+    with jax.named_scope("seq.moe.route"):
+        idx, weights = route(x, p["router"], top_k, norm_topk)
+        local = idx - first
+        held = (local >= 0) & (local < count)
+        flat = jnp.where(held, local, count).reshape(-1)  # absent experts sort last
+        order = jnp.argsort(flat, stable=True)
+        order = jnp.pad(order, (0, max(0, passes * rows - order.shape[0])))
+        group_sizes = jnp.bincount(flat, length=count + 1)[:count].astype(jnp.int32)
+        ends = jnp.cumsum(group_sizes)
+        n_held = ends[-1]
+        flat_w = weights.reshape(-1)
+
+    @jax.checkpoint
+    def one_pass(y, start):
+        def run(y):
+            take = jax.lax.dynamic_slice_in_dim(order, start, rows)
+            # what of every group lies inside [start, start + rows)
+            inside = jnp.clip(ends - start, 0, rows) - jnp.clip(ends - group_sizes - start, 0, rows)
+            part, combined = _held_pass(x, p["experts"], take, inside, flat_w, top_k, cd)
+            return y + part, combined
+
+        # a pass past the last held assignment has nothing to do
+        return jax.lax.cond(start < n_held, run, lambda y: (y, jnp.int32(0)), y)
+
+    y, combined = jax.lax.scan(one_pass, jnp.zeros(x.shape, jnp.float32),
+                               jnp.arange(passes, dtype=jnp.int32) * rows)
+    with jax.named_scope("seq.moe.shared"):
+        gate = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), p["shared_gate"], precision=_HI))
+        y = y + gate[:, None] * swiglu(p["shared"], x, cd)
+    counters = {
+        "expert_tokens": group_sizes,
+        "absent_weight": jnp.where(held, 0.0, weights).sum() / tokens,
+        "dropped": n_held - combined.sum(),
+        "passes": -(-n_held // rows),
+    }
+    return y, counters

@@ -72,7 +72,10 @@ class TestMicroBatcher:
             mb.close()
 
     def test_length_mismatch_is_an_error(self):
-        mb = MicroBatcher(lambda items: [1], max_batch=4, max_wait_ms=5.0)
+        # a wait long enough that both submits share a batch on a loaded
+        # machine too (at 5 ms each went alone under six test workers, and a
+        # lone 1-item batch passes)
+        mb = MicroBatcher(lambda items: [1], max_batch=4, max_wait_ms=250.0)
         try:
             with ThreadPoolExecutor(max_workers=2) as pool:
                 futs = [pool.submit(mb.submit, i) for i in range(2)]

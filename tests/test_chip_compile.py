@@ -266,3 +266,77 @@ def test_sharded_half_step_2x2(topo):
     )
     assert "all-gather" in compiled.as_text()
     assert len(compiled.input_shardings[0][0].device_set) == shards
+
+
+# -- the sequence backbone's programs at the shapes of train-qwen3next-packed8k:
+# rows of 8,192 slots, Qwen3-Next-80B-A3B widths, bfloat16 products
+SEQ_L = 8192
+
+
+def test_delta_rule_scan_row_of_8k(one_chip):
+    """The chunked delta rule and its gradient for one packed row: 32
+    value heads of 128 x 128 state, 128 chunks of 64."""
+    from predictionio_tpu.ops.deltanet import gated_delta_rule
+
+    def loss(q, k, v, g, beta, seg):
+        return gated_delta_rule(
+            q, k, v, g, beta, seg, chunk=64, compute_dtype=jnp.bfloat16).sum()
+
+    qkv = _sds(one_chip, (1, SEQ_L, 32, 128), jnp.bfloat16)
+    gate = _sds(one_chip, (1, SEQ_L, 32), jnp.float32)
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), qkv, qkv, qkv, gate, gate,
+        _sds(one_chip, (1, SEQ_L), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+
+@pytest.mark.parametrize("impl,heads,kv_heads,head_dim", [
+    ("xla", 16, 2, 256), ("pallas", 4, 4, 64)])
+def test_packed_attention_two_rows_of_8k(one_chip, impl, heads, kv_heads, head_dim):
+    """Attention with a segment mask over two packed rows of 8,192 slots,
+    forward and backward: the XLA path at the cell's widths (16 query
+    heads on 2 key/value heads of 256), the Pallas forward kernel at the
+    shipped preset's (4 heads of 64: it takes equal head counts and holds
+    one head's K and V in VMEM)."""
+    from predictionio_tpu.ops.attention import flash_attention, flash_attention_pallas
+
+    def loss(q, k, v, seg):
+        if impl == "pallas":
+            o = flash_attention_pallas(q, k, v, segment_ids=seg, interpret=False)
+        else:
+            o = flash_attention(q, k, v, segment_ids=seg)
+        return o.astype(jnp.float32).sum()
+
+    # the value too: the Pallas path's backward pass recomputes in XLA and
+    # does not need its forward kernel's output
+    compiled = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)),
+        _sds(one_chip, (2, heads, SEQ_L, head_dim), jnp.bfloat16),
+        _sds(one_chip, (2, kv_heads, SEQ_L, head_dim), jnp.bfloat16),
+        _sds(one_chip, (2, kv_heads, SEQ_L, head_dim), jnp.bfloat16),
+        _sds(one_chip, (2, SEQ_L), jnp.int32))
+    # no [pairs, B, H, bq, bk] stack of score tiles (4 GiB when the
+    # compiler can count the loop's trips)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+    assert ("tpu_custom_call" in compiled.as_text()) == (impl == "pallas")
+
+
+def test_expert_layer_16k_tokens_32_of_512(one_chip):
+    """Router over 512, the held experts' grouped products (XLA lowers
+    ``ragged_dot`` to a Mosaic kernel on the chip), shared expert; forward
+    and backward at 16,384 tokens."""
+    from predictionio_tpu.ops.moe import expert_layer
+
+    d, f, e, held = 2048, 512, 512, 32
+    w = lambda *shape: _sds(one_chip, shape, jnp.float32)  # noqa: E731
+    params = {
+        "router": w(d, e), "shared_gate": w(d),
+        "shared": {"wg": w(d, f), "wu": w(d, f), "wd": w(f, d)},
+        "experts": {"wg": w(held, d, f), "wu": w(held, d, f), "wd": w(held, f, d)},
+    }
+
+    def loss(p, x):
+        return expert_layer(p, x, first=0, top_k=10, compute_dtype=jnp.bfloat16)[0].sum()
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)), params, w(2 * SEQ_L, d))
+    assert "ragged" in compiled.as_text()

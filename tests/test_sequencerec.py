@@ -64,8 +64,12 @@ class TestPreparator:
         )
         pd = SeqPreparator(SeqPreparatorParams(seq_len=4)).prepare(None, td)
         assert pd.windows.shape[1] == 5
-        # short history is left-padded with the PAD id
-        assert pd.windows[0, 0] == pd.pad_id
+        # rows are packed, not left-padded: the history fills the first
+        # slots under one segment id, and the slots no history fills are
+        # segment 0 (PR 26: packed rows with segment ids took the place of
+        # one left-padded window a history)
+        assert pd.segments[0].tolist() == [1, 1, 1, 0, 0]
+        assert pd.windows[0, :3].tolist() == [pd.item_map[i] for i in "xyz"]
         # single-item user contributes recents but no window
         assert pd.user_recent["b"] == [pd.item_map["y"]]
 
