@@ -57,7 +57,8 @@ _SCOPE_RUNGS = (8, 32, 128, 512, 2048, 8192, 32768)
 #: Most rows a device block may hold at any width: on the Pallas path a
 #: block's normal equations are ``[R, R, B]`` whatever the width (205 MB
 #: at rank 50), so under the rank it is they, not the gather, that bound
-#: the block.
+#: the block. (An explicit job solves those blocks as ``[k, k, B]``
+#: systems; an implicit one still builds ``[R, R, B]``.)
 _MAX_BLOCK_ROWS = 16384
 
 #: Max rows per device block inside a bucket solve (bounds peak gather
@@ -396,8 +397,9 @@ class ALSConfig:
     #: "two_phase" batches one Cholesky per bucket (measured slower than
     #: chunked on v5e); "pallas" replaces XLA's batched Cholesky with
     #: the fused transposed-layout kernel
-    #: (ops/pallas_kernels.spd_solve_t, ~25× on the solve stage). All
-    #: modes produce identical results up to float reassociation.
+    #: (ops/pallas_kernels.spd_solve_t: 167 ns a 56 x 56 system on a
+    #: v5e, 15.5 ns an 8 x 8 one; PERF.md §6, PR 27). All modes produce
+    #: identical results up to float reassociation.
     solve_mode: str = "auto"
     #: "f32" (default) or "bf16": dtype of the gathered opposite-side
     #: factors feeding the normal-equation einsums (accumulation stays
@@ -711,6 +713,27 @@ class ALSFactors:
     rank: int
 
 
+def _solves_dual(width: int, rank: int, implicit: bool) -> bool:
+    """Whether a bucket's rows are solved in the space of their ratings
+    (``solve_chunk_dual`` in :func:`_solve_side_traced`): explicit
+    feedback and fewer slots than the rank. Read from what the program
+    is given; nothing chooses it."""
+    return not implicit and width < rank
+
+
+def _solve_forms(side, rank: int, implicit: bool) -> dict:
+    """``{"dual_rows", "primal_rows"}``: the rows of one side (padding
+    rows left out) by the form their bucket is solved in."""
+    forms = {"dual_rows": 0, "primal_rows": 0}
+    for b in side.buckets:
+        count = np.count_nonzero if isinstance(b.counts, np.ndarray) else (
+            jnp.count_nonzero)
+        key = "dual_rows" if _solves_dual(
+            b.idx.shape[-1], rank, implicit) else "primal_rows"
+        forms[key] += int(count(b.counts))
+    return forms
+
+
 def _bucket_tensors(side: StagedMatrix):
     return tuple((b.rows, b.idx, b.val, b.counts) for b in side.buckets)
 
@@ -784,6 +807,13 @@ def _solve_side_traced(
       kernel (``ops/pallas_kernels.spd_solve_t``); the XLA batched
       Cholesky was ~2/3 of the iteration wall-clock on v5e.
 
+    Whatever the mode, an explicit bucket narrower than the rank is
+    solved in the dual form (``solve_chunk_dual``: a ``k × k`` system a
+    row, by the ``pallas`` mode's kernel at ``n = max(8, k)`` or, in the
+    other two, by XLA's Cholesky a block): the rule is
+    :func:`_solves_dual`, read from the bucket's shape. Every other
+    bucket, and every implicit job, runs the primal program below.
+
     Under a ``mesh``, the per-chunk SPD systems are embarrassingly
     parallel across solve rows, so the pallas kernel (which does not
     auto-partition under pjit) is wrapped in ``shard_map`` over the
@@ -824,9 +854,40 @@ def _solve_side_traced(
         )
         eye_t = jnp.eye(n_pad, dtype=jnp.float32)[:, :, None]
 
-        def solve_chunk_pallas(c):
+        def spd_solve_lanes(a_t, b_t):
+            """``spd_solve_t`` on ``[n, n, B]`` systems, ``B`` padded to
+            the kernel's lane block (on every device of a mesh's data
+            axis, where each solves its own ``B / n_data`` of them)."""
             from .pallas_kernels import _SPD_BLK, spd_solve_t
 
+            bsz = b_t.shape[-1]
+            if mesh is None:
+                pad_b = -bsz % _SPD_BLK
+                if pad_b:
+                    a_t = jnp.pad(a_t, ((0, 0), (0, 0), (0, pad_b)))
+                    b_t = jnp.pad(b_t, ((0, 0), (0, pad_b)))
+                return spd_solve_t(a_t, b_t)[:, :bsz]
+            from jax.sharding import PartitionSpec as P
+
+            from jax import shard_map
+            from ..parallel.mesh import DATA_AXIS
+
+            n_data = mesh.shape[DATA_AXIS]
+            # each device's local block must itself be a multiple
+            # of the kernel's lane block
+            pad_b = -bsz % (_SPD_BLK * n_data)
+            if pad_b:
+                a_t = jnp.pad(a_t, ((0, 0), (0, 0), (0, pad_b)))
+                b_t = jnp.pad(b_t, ((0, 0), (0, pad_b)))
+            return shard_map(
+                spd_solve_t,
+                mesh=mesh,
+                in_specs=(P(None, None, DATA_AXIS), P(None, DATA_AXIS)),
+                out_specs=P(None, DATA_AXIS),
+                check_vma=False,  # pallas body; replication by spec
+            )(a_t, b_t)[:, :bsz]
+
+        def solve_chunk_pallas(c):
             idx_blk, val_blk, counts_blk = c
             with jax.named_scope("als.gather"):
                 mask = expand_mask(idx_blk, counts_blk)
@@ -853,37 +914,9 @@ def _solve_side_traced(
                     "bkr,bk->rb", g, rhs.astype(g.dtype),
                     preferred_element_type=jnp.float32,
                 )
-            bsz = idx_blk.shape[0]
             with jax.named_scope("als.solve"):
-                if mesh is None:
-                    pad_b = -bsz % _SPD_BLK
-                    if pad_b:
-                        a_t = jnp.pad(a_t, ((0, 0), (0, 0), (0, pad_b)))
-                        b_t = jnp.pad(b_t, ((0, 0), (0, pad_b)))
-                    x_t = spd_solve_t(a_t, b_t)
-                else:
-                    from jax.sharding import PartitionSpec as P
-
-                    from jax import shard_map
-                    from ..parallel.mesh import DATA_AXIS
-
-                    n_data = mesh.shape[DATA_AXIS]
-                    # each device's local block must itself be a multiple
-                    # of the kernel's lane block
-                    pad_b = -bsz % (_SPD_BLK * n_data)
-                    if pad_b:
-                        a_t = jnp.pad(a_t, ((0, 0), (0, 0), (0, pad_b)))
-                        b_t = jnp.pad(b_t, ((0, 0), (0, pad_b)))
-                    x_t = shard_map(
-                        spd_solve_t,
-                        mesh=mesh,
-                        in_specs=(
-                            P(None, None, DATA_AXIS), P(None, DATA_AXIS),
-                        ),
-                        out_specs=P(None, DATA_AXIS),
-                        check_vma=False,  # pallas body; replication by spec
-                    )(a_t, b_t)
-                return x_t[:rank, :bsz].T  # [B, rank]
+                x_t = spd_solve_lanes(a_t, b_t)
+                return x_t[:rank].T  # [B, rank]
 
         def solve_chunk_fused(c):
             idx_blk, val_blk, counts_blk = c
@@ -922,6 +955,57 @@ def _solve_side_traced(
             )(y_pad, yty_arg, lam, alpha, idx_blk, val_blk, counts_blk)
             return x_blk[:bsz]
 
+    def solve_chunk_dual(c):
+        """An explicit block narrower than the rank, solved in the space
+        of its ratings. For ``G`` ``[k, R]`` (a row's gathered, masked
+        factors) and ``c = λ·n_u``,
+        ``(GᵀG + cI_R)⁻¹ Gᵀ r = Gᵀ (GGᵀ + cI_k)⁻¹ r``: an identity, so
+        for ``k < R`` the row costs a ``k × k`` system and one
+        ``[k] × [k, R]`` product, not an ``R × R`` factorisation. Both
+        products run in float32 at ``Precision.HIGHEST`` on the same
+        rows, or the two sides of the identity would see different
+        ``G``. A padded slot (a zero row and column of ``GGᵀ``) takes a
+        unit diagonal and a zero rating: its α is exactly 0 for every
+        λ, zero included, and a padding row gives x = 0. Implicit ALS
+        cannot come here: its base matrix is ``YᵀY + λnI``, not a
+        multiple of the identity."""
+        idx_blk, val_blk, counts_blk = c
+        table = y_pad if solve_mode == "pallas" else y_g
+        with jax.named_scope("als.gather"):
+            mask = expand_mask(idx_blk, counts_blk)
+            g = table[idx_blk] * mask[..., None]  # [B, k, R or n_pad]
+        k = idx_blk.shape[-1]
+        product = functools.partial(
+            jnp.einsum, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+        g = g.astype(jnp.float32)
+        valid = mask > 0
+        ridge = jnp.where(
+            valid, (lam * counts_blk.astype(jnp.float32))[:, None], 1.0
+        )  # [B, k]
+        rhs = jnp.where(valid, val_blk, 0.0)
+        eye_k = jnp.eye(k, dtype=jnp.float32)
+        if solve_mode == "pallas":
+            with jax.named_scope("als.gramian"):
+                kern = product("bkr,bjr->kjb", g, g)
+                kern = kern + eye_k[:, :, None] * ridge.T[:, None, :]
+            with jax.named_scope("als.solve"):
+                pad_k = -k % 8  # the kernel solves zero padding to 0
+                coef = spd_solve_lanes(
+                    jnp.pad(kern, ((0, pad_k), (0, pad_k), (0, 0))),
+                    jnp.pad(rhs.T, ((0, pad_k), (0, 0))),
+                )[:k]
+                x_blk = product("bkr,kb->br", g, coef)
+        else:
+            with jax.named_scope("als.gramian"):
+                kern = product("bkr,bjr->bkj", g, g)
+                kern = kern + eye_k[None] * ridge[:, :, None]
+            coef = _cho_solve(kern, rhs)
+            with jax.named_scope("als.solve"):
+                x_blk = product("bkr,bk->br", g, coef)
+        return x_blk[:, :rank]
+
     for rows, idx, val, counts in buckets:
         width = idx.shape[-1]
         # outer: the rung (what the listed per-bucket metrics read, and
@@ -932,7 +1016,9 @@ def _solve_side_traced(
         ):
             if idx.dtype != jnp.int32:
                 idx = idx.astype(jnp.int32)  # uint16 transfer packing
-            if solve_mode == "pallas":
+            if _solves_dual(width, rank, implicit):
+                solved = jax.lax.map(solve_chunk_dual, (idx, val, counts))
+            elif solve_mode == "pallas":
                 # fused gather+Gramian only pays for itself when the
                 # removed [B, K, R] round trip outweighs its [B, R, R]
                 # transpose — i.e. width >= rank; narrow buckets keep the
@@ -1176,6 +1262,16 @@ def als_train(
             "sort_gather_indices=True requires BucketedMatrix inputs "
             "(sort before staging: sort_bucket_indices(bucketize(...)))"
         )
+    # how often the dual form engages: real rows by the form their bucket
+    # is solved in (counted before staging, where the counts are host
+    # arrays), in ``profile`` and on each program's ``als.enqueue`` span
+    forms = {
+        "user": _solve_forms(by_user, rank, cfg.implicit_prefs),
+        "item": _solve_forms(by_item, rank, cfg.implicit_prefs),
+    }
+    forms["iteration"] = {
+        k: forms["user"][k] + forms["item"][k] for k in forms["user"]
+    }
     if sort_gather:
         # gather-locality pass (host, pre-staging); see sort_bucket_indices
         with span("als.index_sort", {"side": "user"}):
@@ -1204,6 +1300,10 @@ def als_train(
             by_user, by_item, rank, cfg.gather_dtype,
             fused_gather=fused_gather,
         )
+        profile["solve_forms"] = {
+            side: [forms[side]["dual_rows"], forms[side]["primal_rows"]]
+            for side in ("user", "item")
+        }
         profile["bucket_shapes"] = {
             "by_user": [
                 [int(np.prod(b.rows.shape)), b.idx.shape[-1]]
@@ -1291,18 +1391,27 @@ def als_train(
             # solve needs only the user-side buckets, so it starts as
             # soon as they land while the item-side transfer is still in
             # flight (same math — the fused body is these two calls)
-            with span("als.enqueue", {"program": "half_user", "i": i}):
+            with span(
+                "als.enqueue",
+                {"program": "half_user", "i": i, **forms["user"]},
+            ):
                 x = _telemetry.call(
                     "als_half", half, y, ub, lam, alpha,
                     n_rows=by_user.n_rows, side="user", **common,
                 )
-            with span("als.enqueue", {"program": "half_item", "i": i}):
+            with span(
+                "als.enqueue",
+                {"program": "half_item", "i": i, **forms["item"]},
+            ):
                 y = _telemetry.call(
                     "als_half", half, x, ib, lam, alpha,
                     n_rows=by_item.n_rows, side="item", **common,
                 )
         else:
-            with span("als.enqueue", {"program": "iteration", "i": i}):
+            with span(
+                "als.enqueue",
+                {"program": "iteration", "i": i, **forms["iteration"]},
+            ):
                 x, y = _telemetry.call(
                     "als_iteration", iteration,
                     ub, ib, y, lam, alpha,

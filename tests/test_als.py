@@ -868,3 +868,206 @@ class TestFusedTopK:
             assert np.asarray(idx).shape == (2, 5)
             assert (np.asarray(idx)[:, 3:] == -1).all(), mode
             assert np.isneginf(np.asarray(scores)[:, 3:]).all(), mode
+
+
+def _dot_generals(fn, *args, **kwargs):
+    """(precision, output shape) of every ``dot_general`` a function
+    traces, loops and called functions included (``kwargs`` are the
+    function's static arguments)."""
+    import functools
+
+    import jax
+
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                found.append(
+                    (eqn.params["precision"], tuple(eqn.outvars[0].aval.shape))
+                )
+            for value in eqn.params.values():
+                inner = getattr(value, "jaxpr", value)
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+
+    walk(jax.make_jaxpr(functools.partial(fn, **kwargs))(*args).jaxpr)
+    return found
+
+
+def _traces_dual(dots):
+    """The dual body's two products are the program's only ones at
+    ``Precision.HIGHEST``."""
+    import jax
+
+    highest = jax.lax.Precision.HIGHEST
+    return any(
+        p is not None and highest in (p if isinstance(p, tuple) else (p,))
+        for p, _ in dots
+    )
+
+
+class TestDualForm:
+    """Explicit rows narrower than the rank are solved in the space of
+    their ratings (``solve_chunk_dual``): the same solution as the
+    primal normal equations, from a ``k × k`` system."""
+
+    BLOCK, CHUNKS, N_COLS = 16, 2, 300
+
+    @classmethod
+    def _block(cls, width, rank, seed=5):
+        """Two chunks of one bucket as ``stage`` ships them: counts from
+        1 to the width, a row of a single rating, whole padding rows."""
+        rng = np.random.default_rng(seed + 100 * width + rank)
+        n = cls.BLOCK * cls.CHUNKS
+        counts = rng.integers(1, width + 1, n).astype(np.int32)
+        counts[0], counts[1] = width, 1
+        counts[-3:] = 0  # padding rows: the sentinel row id, no ratings
+        rows = np.where(counts > 0, np.arange(n), n).astype(np.int32)
+        idx = np.stack([
+            rng.choice(cls.N_COLS, size=width, replace=False) for _ in range(n)
+        ]).astype(np.int32)
+        val = rng.integers(1, 6, (n, width)).astype(np.float32)
+        slot = np.arange(width)[None, :] < counts[:, None]
+        idx, val = np.where(slot, idx, 0), np.where(slot, val, 0.0)
+        y = (rng.normal(size=(cls.N_COLS, rank)) / np.sqrt(rank)).astype(np.float32)
+        shape = (cls.CHUNKS, cls.BLOCK)
+        bucket = (
+            rows.reshape(shape), idx.reshape(shape + (width,)).astype(np.int32),
+            val.reshape(shape + (width,)).astype(np.float32), counts.reshape(shape),
+        )
+        return y, bucket, n
+
+    @staticmethod
+    def _primal_float64(y, bucket, n, lam):
+        rows, idx, val, counts = (a.reshape((-1,) + a.shape[2:]) for a in bucket)
+        rank = y.shape[1]
+        ref = np.zeros((n, rank))
+        for row, ids, r, c in zip(rows, idx, val, counts):
+            if c == 0:
+                continue
+            g = y[ids[:c]].astype(np.float64)
+            system = g.T @ g + lam * c * np.eye(rank)
+            rhs = g.T @ r[:c].astype(np.float64)
+            if lam > 0:
+                ref[row] = np.linalg.solve(system, rhs)
+            else:  # singular under the rank: the least-norm solution
+                ref[row] = np.linalg.lstsq(g, r[:c].astype(np.float64), rcond=None)[0]
+        return ref
+
+    @pytest.mark.parametrize("mode", ["chunked", "pallas"])
+    @pytest.mark.parametrize("rank", [10, 50])
+    @pytest.mark.parametrize("width", [1, 2, 4, 8, 16, 32])
+    def test_half_step_equals_the_primal_solve(self, width, rank, mode):
+        import jax.numpy as jnp
+
+        from predictionio_tpu.ops import als
+
+        y, bucket, n = self._block(width, rank)
+        dual = width < rank
+        statics = dict(
+            rank=rank, implicit=False, n_rows=n, solve_mode=mode,
+            gather_dtype="f32", mesh=None, fused_gather=False, side="user",
+        )
+        args = (jnp.asarray(y), (tuple(jnp.asarray(a) for a in bucket),))
+        dots = _dot_generals(
+            als._als_half_body, *args, jnp.float32(0.05), jnp.float32(1.0),
+            **statics,
+        )
+        assert _traces_dual(dots) == dual
+        # the products a block traces, batch first: a k x k system and
+        # the row's expansion, or (rank 10, widths 16 and 32: as wide as
+        # the rank) today's R x R system and its right-hand side
+        r_pad = (rank + 7) // 8 * 8 if mode == "pallas" else rank
+        assert [shape for _, shape in dots] == (
+            [(self.BLOCK, width, width), (self.BLOCK, r_pad)] if dual
+            else [(self.BLOCK, r_pad, r_pad), (self.BLOCK, r_pad)]
+        )
+
+        padding = np.asarray(bucket[3]).reshape(-1) == 0
+        for lam in (0.05, 1e-4, 0.0):
+            if lam == 0.0 and not dual:
+                continue  # a primal system of fewer ratings than the rank
+            x = np.asarray(als._als_half(
+                *args, jnp.float32(lam), jnp.float32(1.0), **statics))
+            assert x.shape == (n, rank) and np.isfinite(x).all()
+            assert not x[padding].any()
+            ref = self._primal_float64(y, bucket, n, lam)
+            err = np.abs(x - ref).max(axis=1) / np.maximum(
+                np.abs(ref).max(axis=1), 1e-30)
+            # today's primal system of a row with fewer ratings than the
+            # rank is nearly singular at a small λ, in float32: its rows
+            # are held to the tolerance they always had
+            limit = (1e-4 if lam > 0 else 1e-3) if dual else 2e-3
+            assert err[~padding].max() < limit, (lam, err.max())
+
+    #: |x| and |y| summed and two rows' first entries, from the commit
+    #: before the dual form (679fbee) on this data: rank 12, 3
+    #: iterations, λ 0.05, seed 2
+    PARENT = {
+        ("implicit", "chunked"): (
+            1051.566687341694, 483.6368902791728,
+            [0.41221755743026733, 0.21378718316555023, -0.24483078718185425],
+            [0.42877060174942017, 0.4944628179073334, 0.25755566358566284]),
+        ("implicit", "pallas"): (
+            1051.5666050482369, 483.63693218085973,
+            [0.4122177064418793, 0.21378709375858307, -0.24483032524585724],
+            [0.4287700355052948, 0.4944624602794647, 0.2575548589229584]),
+        ("wide", "chunked"): (
+            649.7081153525505, 232.41504542873008,
+            [0.9020460247993469, 2.4688191413879395, 0.739781379699707],
+            [1.163022756576538, 0.8243908286094666, 0.0441533625125885]),
+        ("wide", "pallas"): (
+            649.7080554750282, 232.41512989299372,
+            [0.9020456671714783, 2.4688150882720947, 0.7397797703742981],
+            [1.163022756576538, 0.824394166469574, 0.044154051691293716]),
+    }
+
+    @pytest.mark.parametrize("mode", ["chunked", "pallas"])
+    @pytest.mark.parametrize("job", ["implicit", "wide"])
+    def test_jobs_the_rule_leaves_out_run_the_parents_program(self, job, mode):
+        """An implicit job (its base matrix is not a multiple of the
+        identity), and an explicit job whose rows all hold the rank's
+        ratings or more, trace nothing of the dual body and give the
+        factors the parent commit gave."""
+        import jax.numpy as jnp
+
+        from predictionio_tpu.ops import als
+
+        if job == "implicit":
+            u, i, v, n_u, n_i = _ladder_data()
+        else:
+            rng = np.random.default_rng(17)
+            n_u, n_i = 60, 40
+            u, i = (a.astype(np.int32) for a in np.nonzero(
+                rng.random((n_u, n_i)) < 0.6))
+            v = rng.integers(1, 6, len(u)).astype(np.float32)
+            assert min(np.bincount(u).min(), np.bincount(i).min()) >= 12
+        cfg = als.ALSConfig(
+            rank=12, iterations=3, lambda_=0.05, seed=2, alpha=1.0,
+            implicit_prefs=job == "implicit", solve_mode=mode,
+        )
+        by_user = als.bucketize(u, i, v, n_u, n_i, pad_to_blocks=True)
+        by_item = als.bucketize(i, u, v, n_i, n_u, pad_to_blocks=True)
+        narrow = {b.width for b in by_user.buckets + by_item.buckets if b.width < 12}
+        assert narrow == ({1, 2, 4, 8} if job == "implicit" else set())
+        profile = {}
+        f = als.als_train(by_user, by_item, cfg, profile=profile)
+        rows = [np.count_nonzero(np.bincount(a)) for a in (u, i)]
+        assert profile["solve_forms"] == {"user": [0, rows[0]], "item": [0, rows[1]]}
+        staged = [als._bucket_tensors(als.stage(s)) for s in (by_user, by_item)]
+        dots = _dot_generals(
+            als._als_iteration_body, *staged, jnp.zeros((n_i, 12)),
+            jnp.float32(0.05), jnp.float32(1.0),
+            rank=12, implicit=cfg.implicit_prefs, n_users=n_u, n_items=n_i,
+            solve_mode=mode, fused_gather=mode == "pallas",
+        )
+        assert dots and not _traces_dual(dots)
+        # and no k x k system a block
+        assert not [s for _, s in dots if len(s) == 3 and s[1] == s[2] < 12]
+        x, y = (np.asarray(a, np.float64) for a in (f.user_factors, f.item_factors))
+        abs_x, abs_y, x5, y7 = self.PARENT[job, mode]
+        np.testing.assert_allclose(
+            [np.abs(x).sum(), np.abs(y).sum()], [abs_x, abs_y], rtol=1e-6)
+        np.testing.assert_allclose(x[5, :3], x5, rtol=1e-5)
+        np.testing.assert_allclose(y[7, :3], y7, rtol=1e-5)

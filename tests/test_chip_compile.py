@@ -80,6 +80,19 @@ def test_spd_solve_rank50(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("n", [8, 16, 32])
+def test_spd_solve_dual_widths(one_chip, n):
+    """The solver at the sizes the dual form gives it: a bucket of
+    ``k < rank`` slots solves ``max(8, k) x max(8, k)`` systems, one
+    16,384-row block at a time."""
+    compiled = _compile(
+        functools.partial(spd_solve_t, interpret=False),
+        _sds(one_chip, (n, n, 16384), jnp.float32),
+        _sds(one_chip, (n, 16384), jnp.float32),
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("b, k, table_dtype", [
     # the smoke's own bucket blocks [rows, width]; each of the first three
     # was refused for the chip before PR 22 (rank-1 SMEM ridge block;
@@ -153,10 +166,12 @@ def test_als_half_step_with_its_kernels(one_chip, monkeypatch):
 ])
 def test_narrow_bucket_half_step(one_chip, monkeypatch, block, width, rung):
     """One bucket narrower than the rank, as the user side of the
-    benchmark's cell solves it: XLA's gather and einsum build, the
-    Pallas solver. The block is what ``_BLOCK_ROWS`` gives the width, and
-    what bounds the program is the block's normal equations ``[56, 56,
-    B]`` (205 MB at 16,384 rows), not the width."""
+    benchmark's cell solves it (explicit, so in the dual form): XLA's
+    gather, the ``[k, k, B]`` system from a float32 product at
+    ``HIGHEST``, the Pallas solver at ``n = max(8, k)``, the row from
+    ``[k] x [k, 56]``. The block is what ``_BLOCK_ROWS`` gives the
+    width; no ``[56, 56, B]`` system (205 MB at 16,384 rows) is left
+    in the program."""
     from predictionio_tpu.ops import als
 
     assert als._block_rows_for(width) == block
@@ -181,13 +196,24 @@ def test_narrow_bucket_half_step(one_chip, monkeypatch, block, width, rung):
     # side / rung / own width / phase: ``als.w8`` holds every width up to 8
     chunk = f"als.user_side/als.w{rung}/als.k{width}/while/body/closed_call"
     assert f"{chunk}/als.gather/" in text
+    assert f"{chunk}/als.gramian/bkr,bjr->kjb/" in text
     assert f"{chunk}/als.solve/spd_solve_t/pallas_call" in text
+    assert f"{chunk}/als.solve/bkr,kb->br/" in text
     assert f"als.user_side/als.w{rung}/als.k{width}/als.scatter/scatter" in text
+    # float32 at HIGHEST wherever the compiler kept a product as one (it
+    # writes the expansion, and width 1's system, as multiply and
+    # reduce): the text names a precision only where it is not the default
+    products = text.count(" convolution(")
+    assert products == text.count("operand_precision={highest,highest}")
+    assert products >= (width > 1)
+    n = max(8, width)
+    assert f"f32[{n},{n},{block}]" in text
+    assert f"f32[{RANK_PAD},{RANK_PAD},{block}]" not in text
     normal_equations = RANK_PAD * RANK_PAD * block * 4
     padded_table = n_items * RANK_PAD * 4
     assert (
         compiled.memory_analysis().temp_size_in_bytes
-        < padded_table + 2 * normal_equations
+        < padded_table + normal_equations
     )
 
 

@@ -209,6 +209,29 @@ class TestTrainingSpans:
             "half_item", "half_user", "iteration", "iteration",
         ]
         assert tagged("als.enqueue", "i") == [0, 0, 1, 2]
+        # how often the dual form engages: real rows by the form their
+        # bucket is solved in (explicit, rank 4: widths 1 and 2 dual),
+        # on the span of each program, by the side or sides it solves
+        sides = {}
+        for side, (rows, cols, n, m) in (
+            ("user", (pd.users, pd.items, 60, 25)),
+            ("item", (pd.items, pd.users, 25, 60)),
+        ):
+            buckets = als.bucketize(rows, cols, pd.ratings, n, m).buckets
+            sides[side] = tuple(
+                sum(len(b.rows) for b in buckets if (b.width < 4) == dual)
+                for dual in (True, False)
+            )
+            assert sum(sides[side]) == len(np.unique(rows))
+        sides["both"] = tuple(map(sum, zip(sides["user"], sides["item"])))
+        by_program = {
+            s["tags"]["program"]: (s["tags"]["dual_rows"], s["tags"]["primal_rows"])
+            for s in children if s["name"] == "als.enqueue"
+        }
+        assert by_program == {
+            "half_user": sides["user"], "half_item": sides["item"],
+            "iteration": sides["both"],
+        }
         counts = {}
         for s in children:
             counts[s["name"]] = counts.get(s["name"], 0) + 1
@@ -234,11 +257,20 @@ class TestTrainingSpans:
         by_user = als.bucketize(pd.users, pd.items, pd.ratings, 60, 25)
         by_item = als.bucketize(pd.items, pd.users, pd.ratings, 25, 60)
         cfg = als.ALSConfig(rank=4, iterations=3, seed=1)
+        profile = {}
         with span("train") as root:
-            als.als_train(by_user, by_item, cfg, profile={})
+            als.als_train(by_user, by_item, cfg, profile=profile)
         spans = default_tracer().store.for_trace(root.trace_id)
         waits = [s for s in spans if s["name"] == "als.wait_device"]
         assert [s["tags"]["i"] for s in waits] == [0, 1, 2]
+        # the counter of the dual form, as the spans carry it
+        enqueued = {s["tags"]["program"]: s["tags"] for s in spans
+                    if s["name"] == "als.enqueue"}
+        assert profile["solve_forms"] == {
+            side: [enqueued[f"half_{side}"][k] for k in ("dual_rows", "primal_rows")]
+            for side in ("user", "item")
+        }
+        assert sum(profile["solve_forms"]["user"]) == len(np.unique(pd.users))
         # and never without ``profile``: nothing is fenced that was not
         with span("train") as bare:
             als.als_train(by_user, by_item, cfg)
@@ -338,6 +370,11 @@ class TestDeviceScopes:
         for narrow in (1, 2, 4, 16):
             assert f"als.w{narrow}/" not in text
         assert "als.user_side/als.w8/als.k2/als.scatter/scatter" in text
+        # an explicit bucket under the rank (16) runs the dual body: its
+        # two products carry the phase they are counted under, and an
+        # implicit job traces neither
+        for product in ("als.gramian/bkr,bjr->kjb", "als.solve/bkr,kb->br"):
+            assert (product in text) == (not implicit)
         # (a chunk's phases are a called function in this text, so their
         # stack is whole only in the compiled program:
         # tests/test_chip_compile.py reads it there)
