@@ -21,14 +21,13 @@ even on this single chip).
 
 Env knobs: ``BENCH_SCALE`` (default 1.0) scales the rating count for quick
 smoke runs; ``BENCH_ITERATIONS`` (default 10); ``BENCH_SYNTH_CACHE``
-(off by default; the revalidation queue sets it) names a directory where
+(off by default) names a directory where
 the deterministic synthetic dataset is cached across runs — cache files
 are keyed by (generator version, scale, seed). Lever knobs
-(``BENCH_SOLVE_MODE``/``BENCH_GATHER_DTYPE``/``BENCH_SORT_GATHER``/
-``BENCH_FUSED_GATHER``) are documented at their ALSConfig fields; since
-round 12 the fast paths default ON (sort-gather rides every run,
-``BENCH_SORT_GATHER=0`` opts out; fused-gather resolves with the
-solver, ``BENCH_FUSED_GATHER=0`` forces it off) and every round trains
+(``BENCH_SOLVE_MODE``/``BENCH_GATHER_DTYPE``/``BENCH_SORT_GATHER``)
+are documented at their ALSConfig fields (sort-gather rides every run,
+``BENCH_SORT_GATHER=0`` opts out; the fused gather+Gramian build comes
+with the ``pallas`` solver) and every round trains
 a bf16-gather twin whose holdout RMSE must stay within
 ``BENCH_BF16_RMSE_GATE`` (default 0.01) of the f32 run —
 ``BENCH_BF16_GATE=0`` opts out, a drift fails the bench loudly. The
@@ -65,8 +64,7 @@ def synth_ml20m(scale: float, seed: int = 0):
 
     Deterministic in (scale, seed), so when ``BENCH_SYNTH_CACHE`` names a
     directory the triplets are saved there once and reloaded by later
-    runs — the revalidation queue runs this bench ~8 times back to back
-    and each run would otherwise repeat the ~minute of host-side
+    runs, each of which would otherwise repeat the ~minute of host-side
     generation."""
     cache_dir = os.environ.get("BENCH_SYNTH_CACHE")
     cache = None
@@ -649,20 +647,13 @@ def run_bench(scale: float, iterations: int) -> int:
 
     solve_mode = os.environ.get("BENCH_SOLVE_MODE", "auto")
     gather_dtype = os.environ.get("BENCH_GATHER_DTYPE", "f32")
-    # fast paths default ON (round 12): sort-gather is host-side and
-    # proven equivalence-safe (ROUND7_NOTES), so it rides every run
-    # unless BENCH_SORT_GATHER=0 opts out; fused_gather tri-states —
-    # unset resolves WITH the solver (on exactly when solve_mode
-    # resolves to pallas), "0"/"1" force it
+    # sort-gather is host-side and proven equivalence-safe
+    # (ROUND7_NOTES), so it rides every run unless BENCH_SORT_GATHER=0
+    # opts out
     sort_gather = os.environ.get("BENCH_SORT_GATHER", "1") == "1"
-    fused_env = os.environ.get("BENCH_FUSED_GATHER")
-    fused_gather = None if fused_env is None else fused_env == "1"
-    if fused_gather and solve_mode == "auto":
-        solve_mode = "pallas"  # explicit fused build forces the solver
     cfg = ALSConfig(
         rank=50, iterations=iterations, lambda_=0.05, seed=0,
         solve_mode=solve_mode, gather_dtype=gather_dtype,
-        fused_gather=fused_gather,
     )
     if sort_gather:
         from predictionio_tpu.ops.als import sort_bucket_indices
@@ -677,7 +668,6 @@ def run_bench(scale: float, iterations: int) -> int:
     warm_cfg = ALSConfig(
         rank=cfg.rank, iterations=2, lambda_=cfg.lambda_, seed=cfg.seed,
         solve_mode=solve_mode, gather_dtype=gather_dtype,
-        fused_gather=fused_gather,
     )
     wu = stage(_maybe_sort(bucketize(users[tr], items[tr], ratings[tr],
                                      n_users, n_items, pad_to_blocks=True)))
@@ -761,7 +751,7 @@ def run_bench(scale: float, iterations: int) -> int:
         "solve_mode": profile.get("solve_mode", solve_mode),
         "gather_dtype": profile.get("gather_dtype", gather_dtype),
         "sort_gather": sort_gather,
-        "fused_gather": profile.get("fused_gather", bool(fused_gather)),
+        "fused_gather": profile.get("fused_gather", False),
         # compile/retrace accounting for THIS process (warmup included):
         # a bench round whose timed section quietly recompiled is not
         # measuring steady state, and this field says so
@@ -794,14 +784,15 @@ def run_bench(scale: float, iterations: int) -> int:
 
         gate = float(os.environ.get("BENCH_BF16_RMSE_GATE", "0.01"))
         twin_dtype = "bf16" if record["gather_dtype"] == "f32" else "f32"
-        # the twin runs the EINSUM build: gramian_fused upcasts bf16
-        # tables to f32 at kernel entry (Mosaic cannot DMA half-width
-        # sublanes), so a fused-path twin would measure f32 math under
-        # a bf16 label — the einsum path is where the bf16 lever
-        # actually feeds the MXU at reduced precision, and the only
-        # path where it buys HBM bytes (estimate_iteration_hbm_bytes)
+        # the twin runs the EINSUM build (the ``chunked`` solve):
+        # gramian_fused upcasts bf16 tables to f32 at kernel entry
+        # (Mosaic cannot DMA half-width sublanes), so a fused-path twin
+        # would measure f32 math under a bf16 label — the einsum path
+        # is where the bf16 lever actually feeds the MXU at reduced
+        # precision, and the only path where it buys HBM bytes
+        # (estimate_iteration_hbm_bytes)
         twin_cfg = _dc.replace(
-            cfg, gather_dtype=twin_dtype, fused_gather=False
+            cfg, gather_dtype=twin_dtype, solve_mode="chunked"
         )
         twin = als_train(by_user, by_item, twin_cfg)
         twin_rmse = rmse(twin, users[test], items[test], ratings[test])
@@ -813,7 +804,7 @@ def run_bench(scale: float, iterations: int) -> int:
             # precision math against the f32 twin
             bf16_leg = als_train(
                 by_user, by_item,
-                _dc.replace(cfg, gather_dtype="bf16", fused_gather=False),
+                _dc.replace(cfg, gather_dtype="bf16", solve_mode="chunked"),
             )
             bf16_rmse = rmse(
                 bf16_leg, users[test], items[test], ratings[test]

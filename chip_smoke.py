@@ -554,8 +554,7 @@ def phase_sharded_compare(run: Run) -> None:
     rtol, atol = 1e-3, 2e-3
     iterations = 2
     f32_matmuls = {"JAX_DEFAULT_MATMUL_PRECISION": "highest"}
-    write_engine_json(run, iterations, solve_mode="chunked",
-                      fused_gather=False)
+    write_engine_json(run, iterations, solve_mode="chunked")
     n = run.chips
     say(f"sharded: rank {run.w.rank}, {iterations} iterations, "
         f"solve_mode chunked, {json.dumps(f32_matmuls)}, "

@@ -342,6 +342,18 @@ class Engine:
     def engine_instance_to_engine_params(self, instance) -> EngineParams:
         """Rebuild EngineParams from a stored EngineInstance row
         (``Engine.scala:372-425``) — the deploy path's parameter source."""
+        def stored(params_cls, raw) -> Params:
+            """A stored block's params, less the fields its class has
+            retired since the instance was trained."""
+            retired = set(raw or ()) & set(getattr(params_cls, "retired_fields", ()))
+            if retired:
+                logger.info(
+                    "engine instance %s: dropping retired %s fields %s",
+                    instance.id, params_cls.__name__, sorted(retired),
+                )
+                raw = {k: v for k, v in raw.items() if k not in retired}
+            return extract_params(params_cls, raw)
+
         def parse(text: str, class_map: ClassMap, stage: str) -> Tuple[str, Params]:
             if not text:
                 return ("", _default_params(class_map, ""))
@@ -354,7 +366,7 @@ class Engine:
                     "removed component)."
                 )
             cls = class_map[name]
-            return (name, extract_params(_component_params_class(cls), obj.get("params")))
+            return (name, stored(_component_params_class(cls), obj.get("params")))
 
         algo_list: List[Tuple[str, Params]] = []
         if instance.algorithms_params:
@@ -368,7 +380,7 @@ class Engine:
                     )
                 cls = self.algorithm_class_map[name]
                 algo_list.append(
-                    (name, extract_params(_component_params_class(cls), block.get("params")))
+                    (name, stored(_component_params_class(cls), block.get("params")))
                 )
         else:
             algo_list = [("", _default_params(self.algorithm_class_map, ""))]

@@ -14,7 +14,7 @@ from __future__ import annotations
 import dataclasses
 import types
 import typing
-from typing import Any, Dict, Mapping, Optional, Type, TypeVar
+from typing import Any, ClassVar, Dict, Mapping, Optional, Tuple, Type, TypeVar
 
 T = TypeVar("T")
 
@@ -31,6 +31,13 @@ class Params:
 
     Subclasses are frozen dataclasses; fields define the accepted JSON keys.
     """
+
+    #: names of fields this class once had: an engine instance stored by an
+    #: earlier release carries them (``params_to_json`` writes every field),
+    #: and the deploy path drops them when it reads the row back
+    #: (``Engine.engine_instance_to_engine_params``). ``extract_params``
+    #: does not look here: an ``engine.json`` that names one is rejected.
+    retired_fields: ClassVar[Tuple[str, ...]] = ()
 
 
 @dataclasses.dataclass(frozen=True)

@@ -238,7 +238,7 @@ class ALSAlgorithmParams(Params):
     #: sharded trainer snapshots canonical row order, so the resume
     #: shard count is free to differ (docs/checkpoint.md).
     checkpoint_every: Optional[int] = None
-    #: "auto" | "chunked" | "two_phase" | "pallas" — see
+    #: "auto" | "chunked" | "pallas" — see
     #: ops.als.ALSConfig.solve_mode ("auto" picks the fused pallas
     #: Cholesky kernel on a single-chip TPU run, "chunked" elsewhere)
     solve_mode: str = "auto"
@@ -252,11 +252,6 @@ class ALSAlgorithmParams(Params):
     #: ON — pass False for the legacy unsorted path (see
     #: ops.als.ALSConfig.sort_gather_indices)
     sort_gather_indices: Optional[bool] = None
-    #: Build normal equations with the fused gather+Gramian Pallas
-    #: kernel. None (default) resolves to ON exactly when solve_mode
-    #: resolves to "pallas" — pass False for the einsum build (see
-    #: ops.als.ALSConfig.fused_gather)
-    fused_gather: Optional[bool] = None
     #: Serving top-k path: "auto" (default) streams item blocks through
     #: the fused Pallas score+select kernel — never materializing the
     #: [batch, n_items] score matrix in HBM — when on TPU and that
@@ -282,6 +277,8 @@ class ALSAlgorithmParams(Params):
     #: (recorded in the gate status), the analogue of the bench's
     #: BENCH_BF16_RMSE_GATE override.
     quant_gate_min_match: float = 1.0
+
+    retired_fields = ("fused_gather",)
 
 
 @dataclasses.dataclass
@@ -408,7 +405,6 @@ class ALSAlgorithm(Algorithm):
             solve_mode=p.solve_mode,
             gather_dtype=p.gather_dtype,
             sort_gather_indices=p.sort_gather_indices,
-            fused_gather=p.fused_gather,
         )
         from ..ckpt import resolve_every, resolve_resume
         from ..ops.als_sharded import als_train_sharded

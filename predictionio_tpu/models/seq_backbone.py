@@ -278,7 +278,7 @@ def _rope(t, pos, rot: int, theta: float):
     return jnp.concatenate([r * cos + half * sin, rest], -1)
 
 
-def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule, impl):
+def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule):
     b, l, _ = x.shape
     h, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     cd, f32 = _dt(cfg.compute_dtype), jnp.float32
@@ -301,7 +301,7 @@ def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule, 
     o = attention(
         q.astype(cd).transpose(0, 2, 1, 3), k.astype(cd).transpose(0, 2, 1, 3),
         v.astype(cd).transpose(0, 2, 1, 3), mesh=mesh, causal=True,
-        schedule=schedule, impl=impl, segment_ids=seg, block=cfg.attn_block,
+        schedule=schedule, segment_ids=seg, block=cfg.attn_block,
     )
     o = o.transpose(0, 2, 1, 3).reshape(b, l, h * hd).astype(f32)
     if cfg.attn_gate:
@@ -323,13 +323,13 @@ def _ffn(cfg: BackboneConfig, p: Dict, x):
     return jnp.dot(hidden.astype(cd), p["mlp_out"].astype(cd), preferred_element_type=f32), {}
 
 
-def _layer(cfg: BackboneConfig, full: bool, mesh, schedule, impl, x, seg, pos,
+def _layer(cfg: BackboneConfig, full: bool, mesh, schedule, x, seg, pos,
            norm_in, mixer, norm_post, ffn):
     h = _norm(cfg, norm_in, x)
     ran = {}
     if full:
         with jax.named_scope("seq.attn"):
-            x = x + _attention_mixer(cfg, mixer, h, seg, pos, mesh, schedule, impl)
+            x = x + _attention_mixer(cfg, mixer, h, seg, pos, mesh, schedule)
     else:
         with jax.named_scope("seq.deltanet"):
             mixed, ran = gated_deltanet(
@@ -344,7 +344,7 @@ def _layer(cfg: BackboneConfig, full: bool, mesh, schedule, impl, x, seg, pos,
 
 
 def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
-                  schedule: str = "auto", impl: str = "xla"):
+                  schedule: str = "auto"):
     """tokens, seg [B, L] -> the residual stream after the last layer
     [B, L, D] (float32, before the final norm); the expert layers'
     counters, stacked [periods, layers of a period, ...]; and what the
@@ -363,7 +363,7 @@ def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
             x = x + table[pos]
 
     def layer_fn(full):
-        fn = lambda *a: _layer(cfg, full, mesh, schedule, impl, *a)  # noqa: E731
+        fn = lambda *a: _layer(cfg, full, mesh, schedule, *a)  # noqa: E731
         return jax.checkpoint(fn)
 
     linear_layer, full_layer = layer_fn(False), layer_fn(True)
@@ -430,9 +430,9 @@ def split_rows(rows, segs):
 
 
 def loss_fn(cfg: BackboneConfig, params: Dict, rows, segs, mesh=None,
-            schedule: str = "auto", impl: str = "xla"):
+            schedule: str = "auto"):
     """The training loss of one batch of packed rows, and (aux) the final
     hidden states, the counters and what the first delta rule ran on."""
     tokens, seg, targets, valid = split_rows(rows, segs)
-    hidden, counters, ran = hidden_states(cfg, params, tokens, seg, mesh, schedule, impl)
+    hidden, counters, ran = hidden_states(cfg, params, tokens, seg, mesh, schedule)
     return next_item_loss(cfg, params, hidden, targets, valid), (hidden, counters, ran)

@@ -271,10 +271,8 @@ class SeqRecAlgorithmParams(Params):
     #: attention schedule: "flash" (single device), "ring", "ulysses",
     #: or "auto" (ring when the ctx mesh has a seq axis of size > 1)
     schedule: str = "flash"
-    #: attention implementation on the single-device path: "xla"
-    #: (default: blockwise in XLA, forward and backward) or "pallas" (the
-    #: fused forward kernel, ops.attention.flash_attention_pallas)
-    flash_impl: str = "xla"
+
+    retired_fields = ("flash_impl",)
 
     def backbone_config(self) -> bb.BackboneConfig:
         if self.backbone:
@@ -373,24 +371,23 @@ class _Batches:
         return item
 
 
-def make_loss_and_grad(cfg: bb.BackboneConfig, mesh=None, schedule: str = "auto",
-                       impl: str = "xla"):
+def make_loss_and_grad(cfg: bb.BackboneConfig, mesh=None, schedule: str = "auto"):
     """``(params, rows, segs) -> ((loss, (hidden, counters, ran)), grads)``
     (``seq_backbone.loss_fn``): the function the optimizer step is built
     from, jitted."""
     return jax.jit(jax.value_and_grad(
-        lambda mp, rows, segs: bb.loss_fn(cfg, mp, rows, segs, mesh, schedule, impl),
+        lambda mp, rows, segs: bb.loss_fn(cfg, mp, rows, segs, mesh, schedule),
         has_aux=True))
 
 
 @functools.lru_cache(maxsize=8)
-def _programs(cfg: bb.BackboneConfig, learning_rate: float, mesh, schedule: str, impl: str):
+def _programs(cfg: bb.BackboneConfig, learning_rate: float, mesh, schedule: str):
     """The jitted programs of a job, made once per configuration: a second
     job of the same shape compiles nothing."""
     import optax
 
     opt = optax.adamw(learning_rate)
-    loss_and_grad = make_loss_and_grad(cfg, mesh, schedule, impl)
+    loss_and_grad = make_loss_and_grad(cfg, mesh, schedule)
 
     def step(mp, os_, rows, segs):
         (loss, (_, counters, _)), grads = loss_and_grad(mp, rows, segs)
@@ -416,7 +413,7 @@ class SeqRecAlgorithm(Algorithm):
         (params -> state), the donated ``step`` ((params, state, rows,
         segs) -> params, state, loss, counters) and the loss-and-gradient
         function the step is built from (:func:`make_loss_and_grad`)."""
-        return _programs(cfg, self.params.learning_rate, None, "auto", self.params.flash_impl)
+        return _programs(cfg, self.params.learning_rate, None, "auto")
 
     def train(self, ctx, pd: PreparedData) -> SeqRecModel:
         p = self.params
@@ -441,7 +438,7 @@ class SeqRecAlgorithm(Algorithm):
     def _run_steps(self, pd, cfg, batches, batch, mesh, schedule) -> SeqRecModel:
         p = self.params
         vocab = len(pd.item_map)
-        opt_init, step, _ = _programs(cfg, p.learning_rate, mesh, schedule, p.flash_impl)
+        opt_init, step, _ = _programs(cfg, p.learning_rate, mesh, schedule)
         with span("seqrec.init"):
             model_params = bb.init_params(cfg, vocab, pd.seq_len, p.seed)
             opt_state = opt_init(model_params)
@@ -502,8 +499,7 @@ class SeqRecAlgorithm(Algorithm):
         seg = np.asarray([0] * pad + [1] * len(recent), np.int32)[None, :]
         k = min(query.num, len(model.item_map))
         top_s, top_i = _encode_and_select(
-            model.config, self.params.flash_impl, k,
-            model.device_params(), jnp.asarray(tokens), jnp.asarray(seg))
+            model.config, k, model.device_params(), jnp.asarray(tokens), jnp.asarray(seg))
         # Next-item prediction keeps previously-seen items eligible (Markov
         # semantics: the next state may be a revisit). Scores are logits;
         # score-and-select on the device, one round trip.
@@ -520,9 +516,9 @@ class SeqRecAlgorithm(Algorithm):
         return Query
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _encode_and_select(cfg: bb.BackboneConfig, impl: str, k: int, params, tokens, seg):
-    hidden, *_ = bb.hidden_states(cfg, params, tokens, seg, impl=impl)
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _encode_and_select(cfg: bb.BackboneConfig, k: int, params, tokens, seg):
+    hidden, *_ = bb.hidden_states(cfg, params, tokens, seg)
     last = bb._norm(cfg, params["final_norm"], hidden[:, -1])
     return top_k_for_vectors(last, bb.head_of(params), k)
 

@@ -58,8 +58,7 @@ __all__ = [
 SCHEMA_VERSION = 1
 
 #: env naming the ledger file training runs append to (bench.py has its
-#: own ``BENCH_LEDGER`` knob so the revalidation queue opts in without
-#: touching the stdout contract)
+#: own ``BENCH_LEDGER`` knob, which leaves its stdout contract alone)
 LEDGER_ENV = "PIO_PERF_LEDGER"
 
 #: Flag a latest value this much worse than the median of its

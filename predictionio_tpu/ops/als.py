@@ -394,11 +394,10 @@ class ALSConfig:
     #: rank <= 80 (single-chip or mesh — under a mesh the kernel runs
     #: per-device inside shard_map over the data axis), else "chunked".
     #: "chunked" fuses each block's Cholesky into the chunk map;
-    #: "two_phase" batches one Cholesky per bucket (measured slower than
-    #: chunked on v5e); "pallas" replaces XLA's batched Cholesky with
+    #: "pallas" replaces XLA's batched Cholesky with
     #: the fused transposed-layout kernel
     #: (ops/pallas_kernels.spd_solve_t: 167 ns a 56 x 56 system on a
-    #: v5e, 15.5 ns an 8 x 8 one; PERF.md §6, PR 27). All modes produce
+    #: v5e, 15.5 ns an 8 x 8 one; PERF.md §6, PR 27). Both produce
     #: identical results up to float reassociation.
     solve_mode: str = "auto"
     #: "f32" (default) or "bf16": dtype of the gathered opposite-side
@@ -420,18 +419,6 @@ class ALSConfig:
     #: Pass ``False`` explicitly to opt out (the legacy unsorted path);
     #: an explicit ``True`` with staged inputs still fails loudly.
     sort_gather_indices: Optional[bool] = None
-    #: Build the normal equations with the fused gather+Gramian Pallas
-    #: kernel (``ops/pallas_kernels.gramian_fused``) instead of the XLA
-    #: gather + einsum: factor rows stream HBM→VMEM exactly once and the
-    #: ``[B, K, R]`` gathered intermediate never exists (~3× less
-    #: gather-stage HBM traffic by the PERF.md accounting). ``None``
-    #: (the default) resolves to True exactly when ``solve_mode``
-    #: resolves to "pallas" (the fused build shares that kernel family's
-    #: VMEM envelope); pass ``False`` explicitly to opt out (the
-    #: einsum-built legacy path). An explicit ``True`` with a
-    #: non-pallas solve mode still fails loudly — a silently ignored
-    #: flag would corrupt the hardware A/B.
-    fused_gather: Optional[bool] = None
 
     def resolve_levers(self, staged_inputs: bool = False) -> dict:
         """The CONCRETE lever settings a train run with this config will
@@ -450,14 +437,17 @@ class ALSConfig:
         sort = self.sort_gather_indices
         if sort is None:
             sort = not staged_inputs
-        fused = self.fused_gather
-        if fused is None:
-            fused = solve_mode == "pallas"
         return {
             "solve_mode": solve_mode,
             "gather_dtype": self.gather_dtype,
             "sort_gather": bool(sort),
-            "fused_gather": bool(fused),
+            # the ``pallas`` solve builds a bucket as wide as the rank or
+            # wider with the fused gather + Gramian kernel
+            # (``ops/pallas_kernels.gramian_fused``): factor rows stream
+            # HBM→VMEM once and no ``[B, K, R]`` block exists. Reported,
+            # not chosen (PERF.md §6, PR 28: the einsum build of those
+            # buckets lost its A/B on the chip, 37.8 s a job against 36.0)
+            "fused_gather": solve_mode == "pallas",
         }
 
 
@@ -788,7 +778,6 @@ def _fused_chunk_solve(
 def _solve_side_traced(
     y, buckets, n_rows, rank, implicit, lam, alpha, yty,
     solve_mode="chunked", gather_dtype="f32", mesh=None,
-    fused_gather=False,
 ):
     """Unrolled bucket loop inside a traced program (no per-bucket dispatch).
 
@@ -797,20 +786,19 @@ def _solve_side_traced(
     * ``"chunked"`` — each lax.map step builds one block's normal
       equations AND Cholesky-solves it. Minimal live memory, but the
       sequential depth is (chunks × Cholesky's ~R-step loop).
-    * ``"two_phase"`` — the lax.map only builds A/b per chunk (the
-      memory-bounded gather stays chunked); ONE batched Cholesky then
-      solves the whole bucket, cutting sequential solve depth from
-      O(chunks × R) to O(R) per bucket at the cost of materializing
-      A [C·B, R, R] (≈1 GB for ML-20M's largest bucket at rank 50).
-    * ``"pallas"`` — builds each chunk's normal equations directly in the
-      transposed [R, R, B] layout and solves with the fused Cholesky
-      kernel (``ops/pallas_kernels.spd_solve_t``); the XLA batched
-      Cholesky was ~2/3 of the iteration wall-clock on v5e.
+    * ``"pallas"`` — solves with the fused Cholesky kernel
+      (``ops/pallas_kernels.spd_solve_t``; the XLA batched Cholesky was
+      ~2/3 of the iteration wall-clock on v5e). A bucket as wide as the
+      rank or wider builds its normal equations with the fused gather +
+      Gramian kernel (``gramian_fused``: the removed ``[B, K, R]`` round
+      trip outweighs its ``[B, R, R]`` transpose from there up); a
+      narrower one, which only an implicit job has, builds them by
+      einsum directly in the solver's transposed ``[R, R, B]`` layout.
 
     Whatever the mode, an explicit bucket narrower than the rank is
     solved in the dual form (``solve_chunk_dual``: a ``k × k`` system a
-    row, by the ``pallas`` mode's kernel at ``n = max(8, k)`` or, in the
-    other two, by XLA's Cholesky a block): the rule is
+    row, by the ``pallas`` mode's kernel at ``n = max(8, k)`` or, under
+    ``chunked``, by XLA's Cholesky a block): the rule is
     :func:`_solves_dual`, read from the bucket's shape. Every other
     bucket, and every implicit job, runs the primal program below.
 
@@ -819,8 +807,7 @@ def _solve_side_traced(
     auto-partition under pjit) is wrapped in ``shard_map`` over the
     ``data`` axis: each device Cholesky-solves its local ``[R, R,
     B/n_data]`` block with zero collectives inside the solve. The XLA
-    paths (chunked/two_phase) partition automatically and ignore
-    ``mesh``.
+    path (``chunked``) partitions automatically and ignores ``mesh``.
     """
     x = jnp.zeros((n_rows, rank), dtype=jnp.float32)
     gdt = jnp.bfloat16 if gather_dtype == "bf16" else jnp.float32
@@ -888,30 +875,24 @@ def _solve_side_traced(
             )(a_t, b_t)[:, :bsz]
 
         def solve_chunk_pallas(c):
+            """An implicit block narrower than the rank (an explicit one
+            goes dual, a wider one fused)."""
             idx_blk, val_blk, counts_blk = c
             with jax.named_scope("als.gather"):
                 mask = expand_mask(idx_blk, counts_blk)
                 g = y_pad[idx_blk] * mask[..., None]  # [B, K, n_pad]
             with jax.named_scope("als.gramian"):
-                if implicit:
-                    maskf = mask.astype(jnp.float32)
-                    c1 = (alpha * jnp.abs(val_blk)) * maskf
-                    pref = (val_blk > 0).astype(jnp.float32) * maskf
-                    a_t = yty_pad[:, :, None] + jnp.einsum(
-                        "bkr,bk,bks->rsb", g, c1.astype(g.dtype), g,
-                        preferred_element_type=jnp.float32,
-                    )
-                    rhs = (1.0 + c1) * pref
-                else:
-                    a_t = jnp.einsum(
-                        "bkr,bks->rsb", g, g,
-                        preferred_element_type=jnp.float32,
-                    )
-                    rhs = val_blk
+                maskf = mask.astype(jnp.float32)
+                c1 = (alpha * jnp.abs(val_blk)) * maskf
+                pref = (val_blk > 0).astype(jnp.float32) * maskf
+                a_t = yty_pad[:, :, None] + jnp.einsum(
+                    "bkr,bk,bks->rsb", g, c1.astype(g.dtype), g,
+                    preferred_element_type=jnp.float32,
+                )
                 n_u = counts_blk.astype(jnp.float32)  # == mask.sum(axis=1)
                 a_t = a_t + (lam * n_u)[None, None, :] * eye_t
                 b_t = jnp.einsum(
-                    "bkr,bk->rb", g, rhs.astype(g.dtype),
+                    "bkr,bk->rb", g, ((1.0 + c1) * pref).astype(g.dtype),
                     preferred_element_type=jnp.float32,
                 )
             with jax.named_scope("als.solve"):
@@ -1019,21 +1000,8 @@ def _solve_side_traced(
             if _solves_dual(width, rank, implicit):
                 solved = jax.lax.map(solve_chunk_dual, (idx, val, counts))
             elif solve_mode == "pallas":
-                # fused gather+Gramian only pays for itself when the
-                # removed [B, K, R] round trip outweighs its [B, R, R]
-                # transpose — i.e. width >= rank; narrow buckets keep the
-                # einsum build
-                fn = (
-                    solve_chunk_fused
-                    if fused_gather and width >= rank
-                    else solve_chunk_pallas
-                )
+                fn = solve_chunk_fused if width >= rank else solve_chunk_pallas
                 solved = jax.lax.map(fn, (idx, val, counts))
-            elif solve_mode == "two_phase":
-                a, b = jax.lax.map(system, (idx, val, counts))
-                solved = _cho_solve(
-                    a.reshape(-1, rank, rank), b.reshape(-1, rank)
-                )
             else:
                 solved = jax.lax.map(lambda c: _cho_solve(*system(c)),
                                      (idx, val, counts))
@@ -1046,7 +1014,7 @@ def _solve_side_traced(
 
 def _solve_side_scoped(
     side, y, buckets, n_rows, rank, implicit, lam, alpha,
-    solve_mode, gather_dtype, mesh, fused_gather,
+    solve_mode, gather_dtype, mesh,
 ):
     """One side's solve under its device scope, ``als.user_side`` or
     ``als.item_side`` by the side SOLVED (``y`` is the opposite table):
@@ -1063,14 +1031,13 @@ def _solve_side_scoped(
         return _solve_side_traced(
             y, buckets, n_rows, rank, implicit, lam, alpha, yty,
             solve_mode=solve_mode, gather_dtype=gather_dtype, mesh=mesh,
-            fused_gather=fused_gather,
         )
 
 
 def _als_iteration_body(
     user_buckets, item_buckets, y, lam, alpha,
     rank, implicit, n_users, n_items, solve_mode="chunked",
-    gather_dtype="f32", mesh=None, fused_gather=False,
+    gather_dtype="f32", mesh=None,
 ):
     """One full ALS iteration (user solve + item solve, all buckets) as a
     single device program — one dispatch per iteration. ``lam``/``alpha``
@@ -1081,11 +1048,11 @@ def _als_iteration_body(
     ``iterations`` while staying cheap to compile.)"""
     x = _solve_side_scoped(
         "user", y, user_buckets, n_users, rank, implicit, lam, alpha,
-        solve_mode, gather_dtype, mesh, fused_gather,
+        solve_mode, gather_dtype, mesh,
     )
     y2 = _solve_side_scoped(
         "item", x, item_buckets, n_items, rank, implicit, lam, alpha,
-        solve_mode, gather_dtype, mesh, fused_gather,
+        solve_mode, gather_dtype, mesh,
     )
     return x, y2
 
@@ -1093,7 +1060,7 @@ def _als_iteration_body(
 def _als_half_body(
     y, buckets, lam, alpha,
     rank, implicit, n_rows, solve_mode="chunked",
-    gather_dtype="f32", mesh=None, fused_gather=False, side="user",
+    gather_dtype="f32", mesh=None, side="user",
 ):
     """One HALF iteration (solve one side from the opposite factors) as its
     own device program. The training loop uses this for the first executed
@@ -1104,13 +1071,13 @@ def _als_half_body(
     whole-iteration program (one dispatch each)."""
     return _solve_side_scoped(
         side, y, buckets, n_rows, rank, implicit, lam, alpha,
-        solve_mode, gather_dtype, mesh, fused_gather,
+        solve_mode, gather_dtype, mesh,
     )
 
 
 _HALF_STATICS = (
     "rank", "implicit", "n_rows", "solve_mode",
-    "gather_dtype", "mesh", "fused_gather", "side",
+    "gather_dtype", "mesh", "side",
 )
 
 _als_half = functools.partial(
@@ -1133,7 +1100,7 @@ _als_iteration = functools.partial(
     jax.jit,
     static_argnames=(
         "rank", "implicit", "n_users", "n_items", "solve_mode",
-        "gather_dtype", "mesh", "fused_gather",
+        "gather_dtype", "mesh",
     ),
 )(_als_iteration_body)
 
@@ -1147,7 +1114,7 @@ def _als_iteration_sharded(out_sharding):
         _als_iteration_body,
         static_argnames=(
             "rank", "implicit", "n_users", "n_items", "solve_mode",
-            "gather_dtype", "mesh", "fused_gather",
+            "gather_dtype", "mesh",
         ),
         out_shardings=(out_sharding, out_sharding),
     )
@@ -1191,10 +1158,10 @@ def als_train(
 
     if cfg.iterations < 1:
         raise ValueError(f"ALS iterations must be >= 1, got {cfg.iterations}")
-    if cfg.solve_mode not in ("auto", "chunked", "two_phase", "pallas"):
+    if cfg.solve_mode not in ("auto", "chunked", "pallas"):
         raise ValueError(
-            f"solve_mode must be 'auto', 'chunked', 'two_phase' or "
-            f"'pallas', got {cfg.solve_mode!r}"
+            f"solve_mode must be 'auto', 'chunked' or 'pallas', "
+            f"got {cfg.solve_mode!r}"
         )
     if cfg.gather_dtype not in ("f32", "bf16"):
         raise ValueError(
@@ -1216,15 +1183,6 @@ def als_train(
         raise ValueError(
             f"solve_mode='pallas' supports rank <= 80 (VMEM scratch "
             f"bound), got rank={cfg.rank}; use 'auto' or 'chunked'"
-        )
-    if cfg.fused_gather and solve_mode != "pallas":
-        # only an EXPLICIT True can conflict (the None default resolves
-        # with the solve mode); a silently ignored flag would corrupt
-        # the hardware A/B
-        raise ValueError(
-            "fused_gather=True requires solve_mode to resolve to 'pallas' "
-            f"(resolved to {solve_mode!r}); pass solve_mode='pallas' "
-            "explicitly off-TPU"
         )
     fused_gather = levers["fused_gather"]
     sort_gather = levers["sort_gather"]
@@ -1372,7 +1330,6 @@ def als_train(
         solve_mode=solve_mode,
         gather_dtype=cfg.gather_dtype,
         mesh=mesh if solve_mode == "pallas" else None,
-        fused_gather=fused_gather,
     )
     # jit boundary telemetry (docs/observability.md#profiling): a solve
     # call that compiles is counted (and, past the first, counted as a

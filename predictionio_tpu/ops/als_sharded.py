@@ -33,7 +33,7 @@ on ``shard_map`` so the collective schedule is explicit:
    software pipeline XLA's latency-hiding scheduler can overlap on TPU.
    (Extending the ragged fetch across shards — skipping the dense
    all-gather entirely at shard counts where replicating the table per
-   device no longer fits — remains hardware-day headroom in
+   device no longer fits — has not been built: see
    docs/distributed_training.md.)
 5. **Implicit mode** builds YᵀY as a ``psum`` of per-shard Gramians — the
    collective the ``spmd-*`` lint family pins this file as the clean
@@ -480,11 +480,11 @@ def resolve_sharded_levers(cfg: ALSConfig) -> dict:
     """Lever resolution for the sharded data plane (the PR-12 "record
     resolved, not requested" discipline). The sharded trainer builds
     normal equations with the einsum path and solves with the batched
-    Cholesky per shard — ``solve_mode`` must be ``auto``/``chunked`` and
-    ``fused_gather`` must not be forced on; composing the fused Pallas
-    build inside the mapped body is hardware-day headroom
-    (docs/distributed_training.md#headroom). A silently ignored flag
-    would corrupt the hardware A/B, so explicit conflicts fail loudly."""
+    Cholesky per shard — ``solve_mode`` must be ``auto``/``chunked``;
+    composing the Pallas solver and its fused build inside the mapped
+    body has not been built (docs/distributed_training.md#headroom). An
+    explicit ``pallas`` fails loudly rather than train another program
+    than the one asked for."""
     if cfg.solve_mode not in ("auto", "chunked"):
         raise ValueError(
             "sharded training solves 'chunked' (einsum build + batched "
@@ -494,12 +494,6 @@ def resolve_sharded_levers(cfg: ALSConfig) -> dict:
     if cfg.gather_dtype not in ("f32", "bf16"):
         raise ValueError(
             f"gather_dtype must be 'f32' or 'bf16', got {cfg.gather_dtype!r}"
-        )
-    if cfg.fused_gather:
-        raise ValueError(
-            "fused_gather=True is not supported with shards > 1 (the "
-            "fused Pallas build inside the sharded body is hardware-day "
-            "headroom); leave the tri-state unset"
         )
     sort = cfg.sort_gather_indices
     return {
@@ -563,8 +557,7 @@ def als_train_sharded(
 
     ``profile`` receives the resolved levers (+ ``shards``), per-iteration
     wall clock, the ``shard_plan`` balance evidence (per-shard FLOPs,
-    imbalance ratio, rows per shard) — the per-host bucket stats the
-    hardware-day drive prints to confirm balance on real silicon — and,
+    imbalance ratio, rows per shard) — and,
     when checkpointing, a ``ckpt`` block (written/dropped/errors counts,
     snapshot seconds, the step resumed from).
     """

@@ -13,8 +13,6 @@ Three schedules, one math:
   softmax over the (Q block, KV block) pairs that can hold a kept score:
   O(block²) memory instead of O(L²) forward and backward (a flash-style
   custom VJP), grouped key/value heads, a segment mask for packed rows.
-  :func:`flash_attention_pallas` is a fused forward kernel for equal head
-  counts; the XLA path is the default and the faster on the v5e.
 - :func:`ring_attention` — sequence parallelism over a mesh axis: every
   device keeps its Q chunk, KV chunks rotate around the ring via
   ``ppermute`` (ICI neighbor exchanges), partial results merge with the same
@@ -37,7 +35,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from jax import shard_map
-from jax.experimental import pallas as pl
 
 SEQ_AXIS = "seq"
 
@@ -64,187 +61,6 @@ def _attend_block(q, k, v, m, l, o, mask, scale):
         preferred_element_type=jnp.float32,
     )
     return m_new, l_new, o_new
-
-
-def _flash_kernel(q_ref, k_ref, v_ref, sq_ref, sk_ref, o_ref, *, blk_q, blk_k,
-                  lk, causal, scale, n_kv):
-    """One (batch·head, Q-block) grid step: online softmax over KV blocks.
-
-    Everything lives in VMEM: q block [blk_q, D], full K/V [Lk_pad, D]
-    (fetched once per batch·head — the Q-block grid dim is innermost and
-    their index map is constant in it), score tiles [blk_q, blk_k] that
-    never touch HBM — the O(L²) score matrix is the thing this kernel
-    exists to not materialize. ``sq_ref`` [blk_q, 1] and ``sk_ref``
-    [1, Lk_pad] hold the slots' history ids: a score between two histories
-    is masked.
-    """
-    i = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale  # [blk_q, D]
-    d = q.shape[-1]
-    q_seg = sq_ref[0]  # [blk_q, 1]
-    q_pos = i * blk_q + jax.lax.broadcasted_iota(
-        jnp.int32, (blk_q, blk_k), 0
-    )
-
-    def step(j, carry):
-        m, l, o = carry
-        kj = k_ref[0, pl.ds(j * blk_k, blk_k), :].astype(jnp.float32)
-        vj = v_ref[0, pl.ds(j * blk_k, blk_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, kj, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [blk_q, blk_k]
-        k_pos = j * blk_k + jax.lax.broadcasted_iota(
-            jnp.int32, (blk_q, blk_k), 1
-        )
-        # pio: lint-ok[mosaic-unaligned-lane-slice] blk_k is a static param the AST cannot resolve; on the chip the wrapper's blocks are 256 (a multiple of 128; smaller blocks run in interpret mode only), so j*blk_k offsets and blk_k sizes are lane-aligned (compiled in tests/test_chip_compile.py)
-        keep = (k_pos < lk) & (q_seg == sk_ref[0, :, pl.ds(j * blk_k, blk_k)])
-        if causal:
-            keep = keep & (q_pos >= k_pos)
-        s = jnp.where(keep, s, _NEG_BIG)
-        m_new = jnp.maximum(m, s.max(axis=1))
-        corr = jnp.exp(m - m_new)
-        # a tile may hold none of a row's history: its masked scores must
-        # add nothing while the row's running maximum is still the floor
-        p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
-        l_new = l * corr + p.sum(axis=1)
-        o_new = o * corr[:, None] + jax.lax.dot_general(
-            p, vj, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, o_new
-
-    m0 = jnp.full((blk_q,), _NEG_BIG, dtype=jnp.float32)
-    l0 = jnp.zeros((blk_q,), dtype=jnp.float32)
-    o0 = jnp.zeros((blk_q, d), dtype=jnp.float32)
-    # causal: KV blocks strictly above this Q block's diagonal contribute
-    # nothing — skip them (the classic flash-attention work saving)
-    hi = (
-        jnp.minimum(((i + 1) * blk_q + blk_k - 1) // blk_k, n_kv)
-        if causal else n_kv
-    )
-    m, l, o = jax.lax.fori_loop(0, hi, step, (m0, l0, o0))
-    o_ref[0] = (o / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("causal", "blk_q", "blk_k", "interpret")
-)
-def _flash_pallas_call(q, k, v, seg_q, seg_k, causal, blk_q, blk_k, interpret):
-    b, h, lq, d = q.shape
-    lk = k.shape[2]
-    lq_pad = -lq % blk_q
-    lk_pad = -lk % blk_k
-    if lq_pad:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, lq_pad), (0, 0)))
-    if lk_pad:
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, lk_pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, lk_pad), (0, 0)))
-    bh = b * h
-    qr = q.reshape(bh, lq + lq_pad, d)
-    kr = k.reshape(bh, lk + lk_pad, d)
-    vr = v.reshape(bh, lk + lk_pad, d)
-    n_kv = (lk + lk_pad) // blk_k
-    out = pl.pallas_call(
-        functools.partial(
-            _flash_kernel, blk_q=blk_q, blk_k=blk_k, lk=lk,
-            causal=causal, scale=1.0 / np.sqrt(d), n_kv=n_kv,
-        ),
-        grid=(bh, (lq + lq_pad) // blk_q),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, d), lambda bhi, i: (bhi, i, 0)),
-            pl.BlockSpec((1, lk + lk_pad, d), lambda bhi, i: (bhi, 0, 0)),
-            pl.BlockSpec((1, lk + lk_pad, d), lambda bhi, i: (bhi, 0, 0)),
-            # pio: lint-ok[mosaic-blockspec-tiling] a block dim equal to the array's own dim (1) is allowed: one id a query row, broadcast along lanes in the kernel
-            pl.BlockSpec((1, blk_q, 1), lambda bhi, i: (bhi // h, i, 0)),
-            # pio: lint-ok[mosaic-blockspec-tiling] sublane dim 1 is the array's own dim: the row of key ids, broadcast along sublanes
-            pl.BlockSpec((1, 1, lk + lk_pad), lambda bhi, i: (bhi // h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, blk_q, d), lambda bhi, i: (bhi, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, lq + lq_pad, d), q.dtype),
-        interpret=interpret,
-    )(qr, kr, vr,
-      jnp.pad(seg_q, ((0, 0), (0, lq_pad)), mode="edge")[:, :, None],
-      jnp.pad(seg_k, ((0, 0), (0, lk_pad)), mode="edge")[:, None, :])
-    return out.reshape(b, h, lq + lq_pad, d)[:, :, :lq]
-
-
-def _flash_pallas_run(q, k, v, seg, causal, blk_q, blk_k, interpret):
-    """``seg``: the rows' history ids [B, L] (Lq == Lk), or None."""
-    b, lq, lk = q.shape[0], q.shape[2], k.shape[2]
-    seg_q, seg_k = (jnp.zeros((b, n), jnp.int32) if seg is None else seg for n in (lq, lk))
-    return _flash_pallas_call(q, k, v, seg_q, seg_k, causal, blk_q, blk_k, interpret)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_pallas_diff(q, k, v, seg, causal, blk_q, blk_k, interpret):
-    return _flash_pallas_run(q, k, v, seg, causal, blk_q, blk_k, interpret)
-
-
-def _flash_pallas_fwd(q, k, v, seg, causal, blk_q, blk_k, interpret):
-    # flash-style backward: save only q/k/v and recompute attention in
-    # the VJP (the O(L²) score matrix is never a residual) — here the
-    # recompute runs through the XLA path, whose own backward pass is the
-    # reference math the kernel is equality-tested against
-    return (
-        _flash_pallas_run(q, k, v, seg, causal, blk_q, blk_k, interpret),
-        (q, k, v, seg),
-    )
-
-
-def _flash_pallas_bwd(causal, blk_q, blk_k, interpret, res, g):
-    q, k, v, seg = res
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: flash_attention(
-            q_, k_, v_, causal=causal, segment_ids=seg),
-        q, k, v,
-    )
-    return vjp(g) + (None,)
-
-
-_flash_pallas_diff.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
-
-
-def flash_attention_pallas(
-    q: jax.Array,  # [B, H, L, D]
-    k: jax.Array,
-    v: jax.Array,
-    causal: bool = True,
-    block_q: int = 256,
-    block_k: int = 256,
-    interpret: Optional[bool] = None,
-    segment_ids: Optional[jax.Array] = None,  # [B, L]: packed rows, Lq == Lk
-) -> jax.Array:
-    """Pallas flash attention: fused scores+softmax+PV per Q block, causal
-    upper-triangle KV blocks skipped entirely, a segment mask for packed
-    rows. K/V are VMEM-resident per batch·head, so this single-device
-    kernel targets L up to the VMEM budget (~16k at D=64); beyond that,
-    shard the sequence (ring/Ulysses — which is the framework's
-    long-context answer anyway). As many key/value heads as query heads:
-    grouped heads are the XLA path's.
-
-    Differentiable: a custom VJP recomputes attention through the XLA
-    path in the backward pass (flash-style — only q/k/v are residuals,
-    never the score matrix), so training through this kernel is supported.
-
-    Selected via ``attention(..., impl="pallas")`` / ``flash_impl`` in the
-    sequencerec params. The XLA path is the default: on the v5e, at two
-    rows of 8,192 slots, it took 39.6 ms forward and backward against 51.7
-    (``PERF.md``, PR 26), so this kernel stays what it was plus the mask.
-    ``interpret=None`` auto-selects the interpreter off-TPU.
-    """
-    if k.shape[1] != q.shape[1]:
-        raise ValueError(
-            f"the Pallas kernel takes one key/value head a query head, not {k.shape[1]} "
-            f"for {q.shape[1]}: grouped heads run on the XLA path (impl=\"xla\")")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    lq, lk = q.shape[2], k.shape[2]
-    seg = None if segment_ids is None else segment_ids.astype(jnp.int32)
-    return _flash_pallas_diff(
-        q, k, v, seg, causal, min(block_q, max(8, lq)), min(block_k, lk),
-        interpret,
-    )
 
 
 def _grouped_and_padded(q, k, v, segment_ids, bq: int, bk: int):
@@ -576,22 +392,14 @@ def attention(
     axis: str = SEQ_AXIS,
     causal: bool = True,
     schedule: str = "auto",
-    impl: str = "xla",
     segment_ids: Optional[jax.Array] = None,
     block: int = 512,
 ) -> jax.Array:
     """Dispatch: single-device flash when no mesh / 1-device axis; otherwise
     ring (default) or Ulysses (``schedule="ulysses"``, when heads divide).
-    ``impl="pallas"`` takes the fused forward kernel
-    (:func:`flash_attention_pallas`) on the single-device path; the
-    sharded schedules keep the XLA inner step, and repeat grouped
-    key/value heads to one per query head."""
-    if impl not in ("xla", "pallas"):
-        raise ValueError(f"unknown attention impl {impl!r}")
+    The sharded schedules repeat grouped key/value heads to one per query
+    head."""
     if mesh is None or axis not in mesh.shape or mesh.shape[axis] == 1:
-        if impl == "pallas":
-            return flash_attention_pallas(
-                q, k, v, causal=causal, segment_ids=segment_ids)
         return flash_attention(
             q, k, v, causal=causal, block_k=block, segment_ids=segment_ids)
     if k.shape[1] != q.shape[1]:

@@ -321,11 +321,10 @@ def spd_solve_t(
 # Cost model (why this can win despite per-row DMAs): the XLA path moves
 # ~3 × B·K·R·4 bytes of HBM traffic per chunk; this kernel moves
 # B·K·(R·4 + ~overhead) with K_tile copies in flight to hide latency. The
-# risk is DMA-issue rate on small (rank·4 ≈ 200 B) transfers. Since
-# round 12 the kernel is the DEFAULT build wherever the pallas solver
-# resolves (ALSConfig.fused_gather=None; BENCH_FUSED_GATHER=0 /
-# fused_gather=False opt out) — the issue-rate question is still open
-# on the chip (PERF.md, Open questions).
+# risk is DMA-issue rate on small (rank·4 ≈ 200 B) transfers. The
+# kernel is the build of every bucket as wide as the rank wherever the
+# pallas solver resolves; on a v5e it moves a row in 52–67 ns, XLA's
+# gather in 60 (PERF.md §5–§6).
 #
 # Replaces the same MLlib hot loop as the solver above (reference:
 # ``examples/scala-parallel-recommendation/custom-prepartor/src/main/
@@ -378,7 +377,7 @@ def _gramian_kernel(idx_ref, w2_ref, rhs_ref, ridge_ref, y_ref, yty_ref,
         t = s % k_tiles
 
         def one(k, _):
-            # pio: lint-ok[mosaic-per-row-dma] the per-row gather IS this kernel's design; default-ON with the pallas solver since round 12 (explicit opt-out BENCH_FUSED_GATHER=0 / fused_gather=False), with the DMA-issue rate still unpriced on the chip (PERF.md, Open questions)
+            # pio: lint-ok[mosaic-per-row-dma] the per-row gather IS this kernel's design; the build of every bucket as wide as the rank under the pallas solver; 52-67 ns a row on a v5e (PERF.md §5)
             dma = pltpu.make_async_copy(
                 y_ref.at[pl.ds(idx_ref[b, t * kt + k], 1), :],
                 gbuf.at[slot, pl.ds(k, 1), :],
@@ -508,10 +507,9 @@ def gramian_fused(
     than the unpadded 224 B, which is what the hardware A/B prices.
 
     ``interpret=None`` auto-selects interpreter off-TPU. No XLA fallback:
-    the caller (``_solve_side_traced``) owns the dispatch — default-ON
-    with the pallas solver since round 12, with ``fused_gather=False``
-    as the explicit einsum-build opt-out and narrow (K < rank) buckets
-    auto-kept on the einsum path.
+    the caller (``_solve_side_traced``) owns the dispatch — every bucket
+    as wide as the rank under the pallas solver; narrower (K < rank)
+    buckets keep their own builds.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -563,9 +561,9 @@ def gramian_fused(
         # one sublane of a bf16-tiled VMEM buffer, and the minimum
         # lane-aligned copy is 128 lanes × 32 bits = 512 B — so bf16
         # CANNOT reduce this kernel's gathered bytes below the f32 path's
-        # 512 B/row. Upcasting is exact and keeps BENCH_GATHER_DTYPE=bf16
-        # composable with BENCH_FUSED_GATHER=1 (the combined leg then
-        # measures the fused kernel at f32 table width, honestly).
+        # 512 B/row. Upcasting is exact and keeps ``gather_dtype="bf16"``
+        # composable with this kernel (which then runs at f32 table
+        # width, honestly).
         y = y.astype(jnp.float32)
     # lane-pad the factor table so every per-row DMA is a tiling-aligned
     # 1×r_pad copy (see docstring); the zero lanes are inert in A and b

@@ -234,7 +234,7 @@ def dequantize_rows(qtable: QuantizedTable, ids):
 def estimate_table_bytes(n_rows: int, rank: int, dtype: str = "f32") -> float:
     """Serving footprint model for one factor table — the quant member
     of the ``estimate_*_hbm_bytes`` family (honest roofline accounting;
-    hardware-day item: validate against measured silicon).
+    not yet compared with a chip's own counters).
 
     f32: 4 bytes/element. int8/fp8: 1 byte/element + one f32 scale per
     row. Pinned against actual ``QuantizedTable.table_bytes`` in tests.

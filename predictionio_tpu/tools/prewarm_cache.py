@@ -23,7 +23,7 @@ attached):
 Usage::
 
     python -m predictionio_tpu.tools.prewarm_cache [--scale 1.0]
-        [--variants f32,bf16,fused,fused_bf16]
+        [--variants f32,bf16]
 
 Sorting (``sort_gather_indices``) permutes values host-side without
 changing shapes, so it shares the f32 variant's program — no separate
@@ -46,10 +46,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 #: that is what bench's "auto" resolves to on a TPU backend — the
 #: program compiled here must BE the program the chip runs.
 VARIANTS = {
-    "f32": dict(gather_dtype="f32", fused_gather=False),
-    "bf16": dict(gather_dtype="bf16", fused_gather=False),
-    "fused": dict(gather_dtype="f32", fused_gather=True),
-    "fused_bf16": dict(gather_dtype="bf16", fused_gather=True),
+    "f32": dict(gather_dtype="f32"),
+    "bf16": dict(gather_dtype="bf16"),
 }
 
 DISPATCH_CATALOGS = (2_700, 27_000, 60_000, 120_000)
@@ -128,7 +126,7 @@ def main(argv=None) -> int:
     ap.add_argument("--scale", type=float,
                     default=float(os.environ.get("BENCH_SCALE", "1.0")))
     ap.add_argument("--rank", type=int, default=50)
-    ap.add_argument("--variants", default="f32,bf16,fused,fused_bf16")
+    ap.add_argument("--variants", default="f32,bf16")
     ap.add_argument("--skip-dispatch", action="store_true")
     args = ap.parse_args(argv)
 

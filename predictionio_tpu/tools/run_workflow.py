@@ -192,7 +192,6 @@ def _run_inner(
 #: name — what :func:`result_line` needs to resolve the levers.
 _ALS_LEVER_FIELDS = (
     "rank", "solve_mode", "gather_dtype", "sort_gather_indices",
-    "fused_gather",
 )
 
 

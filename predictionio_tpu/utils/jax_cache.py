@@ -1,8 +1,7 @@
 """Persistent JAX compilation cache shared across processes.
 
 Every train, deploy and bench run is its own process (``pio train`` and
-``pio deploy`` spawn children; the revalidation queue runs each on-chip
-step as a subprocess with its own timeout), and each would otherwise
+``pio deploy`` spawn children), and each would otherwise
 re-pay the full XLA/Mosaic compilation of largely identical programs:
 about a minute for the ML-20M rank-50 ALS programs, plus one serving
 compile per dispatch shape. JAX's persistent compilation cache stores

@@ -2,6 +2,8 @@
 reference, RMSE convergence on synthetic low-rank data, bucketing correctness,
 and the serving top-k kernels."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -218,9 +220,8 @@ class TestScoring:
 # and mesh equivalence tests all compare trainings of the SAME zipf dataset
 # under different lever settings — and this file alone used to burn 260-350s
 # re-training overlapping configs per parametrization. One module-level
-# cache trains each (mode, implicit, fused, meshed) config exactly once per
-# session; every equivalence test reads from it. The pallas run IS the
-# fused=False run of the fused A/B, so the overlap costs nothing twice.
+# cache trains each (mode, implicit, meshed) config exactly once per
+# session; every equivalence test reads from it.
 # ---------------------------------------------------------------------------
 
 _SWEEP_CACHE: dict = {}
@@ -236,7 +237,7 @@ def _sweep_data():
     return u, i, v, n_u, n_i
 
 
-def sweep_factors(mode, implicit=False, fused=False, meshed=False,
+def sweep_factors(mode, implicit=False, meshed=False,
                   gather="f32", sort=None):
     """Factors for one lever setting over the shared dataset, trained at
     most once per session (rank 12, 3 iterations, seed 2 — identical
@@ -245,11 +246,9 @@ def sweep_factors(mode, implicit=False, fused=False, meshed=False,
     ``sort=None`` rides the round-12 default (resolves to sorted for
     these bucketized inputs), so the cached baseline legs ARE the
     flipped-default runs; ``sort=False`` is the explicit legacy opt-out
-    leg the default-equivalence test compares against. ``fused=False``
-    (the signature default) is likewise the explicit einsum-build
-    opt-out — under the flipped defaults a bare pallas config resolves
-    fused ON, pinned in TestLeverDefaults without training anything."""
-    key = (mode, implicit, fused, meshed, gather, sort)
+    leg the default-equivalence test compares against. A ``pallas`` run
+    builds its buckets of 12 slots or more with the fused kernel."""
+    key = (mode, implicit, meshed, gather, sort)
     if key not in _SWEEP_CACHE:
         from predictionio_tpu.ops.als import ALSConfig, als_train_coo
         from predictionio_tpu.parallel.mesh import create_mesh
@@ -258,7 +257,7 @@ def sweep_factors(mode, implicit=False, fused=False, meshed=False,
         cfg = ALSConfig(
             rank=12, iterations=3, lambda_=0.05,
             implicit_prefs=implicit, alpha=1.0, seed=2,
-            solve_mode=mode, fused_gather=fused,
+            solve_mode=mode,
             gather_dtype=gather, sort_gather_indices=sort,
         )
         f = als_train_coo(
@@ -272,20 +271,15 @@ def sweep_factors(mode, implicit=False, fused=False, meshed=False,
 
 
 class TestSolveModes:
-    """"two_phase" (one batched Cholesky per bucket) must reproduce the
-    default chunked solve to float tolerance, explicit and implicit."""
+    """"pallas" (the chip's solve, interpreted here) must reproduce the
+    chunked solve to float tolerance, explicit and implicit."""
 
     @pytest.mark.parametrize("implicit", [False, True])
     def test_alternate_modes_match_chunked(self, implicit):
         chunked = sweep_factors("chunked", implicit=implicit)
-        for mode in ("two_phase", "pallas"):
-            out = sweep_factors(mode, implicit=implicit)
-            np.testing.assert_allclose(
-                chunked[0], out[0], rtol=2e-3, atol=2e-4
-            )
-            np.testing.assert_allclose(
-                chunked[1], out[1], rtol=2e-3, atol=2e-4
-            )
+        out = sweep_factors("pallas", implicit=implicit)
+        np.testing.assert_allclose(chunked[0], out[0], rtol=2e-3, atol=2e-4)
+        np.testing.assert_allclose(chunked[1], out[1], rtol=2e-3, atol=2e-4)
 
     def test_unknown_mode_fails_loudly(self):
         from predictionio_tpu.ops.als import ALSConfig, als_train_coo
@@ -477,44 +471,51 @@ class TestGatherDtype:
 
 
 class TestFusedGather:
-    """fused_gather=True (the fused gather+Gramian Pallas kernel) must
-    reproduce the einsum-built pallas solve — same buckets, same solver,
-    only the normal-equation build differs. Reads the shared sweep
-    cache: the fused=False leg IS TestSolveModes' pallas run."""
+    """Under the ``pallas`` solve a bucket as wide as the rank builds its
+    normal equations with the fused gather+Gramian kernel: the same
+    factors as the einsum build of the ``chunked`` solve, and the kernel
+    is traced where the rule says and nowhere else."""
+
+    @staticmethod
+    def _fused_calls(width, rank, implicit):
+        import jax
+        import jax.numpy as jnp
+
+        from predictionio_tpu.ops import als
+
+        bucket = (
+            jnp.zeros((1, 8), jnp.int32), jnp.zeros((1, 8, width), jnp.int32),
+            jnp.ones((1, 8, width), jnp.float32), jnp.full((1, 8), width, jnp.int32),
+        )
+        text = str(jax.make_jaxpr(functools.partial(
+            als._als_half_body, rank=rank, implicit=implicit, n_rows=8,
+            solve_mode="pallas",
+        ))(jnp.ones((40, rank)), (bucket,), jnp.float32(0.1), jnp.float32(1.0)))
+        return text.count("gramian_fused")
 
     @pytest.mark.parametrize("implicit", [False, True])
     def test_fused_matches_einsum_build(self, implicit):
-        einsum = sweep_factors("pallas", implicit=implicit, fused=False)
-        fused = sweep_factors("pallas", implicit=implicit, fused=True)
+        einsum = sweep_factors("chunked", implicit=implicit)
+        fused = sweep_factors("pallas", implicit=implicit)
         np.testing.assert_allclose(
             einsum[0], fused[0], rtol=2e-3, atol=2e-4
         )
         np.testing.assert_allclose(
             einsum[1], fused[1], rtol=2e-3, atol=2e-4
         )
+        # the sweep's rank is 12: a bucket of 8 slots goes dual (explicit)
+        # or builds by einsum (implicit), one of 16 is the kernel's
+        assert self._fused_calls(8, 12, implicit) == 0
+        assert self._fused_calls(16, 12, implicit) >= 1
 
     def test_fused_on_mesh_matches_single_device(self):
         """Under a data mesh the whole fused build+solve runs per-device
         inside shard_map; factors must match the unmeshed fused run."""
-        single = sweep_factors("pallas", fused=True)
-        meshed = sweep_factors("pallas", fused=True, meshed=True)
+        single = sweep_factors("pallas")
+        meshed = sweep_factors("pallas", meshed=True)
         np.testing.assert_allclose(
             single[0], meshed[0], rtol=2e-3, atol=2e-4
         )
-
-    def test_fused_requires_pallas_solver(self):
-        from predictionio_tpu.ops.als import ALSConfig, als_train_coo
-
-        cfg = ALSConfig(rank=8, iterations=1, solve_mode="chunked",
-                        fused_gather=True)
-        # silently ignoring the flag would corrupt the hardware A/B
-        with pytest.raises(ValueError, match="fused_gather"):
-            als_train_coo(
-                np.array([0, 1], dtype=np.int32),
-                np.array([0, 1], dtype=np.int32),
-                np.ones(2, dtype=np.float32),
-                n_users=2, n_items=2, cfg=cfg,
-            )
 
 
 class TestLeverDefaults:
@@ -539,11 +540,10 @@ class TestLeverDefaults:
 
         levers = ALSConfig(solve_mode="pallas").resolve_levers()
         assert levers["fused_gather"] is True
-        # ...and the explicit opt-out wins over the default
-        opted = ALSConfig(
-            solve_mode="pallas", fused_gather=False
-        ).resolve_levers()
-        assert opted["fused_gather"] is False
+        # a value the solve mode gives, not a field
+        assert ALSConfig(solve_mode="chunked").resolve_levers()["fused_gather"] is False
+        with pytest.raises(TypeError):
+            ALSConfig(fused_gather=False)
 
     def test_staged_inputs_resolve_sort_off(self):
         from predictionio_tpu.ops.als import ALSConfig
@@ -556,11 +556,8 @@ class TestLeverDefaults:
     def test_explicit_opt_outs(self):
         from predictionio_tpu.ops.als import ALSConfig
 
-        levers = ALSConfig(
-            sort_gather_indices=False, fused_gather=False
-        ).resolve_levers()
+        levers = ALSConfig(sort_gather_indices=False).resolve_levers()
         assert levers["sort_gather"] is False
-        assert levers["fused_gather"] is False
 
 
 class TestAllocBlock:
@@ -741,7 +738,8 @@ class TestHbmBytesModel:
         bf16 = estimate_iteration_hbm_bytes(side, empty, rank, "bf16")
         assert bf16 == 4 * (16 * 8 * 2 + 16 * 8 + 4 + 32)
 
-    def test_fused_path_counts_lane_padded_f32_rows(self):
+    @pytest.mark.parametrize("dtype", ["f32", "bf16"])
+    def test_fused_path_counts_lane_padded_f32_rows(self, dtype):
         """The fused kernel DMAs whole 128-lane f32 rows (bf16 upcasts
         at entry), so its gather bytes are dtype-INDEPENDENT and the
         [B, R, R] transpose round trip is charged."""
@@ -757,11 +755,10 @@ class TestHbmBytesModel:
             + 3 * 8 * 8 * 4  # A write + transposed round trip
             + 2 * 8 * 4  # rhs + solution
         )
-        for dtype in ("f32", "bf16"):
-            got = estimate_iteration_hbm_bytes(
-                side, empty, rank, dtype, fused_gather=True
-            )
-            assert got == expect, (dtype, got, expect)
+        got = estimate_iteration_hbm_bytes(
+            side, empty, rank, dtype, fused_gather=True
+        )
+        assert got == expect
 
     def test_fused_gate_spares_narrow_buckets(self):
         """Buckets narrower than the rank keep the einsum build (the
@@ -819,7 +816,8 @@ class TestFusedTopK:
                 {"itemScores": self._item_scores(want_s[row], want_i[row])},
             ), (row, got_i[row], want_i[row])
 
-    def test_users_fused_matches_dense(self):
+    @pytest.mark.parametrize("mode", ["never", "always"])
+    def test_users_fused_matches_dense(self, mode):
         from predictionio_tpu.ops.scoring import (
             top_k_for_users, top_k_for_users_fused,
         )
@@ -829,15 +827,13 @@ class TestFusedTopK:
         itf = rng.normal(size=(64, 8)).astype(np.float32)
         users = np.array([1, 4, 9, 11], dtype=np.int32)
         want = top_k_for_users(uf, itf, users, k=8)
-        for mode in ("never", "always"):
-            got = top_k_for_users_fused(uf, itf, users, k=8, mode=mode)
-            # ranking exact — same items, same order
-            np.testing.assert_array_equal(
-                np.asarray(got[1]), np.asarray(want[1]), err_msg=mode
-            )
-            self._assert_matches(got, want)
+        got = top_k_for_users_fused(uf, itf, users, k=8, mode=mode)
+        # ranking exact — same items, same order
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+        self._assert_matches(got, want)
 
-    def test_similar_items_fused_matches_dense(self):
+    @pytest.mark.parametrize("mode", ["never", "always"])
+    def test_similar_items_fused_matches_dense(self, mode):
         from predictionio_tpu.ops.scoring import (
             top_k_similar_items, top_k_similar_items_fused,
         )
@@ -846,28 +842,25 @@ class TestFusedTopK:
         itf = rng.normal(size=(40, 8)).astype(np.float32)
         queries = np.array([3, 17, 25], dtype=np.int32)
         want = top_k_similar_items(itf, queries, k=6)
-        for mode in ("never", "always"):
-            got = top_k_similar_items_fused(itf, queries, k=6, mode=mode)
-            np.testing.assert_array_equal(
-                np.asarray(got[1]), np.asarray(want[1]), err_msg=mode
-            )
-            self._assert_matches(got, want)
-            # self-exclusion holds on both legs
-            for row, q in enumerate(queries):
-                assert int(q) not in np.asarray(got[1])[row].tolist()
+        got = top_k_similar_items_fused(itf, queries, k=6, mode=mode)
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+        self._assert_matches(got, want)
+        # self-exclusion holds on both legs
+        for row, q in enumerate(queries):
+            assert int(q) not in np.asarray(got[1])[row].tolist()
 
-    def test_sentinel_contract_past_catalog(self):
+    @pytest.mark.parametrize("mode", ["never", "always"])
+    def test_sentinel_contract_past_catalog(self, mode):
         """k beyond the catalog: sub-k slots are (-inf, -1) on BOTH
         legs — callers must never index with the sentinel."""
         from predictionio_tpu.ops.scoring import top_k_fused_vectors
 
         q = np.eye(2, 4, dtype=np.float32)
         itf = np.eye(3, 4, dtype=np.float32)
-        for mode in ("never", "always"):
-            scores, idx = top_k_fused_vectors(q, itf, k=5, mode=mode)
-            assert np.asarray(idx).shape == (2, 5)
-            assert (np.asarray(idx)[:, 3:] == -1).all(), mode
-            assert np.isneginf(np.asarray(scores)[:, 3:]).all(), mode
+        scores, idx = top_k_fused_vectors(q, itf, k=5, mode=mode)
+        assert np.asarray(idx).shape == (2, 5)
+        assert (np.asarray(idx)[:, 3:] == -1).all()
+        assert np.isneginf(np.asarray(scores)[:, 3:]).all()
 
 
 def _dot_generals(fn, *args, **kwargs):
@@ -958,16 +951,19 @@ class TestDualForm:
     @pytest.mark.parametrize("mode", ["chunked", "pallas"])
     @pytest.mark.parametrize("rank", [10, 50])
     @pytest.mark.parametrize("width", [1, 2, 4, 8, 16, 32])
-    def test_half_step_equals_the_primal_solve(self, width, rank, mode):
+    @pytest.mark.parametrize("lam", [0.05, 1e-4, 0.0])
+    def test_half_step_equals_the_primal_solve(self, lam, width, rank, mode):
         import jax.numpy as jnp
 
         from predictionio_tpu.ops import als
 
         y, bucket, n = self._block(width, rank)
         dual = width < rank
+        if lam == 0.0 and not dual:
+            pytest.skip("a primal system of fewer ratings than the rank")
         statics = dict(
             rank=rank, implicit=False, n_rows=n, solve_mode=mode,
-            gather_dtype="f32", mesh=None, fused_gather=False, side="user",
+            gather_dtype="f32", mesh=None, side="user",
         )
         args = (jnp.asarray(y), (tuple(jnp.asarray(a) for a in bucket),))
         dots = _dot_generals(
@@ -977,29 +973,31 @@ class TestDualForm:
         assert _traces_dual(dots) == dual
         # the products a block traces, batch first: a k x k system and
         # the row's expansion, or (rank 10, widths 16 and 32: as wide as
-        # the rank) today's R x R system and its right-hand side
+        # the rank) the primal R x R system and its right-hand side,
+        # which under ``pallas`` are the fused kernel's and no product
+        # of the block's own
         r_pad = (rank + 7) // 8 * 8 if mode == "pallas" else rank
-        assert [shape for _, shape in dots] == (
-            [(self.BLOCK, width, width), (self.BLOCK, r_pad)] if dual
-            else [(self.BLOCK, r_pad, r_pad), (self.BLOCK, r_pad)]
-        )
+        shapes = [shape for _, shape in dots]
+        if dual:
+            assert shapes == [(self.BLOCK, width, width), (self.BLOCK, r_pad)]
+        elif mode == "pallas":
+            assert shapes and not [s for s in shapes if s[0] == self.BLOCK]
+        else:
+            assert shapes == [(self.BLOCK, rank, rank), (self.BLOCK, rank)]
 
         padding = np.asarray(bucket[3]).reshape(-1) == 0
-        for lam in (0.05, 1e-4, 0.0):
-            if lam == 0.0 and not dual:
-                continue  # a primal system of fewer ratings than the rank
-            x = np.asarray(als._als_half(
-                *args, jnp.float32(lam), jnp.float32(1.0), **statics))
-            assert x.shape == (n, rank) and np.isfinite(x).all()
-            assert not x[padding].any()
-            ref = self._primal_float64(y, bucket, n, lam)
-            err = np.abs(x - ref).max(axis=1) / np.maximum(
-                np.abs(ref).max(axis=1), 1e-30)
-            # today's primal system of a row with fewer ratings than the
-            # rank is nearly singular at a small λ, in float32: its rows
-            # are held to the tolerance they always had
-            limit = (1e-4 if lam > 0 else 1e-3) if dual else 2e-3
-            assert err[~padding].max() < limit, (lam, err.max())
+        x = np.asarray(als._als_half(
+            *args, jnp.float32(lam), jnp.float32(1.0), **statics))
+        assert x.shape == (n, rank) and np.isfinite(x).all()
+        assert not x[padding].any()
+        ref = self._primal_float64(y, bucket, n, lam)
+        err = np.abs(x - ref).max(axis=1) / np.maximum(
+            np.abs(ref).max(axis=1), 1e-30)
+        # today's primal system of a row with fewer ratings than the
+        # rank is nearly singular at a small λ, in float32: its rows
+        # are held to the tolerance they always had
+        limit = (1e-4 if lam > 0 else 1e-3) if dual else 2e-3
+        assert err[~padding].max() < limit, err.max()
 
     #: |x| and |y| summed and two rows' first entries, from the commit
     #: before the dual form (679fbee) on this data: rank 12, 3
@@ -1060,7 +1058,7 @@ class TestDualForm:
             als._als_iteration_body, *staged, jnp.zeros((n_i, 12)),
             jnp.float32(0.05), jnp.float32(1.0),
             rank=12, implicit=cfg.implicit_prefs, n_users=n_u, n_items=n_i,
-            solve_mode=mode, fused_gather=mode == "pallas",
+            solve_mode=mode,
         )
         assert dots and not _traces_dual(dots)
         # and no k x k system a block

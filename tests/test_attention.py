@@ -8,9 +8,6 @@ import jax
 import numpy as np
 import pytest
 
-# interpret-mode flash attention at real shapes: minutes on CPU
-pytestmark = pytest.mark.slow
-
 from predictionio_tpu.ops.attention import (
     attention,
     flash_attention,
@@ -94,65 +91,59 @@ def test_ring_rejects_indivisible_length(seq_mesh):
         ring_attention(q, k, v, seq_mesh)
 
 
-class TestFlashPallas:
-    """The fused Pallas flash kernel must match the XLA online-softmax
-    path exactly-ish (same math, different blocking) — including ragged
-    lengths, non-causal, cross-attention (Lq != Lk), and dispatch via
-    attention(impl="pallas")."""
-
-    @pytest.mark.parametrize("causal", [True, False])
-    @pytest.mark.parametrize(
-        "b,h,lq,lk,d,bq,bk",
-        [
-            (2, 4, 64, 64, 16, 32, 32),
-            (1, 2, 60, 60, 8, 32, 16),   # ragged L vs blocks
-            (1, 1, 7, 13, 8, 8, 8),      # tiny + cross-attention
-            (2, 2, 128, 96, 32, 64, 32),
-        ],
-    )
-    def test_matches_xla_flash(self, causal, b, h, lq, lk, d, bq, bk):
-        from predictionio_tpu.ops.attention import flash_attention_pallas
-
-        rng = np.random.default_rng(7)
-        q = rng.normal(size=(b, h, lq, d)).astype(np.float32)
-        k = rng.normal(size=(b, h, lk, d)).astype(np.float32)
-        v = rng.normal(size=(b, h, lk, d)).astype(np.float32)
-        got = np.asarray(flash_attention_pallas(
-            q, k, v, causal=causal, block_q=bq, block_k=bk
-        ))
-        ref = np.asarray(flash_attention(q, k, v, causal=causal,
-                                         block_k=max(16, lk // 2)))
-        np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-5)
-
-    def test_dispatch_impl(self, qkv):
-        q, k, v = qkv
-        ref = naive(q, k, v, True)
-        np.testing.assert_allclose(
-            np.asarray(attention(q, k, v, impl="pallas")), ref,
-            rtol=2e-4, atol=2e-5,
-        )
-        with pytest.raises(ValueError, match="impl"):
-            attention(q, k, v, impl="bogus")
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize(
+    "b,h,lq,lk,d,bq,bk",
+    [
+        (2, 4, 64, 64, 16, 32, 32),
+        (1, 2, 60, 60, 8, 32, 16),   # ragged L vs blocks
+        (1, 1, 7, 13, 8, 8, 8),      # tiny + cross-attention
+        (2, 2, 128, 96, 32, 64, 32),
+    ],
+)
+def test_flash_shapes_match_naive(causal, b, h, lq, lk, d, bq, bk):
+    """Lengths that do not divide the blocks, Lq != Lk and blocks that
+    differ between queries and keys, all against the full score matrix."""
+    rng = np.random.default_rng(7)
+    q = rng.normal(size=(b, h, lq, d)).astype(np.float32)
+    k = rng.normal(size=(b, h, lk, d)).astype(np.float32)
+    v = rng.normal(size=(b, h, lk, d)).astype(np.float32)
+    got = np.asarray(flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk))
+    np.testing.assert_allclose(got, naive(q, k, v, causal), rtol=2e-4, atol=2e-5)
 
 
-def test_flash_pallas_gradients_match_xla():
-    """The custom VJP (pallas forward, flash-style XLA recompute
-    backward) must produce the same gradients as differentiating the
-    XLA path directly."""
-    from predictionio_tpu.ops.attention import flash_attention_pallas
+def test_flash_gradients_match_naive():
+    """The custom VJP (each tile recomputed from q, k and the rows'
+    log-sum-exp) against differentiating the full score matrix."""
+    import jax.numpy as jnp
 
     rng = np.random.default_rng(9)
-    q, k, v = (rng.normal(size=(1, 2, 32, 8)).astype(np.float32)
+    q, k, v = (rng.normal(size=(1, 2, 40, 8)).astype(np.float32)
                for _ in range(3))
 
-    def loss_p(q, k, v):
-        return (flash_attention_pallas(q, k, v, causal=True) ** 2).sum()
+    def full(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        s = jnp.where(np.tril(np.ones((q.shape[2], k.shape[2]), bool)), s, -1e30)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
 
-    def loss_x(q, k, v):
-        return (flash_attention(q, k, v, causal=True) ** 2).sum()
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
 
-    gp = jax.grad(loss_p, argnums=(0, 1, 2))(q, k, v)
-    gx = jax.grad(loss_x, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(gp, gx):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-3, atol=2e-4)
+    blockwise = lambda q, k, v: flash_attention(q, k, v, causal=True, block_k=16)  # noqa: E731
+    got = jax.grad(loss(blockwise), argnums=(0, 1, 2))(q, k, v)
+    ref = jax.grad(loss(full), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-4)
+
+
+def test_dispatch_on_one_device(qkv):
+    """A mesh without a ``seq`` axis, or with one device on it, is the
+    single-device path: grouped key/value heads go in as they are and
+    ``block`` reaches the blockwise kernel."""
+    q, k, v = qkv
+    ref = naive(q, np.repeat(k[:, :2], 2, axis=1), np.repeat(v[:, :2], 2, axis=1), True)
+    one = create_mesh(MeshConfig((("seq", 1),)), devices=jax.devices()[:1])
+    other = create_mesh(MeshConfig((("data", 2),)), devices=jax.devices()[:2])
+    for mesh in (one, other):
+        got = attention(q, k[:, :2], v[:, :2], mesh=mesh, block=16)
+        np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-4, atol=2e-5)

@@ -141,7 +141,7 @@ def test_als_half_step_with_its_kernels(one_chip, monkeypatch):
         _sds(one_chip, (), jnp.float32),
         _sds(one_chip, (), jnp.float32),
         n_rows=n_i, rank=RANK, implicit=False, solve_mode="pallas",
-        mesh=None, gather_dtype="f32", fused_gather=True, side="item",
+        mesh=None, gather_dtype="f32", side="item",
     )
     # a solver call and a fused-build call per bucket wide enough for it
     text = compiled.as_text()
@@ -189,7 +189,7 @@ def test_narrow_bucket_half_step(one_chip, monkeypatch, block, width, rung):
         _sds(one_chip, (), jnp.float32),
         _sds(one_chip, (), jnp.float32),
         n_rows=n_users, rank=RANK, implicit=False, solve_mode="pallas",
-        mesh=None, gather_dtype="f32", fused_gather=True, side="user",
+        mesh=None, gather_dtype="f32", side="user",
     )
     text = compiled.as_text()
     assert "%spd_solve_t" in text and "%gramian_fused" not in text
@@ -215,6 +215,83 @@ def test_narrow_bucket_half_step(one_chip, monkeypatch, block, width, rung):
         compiled.memory_analysis().temp_size_in_bytes
         < padded_table + normal_equations
     )
+
+
+def _bucket_avals(sharding, chunks, block, width):
+    """One staged bucket (rows, idx, val, counts) as ``stage`` ships it."""
+    return (
+        _sds(sharding, (chunks, block), jnp.int32),
+        _sds(sharding, (chunks, block, width), jnp.int32),
+        _sds(sharding, (chunks, block, width), jnp.float32),
+        _sds(sharding, (chunks, block), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("width", [8, 128, 2048])
+def test_implicit_half_step_rank50(one_chip, monkeypatch, width):
+    """The implicit half-step no benchmark cell runs, at the smoke's
+    tables: its base matrix is ``YtY + lambda n I``, so no width goes
+    dual. Under the rank the ``[56, 56, B]`` systems come from the
+    weighted einsum; from the rank up ``gramian_fused`` takes ``YtY``
+    in; both feed the Pallas solver at n = 56."""
+    from predictionio_tpu.ops import als
+
+    block = als._block_rows_for(width)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _compile(
+        als._als_half,
+        _sds(one_chip, (N_ITEMS, RANK), jnp.float32),
+        (_bucket_avals(one_chip, 2, block, width),),
+        _sds(one_chip, (), jnp.float32),
+        _sds(one_chip, (), jnp.float32),
+        n_rows=N_USERS, rank=RANK, implicit=True, solve_mode="pallas",
+        mesh=None, gather_dtype="f32", side="user",
+    )
+    text = compiled.as_text()
+    bucket = f"als.user_side/als.w{als._scope_rung(width)}/als.k{width}"
+    assert "als.user_side/als.yty/" in text
+    assert f"{bucket}/while/body/closed_call/als.solve/spd_solve_t/pallas_call" in text
+    assert ("%gramian_fused" in text) == (width >= RANK)
+    if width < RANK:
+        assert f"f32[{RANK_PAD},{RANK_PAD},{block}]" in text
+        assert f"{bucket}/while/body/closed_call/als.gramian/bkr,bk,bks->rsb/" in text
+
+
+@pytest.mark.parametrize("factor_sharding", ["replicated", "model"])
+def test_mesh_half_step_2x2(topo, monkeypatch, factor_sharding):
+    """``als_train(mesh=...)``'s half-step on the 2 x 2 topology: solve
+    rows over ``data``, the table replicated or row-sharded over
+    ``model``. The Pallas kernels do not partition themselves, so the
+    solver (a narrow bucket's ``k x k`` systems) and the fused build (a
+    wide bucket) each run inside ``shard_map`` on a device's own rows."""
+    from predictionio_tpu.ops import als
+    from predictionio_tpu.parallel.mesh import (
+        DATA_AXIS, MODEL_AXIS, MeshConfig, create_mesh,
+    )
+
+    mesh = create_mesh(MeshConfig(((DATA_AXIS, 2), (MODEL_AXIS, 2))), topo.devices)
+    table = NamedSharding(
+        mesh, P(MODEL_AXIS) if factor_sharding == "model" else P())
+    rows = NamedSharding(mesh, P(None, DATA_AXIS))
+    scalar = NamedSharding(mesh, P())
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = _compile(
+        als._als_half_sharded(table),
+        _sds(table, (N_ITEMS, RANK), jnp.float32),
+        (_bucket_avals(rows, 2, 4096, 8), _bucket_avals(rows, 2, 64, 512)),
+        _sds(scalar, (), jnp.float32),
+        _sds(scalar, (), jnp.float32),
+        # a table sharded over ``model`` holds an even number of rows
+        n_rows=N_USERS + 1, rank=RANK, implicit=False, solve_mode="pallas",
+        mesh=mesh, gather_dtype="f32", side="user",
+    )
+    text = compiled.as_text()
+    narrow = "als.user_side/als.w8/als.k8/while/body/closed_call"
+    wide = "als.user_side/als.w512/als.k512/while/body/closed_call"
+    assert f"{narrow}/als.solve/shard_map/spd_solve_t/pallas_call" in text
+    assert f"{wide}/shard_map/als.gramian/" in text and "%gramian_fused" in text
+    assert f"{wide}/shard_map/als.solve/spd_solve_t/pallas_call" in text
+    assert len(compiled.input_shardings[0][0].device_set) == 4
 
 
 @pytest.mark.parametrize("n_excl", [0, 64])
@@ -316,25 +393,16 @@ def test_delta_rule_scan_row_of_8k(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
 
 
-@pytest.mark.parametrize("impl,heads,kv_heads,head_dim", [
-    ("xla", 16, 2, 256), ("pallas", 4, 4, 64)])
-def test_packed_attention_two_rows_of_8k(one_chip, impl, heads, kv_heads, head_dim):
+@pytest.mark.parametrize("heads,kv_heads,head_dim", [(16, 2, 256), (4, 4, 64)])
+def test_packed_attention_two_rows_of_8k(one_chip, heads, kv_heads, head_dim):
     """Attention with a segment mask over two packed rows of 8,192 slots,
-    forward and backward: the XLA path at the cell's widths (16 query
-    heads on 2 key/value heads of 256), the Pallas forward kernel at the
-    shipped preset's (4 heads of 64: it takes equal head counts and holds
-    one head's K and V in VMEM)."""
-    from predictionio_tpu.ops.attention import flash_attention, flash_attention_pallas
+    forward and backward, at the cell's widths (16 query heads on 2
+    key/value heads of 256) and the shipped preset's (4 heads of 64)."""
+    from predictionio_tpu.ops.attention import flash_attention
 
     def loss(q, k, v, seg):
-        if impl == "pallas":
-            o = flash_attention_pallas(q, k, v, segment_ids=seg, interpret=False)
-        else:
-            o = flash_attention(q, k, v, segment_ids=seg)
-        return o.astype(jnp.float32).sum()
+        return flash_attention(q, k, v, segment_ids=seg).astype(jnp.float32).sum()
 
-    # the value too: the Pallas path's backward pass recomputes in XLA and
-    # does not need its forward kernel's output
     compiled = _compile(
         jax.value_and_grad(loss, argnums=(0, 1, 2)),
         _sds(one_chip, (2, heads, SEQ_L, head_dim), jnp.bfloat16),
@@ -344,7 +412,7 @@ def test_packed_attention_two_rows_of_8k(one_chip, impl, heads, kv_heads, head_d
     # no [pairs, B, H, bq, bk] stack of score tiles (4 GiB when the
     # compiler can count the loop's trips)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
-    assert ("tpu_custom_call" in compiled.as_text()) == (impl == "pallas")
+    assert "tpu_custom_call" not in compiled.as_text()
 
 
 def test_expert_layer_16k_tokens_32_of_512(one_chip):

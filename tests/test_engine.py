@@ -412,3 +412,99 @@ class TestEvaluationWiring:
             g.engine_params_list
         g.engine_params_list = [make_params()]
         assert len(g.engine_params_list) == 1
+
+
+# -- fields a Params class has retired ---------------------------------------
+def _sequence_events(store, app_id):
+    import datetime as dt
+
+    from predictionio_tpu.storage import Event
+
+    t0 = dt.datetime(2021, 1, 1, tzinfo=dt.timezone.utc)
+    store.write([
+        Event(event="view", entity_type="user", entity_id=f"u{u}",
+              target_entity_type="item", target_entity_id=f"i{(u + t) % 6}",
+              event_time=t0 + dt.timedelta(minutes=t))
+        for u in range(6) for t in range(12)
+    ], app_id)
+
+
+def _rating_events(store, app_id):
+    from predictionio_tpu.storage import DataMap, Event
+
+    store.write([
+        Event(event="rate", entity_type="user", entity_id=f"u{u}",
+              target_entity_type="item", target_entity_id=f"i{i}",
+              properties=DataMap({"rating": 5.0 if (u + i) % 2 == 0 else 1.0}))
+        for u in range(8) for i in range(6) if (u * 7 + i) % 5
+    ], app_id)
+
+
+def _sequencerec_case():
+    from predictionio_tpu.models import sequencerec as seq
+
+    params = EngineParams(
+        data_source_params=("", seq.SeqDataSourceParams(app_id=1)),
+        preparator_params=("", seq.SeqPreparatorParams(seq_len=8)),
+        algorithm_params_list=[("", seq.SeqRecAlgorithmParams(
+            d_model=16, n_heads=2, n_layers=1, steps=4))],
+    )
+    return (seq.engine_factory(), params, _sequence_events,
+            seq.Query(recent_items=("i0", "i1"), num=3))
+
+
+def _recommendation_case():
+    from predictionio_tpu.models import recommendation as rec
+
+    params = EngineParams(
+        data_source_params=("", rec.RecDataSourceParams(app_id=1)),
+        algorithm_params_list=[("als", rec.ALSAlgorithmParams(
+            rank=4, num_iterations=2, lambda_=0.05))],
+    )
+    return rec.engine_factory(), params, _rating_events, rec.Query(user="u0", num=3)
+
+
+@pytest.mark.parametrize("case, field, stored_value", [
+    (_sequencerec_case, "flash_impl", "xla"),
+    (_recommendation_case, "fused_gather", None),
+])
+def test_a_stored_instance_with_a_retired_field_deploys(
+        case, field, stored_value, tmp_path, monkeypatch):
+    """An engine instance trained before a Params class lost a field
+    carries it (``params_to_json`` writes every field): the deploy path
+    drops it and the instance serves; the same key in a user's
+    ``engine.json`` is an unknown field like any other."""
+    import json
+
+    import predictionio_tpu.storage.registry as regmod
+    from predictionio_tpu.storage import StorageRegistry
+    from predictionio_tpu.workflow import run_train
+    from predictionio_tpu.workflow.serving import ServerConfig, prepare_deployment
+
+    registry = StorageRegistry(env={"PIO_FS_BASEDIR": str(tmp_path)})
+    monkeypatch.setattr(regmod, "_default_registry", registry)
+    engine, params, write_events, query = case()
+    store = registry.get_events()
+    store.init(1)
+    write_events(store, 1)
+    instance_id = run_train(engine, params, registry, engine_id="retired")
+    md = registry.get_metadata()
+    instance = md.engine_instance_get(instance_id)
+    blocks = json.loads(instance.algorithms_params)
+    assert field not in blocks[0]["params"]
+    blocks[0]["params"][field] = stored_value
+    md.engine_instance_update(
+        dataclasses.replace(instance, algorithms_params=json.dumps(blocks)))
+
+    deployment = prepare_deployment(
+        engine, registry, ServerConfig(engine_instance_id=instance_id))
+    assert deployment.engine_params == params
+    answer = deployment.algorithms[0].predict(deployment.models[0], query)
+    assert 0 < len(answer.item_scores) <= 3
+
+    variant = {
+        "datasource": {"params": {"app_id": 1}},
+        "algorithms": [{"name": blocks[0]["name"], "params": {field: stored_value}}],
+    }
+    with pytest.raises(ParamsError, match=f"unknown fields.*{field}"):
+        engine.json_to_engine_params(variant)

@@ -29,7 +29,6 @@ import pytest
 # deviceless AOT compile of every Pallas kernel: minutes of XLA/Mosaic work
 pytestmark = pytest.mark.slow
 
-from predictionio_tpu.ops.attention import flash_attention_pallas
 from predictionio_tpu.ops.pallas_kernels import (
     gramian_fused,
     spd_solve_t,
@@ -128,29 +127,6 @@ class TestMosaicAOT:
             _sds(topo1, (4,), jnp.float32),
         )
 
-    def test_flash_attention_forward(self, topo1):
-        _compile(
-            functools.partial(
-                flash_attention_pallas, causal=True, interpret=False
-            ),
-            _sds(topo1, (2, 8, 1024, 64), jnp.float32),
-            _sds(topo1, (2, 8, 1024, 64), jnp.float32),
-            _sds(topo1, (2, 8, 1024, 64), jnp.float32),
-        )
-
-    def test_flash_attention_grad(self, topo1):
-        def loss(q, k, v):
-            return flash_attention_pallas(
-                q, k, v, causal=True, interpret=False
-            ).sum()
-
-        _compile(
-            jax.grad(loss, argnums=(0, 1, 2)),
-            _sds(topo1, (2, 4, 512, 64), jnp.float32),
-            _sds(topo1, (2, 4, 512, 64), jnp.float32),
-            _sds(topo1, (2, 4, 512, 64), jnp.float32),
-        )
-
     def test_top_k_streaming(self, topo1):
         _compile(
             functools.partial(top_k_streaming, k=10, interpret=False),
@@ -171,16 +147,6 @@ class TestMosaicAOT:
             _sds(topo1, (512, 50), jnp.float32),
             _sds(topo1, (60_000, 50), jnp.float32),
             _sds(topo1, (512, 64), jnp.int32),
-        )
-
-    def test_flash_attention_bf16(self, topo1):
-        _compile(
-            functools.partial(
-                flash_attention_pallas, causal=True, interpret=False
-            ),
-            _sds(topo1, (2, 4, 512, 64), jnp.bfloat16),
-            _sds(topo1, (2, 4, 512, 64), jnp.bfloat16),
-            _sds(topo1, (2, 4, 512, 64), jnp.bfloat16),
         )
 
     def test_gramian_fused_implicit_yty(self, topo1):
@@ -226,6 +192,5 @@ class TestMosaicAOT:
             jax.ShapeDtypeStruct((), jnp.float32, sharding=sh),
             n_users=n_u, n_items=n_i, rank=32, implicit=True,
             solve_mode="pallas", gather_dtype="f32", mesh=None,
-            fused_gather=False,
         ).compile()
         assert compiled.memory_analysis().generated_code_size_in_bytes > 0

@@ -73,11 +73,10 @@ class TestDistributedALSCompile:
         )
 
     @pytest.mark.parametrize(
-        "solve_mode,fused",
-        [("chunked", False), ("pallas", False), ("pallas", True)],
-        ids=["xla-collectives", "pallas-shard_map", "fused-shard_map"],
+        "solve_mode", ["chunked", "pallas"],
+        ids=["xla-collectives", "pallas-shard_map"],
     )
-    def test_sharded_iteration_compiles(self, problem, solve_mode, fused):
+    def test_sharded_iteration_compiles(self, problem, solve_mode):
         rows_u, rows_i = problem["rows"]
         it = als._als_iteration_sharded(problem["tbl"])
         compiled = it.lower(
@@ -86,7 +85,6 @@ class TestDistributedALSCompile:
             n_users=rows_u, n_items=rows_i, rank=8, implicit=False,
             solve_mode=solve_mode, gather_dtype="f32",
             mesh=problem["mesh"] if solve_mode == "pallas" else None,
-            fused_gather=fused,
         ).compile()
         assert compiled.memory_analysis().generated_code_size_in_bytes > 0
 
@@ -127,7 +125,6 @@ class TestMultiSliceCompile:
             jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
             n_users=rows_u, n_items=rows_i, rank=8, implicit=False,
             solve_mode="chunked", gather_dtype="f32", mesh=None,
-            fused_gather=False,
         ).compile()
         assert compiled.memory_analysis().generated_code_size_in_bytes > 0
 

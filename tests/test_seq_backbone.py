@@ -371,29 +371,25 @@ def test_flash_backward_with_segments_and_grouped_heads(qkv_seg):
         assert rel(a, b) < 1e-5
 
 
-def test_pallas_flash_with_segments(qkv_seg):
-    """The Pallas kernel with the segment mask, one key/value head a query
-    head, forward and through its recomputing VJP."""
-    from predictionio_tpu.ops.attention import flash_attention_pallas
-
+def test_flash_with_segments_and_unequal_blocks(qkv_seg):
+    """Query blocks twice the key blocks, one key/value head a query
+    head, forward and through the recomputing VJP."""
     q, k, v, seg = qkv_seg
     k, v = np.repeat(k, 2, axis=1), np.repeat(v, 2, axis=1)
-    got = flash_attention_pallas(q, k, v, block_q=32, block_k=16, segment_ids=seg)
+    got = flash_attention(q, k, v, block_q=32, block_k=16, segment_ids=seg)
     assert rel(got, _naive_attention(q, k, v, seg)) < 1e-5
-    grads = jax.grad(lambda *a: (flash_attention_pallas(
+    grads = jax.grad(lambda *a: (flash_attention(
         *a, block_q=32, block_k=16, segment_ids=seg) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
     want = jax.grad(lambda *a: (_naive_attention(*a, seg) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(grads, want):
         assert rel(a, b) < 1e-5
 
 
-def test_pallas_flash_leaves_grouped_heads_to_the_xla_path(qkv_seg):
+def test_attention_dispatch_keeps_grouped_heads_and_segments(qkv_seg):
     from predictionio_tpu.ops.attention import attention
 
     q, k, v, seg = qkv_seg
-    with pytest.raises(ValueError, match="grouped heads run on the XLA path"):
-        attention(q, k, v, impl="pallas", segment_ids=seg)
-    assert rel(attention(q, k, v, impl="xla", segment_ids=seg),
+    assert rel(attention(q, k, v, segment_ids=seg, block=32),
                _naive_attention(q, k, v, seg)) < 1e-5
 
 

@@ -131,7 +131,7 @@ class TestDensityBalancing:
     """Rows are dealt to shards by padded solve-FLOP weight, widest
     class first — a deliberately skewed degree histogram still splits
     within a pinned imbalance bound, and the plan surfaces the evidence
-    (``profile["shard_plan"]``) the hardware-day drive prints."""
+    (``profile["shard_plan"]``)."""
 
     @pytest.mark.parametrize("narrow", [False, True])
     def test_skewed_histogram_splits_within_bound(self, narrow):
@@ -272,18 +272,6 @@ class TestLoudConflicts:
                 shards=2,
             )
 
-    def test_explicit_fused_gather(self):
-        u, i, v = self._tiny()
-        with pytest.raises(ValueError, match="fused_gather"):
-            als_train_sharded(
-                u, i, v, 3, 2,
-                ALSConfig(
-                    rank=4, iterations=1, solve_mode="chunked",
-                    fused_gather=True,
-                ),
-                shards=2,
-            )
-
     def test_unknown_gather_dtype(self):
         u, i, v = self._tiny()
         with pytest.raises(ValueError, match="gather_dtype"):
@@ -343,8 +331,8 @@ class TestLoudConflicts:
 
 
 class TestProfileEvidence:
-    """The resolved-lever + balance evidence the bench/ledger and the
-    hardware-day drive read (docs/performance.md#levers)."""
+    """The resolved-lever + balance evidence the bench/ledger reads
+    (docs/performance.md#levers)."""
 
     def test_profile_records_resolved_levers_and_plan(self):
         u, i, v, n_u, n_i = _recipe()

@@ -554,67 +554,3 @@ class TestParquetExportImport:
         from predictionio_tpu.storage.events import EventFilter
 
         assert len(list(ev.find(4, EventFilter()))) == 1
-
-
-class TestRevalReport:
-    """reval_report folds TPU_REVALIDATION.jsonl into the evidence
-    summary — newest record per step wins, malformed lines are skipped,
-    and every section renders from partial evidence."""
-
-    def _write(self, tmp_path, recs, junk=True):
-        p = tmp_path / "ev.jsonl"
-        lines = [json.dumps(r) for r in recs]
-        if junk:
-            lines.insert(1, '{"truncated": ')  # torn line must be skipped
-            lines.append("")
-        p.write_text("\n".join(lines) + "\n")
-        return str(p)
-
-    def test_newest_wins_and_junk_skipped(self, tmp_path):
-        from predictionio_tpu.tools.reval_report import load
-
-        path = self._write(tmp_path, [
-            {"step": "baseline_f32", "value": 20.0},
-            {"step": "baseline_f32", "value": 17.5},
-        ])
-        steps = load(path)
-        assert steps["baseline_f32"]["value"] == 17.5
-
-    def test_report_renders_partial_evidence(self, tmp_path):
-        from predictionio_tpu.tools.reval_report import load, report
-
-        path = self._write(tmp_path, [
-            {"step": "baseline_f32", "value": 17.8,
-             "holdout_rmse": 0.5304, "iteration_s": [2.5, 0.38],
-             "device": "TPU v5 lite0", "rc": 0},
-            {"step": "bf16_gather", "value": 14.2,
-             "holdout_rmse": 0.5306, "rmse_gate": "pass", "rc": 0},
-            {"step": "fused_smoke", "ok": True, "compiled": True,
-             "kernel_max_rel": 1e-6, "rc": 0},
-            {"step": "mesh_pallas", "error": "timed out", "rc": -1},
-            {"step": "dispatch_bench", "catalogs": {
-                "60000": {"dispatch_ms_per_batch": 3.4,
-                          "implied_qps_at_depth1": 150000.0}}},
-            {"step": "loadgen_depth2", "qps": 6200.1, "p99_ms": 30.2},
-            {"step": "loadgen_inproc_depth2_big", "qps": 21000.0,
-             "p99_ms": 9.3},
-            {"step": "unknown_extra", "foo": 1},
-        ])
-        text = report(load(path))
-        assert "17.8s train" in text
-        assert "steady iter 0.380s" in text  # first iter excluded
-        assert "gate=pass" in text
-        assert "fused_smoke**: OK" in text
-        assert "mesh_pallas**: FAILED" in text
-        assert "| 60000 | 3.4 | 150000 |" in text
-        assert "6200.1" in text and "21000.0" in text
-        assert "unknown_extra" in text  # surfaced, not dropped
-
-    def test_cpu_record_marked_invalid(self, tmp_path):
-        from predictionio_tpu.tools.reval_report import load, report
-
-        path = self._write(tmp_path, [
-            {"step": "baseline_f32", "value": 12.0,
-             "platform": "cpu", "rc": 0},
-        ], junk=False)
-        assert "RAN ON cpu — INVALID" in report(load(path))

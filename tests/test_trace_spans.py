@@ -344,7 +344,6 @@ class TestDeviceScopes:
             jnp.zeros((n_i, 16)), jnp.float32(0.1), jnp.float32(1.0),
             rank=16, implicit=implicit, n_users=n_u, n_items=n_i,
             solve_mode="pallas", gather_dtype="f32", mesh=None,
-            fused_gather=True,
         ).as_text(debug_info=True)
         widths = {b.idx.shape[-1] for s in (by_user, by_item) for b in s.buckets}
         assert {1, 2, 4, 8, 16, 32, 128} <= widths
@@ -389,7 +388,7 @@ class TestDeviceScopes:
             jnp.zeros((n_cols, 8)), als._bucket_tensors(staged),
             jnp.float32(0.1), jnp.float32(1.0),
             rank=8, implicit=False, n_rows=n_rows, solve_mode="chunked",
-            gather_dtype="f32", mesh=None, fused_gather=False, side=side,
+            gather_dtype="f32", mesh=None, side=side,
         ).as_text(debug_info=True)
         other = "item" if side == "user" else "user"
         assert f"als.{side}_side" in text
