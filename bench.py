@@ -24,10 +24,9 @@ smoke runs; ``BENCH_ITERATIONS`` (default 10); ``BENCH_SYNTH_CACHE``
 (off by default) names a directory where
 the deterministic synthetic dataset is cached across runs — cache files
 are keyed by (generator version, scale, seed). Lever knobs
-(``BENCH_SOLVE_MODE``/``BENCH_GATHER_DTYPE``/``BENCH_SORT_GATHER``)
-are documented at their ALSConfig fields (sort-gather rides every run,
-``BENCH_SORT_GATHER=0`` opts out; the fused gather+Gramian build comes
-with the ``pallas`` solver) and every round trains
+(``BENCH_SOLVE_MODE``/``BENCH_GATHER_DTYPE``)
+are documented at their ALSConfig fields (the fused gather+Gramian
+build comes with the ``pallas`` solver) and every round trains
 a bf16-gather twin whose holdout RMSE must stay within
 ``BENCH_BF16_RMSE_GATE`` (default 0.01) of the f32 run —
 ``BENCH_BF16_GATE=0`` opts out, a drift fails the bench loudly. The
@@ -269,7 +268,7 @@ out = {{
     "iterations": cfg.iterations,
     "solve_mode": profile.get("solve_mode", "chunked"),
     "gather_dtype": profile.get("gather_dtype", "f32"),
-    "sort_gather": profile.get("sort_gather", True),
+    "sort_gather": False,  # a component of the perf ledger's key; nothing sorts
     "fused_gather": profile.get("fused_gather", False),
     "flopImbalance": (profile.get("shard_plan") or {{}}).get(
         "flopImbalance"
@@ -647,17 +646,10 @@ def run_bench(scale: float, iterations: int) -> int:
 
     solve_mode = os.environ.get("BENCH_SOLVE_MODE", "auto")
     gather_dtype = os.environ.get("BENCH_GATHER_DTYPE", "f32")
-    # sort-gather is host-side and proven equivalence-safe
-    # (ROUND7_NOTES), so it rides every run unless BENCH_SORT_GATHER=0
-    # opts out
-    sort_gather = os.environ.get("BENCH_SORT_GATHER", "1") == "1"
     cfg = ALSConfig(
         rank=50, iterations=iterations, lambda_=0.05, seed=0,
         solve_mode=solve_mode, gather_dtype=gather_dtype,
     )
-    if sort_gather:
-        from predictionio_tpu.ops.als import sort_bucket_indices
-    _maybe_sort = sort_bucket_indices if sort_gather else (lambda b: b)
 
     # Warm the compilation cache with the REAL bucket shapes (jit keys on
     # shapes: a smaller sliver would leave the timed run paying XLA compile).
@@ -669,10 +661,10 @@ def run_bench(scale: float, iterations: int) -> int:
         rank=cfg.rank, iterations=2, lambda_=cfg.lambda_, seed=cfg.seed,
         solve_mode=solve_mode, gather_dtype=gather_dtype,
     )
-    wu = stage(_maybe_sort(bucketize(users[tr], items[tr], ratings[tr],
-                                     n_users, n_items, pad_to_blocks=True)))
-    wi = stage(_maybe_sort(bucketize(items[tr], users[tr], ratings[tr],
-                                     n_items, n_users, pad_to_blocks=True)))
+    wu = stage(bucketize(users[tr], items[tr], ratings[tr],
+                         n_users, n_items, pad_to_blocks=True))
+    wi = stage(bucketize(items[tr], users[tr], ratings[tr],
+                         n_items, n_users, pad_to_blocks=True))
     np.asarray(als_train(wu, wi, warm_cfg).user_factors)
     del wu, wi
 
@@ -683,13 +675,13 @@ def run_bench(scale: float, iterations: int) -> int:
     # view-reshape + async device_put issue — separating them tells the
     # hardware run WHICH host-side cost dominates (the transfer wait
     # itself lands in iteration_s[0], excluded from steady-state)
-    bu = _maybe_sort(bucketize(users[tr], items[tr], ratings[tr], n_users,
-                               n_items, pad_to_blocks=True))
+    bu = bucketize(users[tr], items[tr], ratings[tr], n_users,
+                   n_items, pad_to_blocks=True)
     t_s1 = time.monotonic()
     by_user = stage(bu)  # async puts: item bucketize below overlaps them
     t_s2 = time.monotonic()
-    bi = _maybe_sort(bucketize(items[tr], users[tr], ratings[tr], n_items,
-                               n_users, pad_to_blocks=True))
+    bi = bucketize(items[tr], users[tr], ratings[tr], n_items,
+                   n_users, pad_to_blocks=True)
     t_s3 = time.monotonic()
     by_item = stage(bi)
     t_end = time.monotonic()
@@ -745,12 +737,12 @@ def run_bench(scale: float, iterations: int) -> int:
         "bucket_shapes": profile.get("bucket_shapes"),
         # RESOLVED lever flags from the train run itself (tri-state
         # defaults resolve inside als_train) — the ledger must record
-        # what executed, not what was requested. sort_gather is resolved
-        # HERE: the bench sorts host-side before staging, so the config
-        # flag the train run saw is moot.
+        # what executed, not what was requested. ``sort_gather`` is a
+        # component of the ledger's key (obs/perfledger.py): no run
+        # sorts a row's indices
         "solve_mode": profile.get("solve_mode", solve_mode),
         "gather_dtype": profile.get("gather_dtype", gather_dtype),
-        "sort_gather": sort_gather,
+        "sort_gather": False,
         "fused_gather": profile.get("fused_gather", False),
         # compile/retrace accounting for THIS process (warmup included):
         # a bench round whose timed section quietly recompiled is not

@@ -247,11 +247,6 @@ class ALSAlgorithmParams(Params):
     #: — docs/performance.md#levers — bounds the drift before adopting
     #: bf16)
     gather_dtype: str = "f32"
-    #: Sort each solve row's column indices before staging (gather
-    #: locality; permutation-invariant math). None (default) resolves to
-    #: ON — pass False for the legacy unsorted path (see
-    #: ops.als.ALSConfig.sort_gather_indices)
-    sort_gather_indices: Optional[bool] = None
     #: Serving top-k path: "auto" (default) streams item blocks through
     #: the fused Pallas score+select kernel — never materializing the
     #: [batch, n_items] score matrix in HBM — when on TPU and that
@@ -278,7 +273,7 @@ class ALSAlgorithmParams(Params):
     #: BENCH_BF16_RMSE_GATE override.
     quant_gate_min_match: float = 1.0
 
-    retired_fields = ("fused_gather",)
+    retired_fields = ("fused_gather", "sort_gather_indices")
 
 
 @dataclasses.dataclass
@@ -404,7 +399,6 @@ class ALSAlgorithm(Algorithm):
             alpha=p.alpha,
             solve_mode=p.solve_mode,
             gather_dtype=p.gather_dtype,
-            sort_gather_indices=p.sort_gather_indices,
         )
         from ..ckpt import resolve_every, resolve_resume
         from ..ops.als_sharded import als_train_sharded

@@ -406,24 +406,10 @@ class ALSConfig:
     #: ~0.4% relative input rounding — the λ·n_u ridge keeps the solves
     #: stable, but quality-gate the result (RMSE) before adopting.
     gather_dtype: str = "f32"
-    #: Sort each solve row's gathered column indices ascending before
-    #: staging (host-side, one vectorized argsort per bucket). The
-    #: Gramian sum over K is permutation-invariant, so results are
-    #: identical up to float reassociation (the ROUND7_NOTES contract:
-    #: factors to rtol 1e-3 / atol 1e-4 over 3 iterations, training RMSE
-    #: to 1e-3 — pinned in tests/test_als.py); what changes is HBM
-    #: access locality — adjacent gathers hit adjacent factor rows.
-    #: ``None`` (the default) resolves to True when the inputs are
-    #: host-side :class:`BucketedMatrix` (the sort happens pre-staging)
-    #: and False for already-staged inputs, which cannot be reordered.
-    #: Pass ``False`` explicitly to opt out (the legacy unsorted path);
-    #: an explicit ``True`` with staged inputs still fails loudly.
-    sort_gather_indices: Optional[bool] = None
 
-    def resolve_levers(self, staged_inputs: bool = False) -> dict:
+    def resolve_levers(self) -> dict:
         """The CONCRETE lever settings a train run with this config will
-        execute — ``None`` tri-states resolved against the backend
-        (``solve_mode="auto"``) and the input form (``staged_inputs``).
+        execute — ``solve_mode="auto"`` resolved against the backend.
         One home for the resolution rules, shared by :func:`als_train`
         and the bench/ledger accounting ("record resolved, not
         requested" — docs/performance.md#levers)."""
@@ -434,13 +420,9 @@ class ALSConfig:
                 if (self.rank <= 80 and jax.default_backend() == "tpu")
                 else "chunked"
             )
-        sort = self.sort_gather_indices
-        if sort is None:
-            sort = not staged_inputs
         return {
             "solve_mode": solve_mode,
             "gather_dtype": self.gather_dtype,
-            "sort_gather": bool(sort),
             # the ``pallas`` solve builds a bucket as wide as the rank or
             # wider with the fused gather + Gramian kernel
             # (``ops/pallas_kernels.gramian_fused``): factor rows stream
@@ -659,39 +641,6 @@ def init_factors(n: int, rank: int, seed: int) -> jax.Array:
     return jnp.abs(jax.random.normal(key, (n, rank), dtype=jnp.float32)) / jnp.sqrt(
         jnp.float32(rank)
     )
-
-
-def sort_bucket_indices(side: BucketedMatrix) -> BucketedMatrix:
-    """Reorder each row's valid (idx, val) pairs ascending by column index.
-
-    Gather locality: the normal-equation build gathers one opposite-side
-    factor row (~rank·4 B) per index; sorted indices turn a random walk
-    over the factor table into segment-local accesses. The per-row sum is
-    permutation-invariant, so the solve result is unchanged up to float
-    reassociation. Padding (entries at positions >= counts[i]) keeps its
-    place at the row tail — the counts-based validity mask depends on it.
-    """
-    out = []
-    for b in side.buckets:
-        n, k = b.idx.shape
-        if n == 0 or k <= 1:
-            out.append(b)
-            continue
-        pos = np.arange(k, dtype=np.int64)[None, :]
-        key = np.where(
-            pos < b.counts[:, None].astype(np.int64),
-            b.idx.astype(np.int64),
-            np.iinfo(np.int64).max,
-        )
-        order = np.argsort(key, axis=1, kind="stable")
-        out.append(
-            dataclasses.replace(
-                b,
-                idx=np.take_along_axis(b.idx, order, axis=1),
-                val=np.take_along_axis(b.val, order, axis=1),
-            )
-        )
-    return dataclasses.replace(side, buckets=out)
 
 
 @dataclasses.dataclass
@@ -1167,11 +1116,7 @@ def als_train(
         raise ValueError(
             f"gather_dtype must be 'f32' or 'bf16', got {cfg.gather_dtype!r}"
         )
-    staged_inputs = not (
-        isinstance(by_user, BucketedMatrix)
-        and isinstance(by_item, BucketedMatrix)
-    )
-    levers = cfg.resolve_levers(staged_inputs=staged_inputs)
+    levers = cfg.resolve_levers()
     solve_mode = levers["solve_mode"]
     # The pallas solve kernel has bounded VMEM scratch (rank padded to a
     # multiple of 8, n²·128·4 bytes) — "auto" selects around that limit;
@@ -1185,7 +1130,6 @@ def als_train(
             f"bound), got rank={cfg.rank}; use 'auto' or 'chunked'"
         )
     fused_gather = levers["fused_gather"]
-    sort_gather = levers["sort_gather"]
     rank = cfg.rank
 
     iteration = _als_iteration
@@ -1211,15 +1155,6 @@ def als_train(
         half = _als_half_sharded(tbl_spec)
 
     t_stage = _time.monotonic()
-    if cfg.sort_gather_indices and staged_inputs:
-        # already-staged tensors cannot be reordered host-side; only an
-        # EXPLICIT True can conflict (the None default resolves to False
-        # for staged inputs) and silently ignoring it would corrupt an
-        # A/B measurement
-        raise ValueError(
-            "sort_gather_indices=True requires BucketedMatrix inputs "
-            "(sort before staging: sort_bucket_indices(bucketize(...)))"
-        )
     # how often the dual form engages: real rows by the form their bucket
     # is solved in (counted before staging, where the counts are host
     # arrays), in ``profile`` and on each program's ``als.enqueue`` span
@@ -1230,12 +1165,6 @@ def als_train(
     forms["iteration"] = {
         k: forms["user"][k] + forms["item"][k] for k in forms["user"]
     }
-    if sort_gather:
-        # gather-locality pass (host, pre-staging); see sort_bucket_indices
-        with span("als.index_sort", {"side": "user"}):
-            by_user = sort_bucket_indices(by_user)
-        with span("als.index_sort", {"side": "item"}):
-            by_item = sort_bucket_indices(by_item)
     if isinstance(by_user, BucketedMatrix):
         with span("als.stage", {"side": "user"}):
             by_user = stage(by_user, row_sharding, row_multiple)
@@ -1249,7 +1178,6 @@ def als_train(
         # the bench and perf ledger record these (docs/performance.md)
         profile["solve_mode"] = solve_mode
         profile["gather_dtype"] = cfg.gather_dtype
-        profile["sort_gather"] = sort_gather
         profile["fused_gather"] = fused_gather
         profile["flops_per_iteration"] = estimate_iteration_flops(
             by_user, by_item, rank, cfg.implicit_prefs

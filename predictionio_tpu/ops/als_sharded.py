@@ -42,8 +42,9 @@ on ``shard_map`` so the collective schedule is explicit:
 Equivalence contract (the CI-runnable proof, on the 8-virtual-CPU-device
 test mesh): factors at 1/2/4/8 shards match the single-device trainer
 within the PR-12 reassociation tolerances (rtol 1e-3 / atol 1e-4, holdout
-RMSE 1e-3) — sharding changes accumulation ORDER (per-shard index sorting
-happens in permuted id space), never the per-row math. The multi-host
+RMSE 1e-3) — sharding changes accumulation ORDER (each shard's rows
+hold their ratings in the permuted id space's bucketize order), never
+the per-row math. The multi-host
 ``jax.distributed`` drive has not been run
 (docs/distributed_training.md).
 """
@@ -78,7 +79,6 @@ from .als import (
     als_train,
     bucketize,
     init_factors,
-    sort_bucket_indices,
 )
 
 __all__ = [
@@ -276,7 +276,6 @@ def _build_side(
     row_plan: ShardPlan,
     col_plan: ShardPlan,
     bucket_widths: Sequence[int],
-    sort: bool,
 ):
     """Per-shard right-sized buckets, stacked into shard-leading slabs.
 
@@ -311,10 +310,6 @@ def _build_side(
             bucket_widths=bucket_widths,
             pad_to_blocks=True,
         )
-        if sort:
-            # gather locality in the PERMUTED id space (adjacent permuted
-            # ids are adjacent rows of the gathered table)
-            bm = sort_bucket_indices(bm)
         per_shard.append({b.width: b for b in bm.buckets})
     all_widths = sorted({w for shard in per_shard for w in shard})
     idx_dtype = _idx_dtype(n_cols_perm)
@@ -495,11 +490,9 @@ def resolve_sharded_levers(cfg: ALSConfig) -> dict:
         raise ValueError(
             f"gather_dtype must be 'f32' or 'bf16', got {cfg.gather_dtype!r}"
         )
-    sort = cfg.sort_gather_indices
     return {
         "solve_mode": "chunked",
         "gather_dtype": cfg.gather_dtype,
-        "sort_gather": True if sort is None else bool(sort),
         "fused_gather": False,
     }
 
@@ -614,14 +607,13 @@ def als_train_sharded(
     item_deg = np.bincount(items, minlength=n_items)
     user_plan = plan_side(user_deg, n, rank=rank)
     item_plan = plan_side(item_deg, n, rank=rank)
-    sort = levers["sort_gather"]
     user_slabs_np, user_padded = _build_side(
         users, items, ratings, user_plan, item_plan,
-        DEFAULT_BUCKET_WIDTHS, sort,
+        DEFAULT_BUCKET_WIDTHS,
     )
     item_slabs_np, item_padded = _build_side(
         items, users, ratings, item_plan, user_plan,
-        DEFAULT_BUCKET_WIDTHS, sort,
+        DEFAULT_BUCKET_WIDTHS,
     )
     table_sharding = NamedSharding(mesh, P(SHARD_AXIS))
     slab_sharding = NamedSharding(mesh, P(SHARD_AXIS))

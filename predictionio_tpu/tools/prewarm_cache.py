@@ -24,10 +24,6 @@ Usage::
 
     python -m predictionio_tpu.tools.prewarm_cache [--scale 1.0]
         [--variants f32,bf16]
-
-Sorting (``sort_gather_indices``) permutes values host-side without
-changing shapes, so it shares the f32 variant's program — no separate
-compile exists to warm.
 """
 
 from __future__ import annotations

@@ -190,9 +190,7 @@ def _run_inner(
 
 #: The ALSConfig fields an algorithm's params may carry under the same
 #: name — what :func:`result_line` needs to resolve the levers.
-_ALS_LEVER_FIELDS = (
-    "rank", "solve_mode", "gather_dtype", "sort_gather_indices",
-)
+_ALS_LEVER_FIELDS = ("rank", "solve_mode", "gather_dtype")
 
 
 def resolved_levers(

@@ -2,6 +2,7 @@
 reference, RMSE convergence on synthetic low-rank data, bucketing correctness,
 and the serving top-k kernels."""
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -237,18 +238,13 @@ def _sweep_data():
     return u, i, v, n_u, n_i
 
 
-def sweep_factors(mode, implicit=False, meshed=False,
-                  gather="f32", sort=None):
+def sweep_factors(mode, implicit=False, meshed=False, gather="f32"):
     """Factors for one lever setting over the shared dataset, trained at
     most once per session (rank 12, 3 iterations, seed 2 — identical
-    across every consumer so the cached runs stay comparable).
-
-    ``sort=None`` rides the round-12 default (resolves to sorted for
-    these bucketized inputs), so the cached baseline legs ARE the
-    flipped-default runs; ``sort=False`` is the explicit legacy opt-out
-    leg the default-equivalence test compares against. A ``pallas`` run
-    builds its buckets of 12 slots or more with the fused kernel."""
-    key = (mode, implicit, meshed, gather, sort)
+    across every consumer so the cached runs stay comparable). A
+    ``pallas`` run builds its buckets of 12 slots or more with the fused
+    kernel."""
+    key = (mode, implicit, meshed, gather)
     if key not in _SWEEP_CACHE:
         from predictionio_tpu.ops.als import ALSConfig, als_train_coo
         from predictionio_tpu.parallel.mesh import create_mesh
@@ -258,7 +254,7 @@ def sweep_factors(mode, implicit=False, meshed=False,
             rank=12, iterations=3, lambda_=0.05,
             implicit_prefs=implicit, alpha=1.0, seed=2,
             solve_mode=mode,
-            gather_dtype=gather, sort_gather_indices=sort,
+            gather_dtype=gather,
         )
         f = als_train_coo(
             u, i, v, n_users=n_u, n_items=n_i, cfg=cfg,
@@ -325,92 +321,87 @@ class TestPallasModeGuards:
             als_train_coo(u, i, v, n_users=3, n_items=2, cfg=cfg)
 
 
-class TestSortGatherIndices:
-    """Within-row index sorting (gather locality) must be invisible to the
-    math: the Gramian sum over K is permutation-invariant *in exact
-    arithmetic*. In float32 the sort reorders the einsum accumulation, so
-    factors agree only to reassociation rounding — ~1e-5 per solve,
-    amplified through the alternating iterations (ROUND7_NOTES.md pins
-    the analysis; the seed's atol=1e-5 over 3 iterations sat exactly on
-    that noise floor). The contract worth pinning is two-part: the
-    *multiset* of (idx, val) pairs per row is exactly preserved
-    (bit-level, below) and training quality is unchanged — factors equal
-    to a documented reassociation tolerance and training RMSE equal to
-    1e-3."""
+class TestRowOrderInvariance:
+    """The order of a row's ratings inside its bucket is not part of the
+    result: the Gramian sum over K is permutation-invariant *in exact
+    arithmetic*, and in float32 another order only reassociates the
+    einsum's accumulation — ~1e-5 per solve, amplified through the
+    alternating iterations (ROUND7_NOTES.md pins the analysis). Whatever
+    order ``bucketize`` leaves is the one that trains (a host-side sort
+    of each row bought the chip's gather nothing: PERF.md §6, PR 29), and
+    the sharded trainer's permuted id space leans on the same contract."""
 
-    def test_sorted_buckets_preserve_rows_and_padding(self):
-        from predictionio_tpu.ops.als import bucketize, sort_bucket_indices
+    @staticmethod
+    def _shuffled(side, rng):
+        """Each row's valid (idx, val) pairs in a random order; padding
+        stays at the row's tail, where the counts-based mask expects it."""
+        out = []
+        for b in side.buckets:
+            idx, val = b.idx.copy(), b.val.copy()
+            for r, c in enumerate(b.counts):
+                order = rng.permutation(int(c))
+                idx[r, :c], val[r, :c] = idx[r, order], val[r, order]
+            out.append(dataclasses.replace(b, idx=idx, val=val))
+        return dataclasses.replace(side, buckets=out)
 
-        rng = np.random.default_rng(5)
-        nnz, n_u, n_i = 5000, 300, 120
-        u = rng.integers(0, n_u, nnz).astype(np.int32)
-        i = rng.integers(0, n_i, nnz).astype(np.int32)
-        v = rng.normal(size=nnz).astype(np.float32)
-        side = bucketize(u, i, v, n_u, n_i, pad_to_blocks=True)
-        sorted_side = sort_bucket_indices(side)
-        for b0, b1 in zip(side.buckets, sorted_side.buckets):
-            np.testing.assert_array_equal(b0.rows, b1.rows)
-            np.testing.assert_array_equal(b0.counts, b1.counts)
-            for r in range(b0.idx.shape[0]):
-                c = int(b0.counts[r])
-                # valid prefix: same multiset, now ascending
-                assert sorted(b0.idx[r, :c].tolist()) == b1.idx[r, :c].tolist()
-                # (idx, val) pairing preserved
-                assert (
-                    sorted(zip(b0.idx[r, :c], b0.val[r, :c]))
-                    == sorted(zip(b1.idx[r, :c], b1.val[r, :c]))
-                )
-                # padding tail untouched in place
-                np.testing.assert_array_equal(b0.idx[r, c:], b1.idx[r, c:])
-
-    def test_staged_input_with_sort_flag_is_loud(self):
-        """The flag can only act pre-staging; silently ignoring it would
-        corrupt an A/B measurement."""
+    @pytest.mark.parametrize("implicit", [False, True])
+    def test_training_result_unchanged_by_row_order(self, implicit):
         from predictionio_tpu.ops.als import (
-            ALSConfig, als_train, bucketize, stage,
+            ALSConfig, ALSFactors, als_train, bucketize, rmse,
         )
 
-        rng = np.random.default_rng(7)
-        u = rng.integers(0, 50, 500).astype(np.int32)
-        i = rng.integers(0, 30, 500).astype(np.int32)
-        v = np.ones(500, dtype=np.float32)
-        bu = stage(bucketize(u, i, v, 50, 30, pad_to_blocks=True))
-        bi = stage(bucketize(i, u, v, 30, 50, pad_to_blocks=True))
-        with pytest.raises(ValueError, match="sort_gather_indices"):
-            als_train(
-                bu, bi,
-                ALSConfig(rank=4, iterations=1, sort_gather_indices=True),
-            )
-
-    def test_training_result_unchanged(self):
-        """The round-12 default flip's equivalence proof: the DEFAULT
-        config (sort resolves ON for bucketized inputs) vs the explicit
-        ``sort_gather_indices=False`` legacy opt-out, riding the shared
-        sweep cache — the sorted leg IS every other equivalence test's
-        baseline, so the flip costs one extra cached training run."""
-        from predictionio_tpu.ops.als import ALSFactors, rmse
-
-        u, i, v, _, _ = _sweep_data()
-        sorted_run = sweep_factors("chunked")  # default ⇒ sorted
-        legacy = sweep_factors("chunked", sort=False)
-        # Factor parity to the f32 reassociation tolerance: the sort
-        # reorders each row's einsum accumulation, so per-solve rounding
-        # is ~1e-5 and three alternating iterations amplify it through
-        # the Cholesky solves (ROUND7_NOTES.md). The seed-era atol=1e-5
-        # bound asserted bitwise-ish equality that f32 cannot promise.
-        np.testing.assert_allclose(
-            sorted_run[0], legacy[0], rtol=1e-3, atol=1e-4,
+        u, i, v, n_u, n_i = _sweep_data()
+        cfg = ALSConfig(
+            rank=12, iterations=3, lambda_=0.05, implicit_prefs=implicit,
+            alpha=1.0, seed=2, solve_mode="chunked",
         )
-        # ...and the bound that actually matters for an A/B: training
-        # quality is unchanged.
-        r_sorted = rmse(ALSFactors(*sorted_run, rank=12), u, i, v)
-        r_legacy = rmse(ALSFactors(*legacy, rank=12), u, i, v)
-        assert abs(r_sorted - r_legacy) < 1e-3
+        by_user = bucketize(u, i, v, n_u, n_i, pad_to_blocks=True)
+        by_item = bucketize(i, u, v, n_i, n_u, pad_to_blocks=True)
+        rng = np.random.default_rng(3)
+        base = sweep_factors("chunked", implicit=implicit)
+        f = als_train(
+            self._shuffled(by_user, rng), self._shuffled(by_item, rng), cfg
+        )
+        shuffled = (np.asarray(f.user_factors), np.asarray(f.item_factors))
+        np.testing.assert_allclose(base[0], shuffled[0], rtol=1e-3, atol=1e-4)
+        np.testing.assert_allclose(base[1], shuffled[1], rtol=1e-3, atol=1e-4)
+        r_base = rmse(ALSFactors(*base, rank=12), u, i, v)
+        r_shuffled = rmse(ALSFactors(*shuffled, rank=12), u, i, v)
+        assert abs(r_base - r_shuffled) < 1e-3
 
-    def test_staged_input_default_resolves_unsorted(self):
-        """Staged inputs + the None default must NOT raise (the flip
-        keeps pre-staged callers working): the sort resolves OFF and the
-        resolved levers say so in the profile."""
+
+class TestTrainEntries:
+    """The ways into a job: ``als_train_coo`` is bucketize twice and
+    ``als_train``, which also takes matrices a caller staged itself; what
+    the host does before the first program changes nothing of what the
+    device computes."""
+
+    @pytest.mark.parametrize("implicit", [False, True])
+    def test_coo_entry_equals_prebucketized_matrices_bitwise(self, implicit):
+        from predictionio_tpu.ops.als import (
+            ALSConfig, als_train, als_train_coo, bucketize,
+        )
+
+        u, i, v, n_u, n_i = _sweep_data()
+        cfg = ALSConfig(
+            rank=12, iterations=3, lambda_=0.05, implicit_prefs=implicit,
+            alpha=1.0, seed=2, solve_mode="chunked",
+        )
+        coo = als_train_coo(u, i, v, n_users=n_u, n_items=n_i, cfg=cfg)
+        staged = als_train(
+            bucketize(u, i, v, n_u, n_i, pad_to_blocks=True),
+            bucketize(i, u, v, n_i, n_u, pad_to_blocks=True),
+            cfg,
+        )
+        np.testing.assert_array_equal(
+            np.asarray(coo.user_factors), np.asarray(staged.user_factors))
+        np.testing.assert_array_equal(
+            np.asarray(coo.item_factors), np.asarray(staged.item_factors))
+
+    def test_staged_inputs_train_and_report_their_levers(self):
+        """Pre-staged callers (``bench.py``, ``tools/prewarm_cache.py``)
+        hand ``als_train`` device tensors: the run's resolved levers are
+        in the profile."""
         from predictionio_tpu.ops.als import (
             ALSConfig, als_train, bucketize, stage,
         )
@@ -426,9 +417,35 @@ class TestSortGatherIndices:
             bu, bi, ALSConfig(rank=4, iterations=1), profile=profile,
         )
         assert np.isfinite(np.asarray(factors.user_factors)).all()
-        assert profile["sort_gather"] is False
+        assert "sort_gather" not in profile
         assert profile["fused_gather"] is False  # chunked on CPU
         assert profile["gather_dtype"] == "f32"
+
+    def test_item_side_failure_leaves_as_itself(self, monkeypatch):
+        """An exception in the item side's bucketize surfaces from
+        ``als_train_coo`` unchanged."""
+        from predictionio_tpu.ops import als
+
+        class ItemSideBroke(Exception):
+            pass
+
+        boom = ItemSideBroke("item side")
+        inner, calls = als.bucketize, []
+
+        def bucketize(rows, cols, *args, **kwargs):
+            calls.append(rows)
+            if len(calls) == 2:
+                raise boom
+            return inner(rows, cols, *args, **kwargs)
+
+        monkeypatch.setattr(als, "bucketize", bucketize)
+        u, i, v, n_u, n_i = _sweep_data()
+        with pytest.raises(ItemSideBroke) as raised:
+            als.als_train_coo(
+                u, i, v, n_users=n_u, n_items=n_i,
+                cfg=als.ALSConfig(rank=4, iterations=2, solve_mode="chunked"),
+            )
+        assert raised.value is boom
 
 
 class TestGatherDtype:
@@ -519,18 +536,17 @@ class TestFusedGather:
 
 
 class TestLeverDefaults:
-    """The round-12 default flip, pinned WITHOUT training anything:
-    ``resolve_levers`` is the one home for the tri-state resolution the
-    trainer, the bench and the ledger all read."""
+    """The resolved levers, pinned WITHOUT training anything:
+    ``resolve_levers`` is the one home for the resolution the trainer,
+    the bench and the ledger all read."""
 
     def test_defaults_resolve_fast_paths_on(self):
         from predictionio_tpu.ops.als import ALSConfig
 
         levers = ALSConfig().resolve_levers()
         # CPU test host: auto solve resolves chunked, so fused follows
-        # it off — but sort is host-side and unconditional for
-        # bucketized inputs
-        assert levers["sort_gather"] is True
+        # it off; these three are all a run resolves
+        assert set(levers) == {"solve_mode", "gather_dtype", "fused_gather"}
         assert levers["solve_mode"] == "chunked"
         assert levers["fused_gather"] is False
         assert levers["gather_dtype"] == "f32"
@@ -545,19 +561,17 @@ class TestLeverDefaults:
         with pytest.raises(TypeError):
             ALSConfig(fused_gather=False)
 
-    def test_staged_inputs_resolve_sort_off(self):
-        from predictionio_tpu.ops.als import ALSConfig
-
-        assert (
-            ALSConfig().resolve_levers(staged_inputs=True)["sort_gather"]
-            is False
-        )
-
     def test_explicit_opt_outs(self):
         from predictionio_tpu.ops.als import ALSConfig
 
-        levers = ALSConfig(sort_gather_indices=False).resolve_levers()
-        assert levers["sort_gather"] is False
+        levers = ALSConfig(solve_mode="chunked", gather_dtype="bf16").resolve_levers()
+        assert levers["solve_mode"] == "chunked"
+        assert levers["gather_dtype"] == "bf16"
+        # the solve has two options
+        assert {f.name for f in dataclasses.fields(ALSConfig)} == {
+            "rank", "iterations", "lambda_", "implicit_prefs", "alpha", "seed",
+            "solve_mode", "gather_dtype",
+        }
 
 
 class TestAllocBlock:
@@ -656,8 +670,8 @@ class TestBucketLadder:
         assert {1, 2, 4, 8, 16, 32, 128} <= widths
         widths_old, x_old, y_old = train(bucket_widths=OLD_LADDER)
         assert widths_old == widths - {1, 2, 4, 16}
-        # the index sort's contract (TestSortGatherIndices): equal up to
-        # float reassociation
+        # the contract of TestRowOrderInvariance: equal up to float
+        # reassociation
         np.testing.assert_allclose(x_new, x_old, rtol=1e-3, atol=1e-4)
         np.testing.assert_allclose(y_new, y_old, rtol=1e-3, atol=1e-4)
         r_new = rmse(ALSFactors(x_new, y_new, rank=12), u, i, v)

@@ -347,7 +347,7 @@ def test_sharded_half_step_2x2(topo):
         np.bincount(items, minlength=n_i), shards, rank=RANK)
     slabs, _ = als_sharded._build_side(
         users, items, vals, user_plan, item_plan,
-        als_sharded.DEFAULT_BUCKET_WIDTHS, True,
+        als_sharded.DEFAULT_BUCKET_WIDTHS,
     )
     assert {1, 2, 4, 8, 16, 32, 128} <= {slab[1].shape[-1] for slab in slabs}
     mesh = create_mesh(

@@ -467,6 +467,7 @@ def _recommendation_case():
 @pytest.mark.parametrize("case, field, stored_value", [
     (_sequencerec_case, "flash_impl", "xla"),
     (_recommendation_case, "fused_gather", None),
+    (_recommendation_case, "sort_gather_indices", False),
 ])
 def test_a_stored_instance_with_a_retired_field_deploys(
         case, field, stored_value, tmp_path, monkeypatch):

@@ -221,9 +221,9 @@ class TestStreamingTopKServing:
 
 
 class TestGatherLeverParams:
-    """The training levers (sort_gather_indices, solve_mode) must be
-    reachable from engine.json via ALSAlgorithmParams and reproduce the
-    default path's factors."""
+    """The training levers (solve_mode, gather_dtype) must be reachable
+    from engine.json via ALSAlgorithmParams and reproduce the default
+    path's factors."""
 
     @pytest.mark.slow  # ~90 s: three full trainings; outside tier-1 budget
     def test_levers_reproduce_default_model(self, registry):
@@ -243,7 +243,7 @@ class TestGatherLeverParams:
         base = run_train(engine, params(), registry, engine_id="lv0")
         levered = run_train(
             engine,
-            params(sort_gather_indices=True, solve_mode="pallas"),
+            params(solve_mode="pallas"),
             registry, engine_id="lv1",
         )
         m0 = load_models(registry, base)[0]

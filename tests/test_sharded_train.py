@@ -27,8 +27,8 @@ from predictionio_tpu.ops.als_sharded import (
 )
 
 #: the PR-12 equivalence tolerances (ROUND7_NOTES contract): sharding
-#: reorders float accumulation (per-shard sorted gathers in permuted id
-#: space, psum'd Gramians), never the per-row math
+#: reorders float accumulation (per-shard gathers in permuted id space,
+#: psum'd Gramians), never the per-row math
 RTOL, ATOL, RMSE_TOL = 1e-3, 1e-4, 1e-3
 
 
@@ -240,7 +240,7 @@ class TestShardsResolution:
         # the degenerate path resolves the SAME levers today's trainer
         # records — shards=1 is not a separate trainer
         assert p_explicit["solve_mode"] == "chunked"
-        assert p_explicit["sort_gather"] is True
+        assert "sort_gather" not in p_explicit
         assert p_explicit["fused_gather"] is False
 
 
@@ -348,7 +348,7 @@ class TestProfileEvidence:
         assert profile["shards"] == 2
         assert profile["solve_mode"] == "chunked"
         assert profile["fused_gather"] is False
-        assert profile["sort_gather"] is True
+        assert "sort_gather" not in profile
         plan = profile["shard_plan"]
         assert plan["shards"] == 2
         assert len(plan["perShardFlops"]["user"]) == 2

@@ -287,7 +287,7 @@ def test_train_via_spawned_subprocess(registry, tmp_path):
     # resolved there, and the compile cache it used
     assert out["device"]["platform"] == "cpu" and out["device"]["count"] >= 1
     assert out["levers"]["als"] == {
-        "solve_mode": "chunked", "gather_dtype": "f32", "sort_gather": True,
+        "solve_mode": "chunked", "gather_dtype": "f32",
         "fused_gather": False, "shards": 1,
     }
     assert set(out["compileCache"]) == {"dir", "hits", "misses"}
@@ -312,7 +312,7 @@ def test_result_line_resolves_levers_per_shard_count(registry, tmp_path):
     assert line["levers"]["als"]["solve_mode"] == "chunked"
     sharded = run_workflow.resolved_levers(registry, instance_id, shards=4)
     assert sharded["als"] == {
-        "solve_mode": "chunked", "gather_dtype": "f32", "sort_gather": True,
+        "solve_mode": "chunked", "gather_dtype": "f32",
         "fused_gather": False, "shards": 4,
     }
     assert run_workflow.resolved_levers(registry, "no-such-instance") == {}

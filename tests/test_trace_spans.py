@@ -28,6 +28,17 @@ from predictionio_tpu.obs.trace import (
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def _host_spans_only():
+    """Profiler options that keep ``TraceAnnotation`` spans and leave the
+    Python tracer off."""
+    from jax.profiler import ProfileOptions
+
+    options = ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    return options
+
+
 def _host_events(trace_dir, prefix="pio."):
     """(name, start_ns, end_ns) of the host events named ``prefix``…"""
     from jax.profiler import ProfileData
@@ -46,13 +57,8 @@ def _host_events(trace_dir, prefix="pio."):
 
 class TestTwoSinks:
     def test_span_under_a_profiler_session_is_in_both(self, tmp_path):
-        from jax.profiler import ProfileOptions
-
         tracer = Tracer("svc")
-        options = ProfileOptions()
-        options.python_tracer_level = 0
-        options.host_tracer_level = 2
-        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        jax.profiler.start_trace(str(tmp_path), profiler_options=_host_spans_only())
         try:
             with tracer.span("outer", tags={"side": "user", "b": 64}) as o:
                 with tracer.span("inner") as i:
@@ -191,7 +197,7 @@ class TestTrainingSpans:
                 s["tags"][key] for s in children if s["name"] == name
             )
 
-        for name in ("als.bucketize", "als.index_sort", "als.stage"):
+        for name in ("als.bucketize", "als.stage"):
             assert tagged(name, "side") == ["item", "user"], name
         # the fill share of the job's padding: every rating once on
         # either side, over the slots the ladder padded them to
@@ -236,7 +242,7 @@ class TestTrainingSpans:
         for s in children:
             counts[s["name"]] = counts.get(s["name"], 0) + 1
         assert counts == {
-            "als.bucketize": 2, "als.index_sort": 2, "als.stage": 2,
+            "als.bucketize": 2, "als.stage": 2,
             "als.init_factors": 1, "als.enqueue": 4,
             "train.wait_device": 1, "train.fetch": 1,
         }
@@ -246,6 +252,76 @@ class TestTrainingSpans:
         assert [s["name"] for s in children][-2:] == [
             "train.wait_device", "train.fetch",
         ]
+
+    @pytest.mark.parametrize("resumed", [False, True], ids=["fresh", "resumed"])
+    @pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
+    def test_host_work_of_a_job_in_order(self, implicit, resumed, tmp_path):
+        """What the host does before the first program, in the order it
+        does it, whatever the feedback and whichever iteration the job
+        starts at: both sides bucketized, both staged, then the two half
+        programs of the first executed iteration and a fused program for
+        each one after it. Nothing between ``als.bucketize`` and
+        ``als.stage``: no pass over the rows' indices."""
+        from predictionio_tpu.ops import als
+        from predictionio_tpu.workflow.checkpoint import CheckpointManager
+
+        pd = _toy_prepared()
+
+        def train(iterations, **kw):
+            cfg = als.ALSConfig(
+                rank=4, iterations=iterations, seed=1, implicit_prefs=implicit)
+            return als.als_train_coo(
+                pd.users, pd.items, pd.ratings, 60, 25, cfg, **kw)
+
+        kw, first = {}, 0
+        if resumed:
+            kw = {"checkpoint": CheckpointManager(str(tmp_path / "ck")),
+                  "checkpoint_every": 1}
+            train(1, **kw)
+            first = 1
+        with span("train") as root:
+            train(3, **kw)
+        spans = [
+            s for s in default_tracer().store.for_trace(root.trace_id)
+            if s["name"].startswith("als.") and s["name"] != "als.init_factors"
+        ]
+        assert [
+            (s["name"], s["tags"].get("side") or s["tags"]["program"])
+            for s in spans
+        ] == [
+            ("als.bucketize", "user"), ("als.bucketize", "item"),
+            ("als.stage", "user"), ("als.stage", "item"),
+            ("als.enqueue", "half_user"), ("als.enqueue", "half_item"),
+        ] + [("als.enqueue", "iteration")] * (2 - first)
+        assert [s["tags"]["i"] for s in spans if s["name"] == "als.enqueue"] == (
+            [first, first] + list(range(first + 1, 3)))
+
+    def test_no_index_sort_span_in_a_traced_job(self, tmp_path):
+        """Nothing sorts a row's indices (PERF.md §6, PR 29): a job under
+        the profiler leaves no ``als.index_sort`` in either sink, beside
+        the spans it does leave."""
+        from predictionio_tpu.models.recommendation import (
+            ALSAlgorithm,
+            ALSAlgorithmParams,
+        )
+
+        algo = ALSAlgorithm(ALSAlgorithmParams(rank=4, num_iterations=2, seed=1))
+        jax.profiler.start_trace(str(tmp_path), profiler_options=_host_spans_only())
+        try:
+            algo.train(None, _toy_prepared())
+        finally:
+            jax.profiler.stop_trace()
+        root = [
+            s for s in default_tracer().store.dump() if s["name"] == "train"
+        ][-1]
+        in_store = {
+            s["name"] for s in default_tracer().store.for_trace(root["traceId"])
+        }
+        in_trace = {name.split()[0] for name, _, _ in _host_events(str(tmp_path))}
+        assert {"als.bucketize", "als.stage", "als.enqueue"} <= in_store
+        assert {"pio.als.bucketize", "pio.als.stage", "pio.als.enqueue"} <= in_trace
+        assert "als.index_sort" not in in_store
+        assert "pio.als.index_sort" not in in_trace
 
     def test_profile_fence_is_a_span_of_its_own(self):
         """``als_train(profile=...)`` fences every iteration (the
