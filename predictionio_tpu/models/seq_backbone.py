@@ -3,23 +3,31 @@ of a configuration.
 
 A configuration gives the layer pattern (``full_attention_interval``: every
 n-th layer is softmax attention, the others gated DeltaNet; 1 = all
-attention), the head and feed-forward widths, the norm (``rms`` with scale
-``1 + w``, or ``layer``), the positions (``rotary`` on part of a head, or a
-``learned`` table), the feed-forward kind (``moe``: routed experts of which
-this share holds a range, plus a shared expert; or ``gelu``) and whether the
-head is the embedding. The keys are those of the public models'
-``config.json``; what such a file does not state (norm, positions, the
-range of experts held, precision) sits in its ``backbone`` group.
+attention), the attention (``gqa``: grouped heads of one width; ``mla``:
+latent attention, queries and keys/values projected down, normed and up
+again, a rotary part of the key that all heads share), the head and
+feed-forward widths, the norm (``rms`` with scale ``1 + w``, or ``layer``),
+the positions (``rotary`` on part of a head, or a ``learned`` table), the
+feed-forward kind (``moe``: routed experts of which this share holds a
+range, plus a shared expert; ``swiglu``; or ``gelu``), how many leading
+layers are dense instead (``first_k_dense_replace``), whether a
+multi-token-prediction module follows the last layer, and whether the head
+is the embedding. The keys are those of the public models' ``config.json``;
+what such a file does not state (norm, positions, the range of experts
+held, precision) sits in its ``backbone`` group.
 
 Parameters are stacked by period (``full_attention_interval`` layers) and
-the periods run in a ``lax.scan``, a model of one period too; each layer is
+the periods run in a ``lax.scan``, a model of one period too; the leading
+dense layers and the prediction module lie outside it; each layer is
 recomputed in the backward pass. Rows are packed: ``seg`` gives each slot its history's
 id (0 = padding), positions count from a history's start, and neither the
 convolution, the delta-rule state nor attention crosses a boundary.
 
 Precision: parameters, residual stream, norms, router, softmax, gates,
-delta-rule state and loss in float32; matrix products take
-``compute_dtype`` inputs (bfloat16 on the chip) and accumulate in float32.
+delta-rule state, the router's bias and loss in float32; matrix products
+take ``compute_dtype`` inputs (bfloat16 on the chip) and accumulate in
+float32. ``state_dtype`` is also what latent attention keeps its running
+softmax statistics in between tiles.
 """
 
 from __future__ import annotations
@@ -36,7 +44,7 @@ import numpy as np
 
 from ..ops.attention import attention
 from ..ops.deltanet import gated_deltanet
-from ..ops.moe import expert_layer
+from ..ops.moe import expert_layer, swiglu
 
 CONF_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
@@ -54,6 +62,17 @@ class BackboneConfig:
     #: the gated attention of Qwen3-Next: an output gate beside the query
     #: and a norm on every head of q and k
     attn_gate: bool = False
+    #: "gqa", or "mla": latent attention at the five widths below
+    attention: str = "gqa"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: the single device's attention kernel: "xla" (the blockwise loop,
+    #: which skips the tiles between histories) or "splash" (JAX's Pallas
+    #: kernel where it can run: ``ops.attention.attention``)
+    attn_kernel: str = "xla"
     partial_rotary_factor: float = 0.25
     rope_theta: float = 1e4
     positions: str = "learned"  # "learned" | "rotary"
@@ -64,15 +83,39 @@ class BackboneConfig:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
-    ffn: str = "gelu"  # "gelu" | "moe"
+    ffn: str = "gelu"  # "gelu" | "swiglu" | "moe"
+    #: leading layers, outside the periods, whose feed-forward is a SwiGLU
+    #: of ``intermediate_size`` whatever ``ffn`` says
+    first_k_dense_replace: int = 0
     intermediate_size: int = 256
     router_width: int = 0
     num_experts_per_tok: int = 0
     moe_intermediate_size: int = 0
     shared_expert_intermediate_size: int = 0
     norm_topk_prob: bool = True
+    scoring_func: str = "softmax"  # "softmax" | "sigmoid" (``ops.moe.route``)
+    routed_scaling_factor: float = 1.0
+    #: a bias [router_width] that chooses the experts and does not weigh
+    #: them (``topk_method`` ``noaux_tc``); no gradient moves it: every
+    #: step adds ``router_bias_rate * sign(mean load - load)``
+    router_bias: bool = False
+    router_bias_rate: float = 0.001
+    #: False = an optimizer step leaves every router's matrix where it was
+    #: (its gradient is computed all the same). For a lone share of an
+    #: expert-parallel group (``experts_held`` a part of ``router_width``):
+    #: the gradient it has comes through its own experts alone, and steps
+    #: along it pull every token onto them; the group's sum would step it
+    router_trains: bool = True
+    #: a sigmoid gate on the shared expert (Qwen3-Next has one)
+    shared_expert_gate: bool = True
     #: (first, count): the contiguous range of routed experts held here
     experts_held: Tuple[int, int] = (0, 0)
+    #: 1 = a multi-token-prediction module after the last layer: the id one
+    #: slot on is embedded beside the last hidden state, one more block,
+    #: the shared head, the id two slots on as target; the loss adds
+    #: ``mtp_loss_weight`` times its cross entropy. Serving ignores it.
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
     tie_word_embeddings: bool = True
     #: std of the normal the matrices are drawn from; None = 1/sqrt(fan_in)
     init_std: Optional[float] = None
@@ -89,7 +132,7 @@ class BackboneConfig:
 
     @property
     def n_periods(self) -> int:
-        return self.num_hidden_layers // self.period
+        return (self.num_hidden_layers - self.first_k_dense_replace) // self.period
 
     @classmethod
     def toy(cls, d_model: int, n_heads: int, n_layers: int) -> "BackboneConfig":
@@ -108,10 +151,24 @@ class BackboneConfig:
         values = {k: v for k, v in merged.items() if k in names}
         if "experts_held" in values:
             values["experts_held"] = tuple(values["experts_held"])
+        # two things a public file says in its own words
+        if merged.get("topk_method") == "noaux_tc":
+            values.setdefault("router_bias", True)
+        if "n_shared_experts" in merged:
+            values.setdefault(
+                "shared_expert_intermediate_size",
+                merged["n_shared_experts"] * merged["moe_intermediate_size"])
         cfg = cls(**values)
-        if cfg.num_hidden_layers % cfg.period:
+        if (cfg.num_hidden_layers - cfg.first_k_dense_replace) % cfg.period:
             raise ValueError(
-                f"{cfg.num_hidden_layers} layers are not whole periods of {cfg.period}")
+                f"{cfg.num_hidden_layers} layers less {cfg.first_k_dense_replace} dense ones "
+                f"are not whole periods of {cfg.period}")
+        if cfg.num_nextn_predict_layers not in (0, 1):
+            raise ValueError("one multi-token-prediction module at most")
+        if cfg.attention == "mla" and not (
+                (cfg.norm, cfg.positions) == ("rms", "rotary") and merged.get("rope_interleave", True)):
+            raise ValueError("latent attention comes with RMS norms and rotary positions "
+                             "on neighbouring pairs (rope_interleave)")
         return cfg
 
     @classmethod
@@ -149,24 +206,40 @@ def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
             lambda leaf: (tuple(axes) + leaf[0], leaf[1]), tree, is_leaf=_is_spec)
 
     h, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    full = {
-        "w_q": ((d, h * hd * (2 if cfg.attn_gate else 1)), "w"),
-        "w_k": ((d, hkv * hd), "w"), "w_v": ((d, hkv * hd), "w"),
-        "w_o": ((h * hd, d), "w"),
-    }
-    if cfg.attn_gate:
-        full.update(q_norm=((hd,), "zero"), k_norm=((hd,), "zero"))
+    if cfg.attention == "mla":
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        full = {
+            "w_qa": ((d, rq), "w"), "q_norm": ((rq,), "zero"), "w_qb": ((rq, h * (dn + dr)), "w"),
+            "w_kva": ((d, rkv + dr), "w"), "kv_norm": ((rkv,), "zero"),
+            "w_kvb": ((rkv, h * (dn + dv)), "w"), "w_o": ((h * dv, d), "w"),
+        }
+    else:
+        full = {
+            "w_q": ((d, h * hd * (2 if cfg.attn_gate else 1)), "w"),
+            "w_k": ((d, hkv * hd), "w"), "w_v": ((d, hkv * hd), "w"),
+            "w_o": ((h * hd, d), "w"),
+        }
+        if cfg.attn_gate:
+            full.update(q_norm=((hd,), "zero"), k_norm=((hd,), "zero"))
+    m = cfg.intermediate_size
+    gated = {"wg": ((d, m), "w"), "wu": ((d, m), "w"), "wd": ((m, d), "w")}
     if cfg.ffn == "moe":
         f, fs = cfg.moe_intermediate_size, cfg.shared_expert_intermediate_size
         count = cfg.experts_held[1]
         ffn = {
-            "router": ((d, cfg.router_width), "w"), "shared_gate": ((d,), "w_vec"),
+            "router": ((d, cfg.router_width), "w"),
             "shared": {"wg": ((d, fs), "w"), "wu": ((d, fs), "w"), "wd": ((fs, d), "w")},
             "experts": {"wg": ((count, d, f), "w"), "wu": ((count, d, f), "w"),
                         "wd": ((count, f, d), "w")},
         }
+        if cfg.shared_expert_gate:
+            ffn["shared_gate"] = ((d,), "w_vec")
+        if cfg.router_bias:
+            ffn["router_bias"] = ((cfg.router_width,), "zero")
+    elif cfg.ffn == "swiglu":
+        ffn = gated
     else:
-        m = cfg.intermediate_size
         ffn = {"mlp_in": ((d, m), "w"), "mlp_out": ((m, d), "w")}
     periods = {
         "full": lead(full, n),
@@ -183,6 +256,15 @@ def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
             "o_norm": ((dv,), "one"), "w_out": ((hv * dv, d), "w"),
         }, n, p - 1)
     shapes = {"embed": ((vocab, d), "embed"), "final_norm": norm, "periods": periods}
+    if cfg.first_k_dense_replace:
+        shapes["dense"] = lead(
+            {"full": full, "norm_in": norm, "norm_post": norm, "ffn": gated},
+            cfg.first_k_dense_replace)
+    if cfg.num_nextn_predict_layers:
+        shapes["mtp"] = {
+            "enorm": norm, "hnorm": norm, "eh_proj": ((2 * d, d), "w"), "norm": norm,
+            "block": {"full": full, "norm_in": norm, "norm_post": norm, "ffn": ffn},
+        }
     if not cfg.tie_word_embeddings:
         shapes["head"] = ((vocab, d), "embed")
     if cfg.positions == "learned":
@@ -229,24 +311,44 @@ def _draw_program(cfg: BackboneConfig, vocab: int, max_positions: int):
 
 def layers_of(params: Dict, cfg: BackboneConfig) -> Dict:
     """The parameters unstacked into a list of per-layer dicts, in the
-    layout of ``testing/qwen3_next_reference.py`` (works on any pytree of
-    the parameters' structure: gradients too)."""
-    per = params["periods"]
+    layout of the plain references (``testing/qwen3_next_reference.py``;
+    with latent attention ``testing/joyai_flash_reference.py``, whose
+    mixer is ``attn``, whose dense layers carry ``mlp`` and whose
+    prediction module is ``mtp``). Works on any pytree of the parameters'
+    structure: gradients too."""
+    mixer_key = "attn" if cfg.attention == "mla" else "full"
+    ffn_key = "moe" if cfg.ffn == "moe" else "mlp"
+
+    def block(blk, mixer_key=mixer_key, ffn_key=ffn_key):
+        mixer = blk["linear"] if mixer_key == "linear" else blk["full"]
+        return {"input_norm": blk["norm_in"]["w"], "post_norm": blk["norm_post"]["w"],
+                ffn_key: blk["ffn"], mixer_key: mixer}
+
     layers = []
+    for j in range(cfg.first_k_dense_replace):
+        dense = jax.tree_util.tree_map(lambda leaf, j=j: leaf[j], params["dense"])
+        layers.append(block(dense, ffn_key="mlp"))
+    per = params["periods"]
     for n in range(cfg.n_periods):
         for j in range(cfg.period):
             take = lambda leaf, n=n, j=j: leaf[n, j]  # noqa: E731
-            layer = {
-                "input_norm": per["norm_in"]["w"][n, j], "post_norm": per["norm_post"]["w"][n, j],
-                "moe": jax.tree_util.tree_map(take, per["ffn"]),
-            }
+            blk = {name: jax.tree_util.tree_map(take, per[name])
+                   for name in ("norm_in", "norm_post", "ffn")}
             if j == cfg.period - 1:
-                layer["full"] = jax.tree_util.tree_map(lambda leaf, n=n: leaf[n], per["full"])
+                blk["full"] = jax.tree_util.tree_map(lambda leaf, n=n: leaf[n], per["full"])
+                layers.append(block(blk))
             else:
-                layer["linear"] = jax.tree_util.tree_map(take, per["linear"])
-            layers.append(layer)
-    return {"embed": params["embed"], "head": params["head"],
-            "final_norm": params["final_norm"]["w"], "layers": layers}
+                blk["linear"] = jax.tree_util.tree_map(take, per["linear"])
+                layers.append(block(blk, mixer_key="linear"))
+    out = {"embed": params["embed"], "head": params["head"],
+           "final_norm": params["final_norm"]["w"], "layers": layers}
+    if "mtp" in params:
+        m = params["mtp"]
+        out["mtp"] = {
+            "enorm": m["enorm"]["w"], "hnorm": m["hnorm"]["w"], "eh_proj": m["eh_proj"],
+            "norm": m["norm"]["w"], "block": block(m["block"]),
+        }
+    return out
 
 
 # -- the block --------------------------------------------------------------
@@ -276,6 +378,64 @@ def _rope(t, pos, rot: int, theta: float):
     r, rest = t[..., :rot], t[..., rot:]
     half = jnp.concatenate([-r[..., rot // 2:], r[..., : rot // 2]], -1)
     return jnp.concatenate([r * cos + half * sin, rest], -1)
+
+
+def _rope_pairs(t, pos, theta: float):
+    """t [B, L, H, r]: every pair of neighbours (2j, 2j + 1) turns by
+    ``pos * theta ** (-2j / r)`` (the interleaved layout)."""
+    r = t.shape[-1]
+    inv = 1.0 / theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    ang = pos.astype(jnp.float32)[..., None] * inv
+    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    a, b = t[..., 0::2], t[..., 1::2]
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin], -1).reshape(t.shape)
+
+
+def _rms(x, w, eps: float):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (1.0 + w)
+
+
+def _latent_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule):
+    """Latent attention. Queries: down to ``q_lora_rank``, RMS norm, up to
+    H x (nope | rope). Keys and values: down to ``kv_lora_rank`` + rope,
+    RMS norm of the latent part, up to H x (nope | value); the rope part
+    is ONE vector a slot that every head's key ends with. Scores over nope
+    + rope, values of their own width. Also returns the q, k, v it handed
+    the attention core (keys and values less their means over the row) and
+    the o that gave, [B, H, L, .]."""
+    b, l, _ = x.shape
+    h, rkv = cfg.num_attention_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    cd, f32, eps = _dt(cfg.compute_dtype), jnp.float32, cfg.rms_norm_eps
+
+    def dot(t, w):
+        return jnp.dot(t.astype(cd), w.astype(cd), preferred_element_type=f32)
+
+    with jax.named_scope("seq.attn.latent"):
+        # the wide projections are kept in the compute dtype, as in the other mixers
+        q = dot(_rms(dot(x, p["w_qa"]), p["q_norm"], eps), p["w_qb"]).astype(cd)
+        q = q.reshape(b, l, h, dn + dr)
+        kva = dot(x, p["w_kva"])
+        k_rope = _rope_pairs(kva[:, :, None, rkv:], pos, cfg.rope_theta)
+        kv = dot(_rms(kva[..., :rkv], p["kv_norm"], eps), p["w_kvb"]).astype(cd)
+        kv = kv.reshape(b, l, h, dn + dv).astype(f32)
+        q_rope = _rope_pairs(q[..., dn:].astype(f32), pos, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :dn], q_rope.astype(cd)], -1)
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_rope, (b, l, h, dr))], -1)
+        # The core is handed what differs between a row's slots: a softmax
+        # does not see a constant added to every key, and a constant added
+        # to every value comes out as itself. What the slots share would
+        # else cost the bfloat16 core its digits, worst in its backward
+        # pass, whose row sums cancel only as far as o is exact.
+        k = k - k.mean(1, keepdims=True)
+        v_mean = kv[..., dn:].mean(1, keepdims=True)
+        v = kv[..., dn:] - v_mean
+        q, k, v = (t.astype(cd).transpose(0, 2, 1, 3) for t in (q, k, v))
+    with jax.named_scope("seq.attn.core"):
+        o = attention(q, k, v, mesh=mesh, causal=True, schedule=schedule, segment_ids=seg,
+                      block=cfg.attn_block, stats_dtype=cfg.state_dtype, kernel=cfg.attn_kernel)
+    out = dot(o.transpose(0, 2, 1, 3).reshape(b, l, h * dv), p["w_o"])
+    return out + dot(v_mean.reshape(b, 1, h * dv), p["w_o"]), {"q": q, "k": k, "v": v, "o": o}
 
 
 def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule):
@@ -310,15 +470,20 @@ def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule):
 
 
 def _ffn(cfg: BackboneConfig, p: Dict, x):
+    """The feed-forward its parameters describe: routed experts, a SwiGLU
+    or the GELU pair."""
     cd, f32 = _dt(cfg.compute_dtype), jnp.float32
-    if cfg.ffn == "moe":
+    if "router" in p:
         b, l, d = x.shape
         with jax.named_scope("seq.moe"):
             y, counters = expert_layer(
                 p, x.reshape(b * l, d), first=cfg.experts_held[0],
                 top_k=cfg.num_experts_per_tok, norm_topk=cfg.norm_topk_prob,
-                compute_dtype=cd)
+                compute_dtype=cd, scoring=cfg.scoring_func, scale=cfg.routed_scaling_factor)
         return y.reshape(b, l, d), counters
+    if "wg" in p:
+        with jax.named_scope("seq.ffn"):
+            return swiglu(p, x, cd), {}
     hidden = jax.nn.gelu(jnp.dot(x.astype(cd), p["mlp_in"].astype(cd), preferred_element_type=f32))
     return jnp.dot(hidden.astype(cd), p["mlp_out"].astype(cd), preferred_element_type=f32), {}
 
@@ -327,7 +492,11 @@ def _layer(cfg: BackboneConfig, full: bool, mesh, schedule, x, seg, pos,
            norm_in, mixer, norm_post, ffn):
     h = _norm(cfg, norm_in, x)
     ran = {}
-    if full:
+    if full and cfg.attention == "mla":
+        with jax.named_scope("seq.attn"):
+            mixed, ran = _latent_mixer(cfg, mixer, h, seg, pos, mesh, schedule)
+            x = x + mixed
+    elif full:
         with jax.named_scope("seq.attn"):
             x = x + _attention_mixer(cfg, mixer, h, seg, pos, mesh, schedule)
     else:
@@ -343,14 +512,21 @@ def _layer(cfg: BackboneConfig, full: bool, mesh, schedule, x, seg, pos,
     return x + y, counters, ran
 
 
+def _layer_fn(cfg: BackboneConfig, full: bool, mesh, schedule):
+    """One layer as ``(x, seg, pos, norm_in, mixer, norm_post, ffn) -> x,
+    counters, ran``, recomputed in the backward pass."""
+    return jax.checkpoint(lambda *a: _layer(cfg, full, mesh, schedule, *a))
+
+
 def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
                   schedule: str = "auto"):
     """tokens, seg [B, L] -> the residual stream after the last layer
     [B, L, D] (float32, before the final norm); the expert layers'
     counters, stacked [periods, layers of a period, ...]; and what the
-    delta rule of each period's first layer was given and gave
-    (``ops.deltanet.gated_deltanet``; stacked [periods, B, L, ...]; empty
-    without such a layer)."""
+    mixer of each period's first layer handed its inner kernel and got back
+    (the delta rule's q, k, v, g, beta and o: ``ops.deltanet.gated_deltanet``;
+    latent attention's q, k, v and o; stacked [periods, B, ...]; empty with
+    neither)."""
     pos = positions_of(seg)
     with jax.named_scope("seq.embed"):
         x = params["embed"][tokens]
@@ -362,12 +538,11 @@ def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
                     f"table ({table.shape[0]} positions: trained with a shorter seq_len)")
             x = x + table[pos]
 
-    def layer_fn(full):
-        fn = lambda *a: _layer(cfg, full, mesh, schedule, *a)  # noqa: E731
-        return jax.checkpoint(fn)
-
-    linear_layer, full_layer = layer_fn(False), layer_fn(True)
+    linear_layer, full_layer = (_layer_fn(cfg, full, mesh, schedule) for full in (False, True))
     p = cfg.period
+    for j in range(cfg.first_k_dense_replace):
+        d = jax.tree_util.tree_map(lambda a, j=j: a[j], params["dense"])
+        x, _, _ = full_layer(x, seg, pos, d["norm_in"], d["full"], d["norm_post"], d["ffn"])
 
     def one_period(x, per):
         counters, first_ran = [], {}
@@ -392,16 +567,18 @@ def head_of(params: Dict):
     return params["head"] if "head" in params else params["embed"]
 
 
-def logits_of(cfg: BackboneConfig, params: Dict, hidden):
-    """hidden [..., D] (before the final norm) -> logits [..., V], float32."""
+def logits_of(cfg: BackboneConfig, params: Dict, hidden, norm: Optional[Dict] = None):
+    """hidden [..., D] (before the final norm, or before ``norm``, the
+    prediction module's own) -> logits [..., V], float32."""
     cd = _dt(cfg.compute_dtype)
     with jax.named_scope("seq.head"):
-        h = _norm(cfg, params["final_norm"], hidden)
+        h = _norm(cfg, params["final_norm"] if norm is None else norm, hidden)
         return jnp.dot(h.astype(cd), head_of(params).T.astype(cd),
                        preferred_element_type=jnp.float32)
 
 
-def next_item_loss(cfg: BackboneConfig, params: Dict, hidden, targets, valid):
+def next_item_loss(cfg: BackboneConfig, params: Dict, hidden, targets, valid,
+                   norm: Optional[Dict] = None):
     """Mean cross entropy of the real targets; logits are made a block of
     tokens at a time and made again in the backward pass."""
     d = hidden.shape[-1]
@@ -411,7 +588,7 @@ def next_item_loss(cfg: BackboneConfig, params: Dict, hidden, targets, valid):
     @jax.checkpoint
     def block(total, xs):
         hb, tb, mb = xs
-        logits = logits_of(cfg, params, hb)
+        logits = logits_of(cfg, params, hb, norm)
         with jax.named_scope("seq.head"):
             logz = jax.nn.logsumexp(logits, axis=-1)
             picked = jnp.take_along_axis(logits, tb[:, None], axis=-1)[:, 0]
@@ -429,10 +606,82 @@ def split_rows(rows, segs):
     return rows[:, :-1], segs[:, :-1], rows[:, 1:], valid
 
 
+def split_rows_mtp(rows, segs):
+    """Packed rows [B, L + 1] -> what the prediction module is given and
+    asked at slot i: the id at i + 1, the id at i + 2 as target (the last
+    slot has none), and which targets count: i, i + 1 and i + 2 lie in one
+    history."""
+    same = (segs[:, :-2] == segs[:, 1:-1]) & (segs[:, 1:-1] == segs[:, 2:]) & (segs[:, :-2] > 0)
+    last = ((0, 0), (0, 1))
+    return rows[:, 1:], jnp.pad(rows[:, 2:], last), jnp.pad(same, last)
+
+
+def mtp_hidden(cfg: BackboneConfig, params: Dict, hidden, next_tokens, seg, mesh=None,
+               schedule: str = "auto"):
+    """The multi-token-prediction module: ``[rms(embed(t_i+1)) | rms(h_i)]
+    W_eh``, then one block of the model's own kind (its routed experts
+    too). hidden [B, L, D] as :func:`hidden_states` gives it -> [B, L, D]
+    (before the module's own last norm) and the block's counters."""
+    m, cd = params["mtp"], _dt(cfg.compute_dtype)
+    both = jnp.concatenate([_norm(cfg, m["enorm"], params["embed"][next_tokens]),
+                            _norm(cfg, m["hnorm"], hidden)], -1)
+    x = jnp.dot(both.astype(cd), m["eh_proj"].astype(cd), preferred_element_type=jnp.float32)
+    blk = m["block"]
+    x, counters, _ = _layer_fn(cfg, True, mesh, schedule)(
+        x, seg, positions_of(seg), blk["norm_in"], blk["full"], blk["norm_post"], blk["ffn"])
+    return x, counters
+
+
 def loss_fn(cfg: BackboneConfig, params: Dict, rows, segs, mesh=None,
             schedule: str = "auto"):
     """The training loss of one batch of packed rows, and (aux) the final
-    hidden states, the counters and what the first delta rule ran on."""
+    hidden states, the counters and what the first mixers ran on. With a
+    prediction module the loss is next-item + ``mtp_loss_weight`` x the
+    module's; the counters then carry ``mtp_loss`` and its block's own as
+    ``mtp_<name>``, and the third aux ``mtp_hidden``."""
     tokens, seg, targets, valid = split_rows(rows, segs)
     hidden, counters, ran = hidden_states(cfg, params, tokens, seg, mesh, schedule)
-    return next_item_loss(cfg, params, hidden, targets, valid), (hidden, counters, ran)
+    loss = next_item_loss(cfg, params, hidden, targets, valid)
+    if cfg.num_nextn_predict_layers:
+        with jax.named_scope("seq.mtp"):
+            next_tokens, targets, valid = split_rows_mtp(rows, segs)
+            x, mtp_counters = mtp_hidden(cfg, params, hidden, next_tokens, seg, mesh, schedule)
+            mtp_loss = next_item_loss(cfg, params, x, targets, valid, params["mtp"]["norm"])
+        loss = loss + cfg.mtp_loss_weight * mtp_loss
+        counters = {**counters, "mtp_loss": mtp_loss,
+                    **{f"mtp_{name}": value for name, value in mtp_counters.items()}}
+        ran = {**ran, "mtp_hidden": x}
+    return loss, (hidden, counters, ran)
+
+
+def step_routers(cfg: BackboneConfig, before: Dict, after: Dict, counters: Dict) -> Dict:
+    """``after`` (the parameters an optimizer step made of ``before``) with
+    what that step does not decide about the routers put right. Every
+    router's bias is set to ``b + router_bias_rate * sign(mean load -
+    load)``, b from ``before`` (the optimizer's weight decay does not reach
+    it), the loads the step's own ``router_tokens`` over all experts; an
+    expert at exactly the mean is left where it is (sign 0). Where
+    ``router_trains`` is off every router's matrix is ``before``'s. With
+    neither, ``after`` as it is."""
+    if cfg.ffn != "moe" or (cfg.router_trains and not cfg.router_bias):
+        return after
+
+    def stepped(bias, tokens):
+        load = tokens.astype(jnp.float32)
+        return bias + cfg.router_bias_rate * jnp.sign(load.mean(-1, keepdims=True) - load)
+
+    def put(tree, path, leaf):
+        return leaf if not path else {**tree, path[0]: put(tree[path[0]], path[1:], leaf)}
+
+    sites = [(("periods", "ffn"), "router_tokens")]
+    if "mtp" in after:
+        sites.append((("mtp", "block", "ffn"), "mtp_router_tokens"))
+    with jax.named_scope("seq.router_bias"):
+        for path, counted in sites:
+            ffn = functools.reduce(lambda tree, key: tree[key], path, before)
+            if cfg.router_bias:
+                after = put(after, path + ("router_bias",),
+                            stepped(ffn["router_bias"], counters[counted]))
+            if not cfg.router_trains:
+                after = put(after, path + ("router",), ffn["router"])
+    return after

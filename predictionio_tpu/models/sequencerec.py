@@ -380,6 +380,14 @@ def make_loss_and_grad(cfg: bb.BackboneConfig, mesh=None, schedule: str = "auto"
         has_aux=True))
 
 
+#: counters of a step that the job keeps from every step: tokens per held
+#: expert [periods, layers of a period, held], per expert of the router's
+#: whole width where a bias balances them [periods, layers, experts], the
+#: same two of the prediction module's block, and the module's loss
+_BY_STEP = ("expert_tokens", "router_tokens", "mtp_expert_tokens", "mtp_router_tokens",
+            "mtp_loss")
+
+
 @functools.lru_cache(maxsize=8)
 def _programs(cfg: bb.BackboneConfig, learning_rate: float, mesh, schedule: str):
     """The jitted programs of a job, made once per configuration: a second
@@ -393,8 +401,10 @@ def _programs(cfg: bb.BackboneConfig, learning_rate: float, mesh, schedule: str)
         (loss, (_, counters, _)), grads = loss_and_grad(mp, rows, segs)
         with jax.named_scope("seq.optimizer"):
             updates, os_ = opt.update(grads, os_, mp)
-            mp = optax.apply_updates(mp, updates)
-        return mp, os_, loss, counters
+            stepped = optax.apply_updates(mp, updates)
+        # what no gradient moves is stepped from the step's own counts
+        stepped = bb.step_routers(cfg, mp, stepped, counters)
+        return stepped, os_, loss, counters
 
     return jax.jit(opt.init), jax.jit(step, donate_argnums=(0, 1)), loss_and_grad
 
@@ -442,7 +452,7 @@ class SeqRecAlgorithm(Algorithm):
         with span("seqrec.init"):
             model_params = bb.init_params(cfg, vocab, pd.seq_len, p.seed)
             opt_state = opt_init(model_params)
-        losses, loads, counters, before = [], [], None, None
+        losses, by_step, counters, before = [], {}, None, None
         for i in range(p.steps):
             with span("seqrec.input", {"i": i}):
                 rows, segs = batches.next()
@@ -450,8 +460,9 @@ class SeqRecAlgorithm(Algorithm):
                 model_params, opt_state, loss, counters = step(
                     model_params, opt_state, rows, segs)
                 losses.append(loss)
-                if "expert_tokens" in counters:
-                    loads.append(counters["expert_tokens"])
+                for name in _BY_STEP:
+                    if name in counters:
+                        by_step.setdefault(name, []).append(counters[name])
                 # one step behind: the device already has step i when the
                 # host waits for step i - 1, so the span is a step long and
                 # the device is never left waiting for the host
@@ -466,8 +477,13 @@ class SeqRecAlgorithm(Algorithm):
         with span("train.fetch"):
             host_params = jax.tree_util.tree_map(np.asarray, model_params)
             host_losses = np.asarray(jax.device_get(losses), np.float32)
-            if loads:  # [steps, periods, layers of a period, held experts]
-                stats["expert_tokens_by_step"] = np.stack(jax.device_get(loads))
+            for name, values in by_step.items():  # [steps, ...]
+                stats[name + "_by_step"] = np.stack(jax.device_get(values))
+            if cfg.router_bias and cfg.ffn == "moe":
+                blocks = [host_params["periods"]] + (
+                    [host_params["mtp"]["block"]] if "mtp" in host_params else [])
+                stats["router_bias_abs_max"] = max(
+                    float(np.abs(blk["ffn"]["router_bias"]).max()) for blk in blocks)
         return SeqRecModel(
             params=host_params, item_map=pd.item_map, user_recent=pd.user_recent,
             seq_len=pd.seq_len, config=cfg, losses=host_losses, stats=stats,

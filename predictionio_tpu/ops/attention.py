@@ -135,10 +135,13 @@ def _pair_tile(q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, after):
     return qi, kj, s, keep[:, None, None], after
 
 
-def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk):
-    """Online softmax over the block pairs; returns o (q's dtype) and the
-    log-sum-exp of every row [B, Hkv, G, Lq] (float32)."""
+def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype=jnp.float32):
+    """Online softmax over the block pairs; returns o (q's dtype, v's
+    width) and the log-sum-exp of every row [B, Hkv, G, Lq] (float32). The
+    running maximum, sum and output are kept in ``stats_dtype`` between
+    tiles and taken up to float32 inside one."""
     b, hkv, g, lq, d = q.shape
+    f32 = jnp.float32
     scale = 1.0 / np.sqrt(d)
     ii, jj = _block_pairs(lq, k.shape[2], bq, bk, causal)
 
@@ -150,8 +153,9 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk):
             mi = jax.lax.dynamic_slice_in_dim(m, i * bq, bq, axis=3)
             qi, kj, s, keep, mi = _pair_tile(
                 q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, mi)
-            li = jax.lax.dynamic_slice_in_dim(l, i * bq, bq, axis=3)
-            oi = jax.lax.dynamic_slice_in_dim(o, i * bq, bq, axis=3)
+            mi = mi.astype(f32)
+            li = jax.lax.dynamic_slice_in_dim(l, i * bq, bq, axis=3).astype(f32)
+            oi = jax.lax.dynamic_slice_in_dim(o, i * bq, bq, axis=3).astype(f32)
             vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=2)
             sm = jnp.where(keep, s, _NEG_BIG)
             m_new = jnp.maximum(mi, sm.max(-1))
@@ -161,21 +165,19 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk):
             o_new = oi * corr[..., None] + jnp.einsum(
                 "bkgqc,bkcd->bkgqd", p.astype(v.dtype), vj,
                 preferred_element_type=jnp.float32)
-            return (
-                jax.lax.dynamic_update_slice_in_dim(m, m_new, i * bq, axis=3),
-                jax.lax.dynamic_update_slice_in_dim(l, l_new, i * bq, axis=3),
-                jax.lax.dynamic_update_slice_in_dim(o, o_new, i * bq, axis=3),
-            )
+            return tuple(
+                jax.lax.dynamic_update_slice_in_dim(old, new.astype(stats_dtype), i * bq, axis=3)
+                for old, new in ((m, m_new), (l, l_new), (o, o_new)))
 
         meet = _pair_meets(seg_q, seg_k, i, j, bq, bk)
         return jax.lax.cond(meet, attend, lambda c: c, carry)
 
-    m0 = jnp.full((b, hkv, g, lq), _NEG_BIG, jnp.float32)
-    l0 = jnp.zeros((b, hkv, g, lq), jnp.float32)
-    o0 = jnp.zeros((b, hkv, g, lq, d), jnp.float32)
+    m0 = jnp.full((b, hkv, g, lq), _NEG_BIG, stats_dtype)
+    l0 = jnp.zeros((b, hkv, g, lq), stats_dtype)
+    o0 = jnp.zeros((b, hkv, g, lq, v.shape[-1]), stats_dtype)
     m, l, o = jax.lax.fori_loop(0, _opaque(len(ii)), body, (m0, l0, o0))
-    l = jnp.maximum(l, 1e-30)
-    return (o / l[..., None]).astype(q.dtype), m + jnp.log(l)
+    l = jnp.maximum(l.astype(f32), 1e-30)
+    return (o.astype(f32) / l[..., None]).astype(q.dtype), m.astype(f32) + jnp.log(l)
 
 
 def _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk):
@@ -225,17 +227,17 @@ def _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash(q, k, v, seg_q, seg_k, causal, bq, bk, lk):
-    return _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype):
+    return _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype)[0]
 
 
-def _flash_vjp_fwd(q, k, v, seg_q, seg_k, causal, bq, bk, lk):
-    o, lse = _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk)
+def _flash_vjp_fwd(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype):
+    o, lse = _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype)
     return o, (q, k, v, seg_q, seg_k, o, lse)
 
 
-def _flash_vjp_bwd(causal, bq, bk, lk, res, do):
+def _flash_vjp_bwd(causal, bq, bk, lk, stats_dtype, res, do):
     q, k, v, seg_q, seg_k, o, lse = res
     dq, dk, dv = _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk)
     return dq, dk, dv, None, None
@@ -244,17 +246,19 @@ def _flash_vjp_bwd(causal, bq, bk, lk, res, do):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_k", "block_q"))
+@functools.partial(jax.jit, static_argnames=("causal", "block_k", "block_q", "stats_dtype"))
 def flash_attention(
     q: jax.Array,  # [B, H, Lq, D]
     k: jax.Array,  # [B, Hkv, Lk, D], H a multiple of Hkv
-    v: jax.Array,  # [B, Hkv, Lk, D]
+    v: jax.Array,  # [B, Hkv, Lk, Dv]: the values' width is their own
     causal: bool = True,
     block_k: int = 512,
     segment_ids: Optional[jax.Array] = None,  # [B, L]: packed rows, Lq == Lk
     block_q: Optional[int] = None,
+    stats_dtype: str = "float32",
 ) -> jax.Array:
-    """Blockwise attention with online softmax (single device).
+    """Blockwise attention with online softmax (single device): [B, H, Lq,
+    Dv]. Scores are scaled by ``1 / sqrt(D)``, the width q and k share.
 
     Query heads share key/value heads in groups (``H / Hkv`` each). With
     ``segment_ids`` a slot attends only to slots of its own id (histories
@@ -269,8 +273,63 @@ def flash_attention(
     qg, k, v, seg_q, seg_k = _grouped_and_padded(q, k, v, segment_ids, bq, bk)
     seg_q = jnp.pad(seg_q, ((0, 0), (0, -lq % bq)), mode="edge")
     seg_k = jnp.pad(seg_k, ((0, 0), (0, -lk % bk)), mode="edge")
-    o = _flash(qg, k, v, seg_q, seg_k, causal, bq, bk, lk)
-    return o[:, :, :, :lq].reshape(b, h, lq, d)
+    o = _flash(qg, k, v, seg_q, seg_k, causal, bq, bk, lk, jnp.dtype(stats_dtype))
+    return o[:, :, :, :lq].reshape(b, h, lq, v.shape[-1])
+
+
+#: tile edge of the Pallas kernel, forward and backward: the best of 512,
+#: 1024 and 2048 (which does not fit the chip's fast memory) at 32 heads x
+#: 8,192 slots, keys of 192, values of 128 (PERF.md section 6, PR 31)
+SPLASH_BLOCK = 1024
+
+
+@functools.lru_cache(maxsize=8)
+def _splash_kernel(heads: int, length: int, block: int, interpret: bool):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as kernel,
+        splash_attention_mask as masks,
+    )
+
+    causal = masks.MultiHeadMask([masks.CausalMask((length, length))] * heads)
+    sizes = kernel.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block, block_q_dkv=block,
+        block_kv_dkv=block, block_kv_dkv_compute=block, use_fused_bwd_kernel=True)
+    # the kernel keeps its block tables as arrays: made under a trace they
+    # would be that trace's, and this cache outlives it
+    with jax.ensure_compile_time_eval():
+        made = kernel.make_splash_mha(
+            causal, head_shards=1, q_seq_shards=1, block_sizes=sizes, interpret=interpret)
+    return made, kernel.SegmentIds
+
+
+def splash_attention(
+    q: jax.Array,  # [B, H, L, D]
+    k: jax.Array,  # [B, H, L, D]
+    v: jax.Array,  # [B, H, L, Dv]
+    segment_ids: jax.Array,  # [B, L]
+    block: int = SPLASH_BLOCK,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal attention inside histories by JAX's own Pallas kernel for the
+    TPU (``jax.experimental.pallas.ops.tpu.splash_attention``, forward and
+    fused backward): score tiles never leave the chip's fast memory, where
+    :func:`flash_attention`'s XLA loop writes each to HBM. It computes
+    EVERY tile on or below the diagonal and masks by ``segment_ids``
+    inside it, so its time does not depend on where the histories end: the
+    faster of the two on long histories, the slower where most tiles lie
+    between short ones. Softmax statistics in float32. L a multiple of the
+    block (itself of 128)."""
+    b, h, length, d = q.shape
+    made, ids = _splash_kernel(h, length, min(block, length), interpret)
+    scaled = (q.astype(jnp.float32) * (1.0 / np.sqrt(d))).astype(q.dtype)
+    seg = segment_ids.astype(jnp.int32)
+    return jax.vmap(lambda q_, k_, v_, s: made(q_, k_, v_, ids(s, s)))(scaled, k, v, seg)
+
+
+def _splash_fits(q, k, stats_dtype: str) -> bool:
+    length = q.shape[2]
+    return (jax.default_backend() == "tpu" and stats_dtype == "float32" and q.shape == k.shape
+            and length % 128 == 0 and length % min(SPLASH_BLOCK, length) == 0)
 
 
 def ring_attention(
@@ -325,7 +384,7 @@ def ring_attention(
 
         m0 = jnp.full((b, h, chunk), _NEG_BIG, dtype=jnp.float32)
         l0 = jnp.zeros((b, h, chunk), dtype=jnp.float32)
-        o0 = jnp.zeros((b, h, chunk, d), dtype=jnp.float32)
+        o0 = jnp.zeros((b, h, chunk, v.shape[-1]), dtype=jnp.float32)
         m, l_, o, _, _, _ = jax.lax.fori_loop(
             0, n, step, (m0, l0, o0, kc, vc, sq)
         )
@@ -394,14 +453,26 @@ def attention(
     schedule: str = "auto",
     segment_ids: Optional[jax.Array] = None,
     block: int = 512,
+    stats_dtype: str = "float32",
+    kernel: str = "xla",
 ) -> jax.Array:
     """Dispatch: single-device flash when no mesh / 1-device axis; otherwise
     ring (default) or Ulysses (``schedule="ulysses"``, when heads divide).
     The sharded schedules repeat grouped key/value heads to one per query
-    head."""
+    head, and keep their softmax statistics in float32 whatever
+    ``stats_dtype`` says. ``kernel="splash"`` asks the single device for
+    :func:`splash_attention` in place of the XLA loop; it is given where it
+    can run (a TPU, packed rows of whole blocks, one key/value head a query
+    head, float32 statistics) and the XLA loop elsewhere."""
     if mesh is None or axis not in mesh.shape or mesh.shape[axis] == 1:
+        if (kernel == "splash" and causal and segment_ids is not None
+                and _splash_fits(q, k, stats_dtype)):
+            return splash_attention(q, k, v, segment_ids)
+        if kernel not in ("xla", "splash"):
+            raise ValueError(f"unknown attention kernel {kernel!r}")
         return flash_attention(
-            q, k, v, causal=causal, block_k=block, segment_ids=segment_ids)
+            q, k, v, causal=causal, block_k=block, segment_ids=segment_ids,
+            stats_dtype=stats_dtype)
     if k.shape[1] != q.shape[1]:
         k = jnp.repeat(k, q.shape[1] // k.shape[1], axis=1)
         v = jnp.repeat(v, q.shape[1] // v.shape[1], axis=1)

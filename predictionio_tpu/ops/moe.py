@@ -1,12 +1,18 @@
 """A sparse expert layer that is told which experts it holds.
 
-The router scores ALL ``n_experts`` in float32 and keeps each token's
-``top_k`` largest, renormalised over those ``top_k``. This share holds the
-contiguous range ``[first, first + count)``: it computes, for every token,
-the part of the result that its own experts give (a grouped matrix product
-over the assignments sorted by expert) plus the shared expert, which every
-share computes alike. What the absent experts would add is left out; no
-code stands in for the chips that hold them.
+The router scores ALL ``n_experts`` in float32 and keeps ``top_k`` of them
+a token (:func:`route`). ``softmax``: a softmax over all experts, the
+``top_k`` largest, renormalised over those ``top_k`` (``norm_topk``).
+``sigmoid``: a sigmoid of every logit on its own; the ``top_k`` largest of
+score + ``router_bias`` are chosen, a bias that no gradient moves and the
+trainer steps from the experts' loads; their weights are the scores WITHOUT
+the bias, renormalised over the ``top_k`` and times ``scale``. This share
+holds the contiguous range ``[first, first + count)``: it computes, for
+every token, the part of the result that its own experts give (a grouped
+matrix product over the assignments sorted by expert) plus the shared
+expert (behind a sigmoid gate where the layer has a ``shared_gate``), which
+every share computes alike. What the absent experts would add is left out;
+no code stands in for the chips that hold them.
 
 No assignment is dropped. Shapes are static, so the sorted assignments are
 taken ``pass_rows`` at a time (default: twice this share's mean load), in
@@ -36,15 +42,26 @@ def swiglu(w: Dict, x, cd):
                    preferred_element_type=f32)
 
 
-def route(x, router, top_k: int, norm_topk: bool = True):
+def route(x, router, top_k: int, norm_topk: bool = True, scoring: str = "softmax",
+          bias=None, scale: float = 1.0):
     """x [T, D], router [D, E] -> expert ids [T, k] and weights [T, k]
-    (float32; softmax over all E, the k largest, renormalised)."""
+    (float32). ``softmax``: over all E, the k largest, renormalised.
+    ``sigmoid``: the k largest of sigmoid + ``bias`` [E]; the weights are
+    the sigmoids alone, renormalised, times ``scale``."""
     logits = jnp.dot(x.astype(jnp.float32), router, precision=_HI)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top, idx = jax.lax.top_k(probs, top_k)
+    if scoring == "softmax":
+        top, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        if norm_topk:
+            top = top / top.sum(-1, keepdims=True)
+        return idx, top
+    if scoring != "sigmoid":
+        raise ValueError(f"unknown scoring function {scoring!r}")
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores if bias is None else scores + bias, top_k)
+    top = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk:
-        top = top / top.sum(-1, keepdims=True)
-    return idx, top
+        top = top / (top.sum(-1, keepdims=True) + 1e-20)
+    return idx, top * scale
 
 
 def _held_pass(x, experts: Dict, take, group_sizes, weights, top_k: int, cd):
@@ -79,11 +96,14 @@ def _passes_for(all_rows: int, rows: int) -> int:
 
 
 def expert_layer(p: Dict, x, *, first: int, top_k: int, norm_topk: bool = True,
-                 pass_rows: int = 0, compute_dtype=jnp.float32) -> Tuple[jax.Array, Dict]:
+                 pass_rows: int = 0, compute_dtype=jnp.float32, scoring: str = "softmax",
+                 scale: float = 1.0) -> Tuple[jax.Array, Dict]:
     """x [T, D] (normed) -> y [T, D] float32 and the step's counters.
-    ``p``: ``router`` [D, E], ``shared_gate`` [D], ``shared`` and ``experts``
-    (``wg``, ``wu`` [.., D, F], ``wd`` [.., F, D]; experts with a leading
-    [count] axis: the experts ``first .. first + count - 1``)."""
+    ``p``: ``router`` [D, E], ``shared`` and ``experts`` (``wg``, ``wu``
+    [.., D, F], ``wd`` [.., F, D]; experts with a leading [count] axis: the
+    experts ``first .. first + count - 1``); where the layer has them,
+    ``shared_gate`` [D] and ``router_bias`` [E] (then the counters also
+    give ``router_tokens`` [E]: the tokens of every expert, held or not)."""
     x = jnp.asarray(x)
     tokens = x.shape[0]
     count = p["experts"]["wg"].shape[0]
@@ -93,7 +113,8 @@ def expert_layer(p: Dict, x, *, first: int, top_k: int, norm_topk: bool = True,
     rows = min(all_rows, pass_rows or max(8, 2 * tokens * top_k * count // n_experts))
     passes = _passes_for(all_rows, rows)
     with jax.named_scope("seq.moe.route"):
-        idx, weights = route(x, p["router"], top_k, norm_topk)
+        bias = p.get("router_bias")
+        idx, weights = route(x, p["router"], top_k, norm_topk, scoring, bias, scale)
         local = idx - first
         held = (local >= 0) & (local < count)
         flat = jnp.where(held, local, count).reshape(-1)  # absent experts sort last
@@ -119,12 +140,17 @@ def expert_layer(p: Dict, x, *, first: int, top_k: int, norm_topk: bool = True,
     y, combined = jax.lax.scan(one_pass, jnp.zeros(x.shape, jnp.float32),
                                jnp.arange(passes, dtype=jnp.int32) * rows)
     with jax.named_scope("seq.moe.shared"):
-        gate = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), p["shared_gate"], precision=_HI))
-        y = y + gate[:, None] * swiglu(p["shared"], x, cd)
+        if "shared_gate" in p:
+            gate = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), p["shared_gate"], precision=_HI))
+            y = y + gate[:, None] * swiglu(p["shared"], x, cd)
+        else:
+            y = y + swiglu(p["shared"], x, cd)
     counters = {
         "expert_tokens": group_sizes,
-        "absent_weight": jnp.where(held, 0.0, weights).sum() / tokens,
+        "absent_weight": jnp.where(held, 0.0, weights).sum() / (tokens * scale),
         "dropped": n_held - combined.sum(),
         "passes": -(-n_held // rows),
     }
+    if bias is not None:
+        counters["router_tokens"] = jnp.bincount(idx.reshape(-1), length=n_experts).astype(jnp.int32)
     return y, counters

@@ -434,3 +434,82 @@ def test_expert_layer_16k_tokens_32_of_512(one_chip):
 
     compiled = _compile(jax.grad(loss, argnums=(0, 1)), params, w(2 * SEQ_L, d))
     assert "ragged" in compiled.as_text()
+
+
+# -- and at the shapes of train-joyai-long8k: JoyAI-LLM-Flash widths, latent
+# attention on keys of 192 and values of 128, 16 of 256 experts
+def _report(name, compiled):
+    stats = compiled.memory_analysis()
+    print(f"{name}: {stats}")
+    return stats
+
+
+@pytest.mark.parametrize("kernel", ["xla", "splash"])
+def test_latent_attention_two_rows_of_8k(one_chip, kernel):
+    """The attention core with a value width of its own, forward and
+    backward: the XLA loop (the cell's control runs it) and JAX's Pallas
+    kernel (the cell's timed path), 32 heads."""
+    from predictionio_tpu.ops.attention import flash_attention, splash_attention
+
+    def loss(q, k, v, seg):
+        if kernel == "splash":
+            return splash_attention(q, k, v, seg).astype(jnp.float32).sum()
+        return flash_attention(q, k, v, segment_ids=seg).astype(jnp.float32).sum()
+
+    wide = _sds(one_chip, (2, 32, SEQ_L, 192), jnp.bfloat16)
+    compiled = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)), wide, wide,
+        _sds(one_chip, (2, 32, SEQ_L, 128), jnp.bfloat16), _sds(one_chip, (2, SEQ_L), jnp.int32))
+    stats = _report(f"latent attention core, {kernel}", compiled)
+    assert stats.temp_size_in_bytes < 4 * 2**30
+    assert ("tpu_custom_call" in compiled.as_text()) == (kernel == "splash")
+
+
+def test_expert_layer_16k_tokens_16_of_256_sigmoid(one_chip):
+    """Sigmoid router over 256 with its bias, 8 a token, 16 held experts of
+    768, the shared expert without a gate; forward and backward."""
+    from predictionio_tpu.ops.moe import expert_layer
+
+    d, f, e, held = 2048, 768, 256, 16
+    w = lambda *shape: _sds(one_chip, shape, jnp.float32)  # noqa: E731
+    params = {
+        "router": w(d, e), "router_bias": w(e),
+        "shared": {"wg": w(d, f), "wu": w(d, f), "wd": w(f, d)},
+        "experts": {"wg": w(held, d, f), "wu": w(held, d, f), "wd": w(held, f, d)},
+    }
+
+    def loss(p, x):
+        y, counters = expert_layer(p, x, first=0, top_k=8, compute_dtype=jnp.bfloat16,
+                                   scoring="sigmoid", scale=2.5)
+        return y.sum(), counters["router_tokens"]
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1), has_aux=True), params, w(2 * SEQ_L, d))
+    _report("expert layer, 16 of 256", compiled)
+    assert "ragged" in compiled.as_text()
+
+
+def test_latent_sparse_layer_two_rows_of_8k(one_chip, monkeypatch):
+    """One sparse layer of the shipped configuration as the step runs it
+    (recomputed in the backward pass, the Pallas core: the code asks for
+    the backend, which here is the CPU, so the test answers for it)."""
+    from predictionio_tpu.models import seq_backbone as bb
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = bb.BackboneConfig.load("joyai-flash-48b-a3b-ep16")
+    assert cfg.attn_kernel == "splash"
+    shapes = jax.eval_shape(lambda: bb.init_params(cfg, 16160, SEQ_L, 0))
+    block = jax.tree_util.tree_map(
+        lambda s: _sds(one_chip, s.shape, s.dtype), shapes["mtp"]["block"])
+    layer = bb._layer_fn(cfg, True, None, "auto")
+
+    def loss(blk, x, seg):
+        y, counters, _ = layer(x, seg, bb.positions_of(seg), blk["norm_in"], blk["full"],
+                               blk["norm_post"], blk["ffn"])
+        return y.sum(), counters["dropped"]
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1), has_aux=True), block,
+        _sds(one_chip, (2, SEQ_L, 2048), jnp.float32), _sds(one_chip, (2, SEQ_L), jnp.int32))
+    stats = _report("latent-attention sparse layer", compiled)
+    assert "tpu_custom_call" in compiled.as_text() and "ragged" in compiled.as_text()
+    assert stats.temp_size_in_bytes < 6 * 2**30
