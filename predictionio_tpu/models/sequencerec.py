@@ -47,6 +47,7 @@ from ..controller import (
     Preparator,
 )
 from ..obs.trace import span
+from ..ops.deltanet import walk_kind
 from ..ops.scoring import top_k_for_vectors
 from ..storage import BiMap, EventFilter, get_registry
 from . import seq_backbone as bb
@@ -409,6 +410,16 @@ def _programs(cfg: bb.BackboneConfig, learning_rate: float, mesh, schedule: str)
     return jax.jit(opt.init), jax.jit(step, donate_argnums=(0, 1)), loss_and_grad
 
 
+def _delta_rule_walk(cfg: bb.BackboneConfig) -> Dict[str, str]:
+    """Which walk the step's gated-DeltaNet layers run ("pallas" or "scan":
+    ``ops.deltanet.walk_kind``, what the rule itself asks where the step is
+    traced); nothing for a backbone without such layers."""
+    if cfg.period == 1:
+        return {}
+    return {"delta_rule_walk": walk_kind(
+        cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.chunk)}
+
+
 class SeqRecAlgorithm(Algorithm):
     """Next-item trainer over packed histories (optax AdamW)."""
 
@@ -429,7 +440,7 @@ class SeqRecAlgorithm(Algorithm):
         p = self.params
         cfg = p.backbone_config()
         tags = {"backbone": p.backbone or "toy", "steps": p.steps,
-                "layers": cfg.num_hidden_layers}
+                "layers": cfg.num_hidden_layers, **_delta_rule_walk(cfg)}
         # the job's root span: under no server it starts a trace of its own
         with span("train", tags):
             return self._train(ctx, pd, cfg)
@@ -471,7 +482,8 @@ class SeqRecAlgorithm(Algorithm):
                 before = loss
         with span("train.wait_device"):
             jax.block_until_ready(model_params)
-        stats = {"fill": pd.fill, "steps": p.steps, "tokens_per_step": batch * pd.seq_len}
+        stats = {"fill": pd.fill, "steps": p.steps, "tokens_per_step": batch * pd.seq_len,
+                 **_delta_rule_walk(cfg)}
         if counters:
             stats.update(jax.tree_util.tree_map(np.asarray, counters))
         with span("train.fetch"):

@@ -5,12 +5,21 @@ Per head, with state ``S`` [dk, dv], zero at the start of every history::
 
     S <- alpha_t S;  u_t = beta_t (v_t - S^T k_t);  S <- S + k_t u_t^T;  o_t = S^T q_t
 
-:func:`gated_delta_rule` computes that in chunks of ``chunk`` tokens: inside
-a chunk the ``u_t`` solve a unit lower-triangular system (:func:`tri_inv`,
-blocked forward substitution), which every chunk does at once; only the
-state walks from chunk to chunk in a ``lax.scan``. The gradient is the same
-scan run backwards (autodiff of the chunked form; each step recomputes
-its own products, so a chunk keeps its incoming state and nothing else).
+:func:`gated_delta_rule` computes that in chunks of ``chunk`` tokens, in
+three phases. The preparation: inside a chunk the ``u_t`` solve a unit
+lower-triangular system (:func:`tri_inv`, blocked forward substitution),
+which every chunk does at once. The walk (:func:`_walk`): only the state
+goes from chunk to chunk, and the walk emits each chunk's incoming state
+and corrected values and nothing else. The outputs: two batch products
+over all chunks from what the walk emitted.
+
+The walk has a VJP of its own: the state's cotangent walks the chunks once
+in reverse, through the same step function (:func:`_walk_step`), and every
+other cotangent is a batch product; the preparation and the outputs are
+differentiated as they stand. Nothing inside the rule is recomputed. Where
+the tiles allow (``dk`` and ``dv`` multiples of 128, the chunk a multiple of
+16, a TPU) the walk is a Pallas kernel that keeps the state in VMEM; a
+``lax.scan`` over the same step function otherwise (:func:`walk_kind`).
 
 Packed rows: ``seg`` gives each slot the id of its history (one contiguous
 run per id). A history's first token resets the state, which the chunked
@@ -19,21 +28,25 @@ short convolution reads zero where a tap would reach into the neighbour.
 
 Precision: gates ``alpha`` (as ``g = log alpha`` and its running sums) and
 the state are ``gate_dtype`` and ``state_dtype`` (float32); the triangular
-systems and the two products that read the state are float32 at
-``Precision.HIGHEST``; the other products take ``compute_dtype`` inputs
-(bfloat16 on the chip) and accumulate in float32.
+systems and the products that read the state (or, backwards, its
+cotangent) are float32 at ``Precision.HIGHEST``; the other products take
+``compute_dtype`` inputs (bfloat16 on the chip) and accumulate in float32.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _HI = jax.lax.Precision.HIGHEST
 _BLOCK = 16  # diagonal blocks solved row by row; the rest by products
+_HEADS = 8  # heads whose states share a grid step of the walk's kernel
 
 
 def _tri_inv_impl(a):
@@ -97,27 +110,22 @@ def causal_conv(x, w, seg):
     return out
 
 
-@functools.partial(
-    jax.jit, static_argnames=("chunk", "compute_dtype", "state_dtype", "gate_dtype"))
-def gated_delta_rule(q, k, v, g, beta, seg, chunk: int = 64,
-                     compute_dtype=jnp.float32, state_dtype=jnp.float32,
-                     gate_dtype=jnp.float32):
-    """q, k [B, L, H, dk] (already normalised and scaled), v [B, L, H, dv],
-    g = log alpha and beta [B, L, H], seg [B, L] -> o [B, L, H, dv] float32."""
-    bsz, length, heads, dk = q.shape
-    dv = v.shape[-1]
-    pad = -length % chunk
-    if pad:  # slots of a history of their own, which write nothing
-        q, k, v, g, beta = (
-            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in (q, k, v, g, beta))
-        seg = jnp.pad(seg, ((0, 0), (0, pad)), constant_values=-2)
-    n = (length + pad) // chunk
+def _prepare(q, k, v, g, beta, seg, chunk, compute_dtype, gate_dtype):
+    """What every chunk makes of its own slots, all chunks at once; q, k, v
+    [B, L, H, d], L a multiple of ``chunk``. Returns, chunk axis first
+    ([N, B, H, ...]): ``u`` [C, dv] float32, the chunk's values corrected
+    for its own keys; ``-w`` [C, dk], what they still owe the incoming
+    state; q and k [C, dk] with the decay from the chunk's first slot and
+    to its last; the scores inside the chunk [C, C]; and ``keep`` [],
+    what of the incoming state reaches the next chunk."""
+    bsz, length, heads, _ = q.shape
+    n = length // chunk
 
-    def chunks(a):  # [B, L, H, ...] -> [N, B, H, C, ...]: the scan's own layout
+    def chunks(a):  # [B, L, H, ...] -> [N, B, H, C, ...]: the walk's own layout
         a = a.reshape((bsz, n, chunk, heads) + a.shape[3:])
         return jnp.moveaxis(a, (1, 3), (0, 2))
 
-    f32 = jnp.float32
+    f32, cd = jnp.float32, compute_dtype
     qc, kc, vc = chunks(q.astype(f32)), chunks(k.astype(f32)), chunks(v.astype(f32))
     bc = chunks(beta.astype(f32))  # [N, B, H, C]
     gc = jnp.cumsum(chunks(g.astype(gate_dtype)), axis=-1)  # inclusive, per chunk
@@ -136,38 +144,165 @@ def gated_delta_rule(q, k, v, g, beta, seg, chunk: int = 64,
     kk = jnp.einsum("nbhid,nbhjd->nbhij", kc, kc, precision=_HI)
     a = bc[..., None] * kk * decay * jnp.tril(jnp.ones((chunk, chunk), f32), -1)
     t = tri_inv(a)
-    w = jnp.einsum("nbhij,nbhjd->nbhid", t, kc * (bc * egc * carried)[..., None], precision=_HI)
+    w = jnp.einsum("nbhij,nbhjd->nbhid", t, kc * (-bc * egc * carried)[..., None], precision=_HI)
     u = jnp.einsum("nbhij,nbhjd->nbhid", t, vc * bc[..., None], precision=_HI)
     attn = jnp.einsum("nbhid,nbhjd->nbhij", qc, kc, precision=_HI) * decay
-    cd = compute_dtype
-    xs = (
-        w.astype(cd), u, (qc * (egc * carried)[..., None]).astype(cd),
+    return (
+        u, w.astype(cd), (qc * (egc * carried)[..., None]).astype(cd),
         (kc * (e_last * to_last)[..., None]).astype(cd), attn.astype(cd),
         (egc[..., -1] * carried[..., -1]).astype(gate_dtype),
     )
 
-    def with_state(a, s):
-        """[C, dk] rows against the [dk, dv] state. A float32 state is
-        read as float32 (``HIGHEST``: it is not rounded to feed the MXU, or
-        keeping it in float32 would buy nothing); a lower one as it is."""
-        if s.dtype == f32:
-            return jnp.einsum("bhck,bhkv->bhcv", a.astype(f32), s, precision=_HI)
-        return jnp.einsum("bhck,bhkv->bhcv", a, s.astype(cd), preferred_element_type=f32)
 
-    @jax.checkpoint
-    def step(s, x):
-        w_n, u_n, q_n, k_n, attn_n, keep = x
-        v_new = u_n - with_state(w_n, s)
-        o = with_state(q_n, s) + jnp.einsum(
-            "bhij,bhjv->bhiv", attn_n, v_new.astype(cd), preferred_element_type=f32)
-        s = s * keep[..., None, None].astype(state_dtype) + jnp.einsum(
-            "bhck,bhcv->bhkv", k_n, v_new.astype(cd), preferred_element_type=f32
-        ).astype(state_dtype)
-        return s, o
+def _with_state(spec: str, a, s):
+    """A product with the state (or its cotangent) ``s``. A float32 state
+    is read as float32 (``HIGHEST``: it is not rounded to feed the MXU, or
+    keeping it in float32 would buy nothing); a lower one as it is."""
+    if s.dtype == jnp.float32:
+        return jnp.einsum(spec, a.astype(jnp.float32), s, precision=_HI)
+    return jnp.einsum(spec, a, s.astype(a.dtype), preferred_element_type=jnp.float32)
 
-    s0 = jnp.zeros((bsz, heads, dk, dv), state_dtype)
-    _, o = jax.lax.scan(step, s0, xs)  # [N, B, H, C, dv]
-    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(bsz, n * chunk, heads, dv)
+
+def _walk_step(state, add, rows, cols, keep, extra=None):
+    """One chunk of the walk, either way; leading axes are heads. Forward
+    (``add`` u, ``rows`` -w, ``cols`` k): ``x`` is the chunk's corrected
+    values, the new state what ``keep`` leaves of the old plus the chunk's
+    own writes. Backward (``add`` the cotangent of x from the outputs,
+    ``rows`` k, ``cols`` -w, ``extra`` the cotangent of the emitted state):
+    ``state`` is the cotangent of the next chunk's incoming state, ``x``
+    that of the corrected values. ``x`` comes in ``cols``' dtype, float32
+    ones multiply at full precision. -> new state, x."""
+    x = (add + _with_state("...ck,...kv->...cv", rows, state)).astype(cols.dtype)
+    wrote = jnp.einsum("...ck,...cv->...kv", cols, x, preferred_element_type=jnp.float32,
+                       precision=_HI if cols.dtype == jnp.float32 else None)
+    new = state * keep.astype(state.dtype) + wrote.astype(state.dtype)
+    if extra is not None:
+        new = new + extra.astype(state.dtype)
+    return new, x
+
+
+def _walk_scan(state_dtype, reverse, add, rows, cols, keep, extra):
+    def step(state, blocks):
+        new, x = _walk_step(state, *blocks)
+        return new, (state, x)
+
+    blocks = (add, rows, cols, keep[..., None, None]) + (() if extra is None else (extra,))
+    start = jnp.zeros(rows.shape[1:-2] + (rows.shape[-1], add.shape[-1]), state_dtype)
+    return jax.lax.scan(step, start, blocks, reverse=reverse)[1]
+
+
+def _walk_pallas(state_dtype, reverse, add, rows, cols, keep, extra, interpret):
+    """The walk with the state in VMEM: grid (blocks of heads, chunks), the
+    chunks in order (or in reverse order); a grid step reads one chunk's
+    blocks and writes the incoming state and x of that chunk."""
+    lead, (c, dk), dv = rows.shape[:-2], rows.shape[-2:], add.shape[-1]
+    n, bh = lead[0], math.prod(lead[1:])
+    hb = max(d for d in range(1, _HEADS + 1) if bh % d == 0)
+
+    def at(h, i):
+        return (n - 1 - i if reverse else i, h, 0, 0)
+
+    operands = [
+        (add, (c, dv)), (rows, (c, dk)), (cols, (c, dk)),
+        (jnp.broadcast_to(keep[..., None, None], lead + (1, dv)), (1, dv)),
+    ] + ([] if extra is None else [(extra, (dk, dv))])
+
+    def kernel(*refs):
+        blocks, (states_ref, x_ref, carry) = refs[:len(operands)], refs[len(operands):]
+
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            carry[...] = jnp.zeros_like(carry)
+
+        state = carry[...]
+        states_ref[...] = state
+        carry[...], x_ref[...] = _walk_step(state, *(ref[...] for ref in blocks))
+
+    states, x = pl.pallas_call(
+        kernel,
+        grid=(bh // hb, n),
+        in_specs=[pl.BlockSpec((None, hb) + tail, at) for _, tail in operands],
+        out_specs=[pl.BlockSpec((None, hb, dk, dv), at), pl.BlockSpec((None, hb, c, dv), at)],
+        out_shape=[jax.ShapeDtypeStruct((n, bh, dk, dv), state_dtype),
+                   jax.ShapeDtypeStruct((n, bh, c, dv), cols.dtype)],
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), state_dtype)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*(a.reshape((n, bh) + a.shape[len(lead):]) for a, _ in operands))
+    return states.reshape(lead + (dk, dv)), x.reshape(lead + (c, dv))
+
+
+def walk_kind(dk: int, dv: int, chunk: int, interpret: bool = False) -> str:
+    """Which walk :func:`gated_delta_rule` runs at these widths: "pallas"
+    where a head's state and a chunk's rows are whole tiles and the backend
+    is a TPU (``interpret``: or the kernel's interpreter, for tests),
+    "scan" otherwise."""
+    tiles = dk % 128 == 0 and dv % 128 == 0 and chunk % 16 == 0
+    return "pallas" if tiles and (interpret or jax.default_backend() == "tpu") else "scan"
+
+
+def _run_walk(kind, interpret, state_dtype, reverse, add, rows, cols, keep, extra=None):
+    with jax.named_scope("seq.deltanet.scan.walk"):
+        if kind == "pallas":
+            return _walk_pallas(state_dtype, reverse, add, rows, cols, keep, extra, interpret)
+        return _walk_scan(state_dtype, reverse, add, rows, cols, keep, extra)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _walk(kind, interpret, state_dtype, add, rows, cols, keep):
+    """The sequential part of the rule, chunk axis first: from a zero
+    state, ``x_n = add_n + rows_n S_n`` and ``S_n+1 = keep_n S_n + cols_n^T
+    x_n`` -> every chunk's incoming state S_n [N, ..., dk, dv]
+    (``state_dtype``) and x_n [N, ..., C, dv] (``cols``' dtype)."""
+    return _run_walk(kind, interpret, state_dtype, False, add, rows, cols, keep)
+
+
+def _walk_fwd(kind, interpret, state_dtype, add, rows, cols, keep):
+    states, x = _run_walk(kind, interpret, state_dtype, False, add, rows, cols, keep)
+    return (states, x), (rows, cols, keep, states, x)
+
+
+def _walk_bwd(kind, interpret, state_dtype, kept, cotangents):
+    rows, cols, keep, states, x = kept
+    d_states, d_x = cotangents
+    f32 = jnp.float32
+    # the same walk from the last chunk to the first, k and -w changing places
+    d_next, dx = _run_walk(
+        kind, interpret, state_dtype, True, d_x, cols,
+        rows.astype(f32) if state_dtype == f32 else rows, keep, extra=d_states)
+    with jax.named_scope("seq.deltanet.scan.out"):
+        d_rows = _with_state("...cv,...kv->...ck", dx, states)
+        d_cols = _with_state("...cv,...kv->...ck", x, d_next)
+        d_keep = jnp.sum(d_next.astype(f32) * states.astype(f32), axis=(-1, -2))
+    return (dx.astype(f32), d_rows.astype(rows.dtype), d_cols.astype(cols.dtype),
+            d_keep.astype(keep.dtype))
+
+
+_walk.defvjp(_walk_fwd, _walk_bwd)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("chunk", "compute_dtype", "state_dtype", "gate_dtype", "interpret"))
+def gated_delta_rule(q, k, v, g, beta, seg, chunk: int = 64,
+                     compute_dtype=jnp.float32, state_dtype=jnp.float32,
+                     gate_dtype=jnp.float32, interpret: bool = False):
+    """q, k [B, L, H, dk] (already normalised and scaled), v [B, L, H, dv],
+    g = log alpha and beta [B, L, H], seg [B, L] -> o [B, L, H, dv] float32."""
+    bsz, length, heads, dk = q.shape
+    dv = v.shape[-1]
+    pad = -length % chunk
+    if pad:  # slots of a history of their own, which write nothing
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in (q, k, v, g, beta))
+        seg = jnp.pad(seg, ((0, 0), (0, pad)), constant_values=-2)
+    with jax.named_scope("seq.deltanet.scan.prep"):
+        u, w, qc, kc, attn, keep = _prepare(q, k, v, g, beta, seg, chunk, compute_dtype, gate_dtype)
+    states, x = _walk(walk_kind(dk, dv, chunk, interpret), interpret, state_dtype, u, w, kc, keep)
+    with jax.named_scope("seq.deltanet.scan.out"):
+        o = _with_state("...ck,...kv->...cv", qc, states) + jnp.einsum(
+            "...ij,...jv->...iv", attn, x, preferred_element_type=jnp.float32)  # [N, B, H, C, dv]
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(bsz, length + pad, heads, dv)
     return o[:, :length]
 
 
@@ -217,9 +352,9 @@ def gated_deltanet(p: Dict, x, seg, *, key_heads: int, value_heads: int, key_dim
             g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
             return q, k, v.astype(cd), g, beta
 
-    rule = jax.checkpoint(functools.partial(
+    rule = functools.partial(
         gated_delta_rule, chunk=chunk, compute_dtype=cd, state_dtype=state_dtype,
-        gate_dtype=gate_dtype))
+        gate_dtype=gate_dtype)
 
     @jax.checkpoint
     def finish(o, qkvz, o_norm):

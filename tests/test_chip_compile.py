@@ -371,19 +371,42 @@ def test_sharded_half_step_2x2(topo):
     assert len(compiled.input_shardings[0][0].device_set) == shards
 
 
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Code that asks for the backend is told "tpu" (here it is the CPU),
+    and the delta rule, whose jit keeps a trace made under one answer for
+    the next caller with the same shapes, is traced anew on both sides."""
+    from predictionio_tpu.ops.deltanet import gated_delta_rule
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    gated_delta_rule.clear_cache()
+    yield
+    gated_delta_rule.clear_cache()
+
+
 # -- the sequence backbone's programs at the shapes of train-qwen3next-packed8k:
 # rows of 8,192 slots, Qwen3-Next-80B-A3B widths, bfloat16 products
 SEQ_L = 8192
 
 
-def test_delta_rule_scan_row_of_8k(one_chip):
+@pytest.mark.parametrize("walk,state", [
+    ("scan", "float32"), ("pallas", "float32"), ("pallas", "bfloat16")])
+def test_delta_rule_scan_row_of_8k(one_chip, request, walk, state):
     """The chunked delta rule and its gradient for one packed row: 32
-    value heads of 128 x 128 state, 128 chunks of 64."""
+    value heads of 128 x 128 state, 128 chunks of 64. The walk over the
+    chunks as the ``lax.scan`` (what the code picks where the backend is
+    not a TPU, as here) and as the Pallas kernel (the test answers for the
+    backend), which the benchmark's control build runs with state and
+    gates in bfloat16."""
     from predictionio_tpu.ops.deltanet import gated_delta_rule
+
+    if walk == "pallas":
+        request.getfixturevalue("as_tpu")
 
     def loss(q, k, v, g, beta, seg):
         return gated_delta_rule(
-            q, k, v, g, beta, seg, chunk=64, compute_dtype=jnp.bfloat16).sum()
+            q, k, v, g, beta, seg, chunk=64, compute_dtype=jnp.bfloat16,
+            state_dtype=jnp.dtype(state), gate_dtype=jnp.dtype(state)).sum()
 
     qkv = _sds(one_chip, (1, SEQ_L, 32, 128), jnp.bfloat16)
     gate = _sds(one_chip, (1, SEQ_L, 32), jnp.float32)
@@ -391,6 +414,9 @@ def test_delta_rule_scan_row_of_8k(one_chip):
         jax.grad(loss, argnums=(0, 1, 2, 3, 4)), qkv, qkv, qkv, gate, gate,
         _sds(one_chip, (1, SEQ_L), jnp.int32))
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+    # one walk forward and one in reverse, and no third
+    kernels = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    assert kernels == (2 if walk == "pallas" else 0)
 
 
 @pytest.mark.parametrize("heads,kv_heads,head_dim", [(16, 2, 256), (4, 4, 64)])
@@ -513,3 +539,27 @@ def test_latent_sparse_layer_two_rows_of_8k(one_chip, monkeypatch):
     stats = _report("latent-attention sparse layer", compiled)
     assert "tpu_custom_call" in compiled.as_text() and "ragged" in compiled.as_text()
     assert stats.temp_size_in_bytes < 6 * 2**30
+
+
+def test_qwen3next_step_two_rows_of_8k_fits_the_chip(one_chip, as_tpu):
+    """The whole optimizer step of ``train-qwen3next-packed8k`` (2 rows of
+    8,193 slots, 626 M parameters with their AdamW moments, donated) as
+    the job compiles it. The chip's compiler refuses a program that does
+    not fit the chip's 15.75 GiB, and this one stands close to that: what
+    a change keeps alive beside it shows here first."""
+    from predictionio_tpu.models import seq_backbone as bb
+    from predictionio_tpu.models import sequencerec
+
+    cfg = bb.BackboneConfig.load("qwen3next-80b-a3b-ep16")
+    opt_init, step, _ = sequencerec._programs(cfg, 3e-4, None, "auto")
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: bb.init_params(cfg, 18992, SEQ_L, 0)))
+    rows = _sds(one_chip, (2, SEQ_L + 1), jnp.int32)
+    try:
+        compiled = _compile(step, params, on_chip(jax.eval_shape(opt_init, params)), rows, rows)
+    finally:
+        step.clear_cache()  # the job's own program object, kept by ``_programs``
+    _report("qwen3next step", compiled)
+    assert sequencerec._delta_rule_walk(cfg) == {"delta_rule_walk": "pallas"}
+    assert "tpu_custom_call" in compiled.as_text()

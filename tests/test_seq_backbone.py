@@ -117,7 +117,9 @@ def test_no_assignment_dropped_on_the_normal_path(both):
 
 
 # -- the delta rule ---------------------------------------------------------
-def _rule_inputs(length, heads=3, dk=8, dv=8, seed=0):
+def _rule_inputs(length, heads=3, dk=8, dv=8, seed=0, starts=None):
+    """One row; ``starts``: the first slot of every history but the first
+    (by default a third of the way and five slots from the end)."""
     rng = np.random.default_rng(seed)
     q = rng.normal(size=(1, length, heads, dk)).astype(np.float32)
     k = rng.normal(size=(1, length, heads, dk)).astype(np.float32)
@@ -126,8 +128,8 @@ def _rule_inputs(length, heads=3, dk=8, dv=8, seed=0):
     g = -rng.uniform(0.01, 2.0, size=(1, length, heads)).astype(np.float32)
     beta = rng.uniform(0.1, 1.0, size=(1, length, heads)).astype(np.float32)
     seg = np.ones((1, length), np.int32)
-    seg[0, length // 3:] = 2
-    seg[0, length - 5:] = 3
+    for at in (length // 3, length - 5) if starts is None else starts:
+        seg[0, at:] += 1
     return q, k, v, g, beta, seg
 
 
@@ -137,23 +139,105 @@ def _recurrence(q, k, v, g, beta, seg):
         return ref.delta_rule(q[0], k[0], v[0], jnp.exp(g[0]), beta[0], jnp.asarray(start))[None]
 
 
-@pytest.mark.parametrize("length,chunk", [(37, 16), (64, 16), (50, 64), (96, 32), (19, 8)])
-def test_chunked_scan_is_the_recurrence_forward(length, chunk):
-    args = _rule_inputs(length)
-    got = deltanet.gated_delta_rule(*args, chunk=chunk)
-    assert rel(got, _recurrence(*args)) < 2e-5
+def _primitives_of(fn, *args):
+    """(name, name stack, parameters) of every primitive of ``fn``'s
+    jaxpr, nested ones too; a stack runs from the outermost jaxpr down."""
+    def eqns(jaxpr, outer):
+        for eqn in jaxpr.eqns:
+            stack = f"{outer}/{eqn.source_info.name_stack}"
+            yield eqn.primitive.name, stack, eqn.params
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub, stack)
+
+    return list(eqns(jax.make_jaxpr(fn)(*args).jaxpr, ""))
 
 
-@pytest.mark.parametrize("length,chunk", [(37, 16), (50, 64), (48, 16)])
-def test_chunked_scan_is_the_recurrence_backward(length, chunk):
-    q, k, v, g, beta, seg = _rule_inputs(length, seed=3)
-    weight = np.random.default_rng(4).normal(size=(1, length, 3, 8)).astype(np.float32)
-    chunked = jax.grad(lambda *a: (deltanet.gated_delta_rule(*a, seg, chunk=chunk) * weight).sum(),
-                       argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+# rows of heads of 8 are the toy preset's tiles (the scan walk whatever is
+# asked); rows of heads of 128 in chunks of 64 are the tiles the kernel takes:
+# there the same cases run through the scan and through the kernel's interpreter
+_SMALL = [dict(length=n, chunk=c) for n, c in [(37, 16), (64, 16), (50, 64), (96, 32), (19, 8)]]
+_SMALL_BACKWARD = [dict(length=n, chunk=c, seed=3) for n, c in [(37, 16), (50, 64), (48, 16)]]
+_TILES = dict(heads=2, dk=128, dv=128, chunk=64)
+_WIDE = {
+    "first_slot": dict(length=192, starts=(64, 128), **_TILES),  # a history starts a chunk
+    "last_slot": dict(length=192, starts=(63, 127), **_TILES),  # and ends one with its first slot
+    "three_chunks": dict(length=256, starts=(70, 250), **_TILES),  # slots 70-249: chunks 1, 2, 3
+    "ragged": dict(length=150, starts=(50, 145), **_TILES),  # not a multiple of the chunk
+}
+_RULE_CASES = (
+    [pytest.param("forward", "scan", c, id=f"forward-scan-{c['length']}-{c['chunk']}") for c in _SMALL]
+    + [pytest.param("backward", "scan", c, id=f"backward-scan-{c['length']}-{c['chunk']}")
+       for c in _SMALL_BACKWARD]
+    + [pytest.param(way, walk, c, id=f"{way}-{walk}-{name}")
+       for way in ("forward", "backward") for walk in ("scan", "kernel") for name, c in _WIDE.items()])
+
+
+@pytest.mark.parametrize("way,walk,case", _RULE_CASES)
+def test_chunked_rule_is_the_recurrence(way, walk, case):
+    case = dict(case)
+    chunk = case.pop("chunk")
+    q, k, v, g, beta, seg = _rule_inputs(**case)
+    rule = lambda *a: deltanet.gated_delta_rule(  # noqa: E731
+        *a, seg, chunk=chunk, interpret=walk == "kernel")
+    kernels = [name for name, _, _ in _primitives_of(rule, q, k, v, g, beta) if name == "pallas_call"]
+    assert len(kernels) == (1 if walk == "kernel" else 0)
+    if way == "forward":
+        assert rel(rule(q, k, v, g, beta), _recurrence(q, k, v, g, beta, seg)) < 2e-5
+        return
+    weight = np.random.default_rng(4).normal(size=v.shape).astype(np.float32)
+    chunked = jax.grad(lambda *a: (rule(*a) * weight).sum(), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
     plain = jax.grad(lambda *a: (_recurrence(*a, seg) * weight).sum(),
                      argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
     for a, b in zip(chunked, plain):
         assert rel(a, b) < 1e-4
+
+
+@pytest.mark.parametrize("walk", ["scan", "kernel"])
+def test_a_bfloat16_state_stays_apart_from_a_float32_one(walk):
+    """The benchmark's control build is this rule with state and gates in
+    bfloat16, and has to read at least five times further from the
+    recurrence than the sound build (PERF.md section 2: 0.0126 against
+    0.0024 on the chip, limit 0.006); either walk honours the two dtypes."""
+    q, k, v, g, beta, seg = _rule_inputs(512, starts=(200, 230), seed=6, **{
+        key: _TILES[key] for key in ("heads", "dk", "dv")})
+    want = _recurrence(q, k, v, g, beta, seg)
+
+    def distance(dtype):
+        got = deltanet.gated_delta_rule(
+            q, k, v, g, beta, seg, chunk=64, compute_dtype=jnp.bfloat16, state_dtype=dtype,
+            gate_dtype=dtype, interpret=walk == "kernel")
+        return rel(got, want)
+
+    sound, control = distance(jnp.float32), distance(jnp.bfloat16)
+    assert sound < 0.003 and control > 5 * sound, (sound, control)
+
+
+def test_the_walk_is_made_three_times_forward_and_once_backward(cfg, params):
+    """Through one gated-DeltaNet layer as the step differentiates it
+    (``_layer_fn``: the layer is recomputed, and inside it each row): the
+    layer's forward pass, its recomputation, the row's recomputation, and
+    one walk in reverse. Nothing inside the rule is made again."""
+    layer = bb._layer_fn(cfg, False, None, "auto")
+    per = jax.tree_util.tree_map(lambda a: a[0], params["periods"])
+    pick = lambda tree: jax.tree_util.tree_map(lambda a: a[0], tree)  # noqa: E731
+    seg = jnp.ones((2, L), jnp.int32)
+    x = jnp.ones((2, L, cfg.hidden_size), jnp.float32)
+
+    def loss(x, mixer):
+        return layer(x, seg, bb.positions_of(seg), pick(per["norm_in"]), mixer,
+                     pick(per["norm_post"]), pick(per["ffn"]))[0].sum()
+
+    walks = [params_["reverse"] for name, stack, params_ in _primitives_of(
+        jax.grad(loss, argnums=(0, 1)), x, pick(per["linear"]))
+        if name == "scan" and "seq.deltanet.scan.walk" in stack]
+    assert walks.count(False) == 3 and walks.count(True) == 1
+
+    q, k, v, g, beta, seg = _rule_inputs(37)
+    inside = _primitives_of(jax.grad(lambda *a: deltanet.gated_delta_rule(*a, seg, chunk=16).sum(),
+                                argnums=(0, 1, 2, 3, 4)), q, k, v, g, beta)
+    assert not [name for name, _, _ in inside if name in ("checkpoint", "remat", "remat2")]
+    scans = [params_["reverse"] for name, _, params_ in inside if name == "scan"]
+    assert scans == [False, True]
 
 
 def test_tri_inv_inverts_and_differentiates():
@@ -491,6 +575,8 @@ def test_pio_train_and_predict_with_the_backbone_configuration(tmp_path, monkeyp
     assert model.config.full_attention_interval == 4 and model.config.experts_held == (2, 3)
     assert model.losses[-1] < model.losses[0]
     assert 0.0 < model.stats["fill"] <= 1.0
+    # heads of 16 on the CPU: not the kernel's tiles
+    assert model.stats["delta_rule_walk"] == "scan"
     answer = SeqRecAlgorithm(algo_params).predict(
         model, Query(recent_items=("i0", "i1", "i2"), num=3))
     assert len(answer.item_scores) == 3
