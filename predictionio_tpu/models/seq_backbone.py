@@ -1,27 +1,31 @@
 """The sequence recommender's backbone: a decoder block that is a function
 of a configuration.
 
-A configuration gives the layer pattern (``full_attention_interval``: every
-n-th layer is softmax attention, the others gated DeltaNet; 1 = all
-attention), the attention (``gqa``: grouped heads of one width; ``mla``:
+A configuration gives the layer pattern (``layer_types``: a mixer a layer,
+``full_attention``, ``linear_attention`` = gated DeltaNet, or ``conv`` = the
+gated short convolution; or ``full_attention_interval``: every n-th layer
+is softmax attention, the others gated DeltaNet; 1 = all attention), the
+attention (``gqa``: grouped heads of one width; ``mla``:
 latent attention, queries and keys/values projected down, normed and up
 again, a rotary part of the key that all heads share), the head and
 feed-forward widths, the norm (``rms`` with scale ``1 + w``, or ``layer``),
 the positions (``rotary`` on part of a head, or a ``learned`` table), the
 feed-forward kind (``moe``: routed experts of which this share holds a
-range, plus a shared expert; ``swiglu``; or ``gelu``), how many leading
-layers are dense instead (``first_k_dense_replace``), whether a
+range, plus a shared expert where the file gives one; ``swiglu``; or ``gelu``), how many leading
+layers are dense instead (``first_k_dense_replace``, ``num_dense_layers``), whether a
 multi-token-prediction module follows the last layer, and whether the head
 is the embedding. The keys are those of the public models' ``config.json``;
 what such a file does not state (norm, positions, the range of experts
 held, precision) sits in its ``backbone`` group.
 
-Parameters are stacked by period (``full_attention_interval`` layers) and
-the periods run in a ``lax.scan``, a model of one period too; the leading
-dense layers and the prediction module lie outside it; each layer is
-recomputed in the backward pass. Rows are packed: ``seg`` gives each slot its history's
-id (0 = padding), positions count from a history's start, and neither the
-convolution, the delta-rule state nor attention crosses a boundary.
+Parameters are stacked by period (the shortest run of mixers that the layers
+after the leading dense ones repeat) and the periods run in a ``lax.scan``,
+a model of one period too; the leading dense layers (one kind of mixer,
+whichever the pattern gives them) and the prediction module lie outside it;
+each layer is recomputed in the backward pass. Rows are packed: ``seg`` gives
+each slot its history's id (0 = padding), positions count from a history's
+start, and neither the convolution, the delta-rule state nor attention
+crosses a boundary.
 
 Precision: parameters, residual stream, norms, router, softmax, gates,
 delta-rule state, the router's bias and loss in float32; matrix products
@@ -45,6 +49,11 @@ import numpy as np
 from ..ops.attention import attention
 from ..ops.deltanet import gated_deltanet
 from ..ops.moe import expert_layer, swiglu
+from ..ops.shortconv import short_conv
+
+#: a public file's word for a layer's mixer -> the kind the parameters are
+#: stacked under
+_KINDS = {"full_attention": "full", "linear_attention": "linear", "conv": "conv"}
 
 CONF_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
@@ -56,12 +65,18 @@ class BackboneConfig:
     hidden_size: int = 64
     num_hidden_layers: int = 2
     full_attention_interval: int = 1
+    #: the mixer of every layer in a public file's words (``_KINDS``); empty
+    #: = ``full_attention_interval`` decides, and leading dense layers are
+    #: full attention
+    layer_types: Tuple[str, ...] = ()
     num_attention_heads: int = 4
     num_key_value_heads: int = 4
     head_dim: int = 16
     #: the gated attention of Qwen3-Next: an output gate beside the query
     #: and a norm on every head of q and k
     attn_gate: bool = False
+    #: the norm on every head of q and k without the gate
+    qk_norm: bool = False
     #: "gqa", or "mla": latent attention at the five widths below
     attention: str = "gqa"
     q_lora_rank: int = 0
@@ -83,6 +98,8 @@ class BackboneConfig:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
+    #: taps of the gated short convolution (``layer_types`` ``conv``)
+    conv_L_cache: int = 3
     ffn: str = "gelu"  # "gelu" | "swiglu" | "moe"
     #: leading layers, outside the periods, whose feed-forward is a SwiGLU
     #: of ``intermediate_size`` whatever ``ffn`` says
@@ -95,6 +112,9 @@ class BackboneConfig:
     norm_topk_prob: bool = True
     scoring_func: str = "softmax"  # "softmax" | "sigmoid" (``ops.moe.route``)
     routed_scaling_factor: float = 1.0
+    #: what the sigmoid router adds to the chosen scores' sum before it
+    #: divides by it (DeepSeek-V3's 1e-20; LFM2's 1e-6)
+    norm_topk_eps: float = 1e-20
     #: a bias [router_width] that chooses the experts and does not weigh
     #: them (``topk_method`` ``noaux_tc``); no gradient moves it: every
     #: step adds ``router_bias_rate * sign(mean load - load)``
@@ -127,12 +147,47 @@ class BackboneConfig:
     loss_block: int = 2048
 
     @property
+    def kinds(self) -> Tuple[str, ...]:
+        """The mixer of every layer: ``full``, ``linear`` or ``conv``."""
+        if self.layer_types:
+            return tuple(_KINDS[t] for t in self.layer_types)
+        p = self.full_attention_interval
+        one = ("linear",) * (p - 1) + ("full",)
+        k = self.first_k_dense_replace
+        return ("full",) * k + one * ((self.num_hidden_layers - k) // p)
+
+    @property
+    def period_kinds(self) -> Tuple[str, ...]:
+        """One period: the shortest run of mixers that the layers after the
+        leading dense ones are whole repeats of."""
+        rest = self.kinds[self.first_k_dense_replace:]
+        for p in range(1, len(rest) + 1):
+            if len(rest) % p == 0 and rest == rest[:p] * (len(rest) // p):
+                return rest[:p]
+        return rest
+
+    @property
     def period(self) -> int:
-        return self.full_attention_interval
+        return len(self.period_kinds)
 
     @property
     def n_periods(self) -> int:
         return (self.num_hidden_layers - self.first_k_dense_replace) // self.period
+
+    def stacked(self, kind: str) -> int:
+        """The layers of ``kind`` in a period as its parameters are stacked
+        behind the period's own axis; 0 = no second axis: a period's one
+        full layer (the layout of every model trained before there were
+        patterns with more)."""
+        held = self.period_kinds.count(kind)
+        return 0 if (kind, held) == ("full", 1) else held
+
+    def mixers(self) -> Dict[str, int]:
+        """Layers by the mixer they run: ``deltanet``, ``shortconv``, and
+        ``gqa`` or ``mla`` (the prediction module's block counts too)."""
+        names = {"linear": "deltanet", "conv": "shortconv", "full": self.attention}
+        found = [names[k] for k in self.kinds] + [self.attention] * self.num_nextn_predict_layers
+        return {name: found.count(name) for name in sorted(set(found))}
 
     @classmethod
     def toy(cls, d_model: int, n_heads: int, n_layers: int) -> "BackboneConfig":
@@ -149,20 +204,38 @@ class BackboneConfig:
         names = {f.name for f in dataclasses.fields(cls)}
         merged = {**d, **d.get("backbone", {})}
         values = {k: v for k, v in merged.items() if k in names}
-        if "experts_held" in values:
-            values["experts_held"] = tuple(values["experts_held"])
-        # two things a public file says in its own words
-        if merged.get("topk_method") == "noaux_tc":
+        for name in ("experts_held", "layer_types"):
+            if name in values:
+                values[name] = tuple(values[name])
+        # what a public file says in its own words
+        if merged.get("topk_method") == "noaux_tc" or merged.get("use_expert_bias"):
             values.setdefault("router_bias", True)
+        for theirs, ours in (("num_dense_layers", "first_k_dense_replace"),
+                             ("norm_eps", "rms_norm_eps")):
+            if theirs in merged:
+                values.setdefault(ours, merged[theirs])
+        if "rope_theta" in merged.get("rope_parameters", {}):
+            values.setdefault("rope_theta", merged["rope_parameters"]["rope_theta"])
         if "n_shared_experts" in merged:
             values.setdefault(
                 "shared_expert_intermediate_size",
                 merged["n_shared_experts"] * merged["moe_intermediate_size"])
         cfg = cls(**values)
-        if (cfg.num_hidden_layers - cfg.first_k_dense_replace) % cfg.period:
+        if cfg.layer_types:
+            unknown = sorted(set(cfg.layer_types) - set(_KINDS))
+            if unknown:
+                raise ValueError(f"layer_types names mixers unknown here: {unknown}")
+            if len(cfg.layer_types) != cfg.num_hidden_layers:
+                raise ValueError(f"layer_types names {len(cfg.layer_types)} layers "
+                                 f"for {cfg.num_hidden_layers}")
+            if len(set(cfg.kinds[:cfg.first_k_dense_replace])) > 1:
+                raise ValueError("the leading dense layers are stacked: one kind of mixer for all")
+        elif (cfg.num_hidden_layers - cfg.first_k_dense_replace) % cfg.full_attention_interval:
             raise ValueError(
                 f"{cfg.num_hidden_layers} layers less {cfg.first_k_dense_replace} dense ones "
-                f"are not whole periods of {cfg.period}")
+                f"are not whole periods of {cfg.full_attention_interval}")
+        if merged.get("conv_bias"):
+            raise ValueError("the short convolution and its projections carry no bias here")
         if cfg.num_nextn_predict_layers not in (0, 1):
             raise ValueError("one multi-token-prediction module at most")
         if cfg.attention == "mla" and not (
@@ -196,7 +269,9 @@ def _is_spec(x) -> bool:
 
 def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
     """name -> (shape, kind): 'w' a matrix (fan-in = second-to-last axis),
-    'zero', 'one', 'embed', or a kind of its own."""
+    'zero', 'one', 'embed', or a kind of its own. A period's mixers are
+    stacked by kind, [periods, layers of that kind in a period, ...]
+    (``BackboneConfig.stacked``)."""
     d, p, n = cfg.hidden_size, cfg.period, cfg.n_periods
     norm = {"w": ((d,), "zero")} if cfg.norm == "rms" else {
         "g": ((d,), "one"), "b": ((d,), "zero")}
@@ -220,8 +295,21 @@ def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
             "w_k": ((d, hkv * hd), "w"), "w_v": ((d, hkv * hd), "w"),
             "w_o": ((h * hd, d), "w"),
         }
-        if cfg.attn_gate:
+        if cfg.attn_gate or cfg.qk_norm:
             full.update(q_norm=((hd,), "zero"), k_norm=((hd,), "zero"))
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    mixers = {
+        "full": full,
+        "linear": {
+            "w_qkvz": ((d, 2 * hk * dk + 2 * hv * dv), "w"), "w_ba": ((d, 2 * hv), "w"),
+            "conv_w": ((cfg.linear_conv_kernel_dim, 2 * hk * dk + hv * dv), "w"),
+            "A_log": ((hv,), "a_log"), "dt_bias": ((hv,), "dt_bias"),
+            "o_norm": ((dv,), "one"), "w_out": ((hv * dv, d), "w"),
+        },
+        "conv": {"w_in": ((d, 3 * d), "w"), "conv_w": ((cfg.conv_L_cache, d), "w"),
+                 "w_out": ((d, d), "w")},
+    }
     m = cfg.intermediate_size
     gated = {"wg": ((d, m), "w"), "wu": ((d, m), "w"), "wd": ((m, d), "w")}
     if cfg.ffn == "moe":
@@ -229,12 +317,13 @@ def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
         count = cfg.experts_held[1]
         ffn = {
             "router": ((d, cfg.router_width), "w"),
-            "shared": {"wg": ((d, fs), "w"), "wu": ((d, fs), "w"), "wd": ((fs, d), "w")},
             "experts": {"wg": ((count, d, f), "w"), "wu": ((count, d, f), "w"),
                         "wd": ((count, f, d), "w")},
         }
-        if cfg.shared_expert_gate:
-            ffn["shared_gate"] = ((d,), "w_vec")
+        if fs:
+            ffn["shared"] = {"wg": ((d, fs), "w"), "wu": ((d, fs), "w"), "wd": ((fs, d), "w")}
+            if cfg.shared_expert_gate:
+                ffn["shared_gate"] = ((d,), "w_vec")
         if cfg.router_bias:
             ffn["router_bias"] = ((cfg.router_width,), "zero")
     elif cfg.ffn == "swiglu":
@@ -242,23 +331,17 @@ def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
     else:
         ffn = {"mlp_in": ((d, m), "w"), "mlp_out": ((m, d), "w")}
     periods = {
-        "full": lead(full, n),
         "norm_in": lead(norm, n, p), "norm_post": lead(norm, n, p),
         "ffn": lead(ffn, n, p),
     }
-    if p > 1:
-        hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-        dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
-        periods["linear"] = lead({
-            "w_qkvz": ((d, 2 * hk * dk + 2 * hv * dv), "w"), "w_ba": ((d, 2 * hv), "w"),
-            "conv_w": ((cfg.linear_conv_kernel_dim, 2 * hk * dk + hv * dv), "w"),
-            "A_log": ((hv,), "a_log"), "dt_bias": ((hv,), "dt_bias"),
-            "o_norm": ((dv,), "one"), "w_out": ((hv * dv, d), "w"),
-        }, n, p - 1)
+    for kind in set(cfg.period_kinds):
+        held = cfg.stacked(kind)
+        periods[kind] = lead(mixers[kind], n, held) if held else lead(mixers[kind], n)
     shapes = {"embed": ((vocab, d), "embed"), "final_norm": norm, "periods": periods}
     if cfg.first_k_dense_replace:
+        kind = cfg.kinds[0]
         shapes["dense"] = lead(
-            {"full": full, "norm_in": norm, "norm_post": norm, "ffn": gated},
+            {kind: mixers[kind], "norm_in": norm, "norm_post": norm, "ffn": gated},
             cfg.first_k_dense_replace)
     if cfg.num_nextn_predict_layers:
         shapes["mtp"] = {
@@ -314,34 +397,31 @@ def layers_of(params: Dict, cfg: BackboneConfig) -> Dict:
     layout of the plain references (``testing/qwen3_next_reference.py``;
     with latent attention ``testing/joyai_flash_reference.py``, whose
     mixer is ``attn``, whose dense layers carry ``mlp`` and whose
-    prediction module is ``mtp``). Works on any pytree of the parameters'
-    structure: gradients too."""
-    mixer_key = "attn" if cfg.attention == "mla" else "full"
+    prediction module is ``mtp``; ``testing/lfm2_moe_reference.py``, whose
+    mixers are ``conv`` and ``full`` and whose head is its embedding).
+    Works on any pytree of the parameters' structure: gradients too."""
+    full_key = "attn" if cfg.attention == "mla" else "full"
     ffn_key = "moe" if cfg.ffn == "moe" else "mlp"
 
-    def block(blk, mixer_key=mixer_key, ffn_key=ffn_key):
-        mixer = blk["linear"] if mixer_key == "linear" else blk["full"]
+    def block(blk, kind="full", ffn_key=ffn_key):
         return {"input_norm": blk["norm_in"]["w"], "post_norm": blk["norm_post"]["w"],
-                ffn_key: blk["ffn"], mixer_key: mixer}
+                ffn_key: blk["ffn"], full_key if kind == "full" else kind: blk[kind]}
 
     layers = []
     for j in range(cfg.first_k_dense_replace):
         dense = jax.tree_util.tree_map(lambda leaf, j=j: leaf[j], params["dense"])
-        layers.append(block(dense, ffn_key="mlp"))
+        layers.append(block(dense, cfg.kinds[0], ffn_key="mlp"))
     per = params["periods"]
     for n in range(cfg.n_periods):
-        for j in range(cfg.period):
-            take = lambda leaf, n=n, j=j: leaf[n, j]  # noqa: E731
-            blk = {name: jax.tree_util.tree_map(take, per[name])
+        at_n = jax.tree_util.tree_map(lambda leaf, n=n: leaf[n], per)
+        for j, kind in enumerate(cfg.period_kinds):
+            blk = {name: jax.tree_util.tree_map(lambda leaf, j=j: leaf[j], at_n[name])
                    for name in ("norm_in", "norm_post", "ffn")}
-            if j == cfg.period - 1:
-                blk["full"] = jax.tree_util.tree_map(lambda leaf, n=n: leaf[n], per["full"])
-                layers.append(block(blk))
-            else:
-                blk["linear"] = jax.tree_util.tree_map(take, per["linear"])
-                layers.append(block(blk, mixer_key="linear"))
-    out = {"embed": params["embed"], "head": params["head"],
-           "final_norm": params["final_norm"]["w"], "layers": layers}
+            blk[kind] = _mixer_of(cfg, at_n, j)
+            layers.append(block(blk, kind))
+    out = {"embed": params["embed"], "final_norm": params["final_norm"]["w"], "layers": layers}
+    if "head" in params:
+        out["head"] = params["head"]
     if "mtp" in params:
         m = params["mtp"]
         out["mtp"] = {
@@ -349,6 +429,16 @@ def layers_of(params: Dict, cfg: BackboneConfig) -> Dict:
             "norm": m["norm"]["w"], "block": block(m["block"]),
         }
     return out
+
+
+def _mixer_of(cfg: BackboneConfig, per: Dict, j: int):
+    """The mixer's parameters of layer ``j`` of ONE period ``per`` (the
+    stacked parameters at one index of their leading axis)."""
+    kind = cfg.period_kinds[j]
+    if not cfg.stacked(kind):
+        return per[kind]
+    nth = cfg.period_kinds[:j].count(kind)
+    return jax.tree_util.tree_map(lambda a: a[nth], per[kind])
 
 
 # -- the block --------------------------------------------------------------
@@ -448,7 +538,7 @@ def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule):
     q = qg[..., : h * hd].reshape(b, l, h, hd).astype(f32)
     k = jnp.dot(xc, p["w_k"].astype(cd), preferred_element_type=f32).reshape(b, l, hkv, hd)
     v = jnp.dot(xc, p["w_v"].astype(cd), preferred_element_type=f32).reshape(b, l, hkv, hd)
-    if cfg.attn_gate:
+    if "q_norm" in p:
         eps = cfg.rms_norm_eps
 
         def head_norm(t, w):
@@ -458,11 +548,12 @@ def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule):
     if cfg.positions == "rotary":
         rot = int(cfg.partial_rotary_factor * hd)
         q, k = _rope(q, pos, rot, cfg.rope_theta), _rope(k, pos, rot, cfg.rope_theta)
-    o = attention(
-        q.astype(cd).transpose(0, 2, 1, 3), k.astype(cd).transpose(0, 2, 1, 3),
-        v.astype(cd).transpose(0, 2, 1, 3), mesh=mesh, causal=True,
-        schedule=schedule, segment_ids=seg, block=cfg.attn_block,
-    )
+    with jax.named_scope("seq.attn.core"):
+        o = attention(
+            q.astype(cd).transpose(0, 2, 1, 3), k.astype(cd).transpose(0, 2, 1, 3),
+            v.astype(cd).transpose(0, 2, 1, 3), mesh=mesh, causal=True,
+            schedule=schedule, segment_ids=seg, block=cfg.attn_block,
+        )
     o = o.transpose(0, 2, 1, 3).reshape(b, l, h * hd).astype(f32)
     if cfg.attn_gate:
         o = o * jax.nn.sigmoid(qg[..., h * hd:].astype(f32))
@@ -479,7 +570,8 @@ def _ffn(cfg: BackboneConfig, p: Dict, x):
             y, counters = expert_layer(
                 p, x.reshape(b * l, d), first=cfg.experts_held[0],
                 top_k=cfg.num_experts_per_tok, norm_topk=cfg.norm_topk_prob,
-                compute_dtype=cd, scoring=cfg.scoring_func, scale=cfg.routed_scaling_factor)
+                compute_dtype=cd, scoring=cfg.scoring_func, scale=cfg.routed_scaling_factor,
+                norm_eps=cfg.norm_topk_eps)
         return y.reshape(b, l, d), counters
     if "wg" in p:
         with jax.named_scope("seq.ffn"):
@@ -488,17 +580,22 @@ def _ffn(cfg: BackboneConfig, p: Dict, x):
     return jnp.dot(hidden.astype(cd), p["mlp_out"].astype(cd), preferred_element_type=f32), {}
 
 
-def _layer(cfg: BackboneConfig, full: bool, mesh, schedule, x, seg, pos,
+def _layer(cfg: BackboneConfig, kind: str, mesh, schedule, x, seg, pos,
            norm_in, mixer, norm_post, ffn):
     h = _norm(cfg, norm_in, x)
     ran = {}
-    if full and cfg.attention == "mla":
+    if kind == "full" and cfg.attention == "mla":
         with jax.named_scope("seq.attn"):
             mixed, ran = _latent_mixer(cfg, mixer, h, seg, pos, mesh, schedule)
             x = x + mixed
-    elif full:
+    elif kind == "full":
         with jax.named_scope("seq.attn"):
             x = x + _attention_mixer(cfg, mixer, h, seg, pos, mesh, schedule)
+    elif kind == "conv":
+        with jax.named_scope("seq.shortconv"):
+            mixed, ran = short_conv(mixer, h, seg, compute_dtype=_dt(cfg.compute_dtype),
+                                    gate_dtype=_dt(cfg.gate_dtype))
+            x = x + mixed
     else:
         with jax.named_scope("seq.deltanet"):
             mixed, ran = gated_deltanet(
@@ -512,10 +609,11 @@ def _layer(cfg: BackboneConfig, full: bool, mesh, schedule, x, seg, pos,
     return x + y, counters, ran
 
 
-def _layer_fn(cfg: BackboneConfig, full: bool, mesh, schedule):
-    """One layer as ``(x, seg, pos, norm_in, mixer, norm_post, ffn) -> x,
-    counters, ran``, recomputed in the backward pass."""
-    return jax.checkpoint(lambda *a: _layer(cfg, full, mesh, schedule, *a))
+def _layer_fn(cfg: BackboneConfig, kind: str, mesh, schedule):
+    """One layer whose mixer is of ``kind`` (``full``, ``linear``, ``conv``)
+    as ``(x, seg, pos, norm_in, mixer, norm_post, ffn) -> x, counters,
+    ran``, recomputed in the backward pass."""
+    return jax.checkpoint(lambda *a: _layer(cfg, kind, mesh, schedule, *a))
 
 
 def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
@@ -523,10 +621,11 @@ def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
     """tokens, seg [B, L] -> the residual stream after the last layer
     [B, L, D] (float32, before the final norm); the expert layers'
     counters, stacked [periods, layers of a period, ...]; and what the
-    mixer of each period's first layer handed its inner kernel and got back
-    (the delta rule's q, k, v, g, beta and o: ``ops.deltanet.gated_deltanet``;
-    latent attention's q, k, v and o; stacked [periods, B, ...]; empty with
-    neither)."""
+    first mixer of each period that says so handed its inner kernel and got
+    back (the delta rule's q, k, v, g, beta and o:
+    ``ops.deltanet.gated_deltanet``; latent attention's q, k, v and o; the
+    short convolution's ``bcx`` and ``y``: ``ops.shortconv.short_conv``;
+    stacked [periods, B, ...]; empty where no mixer of a period does)."""
     pos = positions_of(seg)
     with jax.named_scope("seq.embed"):
         x = params["embed"][tokens]
@@ -538,24 +637,21 @@ def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
                     f"table ({table.shape[0]} positions: trained with a shorter seq_len)")
             x = x + table[pos]
 
-    linear_layer, full_layer = (_layer_fn(cfg, full, mesh, schedule) for full in (False, True))
-    p = cfg.period
+    layer_of = {kind: _layer_fn(cfg, kind, mesh, schedule) for kind in set(cfg.kinds)}
     for j in range(cfg.first_k_dense_replace):
+        kind = cfg.kinds[0]
         d = jax.tree_util.tree_map(lambda a, j=j: a[j], params["dense"])
-        x, _, _ = full_layer(x, seg, pos, d["norm_in"], d["full"], d["norm_post"], d["ffn"])
+        x, _, _ = layer_of[kind](x, seg, pos, d["norm_in"], d[kind], d["norm_post"], d["ffn"])
 
     def one_period(x, per):
         counters, first_ran = [], {}
-        for j in range(p):
+        for j, kind in enumerate(cfg.period_kinds):
             pick = lambda tree, j=j: jax.tree_util.tree_map(lambda a: a[j], tree)  # noqa: E731
-            full = j == p - 1
-            mixer = per["full"] if full else pick(per["linear"])
-            x, c, ran = (full_layer if full else linear_layer)(
-                x, seg, pos, pick(per["norm_in"]), mixer, pick(per["norm_post"]),
-                pick(per["ffn"]))
+            mixer = _mixer_of(cfg, per, j)
+            x, c, ran = layer_of[kind](
+                x, seg, pos, pick(per["norm_in"]), mixer, pick(per["norm_post"]), pick(per["ffn"]))
             counters.append(c)
-            if j == 0:
-                first_ran = ran
+            first_ran = first_ran or ran
         stacked = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *counters)
         return x, (stacked, first_ran)
 
@@ -627,7 +723,7 @@ def mtp_hidden(cfg: BackboneConfig, params: Dict, hidden, next_tokens, seg, mesh
                             _norm(cfg, m["hnorm"], hidden)], -1)
     x = jnp.dot(both.astype(cd), m["eh_proj"].astype(cd), preferred_element_type=jnp.float32)
     blk = m["block"]
-    x, counters, _ = _layer_fn(cfg, True, mesh, schedule)(
+    x, counters, _ = _layer_fn(cfg, "full", mesh, schedule)(
         x, seg, positions_of(seg), blk["norm_in"], blk["full"], blk["norm_post"], blk["ffn"])
     return x, counters
 

@@ -414,7 +414,7 @@ def _delta_rule_walk(cfg: bb.BackboneConfig) -> Dict[str, str]:
     """Which walk the step's gated-DeltaNet layers run ("pallas" or "scan":
     ``ops.deltanet.walk_kind``, what the rule itself asks where the step is
     traced); nothing for a backbone without such layers."""
-    if cfg.period == 1:
+    if "linear" not in cfg.kinds:
         return {}
     return {"delta_rule_walk": walk_kind(
         cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.chunk)}
@@ -440,7 +440,8 @@ class SeqRecAlgorithm(Algorithm):
         p = self.params
         cfg = p.backbone_config()
         tags = {"backbone": p.backbone or "toy", "steps": p.steps,
-                "layers": cfg.num_hidden_layers, **_delta_rule_walk(cfg)}
+                "layers": cfg.num_hidden_layers, **_delta_rule_walk(cfg),
+                "mixers": " ".join(f"{name}:{n}" for name, n in cfg.mixers().items())}
         # the job's root span: under no server it starts a trace of its own
         with span("train", tags):
             return self._train(ctx, pd, cfg)
@@ -483,7 +484,7 @@ class SeqRecAlgorithm(Algorithm):
         with span("train.wait_device"):
             jax.block_until_ready(model_params)
         stats = {"fill": pd.fill, "steps": p.steps, "tokens_per_step": batch * pd.seq_len,
-                 **_delta_rule_walk(cfg)}
+                 "mixers": cfg.mixers(), **_delta_rule_walk(cfg)}
         if counters:
             stats.update(jax.tree_util.tree_map(np.asarray, counters))
         with span("train.fetch"):

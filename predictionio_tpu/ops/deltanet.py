@@ -24,7 +24,8 @@ the tiles allow (``dk`` and ``dv`` multiples of 128, the chunk a multiple of
 Packed rows: ``seg`` gives each slot the id of its history (one contiguous
 run per id). A history's first token resets the state, which the chunked
 form does by masking the decay between slots of different histories; the
-short convolution reads zero where a tap would reach into the neighbour.
+short convolution (:func:`.shortconv.causal_conv`) reads zero where a tap would
+reach into the neighbour.
 
 Precision: gates ``alpha`` (as ``g = log alpha`` and its running sums) and
 the state are ``gate_dtype`` and ``state_dtype`` (float32); the triangular
@@ -43,6 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .shortconv import causal_conv
 
 _HI = jax.lax.Precision.HIGHEST
 _BLOCK = 16  # diagonal blocks solved row by row; the rest by products
@@ -96,18 +99,6 @@ def _tri_inv_bwd(t, dt):
 
 
 tri_inv.defvjp(_tri_inv_fwd, _tri_inv_bwd)
-
-
-def causal_conv(x, w, seg):
-    """Depthwise causal convolution, x [B, L, C], w [K, C] (tap K-1 is the
-    current slot), seg [B, L]: a tap in another history reads zero."""
-    taps, length = w.shape[0], x.shape[1]
-    out = x * w[taps - 1]
-    for back in range(1, taps):
-        shifted = jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :length]
-        same = jnp.pad(seg, ((0, 0), (back, 0)), constant_values=-1)[:, :length] == seg
-        out = out + jnp.where(same[..., None], shifted, 0) * w[taps - 1 - back]
-    return out
 
 
 def _prepare(q, k, v, g, beta, seg, chunk, compute_dtype, gate_dtype):

@@ -9,10 +9,10 @@ trainer steps from the experts' loads; their weights are the scores WITHOUT
 the bias, renormalised over the ``top_k`` and times ``scale``. This share
 holds the contiguous range ``[first, first + count)``: it computes, for
 every token, the part of the result that its own experts give (a grouped
-matrix product over the assignments sorted by expert) plus the shared
-expert (behind a sigmoid gate where the layer has a ``shared_gate``), which
-every share computes alike. What the absent experts would add is left out;
-no code stands in for the chips that hold them.
+matrix product over the assignments sorted by expert) plus, where the layer
+has one, the shared expert (behind a sigmoid gate where the layer has a
+``shared_gate``), which every share computes alike. What the absent experts
+would add is left out; no code stands in for the chips that hold them.
 
 No assignment is dropped. Shapes are static, so the sorted assignments are
 taken ``pass_rows`` at a time (default: twice this share's mean load), in
@@ -43,11 +43,12 @@ def swiglu(w: Dict, x, cd):
 
 
 def route(x, router, top_k: int, norm_topk: bool = True, scoring: str = "softmax",
-          bias=None, scale: float = 1.0):
+          bias=None, scale: float = 1.0, norm_eps: float = 1e-20):
     """x [T, D], router [D, E] -> expert ids [T, k] and weights [T, k]
     (float32). ``softmax``: over all E, the k largest, renormalised.
     ``sigmoid``: the k largest of sigmoid + ``bias`` [E]; the weights are
-    the sigmoids alone, renormalised, times ``scale``."""
+    the sigmoids alone, renormalised (over their sum + ``norm_eps``, the
+    public implementation's own constant), times ``scale``."""
     logits = jnp.dot(x.astype(jnp.float32), router, precision=_HI)
     if scoring == "softmax":
         top, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
@@ -60,7 +61,7 @@ def route(x, router, top_k: int, norm_topk: bool = True, scoring: str = "softmax
     _, idx = jax.lax.top_k(scores if bias is None else scores + bias, top_k)
     top = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk:
-        top = top / (top.sum(-1, keepdims=True) + 1e-20)
+        top = top / (top.sum(-1, keepdims=True) + norm_eps)
     return idx, top * scale
 
 
@@ -97,13 +98,14 @@ def _passes_for(all_rows: int, rows: int) -> int:
 
 def expert_layer(p: Dict, x, *, first: int, top_k: int, norm_topk: bool = True,
                  pass_rows: int = 0, compute_dtype=jnp.float32, scoring: str = "softmax",
-                 scale: float = 1.0) -> Tuple[jax.Array, Dict]:
+                 scale: float = 1.0, norm_eps: float = 1e-20) -> Tuple[jax.Array, Dict]:
     """x [T, D] (normed) -> y [T, D] float32 and the step's counters.
-    ``p``: ``router`` [D, E], ``shared`` and ``experts`` (``wg``, ``wu``
-    [.., D, F], ``wd`` [.., F, D]; experts with a leading [count] axis: the
-    experts ``first .. first + count - 1``); where the layer has them,
-    ``shared_gate`` [D] and ``router_bias`` [E] (then the counters also
-    give ``router_tokens`` [E]: the tokens of every expert, held or not)."""
+    ``p``: ``router`` [D, E] and ``experts`` (``wg``, ``wu`` [.., D, F],
+    ``wd`` [.., F, D], with a leading [count] axis: the experts ``first ..
+    first + count - 1``); where the layer has them, ``shared`` (one expert
+    every token goes through), ``shared_gate`` [D] and ``router_bias`` [E]
+    (then the counters also give ``router_tokens`` [E]: the tokens of every
+    expert, held or not)."""
     x = jnp.asarray(x)
     tokens = x.shape[0]
     count = p["experts"]["wg"].shape[0]
@@ -114,7 +116,7 @@ def expert_layer(p: Dict, x, *, first: int, top_k: int, norm_topk: bool = True,
     passes = _passes_for(all_rows, rows)
     with jax.named_scope("seq.moe.route"):
         bias = p.get("router_bias")
-        idx, weights = route(x, p["router"], top_k, norm_topk, scoring, bias, scale)
+        idx, weights = route(x, p["router"], top_k, norm_topk, scoring, bias, scale, norm_eps)
         local = idx - first
         held = (local >= 0) & (local < count)
         flat = jnp.where(held, local, count).reshape(-1)  # absent experts sort last
@@ -139,12 +141,14 @@ def expert_layer(p: Dict, x, *, first: int, top_k: int, norm_topk: bool = True,
 
     y, combined = jax.lax.scan(one_pass, jnp.zeros(x.shape, jnp.float32),
                                jnp.arange(passes, dtype=jnp.int32) * rows)
-    with jax.named_scope("seq.moe.shared"):
-        if "shared_gate" in p:
-            gate = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), p["shared_gate"], precision=_HI))
-            y = y + gate[:, None] * swiglu(p["shared"], x, cd)
-        else:
-            y = y + swiglu(p["shared"], x, cd)
+    if "shared" in p:
+        with jax.named_scope("seq.moe.shared"):
+            if "shared_gate" in p:
+                gate = jax.nn.sigmoid(
+                    jnp.dot(x.astype(jnp.float32), p["shared_gate"], precision=_HI))
+                y = y + gate[:, None] * swiglu(p["shared"], x, cd)
+            else:
+                y = y + swiglu(p["shared"], x, cd)
     counters = {
         "expert_tokens": group_sizes,
         "absent_weight": jnp.where(held, 0.0, weights).sum() / (tokens * scale),

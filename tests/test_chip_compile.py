@@ -526,7 +526,7 @@ def test_latent_sparse_layer_two_rows_of_8k(one_chip, monkeypatch):
     shapes = jax.eval_shape(lambda: bb.init_params(cfg, 16160, SEQ_L, 0))
     block = jax.tree_util.tree_map(
         lambda s: _sds(one_chip, s.shape, s.dtype), shapes["mtp"]["block"])
-    layer = bb._layer_fn(cfg, True, None, "auto")
+    layer = bb._layer_fn(cfg, "full", None, "auto")
 
     def loss(blk, x, seg):
         y, counters, _ = layer(x, seg, bb.positions_of(seg), blk["norm_in"], blk["full"],
@@ -563,3 +563,55 @@ def test_qwen3next_step_two_rows_of_8k_fits_the_chip(one_chip, as_tpu):
     _report("qwen3next step", compiled)
     assert sequencerec._delta_rule_walk(cfg) == {"delta_rule_walk": "pallas"}
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- and at the shapes of train-lfm2-packed8k: LFM2-24B-A2B widths, gated short
+# convolutions beside grouped-query attention on heads of 64, 8 of 64 experts
+def test_short_conv_layer_two_rows_of_8k(one_chip):
+    """One sparse layer whose mixer is the gated short convolution, as the
+    step runs it (recomputed in the backward pass): three taps over 2,048
+    channels, router over 64, 8 held experts of 1,536 and no shared one."""
+    from predictionio_tpu.models import seq_backbone as bb
+
+    cfg = bb.BackboneConfig.load("lfm2-24b-a2b-ep8")
+    shapes = jax.eval_shape(lambda: bb.init_params(cfg, 8192, SEQ_L, 0))["periods"]
+    on_chip = lambda tree, *at: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _sds(one_chip, s.shape[len(at):], s.dtype), tree)
+    block = {"conv": on_chip(shapes["conv"], 0, 0),
+             **{name: on_chip(shapes[name], 0, 0) for name in ("norm_in", "norm_post", "ffn")}}
+    layer = bb._layer_fn(cfg, "conv", None, "auto")
+
+    def loss(blk, x, seg):
+        y, counters, ran = layer(x, seg, bb.positions_of(seg), blk["norm_in"], blk["conv"],
+                                 blk["norm_post"], blk["ffn"])
+        return y.sum(), (counters["dropped"], ran["y"])
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1), has_aux=True), block,
+        _sds(one_chip, (2, SEQ_L, 2048), jnp.float32), _sds(one_chip, (2, SEQ_L), jnp.int32))
+    stats = _report("short-convolution sparse layer", compiled)
+    assert "ragged" in compiled.as_text()  # the grouped products: the compiler's own kernel
+    assert stats.temp_size_in_bytes < 6 * 2**30
+
+
+def test_lfm2_step_two_rows_of_8k_fits_the_chip(one_chip):
+    """The whole optimizer step of ``train-lfm2-packed8k`` (2 rows of 8,193
+    slots, 469 M parameters with their AdamW moments, donated) as the job
+    compiles it: arguments 5.63 GB, temporaries 6.94 GB when this was
+    written (``PERF.md`` section 4)."""
+    from predictionio_tpu.models import seq_backbone as bb
+    from predictionio_tpu.models import sequencerec
+
+    cfg = bb.BackboneConfig.load("lfm2-24b-a2b-ep8")
+    opt_init, step, _ = sequencerec._programs(cfg, 3e-4, None, "auto")
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: bb.init_params(cfg, 8192, SEQ_L, 0)))
+    rows = _sds(one_chip, (2, SEQ_L + 1), jnp.int32)
+    try:
+        compiled = _compile(step, params, on_chip(jax.eval_shape(opt_init, params)), rows, rows)
+    finally:
+        step.clear_cache()  # the job's own program object, kept by ``_programs``
+    stats = _report("lfm2 step", compiled)
+    assert stats.argument_size_in_bytes + stats.temp_size_in_bytes < 15 * 2**30
+    assert cfg.mixers() == {"gqa": 1, "shortconv": 4}

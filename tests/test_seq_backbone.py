@@ -217,7 +217,7 @@ def test_the_walk_is_made_three_times_forward_and_once_backward(cfg, params):
     (``_layer_fn``: the layer is recomputed, and inside it each row): the
     layer's forward pass, its recomputation, the row's recomputation, and
     one walk in reverse. Nothing inside the rule is made again."""
-    layer = bb._layer_fn(cfg, False, None, "auto")
+    layer = bb._layer_fn(cfg, "linear", None, "auto")
     per = jax.tree_util.tree_map(lambda a: a[0], params["periods"])
     pick = lambda tree: jax.tree_util.tree_map(lambda a: a[0], tree)  # noqa: E731
     seg = jnp.ones((2, L), jnp.int32)
