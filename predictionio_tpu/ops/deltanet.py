@@ -8,7 +8,13 @@ Per head, with state ``S`` [dk, dv], zero at the start of every history::
 :func:`gated_delta_rule` computes that in chunks of ``chunk`` tokens, in
 three phases. The preparation: inside a chunk the ``u_t`` solve a unit
 lower-triangular system (:func:`tri_inv`, blocked forward substitution),
-which every chunk does at once. The walk (:func:`_walk`): only the state
+which every chunk does at once. It builds each of its arrays once and
+writes into no slice of one: a diagonal block takes row i of its inverse by
+a select over the whole block, which the compiler resolves into the rows
+computed apart and one write of the blocks, and the block rows below the
+diagonal are concatenated once (the chip pads the blocks' minor dimension
+of 16 to a lane tile of 128, and a row updated in place copied the whole
+padded array, fifteen times a call). The walk (:func:`_walk`): only the state
 goes from chunk to chunk, and the walk emits each chunk's incoming state
 and corrected values and nothing else. The outputs: two batch products
 over all chunks from what the walk emitted.
@@ -55,29 +61,30 @@ _HEADS = 8  # heads whose states share a grid step of the walk's kernel
 def _tri_inv_impl(a):
     """(I + a)^-1 for strictly lower-triangular ``a`` [..., C, C], float32.
     Diagonal blocks of 16 by forward substitution (row i of the inverse is
-    e_i - sum_j a_ij row_j), block rows below them by products."""
+    e_i - sum_j a_ij row_j), block rows below them by products. Nothing is
+    written into a slice of an array: a block takes its row by a select over
+    the whole block (the compiler computes the rows apart and writes the
+    blocks once), and the block rows are concatenated once."""
     c = a.shape[-1]
     b = _BLOCK if c % _BLOCK == 0 else c
     nb = c // b
-    lead = a.shape[:-2]
-    blocks = a.reshape(lead + (nb, b, nb, b))
+    blocks = a.reshape(a.shape[:-2] + (nb, b, nb, b))
     diag = jnp.stack([blocks[..., n, :, n, :] for n in range(nb)], axis=-3)  # [..., nb, b, b]
+    at = jax.lax.broadcasted_iota(jnp.int32, diag.shape, diag.ndim - 2)
     t = jnp.broadcast_to(jnp.eye(b, dtype=a.dtype), diag.shape)
     for i in range(1, b):
         row = -jnp.einsum("...j,...jk->...k", diag[..., i, :], t, precision=_HI)
-        t = t.at[..., i, :].add(row)
+        t = jnp.where(at == i, t + row[..., None, :], t)
     if nb == 1:
         return t.reshape(a.shape)
-    eye_nb = jnp.eye(nb, dtype=a.dtype)
-    # the inverse so far: block-diagonal; a without its diagonal blocks
-    full = jnp.einsum("...nij,nm->...nimj", t, eye_nb).reshape(a.shape)
-    off = (blocks * (1.0 - eye_nb)[:, None, :, None]).reshape(a.shape)
+    ahead = [(0, 0)] * (a.ndim - 1)  # a block row: its diagonal block, zeros right of it
+    full = [jnp.pad(t[..., 0, :, :], ahead + [(0, c - b)])]
     for n in range(1, nb):
-        rows = slice(n * b, (n + 1) * b)
-        below = jnp.einsum("...ij,...jk->...ik", off[..., rows, :], full, precision=_HI)
-        full = full.at[..., rows, :].add(
-            -jnp.einsum("...ij,...jk->...ik", t[..., n, :, :], below, precision=_HI))
-    return full
+        below = jnp.einsum("...ij,...jk->...ik", a[..., n * b:(n + 1) * b, :n * b],
+                           jnp.concatenate(full, axis=-2), precision=_HI)
+        left = -jnp.einsum("...ij,...jk->...ik", t[..., n, :, :], below, precision=_HI)
+        full.append(left + jnp.pad(t[..., n, :, :], ahead + [(n * b, c - (n + 1) * b)]))
+    return jnp.concatenate(full, axis=-2)
 
 
 @jax.custom_vjp
@@ -117,7 +124,8 @@ def _prepare(q, k, v, g, beta, seg, chunk, compute_dtype, gate_dtype):
         return jnp.moveaxis(a, (1, 3), (0, 2))
 
     f32, cd = jnp.float32, compute_dtype
-    qc, kc, vc = chunks(q.astype(f32)), chunks(k.astype(f32)), chunks(v.astype(f32))
+    with jax.named_scope("seq.deltanet.scan.prep.layout"):
+        qc, kc, vc = chunks(q.astype(f32)), chunks(k.astype(f32)), chunks(v.astype(f32))
     bc = chunks(beta.astype(f32))  # [N, B, H, C]
     gc = jnp.cumsum(chunks(g.astype(gate_dtype)), axis=-1)  # inclusive, per chunk
     sc = jnp.moveaxis(seg.reshape(bsz, n, chunk), 1, 0)  # [N, B, C]
@@ -134,14 +142,15 @@ def _prepare(q, k, v, g, beta, seg, chunk, compute_dtype, gate_dtype):
 
     kk = jnp.einsum("nbhid,nbhjd->nbhij", kc, kc, precision=_HI)
     a = bc[..., None] * kk * decay * jnp.tril(jnp.ones((chunk, chunk), f32), -1)
-    t = tri_inv(a)
+    with jax.named_scope("seq.deltanet.scan.prep.tri_inv"):
+        t = tri_inv(a)
     w = jnp.einsum("nbhij,nbhjd->nbhid", t, kc * (-bc * egc * carried)[..., None], precision=_HI)
     u = jnp.einsum("nbhij,nbhjd->nbhid", t, vc * bc[..., None], precision=_HI)
     attn = jnp.einsum("nbhid,nbhjd->nbhij", qc, kc, precision=_HI) * decay
     return (
         u, w.astype(cd), (qc * (egc * carried)[..., None]).astype(cd),
         (kc * (e_last * to_last)[..., None]).astype(cd), attn.astype(cd),
-        (egc[..., -1] * carried[..., -1]).astype(gate_dtype),
+        jnp.squeeze(egc[..., -1:] * carried[..., -1:], -1).astype(gate_dtype),
     )
 
 

@@ -18,6 +18,7 @@ back without one, and would warn on the next run).
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -414,9 +415,23 @@ def test_delta_rule_scan_row_of_8k(one_chip, request, walk, state):
         jax.grad(loss, argnums=(0, 1, 2, 3, 4)), qkv, qkv, qkv, gate, gate,
         _sds(one_chip, (1, SEQ_L), jnp.int32))
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+    text = compiled.as_text()
     # one walk forward and one in reverse, and no third
-    kernels = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    kernels = text.count('custom_call_target="tpu_custom_call"')
     assert kernels == (2 if walk == "pallas" else 0)
+    # the preparation builds each array once: a row of a 16 x 16 block
+    # written in place copied the whole lane-padded array (PR 35)
+    in_place = [line for line in text.splitlines()
+                if " dynamic-update-slice(" in line and "seq.deltanet.scan.prep" in line]
+    assert not in_place, in_place[:3]
+    # and the forward preparation writes the diagonal blocks once: the
+    # compiler computes a block's rows apart (a select over the whole block
+    # for every row would be fifteen passes over the padded array again)
+    entry = text[text.index("ENTRY "):]
+    blocks = [line for line in entry.splitlines()
+              if re.match(r"\s*(ROOT )?%\S+ = f32\[128,1,32,4,16,16\]", line)
+              and "seq.deltanet.scan.prep" in line and "transpose(jvp" not in line]
+    assert len(blocks) <= 2, len(blocks)
 
 
 @pytest.mark.parametrize("heads,kv_heads,head_dim", [(16, 2, 256), (4, 4, 64)])

@@ -240,16 +240,101 @@ def test_the_walk_is_made_three_times_forward_and_once_backward(cfg, params):
     assert scans == [False, True]
 
 
-def test_tri_inv_inverts_and_differentiates():
+def _tri_inv_in_place(a):
+    """``ops/deltanet._tri_inv_impl`` as it stood before PR 35, frozen: the
+    rows of a diagonal block and the block rows below the diagonal written
+    into the whole array one at a time. What the shipped form must equal."""
+    hi = jax.lax.Precision.HIGHEST
+    c = a.shape[-1]
+    b = 16 if c % 16 == 0 else c
+    nb = c // b
+    lead = a.shape[:-2]
+    blocks = a.reshape(lead + (nb, b, nb, b))
+    diag = jnp.stack([blocks[..., n, :, n, :] for n in range(nb)], axis=-3)
+    t = jnp.broadcast_to(jnp.eye(b, dtype=a.dtype), diag.shape)
+    for i in range(1, b):
+        row = -jnp.einsum("...j,...jk->...k", diag[..., i, :], t, precision=hi)
+        t = t.at[..., i, :].add(row)
+    if nb == 1:
+        return t.reshape(a.shape)
+    eye_nb = jnp.eye(nb, dtype=a.dtype)
+    full = jnp.einsum("...nij,nm->...nimj", t, eye_nb).reshape(a.shape)
+    off = (blocks * (1.0 - eye_nb)[:, None, :, None]).reshape(a.shape)
+    for n in range(1, nb):
+        rows = slice(n * b, (n + 1) * b)
+        below = jnp.einsum("...ij,...jk->...ik", off[..., rows, :], full, precision=hi)
+        full = full.at[..., rows, :].add(
+            -jnp.einsum("...ij,...jk->...ik", t[..., n, :, :], below, precision=hi))
+    return full
+
+
+# (leading shape, c): today's; the walk's [N, B, H] lead, four blocks; one
+# block; no multiple of 16 (one block of 24); eight blocks
+_TRI_CASES = [((3,), 64), ((4, 1, 8), 64), ((2,), 16), ((2,), 24), ((2,), 128)]
+
+
+@pytest.mark.parametrize("lead,c", _TRI_CASES, ids=[f"{'x'.join(map(str, s))}-{c}" for s, c in _TRI_CASES])
+def test_tri_inv_inverts_and_differentiates(lead, c):
     rng = np.random.default_rng(5)
-    a = np.tril(rng.normal(size=(3, 64, 64)), -1).astype(np.float32) * 0.3
+    a = np.tril(rng.normal(size=lead + (c, c)), -1).astype(np.float32) * 0.3
     t = deltanet.tri_inv(jnp.asarray(a))
-    want = np.linalg.inv(np.eye(64) + a.astype(np.float64))
+    want = np.linalg.inv(np.eye(c) + a.astype(np.float64))
     assert rel(t, want) < 1e-5
+    # the same arithmetic in the same order as the in-place form it replaced
+    assert np.array_equal(np.asarray(t), np.asarray(_tri_inv_in_place(jnp.asarray(a))))
     weight = rng.normal(size=a.shape).astype(np.float32)
     got = jax.grad(lambda x: (deltanet.tri_inv(x) * weight).sum())(jnp.asarray(a))
-    plain = jax.grad(lambda x: (jnp.linalg.inv(jnp.eye(64) + x) * weight).sum())(jnp.asarray(a))
+    plain = jax.grad(lambda x: (jnp.linalg.inv(jnp.eye(c) + x) * weight).sum())(jnp.asarray(a))
     assert rel(got, np.tril(np.asarray(plain), -1)) < 1e-4
+
+
+@pytest.mark.parametrize("chunk,case", [(16, dict(length=50)), (64, dict(length=150, heads=2, dk=128, dv=128))],
+                         ids=["one_block", "four_blocks"])
+def test_the_rule_is_what_it_was_with_the_in_place_inverse(chunk, case, monkeypatch):
+    """Output and gradients of the whole rule against the same rule with the
+    frozen in-place inverse."""
+    q, k, v, g, beta, seg = _rule_inputs(**case)
+    weight = np.random.default_rng(6).normal(size=v.shape).astype(np.float32)
+
+    def run():
+        deltanet.gated_delta_rule.clear_cache()
+        rule = lambda *a: deltanet.gated_delta_rule(*a, seg, chunk=chunk)  # noqa: E731
+        return rule(q, k, v, g, beta), jax.grad(
+            lambda *a: (rule(*a) * weight).sum(), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    o, grads = run()
+    monkeypatch.setattr(deltanet, "_tri_inv_impl", _tri_inv_in_place)
+    try:
+        o_was, grads_was = run()
+    finally:
+        deltanet.gated_delta_rule.clear_cache()
+    assert rel(o, o_was) < 1e-6
+    for got, was in zip(grads, grads_was):
+        assert rel(got, was) < 1e-5
+
+
+def test_the_preparation_updates_no_array_in_place():
+    """At the cell's widths (heads of 128, chunks of 64) the preparation,
+    forward and backward, holds no scatter and no ``dynamic_update_slice``:
+    on the chip the blocks' minor dimension of 16 pads to a lane tile of
+    128, and a row written in place copied the whole padded array, fifteen
+    times a call (PERF.md section 6, PR 35)."""
+    q, k, v, g, beta, seg = _rule_inputs(128, heads=2, dk=128, dv=128)
+
+    def prepare(q, k, v, g, beta):
+        return deltanet._prepare(q, k, v, g, beta, seg, 64, jnp.float32, jnp.float32)
+
+    def backward(*a):
+        return jax.grad(lambda *b: sum(x.astype(jnp.float32).sum() for x in prepare(*b)),
+                        argnums=(0, 1, 2, 3, 4))(*a)
+
+    for fn in (prepare, backward):
+        names = {name for name, _, _ in _primitives_of(fn, q, k, v, g, beta)}
+        assert not names & {"scatter", "scatter-add", "dynamic_update_slice"}, names
+    stacks = [stack for _, stack, _ in _primitives_of(
+        lambda *a: deltanet.gated_delta_rule(*a, seg, chunk=64), q, k, v, g, beta)]
+    for scope in ("seq.deltanet.scan.prep.tri_inv", "seq.deltanet.scan.prep.layout"):
+        assert any(scope in stack for stack in stacks), scope
 
 
 # -- packing ----------------------------------------------------------------
