@@ -19,9 +19,13 @@ layer (docs/observability.md#profiling):
   ``/metrics``; a live request's ambient trace context gets a
   ``jit.compile`` span so an unexpected compile is visible in
   ``pio trace`` timelines. ``attach_monitoring()`` additionally taps
-  ``jax.monitoring`` for backend-compile durations and persistent
-  compilation-cache hit/miss counts (wired in by
-  ``utils/jax_cache.enable_compilation_cache``).
+  ``jax.monitoring`` (wired in by
+  ``utils/jax_cache.enable_compilation_cache``) for backend-compile
+  durations, persistent compilation-cache hit/miss counts, and the
+  three phases that bring ANY program to the device, instrumented
+  boundary or bare ``jax.jit``: each is a ``jit.trace`` / ``jit.lower``
+  / ``jit.backend`` span under the ambient span and a total in
+  ``snapshot()["cache"]``.
 - :class:`PhaseProfiler` — ``utils/profiling.StepTimer`` grown device
   fences and roofline accounting: each phase records wall time, a
   fenced (``block_until_ready``) device-complete time, and optional
@@ -77,6 +81,53 @@ DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
 #: compile-duration samples kept per function for replay-on-bind and
 #: reports; compiles are rare, so a small cap loses nothing real
 _MAX_SAMPLES = 256
+
+#: ``jax.monitoring``'s names (``jax/_src/dispatch.py``) of the phases
+#: that bring a program to the device, by the ``jit.<phase>`` span each
+#: becomes. JAX raises each three times: a scalar carrying the start
+#: time when the phase begins, a duration and a time span when it ends,
+#: all on the thread that runs the phase, with the program's name as
+#: ``fun_name``.
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+
+#: A phase inside another one (tracing a step traces every jitted
+#: function it calls: 1,255 of them in the toy Qwen3-Next step, 88 of a
+#: millisecond or more) is a span only from here up; its seconds are the
+#: enclosing span's either way. What it encloses is shorter still, so no
+#: recorded span names a parent that was left out.
+_NESTED_FLOOR_S = 0.01
+
+
+class _OpenPhase:
+    """A phase between JAX's event at its start and the one at its end:
+    ``span`` is the open span where the phase is a real one (the
+    outermost on its thread, under an ambient span), ``ctx`` that span's
+    context or, for a phase inside another one, the context it is given
+    when something has to name it (:func:`_context_of`); ``tags`` are
+    read when the span is recorded."""
+
+    __slots__ = ("phase", "fn", "span", "ctx", "tags")
+
+    def __init__(self, phase, fn, span, ctx, tags):
+        self.phase, self.fn, self.span, self.ctx, self.tags = (
+            phase, fn, span, ctx, tags)
+
+
+def _context_of(stack: List[_OpenPhase], at: int):
+    """The span context of the open phase ``stack[at]``. A phase inside
+    another one gets its context only when it is asked for: when it is
+    recorded itself, or when a phase inside it is and has to name its
+    parent (a step's trace opens thousands that never are)."""
+    entry = stack[at]
+    if entry.ctx is None and at > 0:
+        outer = _context_of(stack, at - 1)
+        if outer is not None:
+            entry.ctx = outer.tracer.child_context(outer)
+    return entry.ctx
 
 
 def profiling_enabled(env: Optional[Dict[str, str]] = None) -> bool:
@@ -163,7 +214,23 @@ class JitTelemetry:
         self._cache_hits = 0
         self._cache_misses = 0
         self._backend_compiles = 0
+        self._backend_s = 0.0
+        #: the first ``_MAX_SAMPLES`` of them, for replay-on-bind
         self._backend_samples: List[float] = []
+        #: seconds by phase (``trace``, ``lower``: those that lie inside
+        #: no other phase on their thread, so each second counts once;
+        #: ``retrieval``: reading a program back from the persistent
+        #: cache), the newest samples of each for replay-on-bind, and
+        #: the compile seconds the cache's hits saved
+        self._phase_s: Dict[str, float] = {
+            "trace": 0.0, "lower": 0.0, "retrieval": 0.0,
+        }
+        self._phase_samples: Dict[str, List[float]] = {
+            phase: [] for phase in self._phase_s
+        }
+        self._saved_s = 0.0
+        #: the :class:`_OpenPhase` stack of each thread, innermost last
+        self._open = threading.local()
         self._bound: List[weakref.ref] = []
         self._monitoring = False
 
@@ -243,10 +310,14 @@ class JitTelemetry:
 
     # -- jax.monitoring taps ----------------------------------------------
     def attach_monitoring(self) -> bool:
-        """Tap ``jax.monitoring`` for backend-compile durations and
-        persistent compilation-cache hit/miss events. Idempotent,
-        best-effort (False when jax is unavailable); listeners are
-        process-global and registered at most once."""
+        """Tap ``jax.monitoring`` for what it tells of a program's way to
+        the device: tracing, lowering and the backend's compile (or, on
+        a hit in the persistent cache, the retrieval) of every program
+        as ``jit.trace`` / ``jit.lower`` / ``jit.backend`` spans and as
+        totals, backend-compile durations, and the persistent cache's
+        hit/miss events. Idempotent, best-effort (False when jax is
+        unavailable); listeners are process-global and registered at
+        most once."""
         with self._lock:
             if self._monitoring:
                 return True
@@ -260,35 +331,134 @@ class JitTelemetry:
 
         def on_event(name: str, **kwargs) -> None:
             if name.endswith("/cache_hits"):
-                with self._lock:
-                    self._cache_hits += 1
+                self._cache_event("hit")
             elif name.endswith("/cache_misses"):
-                with self._lock:
-                    self._cache_misses += 1
+                self._cache_event("miss")
 
         def on_duration(name: str, duration: float, **kwargs) -> None:
-            if not name.endswith("backend_compile_duration"):
-                return
-            with self._lock:
-                self._backend_compiles += 1
-                if len(self._backend_samples) < _MAX_SAMPLES:
-                    self._backend_samples.append(float(duration))
-                bound = self._live_registries()
-            for registry in bound:
-                self._instruments(registry)["backend_s"].observe(duration)
+            if name.endswith("backend_compile_duration"):
+                with self._lock:
+                    self._backend_compiles += 1
+                    self._backend_s += float(duration)
+                    if len(self._backend_samples) < _MAX_SAMPLES:
+                        self._backend_samples.append(float(duration))
+                    bound = self._live_registries()
+                for registry in bound:
+                    self._instruments(registry)["backend_s"].observe(duration)
+            elif name.endswith("/cache_retrieval_time_sec"):
+                self._add_phase_seconds("retrieval", duration)
+            elif name.endswith("/compile_time_saved_sec"):
+                with self._lock:
+                    self._saved_s += float(duration)
+
+        def on_phase_start(name: str, value: float, **kwargs) -> None:
+            phase = _PHASES.get(name)
+            if phase is not None:
+                self._phase_started(phase, str(kwargs.get("fun_name", "")))
+
+        def on_phase_end(name: str, start: float, end: float, **kwargs) -> None:
+            phase = _PHASES.get(name)
+            if phase is not None:
+                self._phase_ended(
+                    phase, str(kwargs.get("fun_name", "")), start, end
+                )
 
         try:
             monitoring.register_event_listener(on_event)
             monitoring.register_event_duration_secs_listener(on_duration)
+            monitoring.register_scalar_listener(on_phase_start)
+            monitoring.register_event_time_span_listener(on_phase_end)
         except Exception:
-            # un-latch so a later call may retry; a half-registered pair
-            # (first succeeded, second raised) at worst re-registers the
-            # event listener, double-counting being the lesser evil than
-            # a silently-dead tap for the process lifetime
+            # un-latch so a later call may retry; a half-registered set
+            # (the first succeeded, a later one raised) at worst
+            # re-registers a listener, double-counting being the lesser
+            # evil than a silently-dead tap for the process lifetime
             with self._lock:
                 self._monitoring = False
             return False
         return True
+
+    def _stack(self) -> list:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
+    def _cache_event(self, outcome: str) -> None:
+        """A hit or a miss of the persistent cache: JAX raises it on the
+        compiling thread inside the backend phase, whose span it tags."""
+        with self._lock:
+            if outcome == "hit":
+                self._cache_hits += 1
+            else:
+                self._cache_misses += 1
+        stack = self._stack()
+        if stack and stack[-1].phase == "backend":
+            stack[-1].tags["cache"] = outcome
+
+    def _add_phase_seconds(self, phase: str, seconds: float) -> None:
+        seconds = float(seconds)
+        with self._lock:
+            self._phase_s[phase] += seconds
+            samples = self._phase_samples[phase]
+            if len(samples) < _MAX_SAMPLES:
+                samples.append(seconds)
+            bound = self._live_registries()
+        for registry in bound:
+            self._instruments(registry)["phase_s"].observe(seconds, phase=phase)
+
+    def _phase_started(self, phase: str, fn: str) -> None:
+        """A phase begins on this thread. The outermost one under an
+        ambient span is a real span from here to :meth:`_phase_ended`
+        (a profiler session holds it as ``pio.jit.<phase>``, on its own
+        clock); one inside another phase, a function traced while
+        another is, is only noted here and recorded when it ends, if it
+        lasted. Under no ambient span only the totals grow:
+        ``jit.compile``'s rule."""
+        stack = self._stack()
+        opened = ctx = None
+        # until the cache says otherwise
+        tags = {"fn": fn, "cache": "off"} if phase == "backend" else None
+        if not stack:
+            try:
+                ambient = current_context()
+                if ambient is not None:
+                    tags = tags or {"fn": fn}
+                    opened = ambient.tracer.span("jit." + phase, tags=tags)
+                    ctx = opened.__enter__()
+            except Exception:
+                opened = ctx = None  # telemetry must never fail the traced call
+        stack.append(_OpenPhase(phase, fn, opened, ctx, tags))
+
+    def _phase_ended(self, phase: str, fn: str, start: float, end: float) -> None:
+        stack = self._stack()
+        # the phase that ends is the innermost open one; a start whose
+        # end never came (the listeners were cleared meanwhile) is
+        # dropped on the way, and an end without its start (the tap
+        # attached from another thread meanwhile) counts without a span
+        at = len(stack) - 1
+        while at >= 0 and (stack[at].phase != phase or stack[at].fn != fn):
+            at -= 1
+        if at >= 0:
+            ended = stack[at]
+            try:
+                if ended.span is not None:
+                    ended.span.__exit__(None, None, None)
+                elif at > 0 and end - start >= _NESTED_FLOOR_S:
+                    ctx = _context_of(stack, at)
+                    if ctx is not None:
+                        # JAX's own time span, on ``time.time()``: the
+                        # clock ``Tracer.wall`` stamps ``startMs`` with
+                        ctx.tracer.record(
+                            "jit." + phase, ctx, stack[at - 1].ctx.span_id,
+                            start_wall=start, duration_s=end - start,
+                            tags=ended.tags or {"fn": fn},
+                        )
+            except Exception:
+                pass
+            del stack[at:]
+        if phase != "backend" and not stack:
+            self._add_phase_seconds(phase, end - start)
 
     # -- registry mirroring ------------------------------------------------
     def _instruments(self, registry: MetricsRegistry) -> dict:
@@ -315,6 +485,13 @@ class JitTelemetry:
                 "pio_jit_backend_compile_seconds",
                 "XLA backend compile durations (jax.monitoring, whole "
                 "process)",
+            ),
+            "phase_s": registry.histogram(
+                "pio_jit_phase_seconds",
+                "Seconds of jaxpr tracing, lowering to MLIR and "
+                "retrieval from the persistent cache, one observation "
+                "per program (jax.monitoring, whole process)",
+                labelnames=("phase",),
             ),
         }
 
@@ -344,6 +521,10 @@ class JitTelemetry:
                 for name, st in self._fns.items()
             }
             backend = list(self._backend_samples)
+            phases = {
+                phase: list(samples)
+                for phase, samples in self._phase_samples.items()
+            }
         inst = self._instruments(registry)
         for name, (compiles, retraces, samples) in fns.items():
             if compiles:
@@ -354,6 +535,9 @@ class JitTelemetry:
                 inst["compile_s"].observe(seconds, fn=name)
         for seconds in backend:
             inst["backend_s"].observe(seconds)
+        for phase, samples in phases.items():
+            for seconds in samples:
+                inst["phase_s"].observe(seconds, phase=phase)
         registry.gauge_callback(
             "pio_jit_cache_hits",
             self._hits_locked,
@@ -377,7 +561,12 @@ class JitTelemetry:
     def snapshot(self) -> dict:
         """Current totals, JSON-safe: ``{"fns": {name: {compiles,
         retraces, compile_s}}, "cache": {hits, misses, backend_compiles,
-        backend_compile_s}}``."""
+        backend_compile_s, trace_s, lower_s, retrieval_s,
+        compile_time_saved_s}}``. ``trace_s`` and ``lower_s`` count the
+        phases that lie inside no other one on their thread (a function
+        traced inside another's trace is part of that one's seconds);
+        ``retrieval_s`` lies inside ``backend_compile_s``, which on a
+        hit of the persistent cache is the retrieval."""
         with self._lock:
             return {
                 "fns": {
@@ -392,9 +581,11 @@ class JitTelemetry:
                     "hits": self._cache_hits,
                     "misses": self._cache_misses,
                     "backend_compiles": self._backend_compiles,
-                    "backend_compile_s": round(
-                        sum(self._backend_samples), 4
-                    ),
+                    "backend_compile_s": round(self._backend_s, 4),
+                    "trace_s": round(self._phase_s["trace"], 4),
+                    "lower_s": round(self._phase_s["lower"], 4),
+                    "retrieval_s": round(self._phase_s["retrieval"], 4),
+                    "compile_time_saved_s": round(self._saved_s, 4),
                 },
             }
 
@@ -681,6 +872,12 @@ def render_profile_report(
             f"misses={cache.get('misses', 0):.0f} "
             f"backend_compiles={cache.get('backend_compiles', 0):.0f} "
             f"backend_compile_s={cache.get('backend_compile_s', 0.0):.3f}"
+            # where the tap's totals came along (not from a scrape)
+            + "".join(
+                f" {key}={cache[key]:.3f}"
+                for key in ("trace_s", "lower_s", "retrieval_s")
+                if key in cache
+            )
         )
     if not phases and not jit and cache is None:
         lines.append("(no profile data)")

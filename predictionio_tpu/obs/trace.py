@@ -137,6 +137,11 @@ class SpanStore:
         self._lock = threading.Lock()
         self._spans: deque = deque(maxlen=capacity)
 
+    @property
+    def capacity(self) -> int:
+        """Spans kept; a store this long has begun to forget."""
+        return self._spans.maxlen
+
     def add(self, span: dict) -> None:
         with self._lock:
             self._spans.append(span)

@@ -176,6 +176,241 @@ class TestJitTelemetry:
         assert "retraces" in delta["fns"]["g"]
 
 
+# -- the phases of a program's way to the device ----------------------------
+
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND = "/jax/core/compile/backend_compile_duration"
+CACHE_KEYS = {
+    "hits", "misses", "backend_compiles", "backend_compile_s",
+    "trace_s", "lower_s", "retrieval_s", "compile_time_saved_s",
+}
+
+
+class _Phase:
+    """What JAX's ``dispatch.log_elapsed_time`` raises around a phase, on
+    times of the test's choosing: a scalar carrying the start when it
+    begins; a duration and a time span when it ends."""
+
+    def __init__(self, event, fn, start, end):
+        self.event, self.fn, self.start, self.end = event, fn, start, end
+
+    def __enter__(self):
+        from jax import monitoring
+
+        monitoring.record_scalar(self.event, self.start, fun_name=self.fn)
+
+    def __exit__(self, *exc):
+        from jax import monitoring
+
+        monitoring.record_event_duration_secs(
+            self.event, self.end - self.start, fun_name=self.fn)
+        monitoring.record_event_time_span(
+            self.event, self.start, self.end, fun_name=self.fn)
+
+
+@pytest.fixture
+def tap():
+    """The process's telemetry with its jax.monitoring tap on, a tracer
+    on a clock that stands still, and what the test's phases changed."""
+    from predictionio_tpu.obs.profile import default_telemetry
+    from predictionio_tpu.obs.trace import Tracer
+
+    tel = default_telemetry()
+    assert tel.attach_monitoring() is True
+    tracer = Tracer("test", clock=lambda: 0.0, wall=lambda: 50.0)
+    before = tel.snapshot()
+    return tel, tracer, lambda: tel.delta_since(before)["cache"]
+
+
+class TestJitPhaseTotals:
+    def test_snapshot_and_delta_keep_every_key(self):
+        tel = JitTelemetry(clock=lambda: 0.0)
+        snap = tel.snapshot()
+        assert set(snap) == {"fns", "cache"}
+        assert set(snap["cache"]) == CACHE_KEYS
+        assert all(value == 0 for value in snap["cache"].values())
+        assert tel.delta_since(snap)["cache"] == snap["cache"]
+
+    def test_report_line_carries_the_totals_where_it_is_given_them(self):
+        cache = {**JitTelemetry().snapshot()["cache"], "trace_s": 7.6, "lower_s": 1.25}
+        line = render_profile_report("t", cache=cache).splitlines()[-1]
+        assert line.startswith("compilation cache: hits=0 misses=0")
+        assert line.endswith("trace_s=7.600 lower_s=1.250 retrieval_s=0.000")
+        # a scrape of /metrics brings the four older keys alone
+        scraped = {k: cache[k] for k in ("hits", "misses", "backend_compiles", "backend_compile_s")}
+        assert render_profile_report("t", cache=scraped).splitlines()[-1].endswith(
+            "backend_compile_s=0.000")
+
+    @pytest.mark.parametrize(
+        "inner_s,recorded", [(0.5, True), (0.004, False)],
+        ids=["long-nested-trace-is-a-child", "short-nested-trace-is-left-out"])
+    def test_nested_trace_counts_once(self, tap, inner_s, recorded):
+        from jax import monitoring
+
+        tel, tracer, delta = tap
+        with tracer.span("seqrec.step") as step:
+            with _Phase(TRACE, "step", 100.0, 103.0):
+                with _Phase(TRACE, "gated_delta_rule", 101.0, 101.0 + inner_s):
+                    with _Phase(TRACE, "_where", 101.0, 101.0 + inner_s / 2):
+                        pass
+            with _Phase(LOWER, "jit(step)", 103.0, 104.0):
+                pass
+            with _Phase(BACKEND, "jit(step)", 104.0, 110.0):
+                monitoring.record_event("/jax/compilation_cache/cache_misses")
+        got = delta()
+        assert got == {
+            "hits": 0, "misses": 1, "backend_compiles": 1,
+            "backend_compile_s": 6.0, "trace_s": 3.0, "lower_s": 1.0,
+            "retrieval_s": 0.0, "compile_time_saved_s": 0.0,
+        }
+        spans = tracer.store.for_trace(step.trace_id)
+        by_fn = {(s["name"], s["tags"]["fn"]): s for s in spans if "tags" in s}
+        top = [by_fn[key] for key in (
+            ("jit.trace", "step"), ("jit.lower", "jit(step)"),
+            ("jit.backend", "jit(step)"))]
+        assert all(s["parentId"] == step.span_id for s in top)
+        assert top[2]["tags"] == {"fn": "jit(step)", "cache": "miss"}
+        nested = [s for s in spans if s["name"] == "jit.trace" and s not in top]
+        if not recorded:
+            assert nested == []
+            return
+        inner, innermost = (
+            by_fn["jit.trace", "gated_delta_rule"], by_fn["jit.trace", "_where"])
+        assert sorted(nested, key=id) == sorted([inner, innermost], key=id)
+        assert inner["parentId"] == top[0]["spanId"]
+        assert innermost["parentId"] == inner["spanId"]
+        # JAX's own time span, on the clock ``startMs`` is stamped with
+        assert (inner["startMs"], inner["durationMs"]) == (101000.0, 500.0)
+        assert (innermost["startMs"], innermost["durationMs"]) == (101000.0, 250.0)
+
+    def test_cache_hit_carries_retrieval_and_saved_seconds(self, tap):
+        from jax import monitoring
+
+        tel, tracer, delta = tap
+        with tracer.span("als.enqueue") as enqueue:
+            with _Phase(BACKEND, "jit(_als_iteration_body)", 10.0, 14.0):
+                monitoring.record_event("/jax/compilation_cache/cache_hits")
+                monitoring.record_event_duration_secs(
+                    "/jax/compilation_cache/compile_time_saved_sec", 23.5)
+                monitoring.record_event_duration_secs(
+                    "/jax/compilation_cache/cache_retrieval_time_sec", 3.75)
+        got = delta()
+        assert (got["hits"], got["misses"], got["backend_compiles"]) == (1, 0, 1)
+        assert got["retrieval_s"] == 3.75 and got["compile_time_saved_s"] == 23.5
+        assert got["backend_compile_s"] == 4.0
+        assert got["trace_s"] == got["lower_s"] == 0.0
+        (backend,) = [s for s in tracer.store.for_trace(enqueue.trace_id)
+                      if s["name"] == "jit.backend"]
+        assert backend["tags"]["cache"] == "hit"
+
+    def test_backend_seconds_keep_counting_past_the_replay_samples(self, tap):
+        """The samples kept for a registry bound later are capped; the
+        total is not (it stood still after 256 compiles before PR 36,
+        which a server or a test process passes in minutes)."""
+        from predictionio_tpu.obs import profile
+
+        tel, tracer, delta = tap
+        for _ in range(profile._MAX_SAMPLES + 3):
+            with _Phase(BACKEND, "jit(op)", 0.0, 0.5):
+                pass
+        got = delta()
+        assert got["backend_compiles"] == profile._MAX_SAMPLES + 3
+        assert got["backend_compile_s"] == 0.5 * (profile._MAX_SAMPLES + 3)
+
+    def test_a_backend_phase_no_cache_event_reaches_says_off(self, tap):
+        tel, tracer, delta = tap
+        with tracer.span("predict.dispatch") as dispatch:
+            with _Phase(BACKEND, "jit(top_k)", 1.0, 2.0):
+                pass
+        (backend,) = [s for s in tracer.store.for_trace(dispatch.trace_id)
+                      if s["name"] == "jit.backend"]
+        assert backend["tags"] == {"fn": "jit(top_k)", "cache": "off"}
+        assert delta()["backend_compiles"] == 1
+
+    def test_every_second_counts_once(self, tap):
+        """A phase inside another kind of phase (lowering a rule that
+        traces a jitted helper; an eager operation while tracing) is in
+        the enclosing phase's total alone."""
+        tel, tracer, delta = tap
+        with _Phase(LOWER, "jit(step)", 0.0, 4.0):
+            with _Phase(TRACE, "helper", 1.0, 2.0):
+                pass
+        with _Phase(TRACE, "step", 4.0, 9.0):
+            with _Phase(LOWER, "jit(eager)", 5.0, 6.0):
+                pass
+            with _Phase(BACKEND, "jit(eager)", 6.0, 8.0):
+                pass
+        got = delta()
+        assert (got["lower_s"], got["trace_s"]) == (4.0, 5.0)
+        # every backend compile counts, as it always did
+        assert (got["backend_compiles"], got["backend_compile_s"]) == (1, 2.0)
+        assert tracer.store.dump() == []  # under no span nothing is stored
+
+    def test_an_end_without_its_start_counts_without_a_span(self, tap):
+        from jax import monitoring
+
+        tel, tracer, delta = tap
+        with tracer.span("train") as root:
+            # the tap attached while another thread's phase was open
+            monitoring.record_event_time_span(TRACE, 1.0, 3.0, fun_name="late")
+            # and a start whose end never came: dropped when the phase
+            # around it ends, which still counts
+            with _Phase(TRACE, "outer", 10.0, 12.0):
+                monitoring.record_scalar(TRACE, 11.0, fun_name="cut_short")
+        assert delta()["trace_s"] == 4.0
+        names = [s["tags"]["fn"] for s in tracer.store.for_trace(root.trace_id)
+                 if s["name"] == "jit.trace"]
+        assert names == ["outer"]
+        with _Phase(TRACE, "next", 20.0, 21.0):  # nothing is left open
+            pass
+        assert delta()["trace_s"] == 5.0
+
+    def test_a_failing_tracer_never_fails_the_phase(self, tap, monkeypatch):
+        tel, tracer, delta = tap
+
+        def broken(*args, **kwargs):
+            raise RuntimeError("store is gone")
+
+        with tracer.span("train"):
+            monkeypatch.setattr(tracer, "span", broken)
+            monkeypatch.setattr(tracer, "record", broken)
+            with _Phase(TRACE, "step", 0.0, 2.0):
+                with _Phase(TRACE, "inner", 0.5, 1.5):
+                    pass
+            monkeypatch.undo()
+        assert delta()["trace_s"] == 2.0
+
+    def test_phase_histogram_mirrors_and_replays(self, tap):
+        tel, tracer, delta = tap
+        live = MetricsRegistry()
+        tel.bind(live)
+        before = expo.parse_text(expo.render(live))
+
+        def count(parsed, phase):
+            return sum(
+                value for labels, value in parsed.get("pio_jit_phase_seconds_count", [])
+                if labels.get("phase") == phase)
+
+        from jax import monitoring
+
+        with _Phase(TRACE, "f", 0.0, 1.0):
+            pass
+        with _Phase(LOWER, "jit(f)", 1.0, 1.5):
+            pass
+        monitoring.record_event_duration_secs(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+        after = expo.parse_text(expo.render(live))
+        for phase in ("trace", "lower", "retrieval"):
+            assert count(after, phase) == count(before, phase) + 1, phase
+        # a registry bound later is told what happened before it was
+        late = MetricsRegistry()
+        tel.bind(late)
+        replayed = expo.parse_text(expo.render(late))
+        for phase in ("trace", "lower", "retrieval"):
+            assert count(replayed, phase) >= 1, phase
+
+
 # ---------------------------------------------------------------------------
 # 2. Phase profiling
 # ---------------------------------------------------------------------------

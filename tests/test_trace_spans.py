@@ -26,6 +26,9 @@ from predictionio_tpu.obs.trace import (
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: what the jit telemetry's jax.monitoring tap leaves of a program's way
+#: to the device
+JIT_PHASES = ("jit.trace", "jit.lower", "jit.backend")
 
 
 def _host_spans_only():
@@ -68,14 +71,23 @@ class TestTwoSinks:
         finally:
             jax.profiler.stop_trace()
 
-        events = {name: (s, e) for name, s, e in _host_events(str(tmp_path))}
+        # (whatever ``jnp.ones`` brought to the device here for the
+        # first time left its phases under ``inner``, in a process that
+        # taps jax.monitoring: TestJitPhases)
+        events = {
+            name: (s, e) for name, s, e in _host_events(str(tmp_path))
+            if not name.startswith("pio.jit.")
+        }
         assert set(events) == {
             "pio.outer side=user b=64", "pio.inner", "pio.GET /x",
         }
         outer, inner = events["pio.outer side=user b=64"], events["pio.inner"]
         assert outer[0] <= inner[0] and inner[1] <= outer[1]  # nested
 
-        stored = {s["name"]: s for s in tracer.store.dump()}
+        stored = {
+            s["name"]: s for s in tracer.store.dump()
+            if s["name"] not in JIT_PHASES
+        }
         assert set(stored) == {"outer", "inner", "GET /x"}
         assert stored["inner"]["parentId"] == stored["outer"]["spanId"]
         assert stored["inner"]["traceId"] == stored["outer"]["traceId"]
@@ -185,12 +197,21 @@ class TestTrainingSpans:
         spans = default_tracer().store.for_trace(root["traceId"])
         assert [s for s in spans if s["name"] == "train"] == [root]
         children = [s for s in spans if s["parentId"] == root["spanId"]]
-        # below the children only what JitTelemetry already recorded: a
-        # ``jit.compile`` under the enqueue that compiled
+        # below the children only what JitTelemetry records: a
+        # ``jit.compile`` under the enqueue that compiled and, in a
+        # process that taps jax.monitoring, each program's phases under
+        # the span that brought it to the device, or under the phase
+        # that encloses them
         enqueues = {s["spanId"] for s in children if s["name"] == "als.enqueue"}
+        by_id = {s["spanId"]: s for s in spans}
         for s in spans:
             if s is not root and s not in children:
-                assert s["name"] == "jit.compile" and s["parentId"] in enqueues
+                assert s["name"] in ("jit.compile",) + JIT_PHASES, s
+                if s["name"] == "jit.compile":
+                    assert s["parentId"] in enqueues
+                else:
+                    above = by_id[s["parentId"]]
+                    assert above in children or above["name"] in JIT_PHASES
 
         def tagged(name, key):
             return sorted(
@@ -388,8 +409,286 @@ class TestTrainingSpans:
                 "predict.dispatch", "predict.fetch", "predict.results",
             ]
         # one span per batch each, nothing per request
-        per_batch = [s for s in spans if s["name"] != "jit.compile"]
+        per_batch = [s for s in spans if not s["name"].startswith("jit.")]
         assert len(per_batch) == 4 * len(executes)
+
+
+@pytest.fixture
+def tapped(tmp_path, monkeypatch):
+    """The process's jit telemetry with its jax.monitoring tap on and a
+    persistent compile cache of this test's own, so the first compile of
+    a program is a miss and the next one after ``jax.clear_caches()`` a
+    hit, whatever ran in the process before."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from predictionio_tpu.obs.profile import default_telemetry
+    from predictionio_tpu.utils.jax_cache import enable_compilation_cache
+
+    names = (
+        "jax_enable_compilation_cache",
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes",
+    )
+    before = {name: getattr(jax.config, name) for name in names}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+    try:
+        assert enable_compilation_cache() == str(tmp_path / "cache")
+        yield default_telemetry()
+    finally:
+        for name, value in before.items():
+            jax.config.update(name, value)
+        cc.reset_cache()
+
+
+def _phases_of(trace_id):
+    return [
+        s for s in default_tracer().store.for_trace(trace_id)
+        if s["name"] in JIT_PHASES
+    ]
+
+
+def _inside(span_, outer, slack_ms=1.0):
+    return (
+        outer["startMs"] - slack_ms <= span_["startMs"]
+        and span_["startMs"] + span_["durationMs"]
+        <= outer["startMs"] + outer["durationMs"] + slack_ms
+    )
+
+
+class TestJitPhases:
+    """A program's way to the device as spans: ``JitTelemetry``'s
+    jax.monitoring tap (docs/observability.md, "Spans and scopes")."""
+
+    def test_first_call_then_cache_hit_then_nothing(self, tapped):
+        @jax.jit
+        def fresh(x):
+            return jnp.tanh(x @ x).sum()
+
+        x = np.ones((16, 16), np.float32)  # no program of its own
+        before = tapped.snapshot()
+        with span("train") as root:
+            with span("seqrec.step", {"i": 0}) as step:
+                fresh(x)
+        spans = {s["spanId"]: s for s in default_tracer().store.for_trace(root.trace_id)}
+        # the innermost ambient span is the parent, and holds them (a
+        # function of jax.numpy's that took long to trace inside
+        # ``fresh``, as one may in a young process, is below its trace)
+        phases = [s for s in spans.values() if s["parentId"] == step.span_id]
+        assert [s["name"] for s in phases] == list(JIT_PHASES)
+        for s in spans.values():
+            if s["name"] in JIT_PHASES:
+                assert s in phases or spans[s["parentId"]]["name"] == "jit.trace"
+                assert _inside(s, spans[step.span_id])
+        assert [s["tags"]["fn"] for s in phases] == ["fresh", "jit(fresh)", "jit(fresh)"]
+        assert [s["tags"].get("cache") for s in phases] == [None, None, "miss"]
+        first = tapped.delta_since(before)["cache"]
+        assert (first["misses"], first["hits"], first["backend_compiles"]) == (1, 0, 1)
+        assert first["trace_s"] > 0 and first["lower_s"] > 0
+        assert first["retrieval_s"] == 0 and first["backend_compile_s"] > 0
+
+        # the process forgets the program, the persistent cache does not
+        jax.clear_caches()
+        before = tapped.snapshot()
+        with span("train") as again:
+            fresh(x)
+        phases = [s for s in _phases_of(again.trace_id)
+                  if s["parentId"] == again.span_id]
+        assert [s["name"] for s in phases] == list(JIT_PHASES)
+        assert phases[-1]["tags"] == {"fn": "jit(fresh)", "cache": "hit"}
+        second = tapped.delta_since(before)["cache"]
+        assert (second["misses"], second["hits"], second["backend_compiles"]) == (0, 1, 1)
+        assert second["retrieval_s"] > 0 and second["trace_s"] > 0
+        # what a hit costs is the retrieval, and the backend phase holds it
+        assert second["retrieval_s"] <= second["backend_compile_s"] + 1e-3
+
+        # a program the process holds comes with no phase at all
+        before = tapped.snapshot()
+        with span("train") as third:
+            fresh(x)
+        assert _phases_of(third.trace_id) == []
+        assert tapped.delta_since(before)["cache"] == {
+            key: 0 for key in second
+        }
+
+    def test_a_trace_inside_a_trace_is_its_child(self, tapped, monkeypatch):
+        from predictionio_tpu.obs import profile
+
+        monkeypatch.setattr(profile, "_NESTED_FLOOR_S", 0.0)
+
+        @jax.jit
+        def inner_fn(x):
+            return jnp.sin(x) * 2.0
+
+        @jax.jit
+        def outer_fn(x):
+            return inner_fn(x).sum() + inner_fn(x + 1.0).sum()
+
+        before = tapped.snapshot()
+        with span("train") as root:
+            outer_fn(np.ones((4, 4), np.float32))
+        phases = _phases_of(root.trace_id)
+        top = [s for s in phases if s["parentId"] == root.span_id]
+        assert [(s["name"], s["tags"]["fn"]) for s in top] == [
+            ("jit.trace", "outer_fn"), ("jit.lower", "jit(outer_fn)"),
+            ("jit.backend", "jit(outer_fn)"),
+        ]
+        outer = top[0]
+        by_id = {s["spanId"]: s for s in phases}
+        nested = [s for s in phases if s not in top]
+        # ``inner_fn`` is traced inside the outer trace's interval and as
+        # its child; whatever jax.numpy traced inside either is below
+        # them, never beside
+        inner = [s for s in nested if s["tags"]["fn"] == "inner_fn"]
+        assert inner
+        for s in inner:
+            assert s["name"] == "jit.trace" and s["parentId"] == outer["spanId"]
+            assert _inside(s, outer)
+        for s in nested:
+            assert s["name"] == "jit.trace" and by_id[s["parentId"]]["name"] == "jit.trace"
+        # the total counts the outer trace alone: what lies inside it is
+        # part of its seconds (JAX's own time span against the tracer's
+        # clock: the same interval taken twice, microseconds apart)
+        traced = tapped.delta_since(before)["cache"]["trace_s"]
+        assert traced == pytest.approx(outer["durationMs"] / 1e3, abs=2e-3)
+        assert sum(s["durationMs"] for s in nested) > 0
+
+    def test_short_nested_traces_are_left_out_of_the_store(self, tapped):
+        """Tracing a step traces every jitted function it calls: the
+        ring of 2,048 is for the jobs, so a phase inside another one is
+        a span only from ``_NESTED_FLOOR_S`` up."""
+        @jax.jit
+        def small(x):
+            return x + 1.0
+
+        @jax.jit
+        def calls_small(x):
+            return small(x) * small(x * 2.0)
+
+        with span("train") as root:
+            calls_small(np.ones(3, np.float32))
+        phases = _phases_of(root.trace_id)
+        assert [s["name"] for s in phases
+                if s["parentId"] == root.span_id] == list(JIT_PHASES)
+        from predictionio_tpu.obs.profile import _NESTED_FLOOR_S
+
+        for s in phases:
+            if s["parentId"] != root.span_id:
+                assert s["durationMs"] >= _NESTED_FLOOR_S * 1e3
+
+    def test_under_no_span_only_the_totals_grow(self, tapped):
+        @jax.jit
+        def unseen(x):
+            return jnp.cos(x).sum()
+
+        assert current_context() is None
+        stored = len(default_tracer().store.dump())
+        before = tapped.snapshot()
+        unseen(np.ones(5, np.float32))
+        assert len(default_tracer().store.dump()) == stored
+        delta = tapped.delta_since(before)["cache"]
+        assert delta["backend_compiles"] == 1 and delta["misses"] == 1
+        assert delta["trace_s"] > 0 and delta["lower_s"] > 0
+
+    def test_phase_spans_lie_in_the_profilers_trace(self, tapped, tmp_path):
+        """The outermost phases are real spans, entered when JAX says the
+        phase begins: a profiler session holds them as ``pio.jit.<phase>``
+        inside the span they were opened under, on its own clock."""
+        @jax.jit
+        def profiled(x):
+            return jnp.exp(x).sum()
+
+        jax.profiler.start_trace(
+            str(tmp_path / "profile"), profiler_options=_host_spans_only())
+        try:
+            with span("seqrec.init"):
+                profiled(np.ones(7, np.float32))
+        finally:
+            jax.profiler.stop_trace()
+        events = {
+            name: (s, e) for name, s, e in _host_events(str(tmp_path / "profile"))
+        }
+        outer = events["pio.seqrec.init"]
+        for name in ("pio.jit.trace fn=profiled", "pio.jit.lower fn=jit(profiled)",
+                     "pio.jit.backend fn=jit(profiled) cache=off"):
+            assert outer[0] <= events[name][0] and events[name][1] <= outer[1], name
+
+    def test_wrapped_call_keeps_jit_compile_around_its_phases(self, tapped):
+        """``jit.compile`` is the whole first call of a function that goes
+        through ``JitTelemetry.call``; the phases of the same program lie
+        inside its interval, under the same parent."""
+        @jax.jit
+        def solve(x):
+            return jnp.linalg.inv(x + jnp.eye(3)).sum()
+
+        with span("als.enqueue") as enqueue:
+            tapped.call("test.solve", solve, np.ones((3, 3), np.float32))
+        spans = default_tracer().store.for_trace(enqueue.trace_id)
+        (compile_,) = [s for s in spans if s["name"] == "jit.compile"]
+        assert compile_["tags"] == {"fn": "test.solve", "retrace": False}
+        top = [s for s in spans if s["name"] in JIT_PHASES
+               and s["parentId"] == enqueue.span_id]
+        own = [s for s in top if s["tags"]["fn"] in ("solve", "jit(solve)")]
+        assert [s["name"] for s in own] == list(JIT_PHASES)
+        assert compile_["parentId"] == enqueue.span_id
+        for s in own:
+            assert _inside(s, compile_, slack_ms=5.0)
+
+    def test_a_sequence_jobs_first_trace_shows_where_its_first_step_went(self, tapped):
+        from predictionio_tpu.models import sequencerec
+        from predictionio_tpu.models.sequencerec import (
+            SeqPreparator,
+            SeqPreparatorParams,
+            SeqRecAlgorithm,
+            SeqRecAlgorithmParams,
+            TrainingData,
+        )
+
+        seqs = [[f"i{(u + j) % 9}" for j in range(12)] for u in range(6)]
+        td = TrainingData(user_ids=[f"u{u}" for u in range(6)], sequences=seqs)
+        pd = SeqPreparator(SeqPreparatorParams(seq_len=8)).prepare(None, td)
+        algo = SeqRecAlgorithm(SeqRecAlgorithmParams(
+            d_model=16, n_heads=2, n_layers=1, steps=3, batch_size=4, seed=5))
+        # as a fresh process finds them: no program made, none compiled
+        sequencerec._programs.cache_clear()
+        jax.clear_caches()
+
+        def job():
+            algo.train(None, pd)
+            root = [s for s in default_tracer().store.dump()
+                    if s["name"] == "train" and s["parentId"] is None][-1]
+            return default_tracer().store.for_trace(root["traceId"])
+
+        first = job()
+        by_id = {s["spanId"]: s for s in first}
+
+        def under(s):
+            while s["name"] in JIT_PHASES:
+                s = by_id[s["parentId"]]
+            return s["name"], s.get("tags", {}).get("i")
+
+        phases = [s for s in first if s["name"] in JIT_PHASES]
+        homes = {under(s) for s in phases}
+        assert homes == {("seqrec.init", None), ("seqrec.step", 0)}
+        step0 = [s for s in phases if under(s) == ("seqrec.step", 0)]
+        top = [s for s in step0 if by_id[s["parentId"]]["name"] == "seqrec.step"]
+        assert [(s["name"], s["tags"]["fn"]) for s in top] == [
+            ("jit.trace", "step"), ("jit.lower", "jit(step)"),
+            ("jit.backend", "jit(step)"),
+        ]
+        # what the step's trace enclosed is below it, never beside it
+        for s in step0:
+            if s not in top:
+                assert by_id[s["parentId"]]["name"] in JIT_PHASES
+        # the step's phases make up its first span: that is where it went
+        (span0,) = [s for s in first
+                    if s["name"] == "seqrec.step" and s["tags"]["i"] == 0]
+        assert sum(s["durationMs"] for s in top) <= span0["durationMs"] + 1.0
+        assert sum(s["durationMs"] for s in top) > 0.5 * span0["durationMs"]
+        # a later job of the same shape brings nothing to the device
+        assert [s for s in job() if s["name"] in JIT_PHASES] == []
 
 
 SCOPES_PALLAS = (
