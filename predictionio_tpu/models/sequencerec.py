@@ -384,9 +384,24 @@ def make_loss_and_grad(cfg: bb.BackboneConfig, mesh=None, schedule: str = "auto"
 #: counters of a step that the job keeps from every step: tokens per held
 #: expert [periods, layers of a period, held], per expert of the router's
 #: whole width where a bias balances them [periods, layers, experts], the
-#: same two of the prediction module's block, and the module's loss
+#: same two of the prediction module's block, the module's loss, and the
+#: passes an expert layer's step needed [periods, layers] (the module's
+#: block: a scalar; more than one: it took the overflow branch, ``ops.moe``)
 _BY_STEP = ("expert_tokens", "router_tokens", "mtp_expert_tokens", "mtp_router_tokens",
-            "mtp_loss")
+            "mtp_loss", "passes", "mtp_passes")
+
+
+def _pass_counts(stats: Dict) -> Dict[str, object]:
+    """From ``passes_by_step`` (and the module's): the job's layer-steps,
+    how many of them took the overflow branch, and every step's largest
+    ``passes`` as one string (a span's tag); nothing without expert layers."""
+    kept = [stats[name].reshape(len(stats[name]), -1)
+            for name in ("passes_by_step", "mtp_passes_by_step") if name in stats]
+    if not kept:
+        return {}
+    passes = np.concatenate(kept, axis=1)  # [steps, expert layers]
+    return {"layer_steps": int(passes.size), "overflow_layer_steps": int((passes > 1).sum()),
+            "passes_by_step": " ".join(str(n) for n in passes.max(axis=1))}
 
 
 @functools.lru_cache(maxsize=8)
@@ -444,7 +459,9 @@ class SeqRecAlgorithm(Algorithm):
                 "mixers": " ".join(f"{name}:{n}" for name, n in cfg.mixers().items())}
         # the job's root span: under no server it starts a trace of its own
         with span("train", tags):
-            return self._train(ctx, pd, cfg)
+            model = self._train(ctx, pd, cfg)
+            tags.update(_pass_counts(model.stats))  # the store reads the tags when the span ends
+            return model
 
     def _train(self, ctx, pd: PreparedData, cfg: bb.BackboneConfig) -> SeqRecModel:
         p = self.params
@@ -492,6 +509,8 @@ class SeqRecAlgorithm(Algorithm):
             host_losses = np.asarray(jax.device_get(losses), np.float32)
             for name, values in by_step.items():  # [steps, ...]
                 stats[name + "_by_step"] = np.stack(jax.device_get(values))
+            counts = _pass_counts(stats)
+            stats.update({name: counts[name] for name in counts if name != "passes_by_step"})
             if cfg.router_bias and cfg.ffn == "moe":
                 blocks = [host_params["periods"]] + (
                     [host_params["mtp"]["block"]] if "mtp" in host_params else [])

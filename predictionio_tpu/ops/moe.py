@@ -16,15 +16,25 @@ would add is left out; no code stands in for the chips that hold them.
 
 No assignment is dropped. Shapes are static, so the sorted assignments are
 taken ``pass_rows`` at a time (default: twice this share's mean load), in
-as many passes as all of a step's assignments could need; a pass past the
-last held assignment is skipped, so the usual step runs one. ``dropped`` in
-the counters is the held assignments less the rows the passes that ran
-combined into the result (each pass counts the rows its own mask let
-through), so a pass that is skipped, short or cut wrongly shows there.
+as many passes as all of a step's assignments could need. The usual step
+needs one, and that one runs in line: its sum starts the result and its
+gradient is one backward of one pass. The passes after it stand behind ONE
+``cond`` on whether the held assignments overflow a pass (decided on the
+device from the count the router just produced), and inside it a pass past
+the last held assignment is skipped. The passes' sum has a VJP of its own
+(:func:`_passes`): the gradient is the sum of the passes' gradients, each
+pass recomputed where it is pulled back, accumulated inside the same
+``cond``; so a step that needs one pass fills, carries and adds nothing for
+the passes it does not run, forward or backward. ``dropped`` in the
+counters is the held assignments less the rows the passes that ran
+combined into the result (each pass, the one in line too, counts the rows
+its own mask let through), so a pass that is skipped, short or cut wrongly
+shows there; ``passes`` is how many the step needed.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Tuple
 
 import jax
@@ -96,6 +106,77 @@ def _passes_for(all_rows: int, rows: int) -> int:
     return -(-all_rows // rows)
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def _one_pass(static, x, experts, flat_w, order, group_sizes, start):
+    """The pass over the ``rows`` sorted assignments from ``start`` on: the
+    tokens' sums and how many rows its mask let through. A jitted function
+    (as its pull-back below), so that it is traced and lowered once for a
+    shape, whichever layer, branch or direction asks for it."""
+    rows, _, top_k, cd = static
+    take = jax.lax.dynamic_slice_in_dim(order, start, rows)
+    # what of every group lies inside [start, start + rows)
+    ends = jnp.cumsum(group_sizes)
+    inside = jnp.clip(ends - start, 0, rows) - jnp.clip(ends - group_sizes - start, 0, rows)
+    return _held_pass(x, experts, take, inside, flat_w, top_k, cd)
+
+
+def _over_passes(static, n_held, one):
+    """``one(start)``, a tree of arrays, summed over the passes that hold an
+    assignment. The first pass runs in line and starts the sum; the passes
+    after it, which only a step whose held assignments overflow ``rows``
+    needs, stand behind one ``cond``, and there a pass past the last held
+    assignment is skipped."""
+    rows, passes = static[:2]
+    total = one(jnp.int32(0))
+    if passes == 1:
+        return total
+
+    def one_more(total, start):
+        return jax.lax.cond(
+            start < n_held, lambda total: jax.tree_util.tree_map(jnp.add, total, one(start)),
+            lambda total: total, total), None
+
+    starts = jnp.arange(1, passes, dtype=jnp.int32) * rows
+    return jax.lax.cond(n_held > rows, lambda total: jax.lax.scan(one_more, total, starts)[0],
+                        lambda total: total, total)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _passes(static, x, experts, flat_w, order, group_sizes):
+    """Every held assignment through its experts, ``rows`` sorted
+    assignments a pass: the tokens' sums [T, D] and the rows the passes'
+    masks let through. ``static``: ``rows``, ``passes``, ``top_k`` and the
+    compute dtype. Its gradient is the sum of the passes' own, each
+    recomputed where it is pulled back: nothing is kept from the forward
+    pass but the arguments, and no cotangent is carried through a pass that
+    does not run."""
+    return _over_passes(static, group_sizes.sum(), lambda start: _one_pass(
+        static, x, experts, flat_w, order, group_sizes, start))
+
+
+def _passes_fwd(static, *args):
+    return _passes(static, *args), args
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _pull_pass(static, x, experts, flat_w, order, group_sizes, start, ct):
+    """The cotangents of ``x``, ``experts`` and ``flat_w`` through the pass
+    from ``start`` on, the pass recomputed."""
+    _, vjp = jax.vjp(lambda *inputs: _one_pass(static, *inputs, order, group_sizes, start)[0],
+                     x, experts, flat_w)
+    return vjp(ct)
+
+
+def _passes_bwd(static, args, cotangents):
+    group_sizes = args[-1]
+    pulled = _over_passes(static, group_sizes.sum(), lambda start: _pull_pass(
+        static, *args, start, cotangents[0]))
+    return (*pulled, None, None)  # nothing for ``order`` and ``group_sizes``
+
+
+_passes.defvjp(_passes_fwd, _passes_bwd)
+
+
 def expert_layer(p: Dict, x, *, first: int, top_k: int, norm_topk: bool = True,
                  pass_rows: int = 0, compute_dtype=jnp.float32, scoring: str = "softmax",
                  scale: float = 1.0, norm_eps: float = 1e-20) -> Tuple[jax.Array, Dict]:
@@ -123,24 +204,10 @@ def expert_layer(p: Dict, x, *, first: int, top_k: int, norm_topk: bool = True,
         order = jnp.argsort(flat, stable=True)
         order = jnp.pad(order, (0, max(0, passes * rows - order.shape[0])))
         group_sizes = jnp.bincount(flat, length=count + 1)[:count].astype(jnp.int32)
-        ends = jnp.cumsum(group_sizes)
-        n_held = ends[-1]
+        n_held = group_sizes.sum()
         flat_w = weights.reshape(-1)
 
-    @jax.checkpoint
-    def one_pass(y, start):
-        def run(y):
-            take = jax.lax.dynamic_slice_in_dim(order, start, rows)
-            # what of every group lies inside [start, start + rows)
-            inside = jnp.clip(ends - start, 0, rows) - jnp.clip(ends - group_sizes - start, 0, rows)
-            part, combined = _held_pass(x, p["experts"], take, inside, flat_w, top_k, cd)
-            return y + part, combined
-
-        # a pass past the last held assignment has nothing to do
-        return jax.lax.cond(start < n_held, run, lambda y: (y, jnp.int32(0)), y)
-
-    y, combined = jax.lax.scan(one_pass, jnp.zeros(x.shape, jnp.float32),
-                               jnp.arange(passes, dtype=jnp.int32) * rows)
+    y, combined = _passes((rows, passes, top_k, cd), x, p["experts"], flat_w, order, group_sizes)
     if "shared" in p:
         with jax.named_scope("seq.moe.shared"):
             if "shared_gate" in p:
@@ -152,7 +219,7 @@ def expert_layer(p: Dict, x, *, first: int, top_k: int, norm_topk: bool = True,
     counters = {
         "expert_tokens": group_sizes,
         "absent_weight": jnp.where(held, 0.0, weights).sum() / (tokens * scale),
-        "dropped": n_held - combined.sum(),
+        "dropped": n_held - combined,
         "passes": -(-n_held // rows),
     }
     if bias is not None:

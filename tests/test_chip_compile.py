@@ -456,25 +456,49 @@ def test_packed_attention_two_rows_of_8k(one_chip, heads, kv_heads, head_dim):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
+#: the expert layer at the three sequence cells' widths, and the temporaries
+#: of its gradient's program at the parent of PR 37 (7be4e7e: every pass
+#: inside one ``scan``), compiled here for v5e
+_EXPERT_LAYER_CASES = {
+    "32_of_512": (dict(d=2048, f=512, e=512, held=32, top_k=10), 2_978_895_360),
+    "16_of_256": (dict(d=2048, f=768, e=256, held=16, top_k=8, scoring="sigmoid", scale=2.5), 2_410_094_080),
+    "8_of_64": (dict(d=2048, f=1536, e=64, held=8, top_k=4, scoring="sigmoid"), 2_708_591_104),
+}
+_expert_layer_programs = {}
+
+
+def _expert_layer_gradient(one_chip, case):
+    """The layer's gradient (parameters and input; the counters as aux) at
+    16,384 tokens, compiled once a case for the tests that read it."""
+    from predictionio_tpu.ops.moe import expert_layer
+
+    if case not in _expert_layer_programs:
+        sizes = dict(_EXPERT_LAYER_CASES[case][0])
+        d, f, e, held = (sizes.pop(name) for name in ("d", "f", "e", "held"))
+        w = lambda *shape: _sds(one_chip, shape, jnp.float32)  # noqa: E731
+        params = {"router": w(d, e),
+                  "experts": {"wg": w(held, d, f), "wu": w(held, d, f), "wd": w(held, f, d)}}
+        if case != "8_of_64":  # LFM2's layer has no shared expert
+            params["shared"] = {"wg": w(d, f), "wu": w(d, f), "wd": w(f, d)}
+        if "scoring" in sizes:  # the sigmoid routers carry a bias, Qwen3-Next's shared expert a gate
+            params["router_bias"] = w(e)
+        else:
+            params["shared_gate"] = w(d)
+
+        def loss(p, x):
+            y, counters = expert_layer(p, x, first=0, compute_dtype=jnp.bfloat16, **sizes)
+            return y.sum(), counters
+
+        _expert_layer_programs[case] = _compile(
+            jax.grad(loss, argnums=(0, 1), has_aux=True), params, w(2 * SEQ_L, d))
+    return _expert_layer_programs[case]
+
+
 def test_expert_layer_16k_tokens_32_of_512(one_chip):
     """Router over 512, the held experts' grouped products (XLA lowers
     ``ragged_dot`` to a Mosaic kernel on the chip), shared expert; forward
     and backward at 16,384 tokens."""
-    from predictionio_tpu.ops.moe import expert_layer
-
-    d, f, e, held = 2048, 512, 512, 32
-    w = lambda *shape: _sds(one_chip, shape, jnp.float32)  # noqa: E731
-    params = {
-        "router": w(d, e), "shared_gate": w(d),
-        "shared": {"wg": w(d, f), "wu": w(d, f), "wd": w(f, d)},
-        "experts": {"wg": w(held, d, f), "wu": w(held, d, f), "wd": w(held, f, d)},
-    }
-
-    def loss(p, x):
-        return expert_layer(p, x, first=0, top_k=10, compute_dtype=jnp.bfloat16)[0].sum()
-
-    compiled = _compile(jax.grad(loss, argnums=(0, 1)), params, w(2 * SEQ_L, d))
-    assert "ragged" in compiled.as_text()
+    assert "ragged" in _expert_layer_gradient(one_chip, "32_of_512").as_text()
 
 
 # -- and at the shapes of train-joyai-long8k: JoyAI-LLM-Flash widths, latent
@@ -509,24 +533,58 @@ def test_latent_attention_two_rows_of_8k(one_chip, kernel):
 def test_expert_layer_16k_tokens_16_of_256_sigmoid(one_chip):
     """Sigmoid router over 256 with its bias, 8 a token, 16 held experts of
     768, the shared expert without a gate; forward and backward."""
-    from predictionio_tpu.ops.moe import expert_layer
-
-    d, f, e, held = 2048, 768, 256, 16
-    w = lambda *shape: _sds(one_chip, shape, jnp.float32)  # noqa: E731
-    params = {
-        "router": w(d, e), "router_bias": w(e),
-        "shared": {"wg": w(d, f), "wu": w(d, f), "wd": w(f, d)},
-        "experts": {"wg": w(held, d, f), "wu": w(held, d, f), "wd": w(held, f, d)},
-    }
-
-    def loss(p, x):
-        y, counters = expert_layer(p, x, first=0, top_k=8, compute_dtype=jnp.bfloat16,
-                                   scoring="sigmoid", scale=2.5)
-        return y.sum(), counters["router_tokens"]
-
-    compiled = _compile(jax.grad(loss, argnums=(0, 1), has_aux=True), params, w(2 * SEQ_L, d))
+    compiled = _expert_layer_gradient(one_chip, "16_of_256")
     _report("expert layer, 16 of 256", compiled)
     assert "ragged" in compiled.as_text()
+
+
+def _whiles_outside_conditionals(hlo: str):
+    """The ``while`` instructions of a compiled module that no
+    ``conditional``'s branch encloses: found from the entry computation
+    through every called computation but the branches."""
+    bodies, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(2)
+            bodies[name] = []
+            if head.group(1):
+                entry = name
+        elif name is not None:
+            bodies[name].append(line)
+    seen, todo, found = set(), [entry], []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in bodies[name]:
+            if re.search(r"\) while\(", line):
+                found.append(line.strip())
+            if " conditional(" not in line:  # a branch is run only where its predicate says so
+                todo += re.findall(r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)", line)
+    return found
+
+
+@pytest.mark.parametrize("case", sorted(_EXPERT_LAYER_CASES))
+def test_the_usual_step_of_the_expert_layer_accumulates_no_weight_cotangent_a_pass(one_chip, case):
+    """The gradient of the layer at 16,384 tokens and the three cells'
+    widths: all of a step's assignments could need 8, 8 and 4 passes, the
+    usual step needs one. That one lies in line; the loop over the others,
+    which carries the held experts' cotangents, lies inside a
+    ``conditional``'s branch, so the usual step carries, fills and adds no
+    array of the experts' shape a pass. And the program needs no more
+    temporaries than it did with every pass in one loop."""
+    sizes, parent_temp = _EXPERT_LAYER_CASES[case]
+    d, f, held = sizes["d"], sizes["f"], sizes["held"]
+    compiled = _expert_layer_gradient(one_chip, case)
+    hlo = compiled.as_text()
+    carried = re.compile(rf"f32\[{held},({d},{f}|{f},{d})\]")
+    assert [line for line in re.findall(r".*\) while\(.*", hlo) if carried.search(line)]  # the overflow loop
+    assert not [line for line in _whiles_outside_conditionals(hlo) if carried.search(line)]
+    stats = _report(f"expert layer's gradient, {case}", compiled)
+    print(f"  temporaries {stats.temp_size_in_bytes:,} B; the parent's {parent_temp:,} B")
+    assert stats.temp_size_in_bytes <= parent_temp
 
 
 def test_latent_sparse_layer_two_rows_of_8k(one_chip, monkeypatch):

@@ -417,6 +417,20 @@ def _moe_params(rng, d=16, e=8, f=8, first=0, count=8):
     return full
 
 
+@pytest.fixture
+def untraced_passes():
+    """A pass and its pull-back are jitted functions, traced once for a
+    shape: a test that plants a fault inside one forgets the sound traces
+    first, and its own afterwards."""
+    def forget():
+        moe._one_pass.clear_cache()
+        moe._pull_pass.clear_cache()
+
+    forget()
+    yield forget
+    forget()
+
+
 def _share(full, first, count):
     held = jax.tree_util.tree_map(lambda a: a[first:first + count], full["experts"])
     return {**full, "experts": held}
@@ -477,8 +491,81 @@ def test_no_token_dropped_when_the_router_is_forced_onto_held_experts():
     assert rel(g["experts"]["wd"], w["experts"]["wd"]) < 1e-4
 
 
+def _forced(rng):
+    """The input and share of the test above: every choice is a held one."""
+    full = _moe_params(rng)
+    full["router"][:] = 0.0
+    x = np.abs(rng.normal(size=(64, 16))).astype(np.float32)
+    full["router"][:, 2:5] = 5.0
+    return _share(full, 2, 3), x
+
+
+@pytest.mark.parametrize("leaf", ["x", "wg", "wu", "wd", "router", "shared"])
+@pytest.mark.parametrize("pass_rows,passes", [(0, 1), (48, 4), (100, 2)],
+                         ids=["one_pass", "four_passes", "a_short_last_pass"])
+def test_gradient_through_the_passes_matches_the_reference(pass_rows, passes, leaf):
+    """The passes' sum has a VJP of its own: the first pass in line, the
+    overflow passes behind one ``cond``. In the usual state (one pass
+    needed), with four passes needed and with a last pass that is not full,
+    every gradient is the reference's, which knows no passes."""
+    rng = np.random.default_rng(8)
+    if passes == 1:  # routed as it falls: about 72 held assignments, 144 rows a pass
+        share, x = _share(_moe_params(rng), 2, 3), rng.normal(size=(64, 16)).astype(np.float32)
+    else:
+        share, x = _forced(rng)
+        share["router"][:, 2:5] += 0.3 * rng.normal(size=(16, 3)).astype(np.float32)
+    seen = rng.normal(size=x.shape).astype(np.float32)
+
+    def loss(layer):
+        return lambda p, x: (layer(p, x) * seen).sum()
+
+    def program(p, x):
+        return moe.expert_layer(p, x, first=2, top_k=3, pass_rows=pass_rows)
+
+    counters = program(share, x)[1]
+    assert int(counters["passes"]) == passes and int(counters["dropped"]) == 0
+    got = jax.grad(loss(lambda p, x: program(p, x)[0]), argnums=(0, 1))(share, jnp.asarray(x))
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(loss(lambda p, x: ref.moe_block(p, x, _ref_cfg(2, 3))), argnums=(0, 1))(
+            share, jnp.asarray(x))
+    pick = {"x": lambda g: g[1], "router": lambda g: g[0]["router"],
+            "shared": lambda g: jax.tree_util.tree_leaves((g[0]["shared"], g[0]["shared_gate"]))}
+    of = pick.get(leaf, lambda g: g[0]["experts"][leaf])
+    for a, b in zip(jax.tree_util.tree_leaves(of(got)), jax.tree_util.tree_leaves(of(want))):
+        assert rel(a, b) < 1e-4
+
+
+def test_the_usual_step_carries_no_cotangent_through_a_loop():
+    """The mechanism, read from the gradient's jaxpr: the first pass lies
+    in line (grouped products at the top level), and every ``scan`` lies
+    inside a ``cond`` on the held assignments' count; where one pass covers
+    all of a step's assignments there is no loop and no ``cond`` at all."""
+    rng = np.random.default_rng(8)
+    share, x = _share(_moe_params(rng), 2, 3), rng.normal(size=(64, 16)).astype(np.float32)
+
+    def outline(jaxpr, inside=()):
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            if name in ("scan", "while", "ragged_dot_general"):
+                yield name, inside
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from outline(sub, inside + ((name,) if name in ("cond", "scan", "while") else ()))
+
+    def grad_of(pass_rows):
+        return jax.make_jaxpr(jax.grad(lambda p, x: moe.expert_layer(
+            p, x, first=2, top_k=3, pass_rows=pass_rows)[0].sum(), argnums=(0, 1)))(share, x).jaxpr
+
+    found = list(outline(grad_of(48)))
+    loops = [inside for name, inside in found if name != "ragged_dot_general"]
+    assert loops and all(inside[:1] == ("cond",) for inside in loops)
+    # forward 3 + recomputed 3 + backward 6 in line, as many again inside the overflow branch
+    products = [inside for name, inside in found if name == "ragged_dot_general"]
+    assert sum(inside == () for inside in products) == 12 and len(products) == 24
+    assert all(inside == () for _, inside in outline(grad_of(192)))  # one pass covers all
+
+
 @pytest.mark.parametrize("fault", ["a_pass_too_few", "groups_cut_short"])
-def test_dropped_counts_what_the_passes_did_not_combine(monkeypatch, fault):
+def test_dropped_counts_what_the_passes_did_not_combine(monkeypatch, untraced_passes, fault):
     """``dropped`` is counted from the rows the passes' own masks let
     through, so it reads more than 0 when the loop stops a pass early or a
     pass is told too little of its groups; the sound layer reads 0 on the
@@ -670,6 +757,44 @@ def test_pio_train_and_predict_with_the_backbone_configuration(tmp_path, monkeyp
     get_registry(refresh=True)
 
 
+@pytest.mark.parametrize("forced", [False, True], ids=["usual", "overflow"])
+def test_a_job_counts_the_layer_steps_that_overflowed(forced, monkeypatch):
+    """``passes_by_step``, ``layer_steps`` and ``overflow_layer_steps`` land
+    in the model's stats and as tags of the job's ``train`` span; with
+    passes of 8 rows every layer's step overflows."""
+    import functools
+
+    from predictionio_tpu.models import sequencerec
+    from predictionio_tpu.obs.trace import default_tracer
+    from predictionio_tpu.storage import BiMap
+
+    if forced:
+        monkeypatch.setattr(bb, "expert_layer", functools.partial(moe.expert_layer, pass_rows=8))
+    sequencerec._programs.cache_clear()  # the job's programs are kept by configuration
+    rng = np.random.default_rng(5)
+    pd = sequencerec.PreparedData(
+        item_map=BiMap.string_int([f"i{n}" for n in range(VOCAB)]),
+        windows=rng.integers(1, VOCAB, size=(4, 33)).astype(np.int32),
+        segments=np.ones((4, 33), np.int32), user_recent={}, seq_len=32)
+    try:
+        model = sequencerec.SeqRecAlgorithm(sequencerec.SeqRecAlgorithmParams(
+            backbone="qwen3next-tiny", steps=3, batch_size=2)).train(None, pd)
+    finally:
+        sequencerec._programs.cache_clear()
+    stats = model.stats
+    assert stats["passes_by_step"].shape == (3, 2, 4) and stats["layer_steps"] == 24
+    assert int(np.max(stats["dropped"])) == 0
+    if forced:
+        assert stats["overflow_layer_steps"] == 24 and stats["passes_by_step"].min() > 1
+    else:
+        assert stats["overflow_layer_steps"] == 0 and (stats["passes_by_step"] == 1).all()
+    roots = [s for s in default_tracer().store.dump() if s["name"] == "train" and s["parentId"] is None]
+    tags = roots[-1]["tags"]
+    assert tags["layer_steps"] == 24 and tags["overflow_layer_steps"] == stats["overflow_layer_steps"]
+    assert tags["passes_by_step"].split() == [str(n) for n in stats["passes_by_step"].max(axis=(1, 2))]
+    assert tags["delta_rule_walk"] == "scan"
+
+
 def test_the_two_copies_of_the_reference_are_one_text():
     with open(os.path.join(REPO, "predictionio_tpu", "testing", "qwen3_next_reference.py")) as f:
         ours = f.read()
@@ -700,7 +825,7 @@ def test_the_shipped_configuration_has_the_published_widths():
     assert sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(shapes)) == 625_667_136
 
 
-def test_rows_past_the_last_group_do_not_reach_a_token(monkeypatch):
+def test_rows_past_the_last_group_do_not_reach_a_token(monkeypatch, untraced_passes):
     """On the chip the grouped product leaves the rows past its last group
     unwritten, in the forward pass and in the cotangent of its left operand:
     they hold whatever the buffer held (PR 26's first chip runs scattered
@@ -736,7 +861,11 @@ def test_rows_past_the_last_group_do_not_reach_a_token(monkeypatch):
         return past(d_lhs, group_sizes).astype(lhs.dtype), d_rhs, None
 
     dirty.defvjp(fwd, bwd)
-    monkeypatch.setattr(jax.lax, "ragged_dot", lambda a, b, gs, **kw: dirty(a, b, gs))
+    planted = []
+    monkeypatch.setattr(jax.lax, "ragged_dot",
+                        lambda a, b, gs, **kw: (planted.append(1), dirty(a, b, gs))[1])
+    untraced_passes()  # the clean product's traces
     got = jax.grad(loss, argnums=(0, 1))(share, jnp.asarray(x))
+    assert planted
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(clean)):
         assert np.isfinite(np.asarray(a)).all() and rel(a, b) < 1e-5

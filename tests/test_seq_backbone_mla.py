@@ -496,6 +496,10 @@ def test_pio_train_and_predict_with_the_backbone_configuration(tmp_path, monkeyp
     assert stats["mtp_loss_by_step"].shape == (30,) and stats["mtp_loss_by_step"][-1] < stats["mtp_loss_by_step"][0]
     assert stats["router_tokens_by_step"].shape == (30, 2, 1, 8)
     assert stats["mtp_router_tokens_by_step"].shape == (30, 8)
+    # the module's block is an expert layer too: 30 steps x (2 sparse layers + the module's)
+    assert stats["mtp_passes_by_step"].shape == (30,) and stats["layer_steps"] == 90
+    assert stats["overflow_layer_steps"] == int((stats["passes_by_step"] > 1).sum()
+                                                + (stats["mtp_passes_by_step"] > 1).sum())
     # the bias the job ends with is the rule applied to every step's counts
     bias = np.zeros((2, 1, 8), np.float32)
     for counts in stats["router_tokens_by_step"]:
