@@ -2,17 +2,22 @@
 of a configuration.
 
 A configuration gives the layer pattern (``layer_types``: a mixer a layer,
-``full_attention``, ``linear_attention`` = gated DeltaNet, or ``conv`` = the
-gated short convolution; or ``full_attention_interval``: every n-th layer
-is softmax attention, the others gated DeltaNet; 1 = all attention), the
-attention (``gqa``: grouped heads of one width; ``mla``:
+``full_attention`` or ``attention``, ``linear_attention`` = gated DeltaNet,
+``conv`` = the gated short convolution, or ``mamba`` = the Mamba-2
+state-space mixer, ``ssm`` here; or ``full_attention_interval``: every n-th
+layer is softmax attention, the others gated DeltaNet; 1 = all attention),
+the attention (``gqa``: grouped heads of one width; ``mla``:
 latent attention, queries and keys/values projected down, normed and up
 again, a rotary part of the key that all heads share), the head and
 feed-forward widths, the norm (``rms`` with scale ``1 + w``, or ``layer``),
-the positions (``rotary`` on part of a head, or a ``learned`` table), the
-feed-forward kind (``moe``: routed experts of which this share holds a
-range, plus a shared expert where the file gives one; ``swiglu``; or ``gelu``), how many leading
-layers are dense instead (``first_k_dense_replace``, ``num_dense_layers``), whether a
+the positions (``rotary`` on part of a head, a ``learned`` table, or
+``none``: the mixers' own order is all the model knows of it), Granite's four
+multipliers (on the embedding, on both residual additions of a layer, on the
+attention scores in place of ``1 / sqrt(head)``, and the divisor of the
+logits; all 1 or absent elsewhere), the feed-forward kind (``moe``: routed
+experts of which this share holds a range, plus a shared expert where the
+file gives one; ``swiglu``; or ``gelu``), how many leading layers are dense
+instead (``first_k_dense_replace``, ``num_dense_layers``), whether a
 multi-token-prediction module follows the last layer, and whether the head
 is the embedding. The keys are those of the public models' ``config.json``;
 what such a file does not state (norm, positions, the range of experts
@@ -24,8 +29,8 @@ a model of one period too; the leading dense layers (one kind of mixer,
 whichever the pattern gives them) and the prediction module lie outside it;
 each layer is recomputed in the backward pass. Rows are packed: ``seg`` gives
 each slot its history's id (0 = padding), positions count from a history's
-start, and neither the convolution, the delta-rule state nor attention
-crosses a boundary.
+start, and neither the convolutions, the delta-rule state, the state-space
+state nor attention crosses a boundary.
 
 Precision: parameters, residual stream, norms, router, softmax, gates,
 delta-rule state, the router's bias and loss in float32; matrix products
@@ -50,10 +55,12 @@ from ..ops.attention import attention
 from ..ops.deltanet import gated_deltanet
 from ..ops.moe import expert_layer, swiglu
 from ..ops.shortconv import short_conv
+from ..ops.ssd import mamba2
 
 #: a public file's word for a layer's mixer -> the kind the parameters are
 #: stacked under
-_KINDS = {"full_attention": "full", "linear_attention": "linear", "conv": "conv"}
+_KINDS = {"full_attention": "full", "attention": "full", "linear_attention": "linear",
+          "conv": "conv", "mamba": "ssm"}
 
 CONF_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
@@ -90,7 +97,7 @@ class BackboneConfig:
     attn_kernel: str = "xla"
     partial_rotary_factor: float = 0.25
     rope_theta: float = 1e4
-    positions: str = "learned"  # "learned" | "rotary"
+    positions: str = "learned"  # "learned" | "rotary" | "none"
     norm: str = "layer"  # "layer" | "rms"
     rms_norm_eps: float = 1e-6
     linear_num_key_heads: int = 0
@@ -100,6 +107,21 @@ class BackboneConfig:
     linear_conv_kernel_dim: int = 4
     #: taps of the gated short convolution (``layer_types`` ``conv``)
     conv_L_cache: int = 3
+    #: the Mamba-2 mixer (``layer_types`` ``mamba``): heads, their width, the
+    #: state's width a head, the taps of its convolution, and the groups
+    #: that share B and C (one: nothing else runs here). 0 = not given.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 0
+    mamba_n_groups: int = 0
+    #: what the attention scores are multiplied by; None = 1 / sqrt(head_dim)
+    attention_multiplier: Optional[float] = None
+    #: on the embedding; on what a mixer and a feed-forward add to the
+    #: residual stream; what the logits are divided by
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     ffn: str = "gelu"  # "gelu" | "swiglu" | "moe"
     #: leading layers, outside the periods, whose feed-forward is a SwiGLU
     #: of ``intermediate_size`` whatever ``ffn`` says
@@ -142,13 +164,15 @@ class BackboneConfig:
     compute_dtype: str = "float32"
     state_dtype: str = "float32"
     gate_dtype: str = "float32"
+    #: slots a chunk of the delta rule's and of the state-space scan
+    #: (``mamba_chunk_size`` where the ``backbone`` group gives none)
     chunk: int = 64
     attn_block: int = 512
     loss_block: int = 2048
 
     @property
     def kinds(self) -> Tuple[str, ...]:
-        """The mixer of every layer: ``full``, ``linear`` or ``conv``."""
+        """The mixer of every layer: ``full``, ``linear``, ``conv`` or ``ssm``."""
         if self.layer_types:
             return tuple(_KINDS[t] for t in self.layer_types)
         p = self.full_attention_interval
@@ -183,9 +207,11 @@ class BackboneConfig:
         return 0 if (kind, held) == ("full", 1) else held
 
     def mixers(self) -> Dict[str, int]:
-        """Layers by the mixer they run: ``deltanet``, ``shortconv``, and
-        ``gqa`` or ``mla`` (the prediction module's block counts too)."""
-        names = {"linear": "deltanet", "conv": "shortconv", "full": self.attention}
+        """Layers by the mixer they run: ``deltanet``, ``shortconv``,
+        ``mamba2``, and ``gqa`` or ``mla`` (the prediction module's block
+        counts too)."""
+        names = {"linear": "deltanet", "conv": "shortconv", "ssm": "mamba2",
+                 "full": self.attention}
         found = [names[k] for k in self.kinds] + [self.attention] * self.num_nextn_predict_layers
         return {name: found.count(name) for name in sorted(set(found))}
 
@@ -214,6 +240,13 @@ class BackboneConfig:
                              ("norm_eps", "rms_norm_eps")):
             if theirs in merged:
                 values.setdefault(ours, merged[theirs])
+        if "chunk" not in merged.get("backbone", {}) and "mamba_chunk_size" in merged:
+            values["chunk"] = merged["mamba_chunk_size"]
+        if merged.get("position_embedding_type") == "nope":
+            values.setdefault("positions", "none")
+        # a dense Granite's feed-forward is its "shared" SwiGLU
+        if "shared_intermediate_size" in merged and not merged.get("num_local_experts"):
+            values["intermediate_size"] = merged["shared_intermediate_size"]
         if "rope_theta" in merged.get("rope_parameters", {}):
             values.setdefault("rope_theta", merged["rope_parameters"]["rope_theta"])
         if "n_shared_experts" in merged:
@@ -236,6 +269,24 @@ class BackboneConfig:
                 f"are not whole periods of {cfg.full_attention_interval}")
         if merged.get("conv_bias"):
             raise ValueError("the short convolution and its projections carry no bias here")
+        if "ssm" in cfg.kinds:
+            sizes = ("mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_d_conv")
+            missing = [name for name in sizes if not getattr(cfg, name)]
+            if missing:
+                raise ValueError(f"mamba layers need {', '.join(missing)}: no default is assumed")
+            if cfg.mamba_n_groups != 1:
+                raise ValueError(f"mamba_n_groups is {cfg.mamba_n_groups or 'not given'}: the "
+                                 "state-space scan here shares B and C among all heads (one group)")
+            if "mamba_expand" in merged and (
+                    merged["mamba_expand"] * cfg.hidden_size != cfg.mamba_n_heads * cfg.mamba_d_head):
+                raise ValueError("mamba_expand x hidden_size is not mamba_n_heads x mamba_d_head")
+            if not merged.get("mamba_conv_bias", True) or merged.get("mamba_proj_bias"):
+                raise ValueError("the Mamba-2 mixer here has a bias on its convolution "
+                                 "and none on its projections")
+        if merged.get("attention_bias"):
+            raise ValueError("the attention projections carry no bias here")
+        if cfg.positions not in ("learned", "rotary", "none"):
+            raise ValueError(f"positions {cfg.positions!r}: learned, rotary or none")
         if cfg.num_nextn_predict_layers not in (0, 1):
             raise ValueError("one multi-token-prediction module at most")
         if cfg.attention == "mla" and not (
@@ -299,6 +350,8 @@ def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
             full.update(q_norm=((hd,), "zero"), k_norm=((hd,), "zero"))
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    mh, ns = cfg.mamba_n_heads, cfg.mamba_d_state
+    inner = mh * cfg.mamba_d_head
     mixers = {
         "full": full,
         "linear": {
@@ -309,6 +362,13 @@ def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
         },
         "conv": {"w_in": ((d, 3 * d), "w"), "conv_w": ((cfg.conv_L_cache, d), "w"),
                  "w_out": ((d, d), "w")},
+        "ssm": {
+            "w_in": ((d, 2 * inner + 2 * ns), "w"), "w_dt": ((d, mh), "w"),
+            "conv_w": ((cfg.mamba_d_conv, inner + 2 * ns), "w"),
+            "conv_b": ((inner + 2 * ns,), "zero"),
+            "A_log": ((mh,), "a_log"), "dt_bias": ((mh,), "dt_bias"), "D": ((mh,), "one"),
+            "norm": ((inner,), "one"), "w_out": ((inner, d), "w"),
+        },
     }
     m = cfg.intermediate_size
     gated = {"wg": ((d, m), "w"), "wu": ((d, m), "w"), "wd": ((m, d), "w")}
@@ -398,7 +458,9 @@ def layers_of(params: Dict, cfg: BackboneConfig) -> Dict:
     with latent attention ``testing/joyai_flash_reference.py``, whose
     mixer is ``attn``, whose dense layers carry ``mlp`` and whose
     prediction module is ``mtp``; ``testing/lfm2_moe_reference.py``, whose
-    mixers are ``conv`` and ``full`` and whose head is its embedding).
+    mixers are ``conv`` and ``full`` and whose head is its embedding;
+    ``testing/granite4h_reference.py``, whose mixers are ``ssm`` and
+    ``full`` and whose every layer carries ``mlp``).
     Works on any pytree of the parameters' structure: gradients too."""
     full_key = "attn" if cfg.attention == "mla" else "full"
     ffn_key = "moe" if cfg.ffn == "moe" else "mlp"
@@ -548,6 +610,9 @@ def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule):
     if cfg.positions == "rotary":
         rot = int(cfg.partial_rotary_factor * hd)
         q, k = _rope(q, pos, rot, cfg.rope_theta), _rope(k, pos, rot, cfg.rope_theta)
+    if cfg.attention_multiplier is not None:
+        # the core scales by 1 / sqrt(hd): q carries the rest
+        q = q * (cfg.attention_multiplier * hd ** 0.5)
     with jax.named_scope("seq.attn.core"):
         o = attention(
             q.astype(cd).transpose(0, 2, 1, 3), k.astype(cd).transpose(0, 2, 1, 3),
@@ -580,6 +645,11 @@ def _ffn(cfg: BackboneConfig, p: Dict, x):
     return jnp.dot(hidden.astype(cd), p["mlp_out"].astype(cd), preferred_element_type=f32), {}
 
 
+def _add(cfg: BackboneConfig, x, y):
+    """The residual stream ``x`` after a mixer or a feed-forward gave ``y``."""
+    return x + y if cfg.residual_multiplier == 1.0 else x + cfg.residual_multiplier * y
+
+
 def _layer(cfg: BackboneConfig, kind: str, mesh, schedule, x, seg, pos,
            norm_in, mixer, norm_post, ffn):
     h = _norm(cfg, norm_in, x)
@@ -587,15 +657,23 @@ def _layer(cfg: BackboneConfig, kind: str, mesh, schedule, x, seg, pos,
     if kind == "full" and cfg.attention == "mla":
         with jax.named_scope("seq.attn"):
             mixed, ran = _latent_mixer(cfg, mixer, h, seg, pos, mesh, schedule)
-            x = x + mixed
+            x = _add(cfg, x, mixed)
     elif kind == "full":
         with jax.named_scope("seq.attn"):
-            x = x + _attention_mixer(cfg, mixer, h, seg, pos, mesh, schedule)
+            x = _add(cfg, x, _attention_mixer(cfg, mixer, h, seg, pos, mesh, schedule))
     elif kind == "conv":
         with jax.named_scope("seq.shortconv"):
             mixed, ran = short_conv(mixer, h, seg, compute_dtype=_dt(cfg.compute_dtype),
                                     gate_dtype=_dt(cfg.gate_dtype))
-            x = x + mixed
+            x = _add(cfg, x, mixed)
+    elif kind == "ssm":
+        with jax.named_scope("seq.ssm"):
+            mixed, ran = mamba2(
+                mixer, h, seg, heads=cfg.mamba_n_heads, head_dim=cfg.mamba_d_head,
+                state=cfg.mamba_d_state, eps=cfg.rms_norm_eps, chunk=cfg.chunk,
+                compute_dtype=_dt(cfg.compute_dtype), state_dtype=_dt(cfg.state_dtype),
+                gate_dtype=_dt(cfg.gate_dtype))
+            x = _add(cfg, x, mixed)
     else:
         with jax.named_scope("seq.deltanet"):
             mixed, ran = gated_deltanet(
@@ -604,14 +682,14 @@ def _layer(cfg: BackboneConfig, kind: str, mesh, schedule, x, seg, pos,
                 value_dim=cfg.linear_value_head_dim, eps=cfg.rms_norm_eps, chunk=cfg.chunk,
                 compute_dtype=_dt(cfg.compute_dtype), state_dtype=_dt(cfg.state_dtype),
                 gate_dtype=_dt(cfg.gate_dtype))
-            x = x + mixed
+            x = _add(cfg, x, mixed)
     y, counters = _ffn(cfg, ffn, _norm(cfg, norm_post, x))
-    return x + y, counters, ran
+    return _add(cfg, x, y), counters, ran
 
 
 def _layer_fn(cfg: BackboneConfig, kind: str, mesh, schedule):
-    """One layer whose mixer is of ``kind`` (``full``, ``linear``, ``conv``)
-    as ``(x, seg, pos, norm_in, mixer, norm_post, ffn) -> x, counters,
+    """One layer whose mixer is of ``kind`` (``full``, ``linear``, ``conv``,
+    ``ssm``) as ``(x, seg, pos, norm_in, mixer, norm_post, ffn) -> x, counters,
     ran``, recomputed in the backward pass."""
     return jax.checkpoint(lambda *a: _layer(cfg, kind, mesh, schedule, *a))
 
@@ -624,11 +702,14 @@ def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
     first mixer of each period that says so handed its inner kernel and got
     back (the delta rule's q, k, v, g, beta and o:
     ``ops.deltanet.gated_deltanet``; latent attention's q, k, v and o; the
-    short convolution's ``bcx`` and ``y``: ``ops.shortconv.short_conv``;
+    short convolution's ``bcx`` and ``y``: ``ops.shortconv.short_conv``; the
+    state-space scan's ``u``, ``B``, ``C``, ``dt`` and ``y``: ``ops.ssd.mamba2``;
     stacked [periods, B, ...]; empty where no mixer of a period does)."""
     pos = positions_of(seg)
     with jax.named_scope("seq.embed"):
         x = params["embed"][tokens]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         if cfg.positions == "learned":
             table = params["pos"]
             if tokens.shape[1] > table.shape[0]:
@@ -669,8 +750,9 @@ def logits_of(cfg: BackboneConfig, params: Dict, hidden, norm: Optional[Dict] = 
     cd = _dt(cfg.compute_dtype)
     with jax.named_scope("seq.head"):
         h = _norm(cfg, params["final_norm"] if norm is None else norm, hidden)
-        return jnp.dot(h.astype(cd), head_of(params).T.astype(cd),
-                       preferred_element_type=jnp.float32)
+        logits = jnp.dot(h.astype(cd), head_of(params).T.astype(cd),
+                         preferred_element_type=jnp.float32)
+        return logits if cfg.logits_scaling == 1.0 else logits / cfg.logits_scaling
 
 
 def next_item_loss(cfg: BackboneConfig, params: Dict, hidden, targets, valid,

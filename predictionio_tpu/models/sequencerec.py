@@ -49,6 +49,7 @@ from ..controller import (
 from ..obs.trace import span
 from ..ops.deltanet import walk_kind
 from ..ops.scoring import top_k_for_vectors
+from ..ops.ssd import scan_kind
 from ..storage import BiMap, EventFilter, get_registry
 from . import seq_backbone as bb
 
@@ -435,6 +436,13 @@ def _delta_rule_walk(cfg: bb.BackboneConfig) -> Dict[str, str]:
         cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.chunk)}
 
 
+def _ssd_scan(cfg: bb.BackboneConfig) -> Dict[str, str]:
+    """What runs the step's Mamba-2 layers' state-space scan ("pallas" or
+    "xla": ``ops.ssd.scan_kind``); nothing for a backbone without such
+    layers."""
+    return {"ssd_scan": scan_kind()} if "ssm" in cfg.kinds else {}
+
+
 class SeqRecAlgorithm(Algorithm):
     """Next-item trainer over packed histories (optax AdamW)."""
 
@@ -455,7 +463,7 @@ class SeqRecAlgorithm(Algorithm):
         p = self.params
         cfg = p.backbone_config()
         tags = {"backbone": p.backbone or "toy", "steps": p.steps,
-                "layers": cfg.num_hidden_layers, **_delta_rule_walk(cfg),
+                "layers": cfg.num_hidden_layers, **_delta_rule_walk(cfg), **_ssd_scan(cfg),
                 "mixers": " ".join(f"{name}:{n}" for name, n in cfg.mixers().items())}
         # the job's root span: under no server it starts a trace of its own
         with span("train", tags):
@@ -501,7 +509,7 @@ class SeqRecAlgorithm(Algorithm):
         with span("train.wait_device"):
             jax.block_until_ready(model_params)
         stats = {"fill": pd.fill, "steps": p.steps, "tokens_per_step": batch * pd.seq_len,
-                 "mixers": cfg.mixers(), **_delta_rule_walk(cfg)}
+                 "mixers": cfg.mixers(), **_delta_rule_walk(cfg), **_ssd_scan(cfg)}
         if counters:
             stats.update(jax.tree_util.tree_map(np.asarray, counters))
         with span("train.fetch"):

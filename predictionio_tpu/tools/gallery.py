@@ -86,12 +86,14 @@ def fetch_cached(url: str, timeout: float = 30.0) -> bytes:
             with open(body_path, "rb") as fh:
                 return fh.read()
         raise GalleryError(f"GET {url} → HTTP {exc.code}") from exc
-    except urllib.error.URLError as exc:
-        # offline: serve the cache when we have one (Template.scala:106-113)
+    except (urllib.error.URLError, ConnectionError) as exc:
+        # offline, or the connection reset while the answer was read (urllib
+        # wraps only the errors of sending the request): serve the cache when
+        # we have one (Template.scala:106-113)
         if os.path.exists(body_path):
             with open(body_path, "rb") as fh:
                 return fh.read()
-        raise GalleryError(f"GET {url} unreachable: {exc.reason}") from exc
+        raise GalleryError(f"GET {url} unreachable: {getattr(exc, 'reason', exc)}") from exc
 
 
 def list_remote(url: Optional[str] = None) -> List[dict]:

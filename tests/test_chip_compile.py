@@ -688,3 +688,59 @@ def test_lfm2_step_two_rows_of_8k_fits_the_chip(one_chip):
     stats = _report("lfm2 step", compiled)
     assert stats.argument_size_in_bytes + stats.temp_size_in_bytes < 15 * 2**30
     assert cfg.mixers() == {"gqa": 1, "shortconv": 4}
+
+
+# -- and at the shapes of train-granite4h-packed: granite-4.0-h-micro widths, nine
+# Mamba-2 layers (64 heads of 64 on a state of 128, chunks of 256) beside one
+# grouped-query attention layer, ONE row of 8,192 slots a step
+def test_mamba2_layer_one_row_of_8k(one_chip):
+    """One Mamba-2 layer with its dense SwiGLU as the step runs it
+    (recomputed in the backward pass): the [256, 256] matrices a head and
+    chunk are its largest arrays, and the scan keeps none of them across
+    its own backward pass."""
+    from predictionio_tpu.models import seq_backbone as bb
+
+    cfg = bb.BackboneConfig.load("granite4h-micro-vp8")
+    shapes = jax.eval_shape(lambda: bb.init_params(cfg, 12544, SEQ_L, 0))["periods"]
+    on_chip = lambda tree, *at: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _sds(one_chip, s.shape[len(at):], s.dtype), tree)
+    block = {"ssm": on_chip(shapes["ssm"], 0, 0),
+             **{name: on_chip(shapes[name], 0, 0) for name in ("norm_in", "norm_post", "ffn")}}
+    layer = bb._layer_fn(cfg, "ssm", None, "auto")
+
+    def loss(blk, x, seg):
+        y, _, ran = layer(x, seg, bb.positions_of(seg), blk["norm_in"], blk["ssm"],
+                          blk["norm_post"], blk["ffn"])
+        return y.sum(), ran["y"]
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1), has_aux=True), block,
+        _sds(one_chip, (1, SEQ_L, 2048), jnp.float32), _sds(one_chip, (1, SEQ_L), jnp.int32))
+    stats = _report("mamba-2 layer", compiled)
+    assert stats.temp_size_in_bytes < 3 * 2**30
+    assert "while" not in compiled.as_text().split("ENTRY")[1]  # no loop over slots or chunks
+
+
+def test_granite4h_step_one_row_of_8k_fits_the_chip(one_chip):
+    """The whole optimizer step of ``train-granite4h-packed`` (1 row of
+    8,193 slots, 772 M parameters with their AdamW moments, donated) as the
+    job compiles it: arguments 9.27 GB, temporaries 4.77 GB when this was
+    written (``PERF.md`` section 4); the chip's 15.75 GiB hold both."""
+    from predictionio_tpu.models import seq_backbone as bb
+    from predictionio_tpu.models import sequencerec
+
+    cfg = bb.BackboneConfig.load("granite4h-micro-vp8")
+    opt_init, step, _ = sequencerec._programs(cfg, 3e-4, None, "auto")
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: bb.init_params(cfg, 12544, SEQ_L, 0)))
+    assert sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(params)) == 772_160_448
+    rows = _sds(one_chip, (1, SEQ_L + 1), jnp.int32)
+    try:
+        compiled = _compile(step, params, on_chip(jax.eval_shape(opt_init, params)), rows, rows)
+    finally:
+        step.clear_cache()  # the job's own program object, kept by ``_programs``
+    stats = _report("granite4h step", compiled)
+    assert stats.argument_size_in_bytes + stats.temp_size_in_bytes <= 15.75 * 2**30
+    assert cfg.mixers() == {"gqa": 1, "mamba2": 9}
+    assert sequencerec._ssd_scan(cfg) == {"ssd_scan": "xla"}

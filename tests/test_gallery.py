@@ -111,6 +111,24 @@ def test_offline_falls_back_to_cache(gallery_server, monkeypatch):
         fetch_cached(url.replace("/index.json", "/never.json"))
 
 
+def test_a_connection_reset_while_reading_falls_back_to_cache(gallery_server, monkeypatch):
+    """urllib wraps only the errors of SENDING a request in URLError; a reset
+    while the answer is read arrives bare (seen under six test workers, when
+    another server took the closed one's port)."""
+    import urllib.request
+
+    srv, url = gallery_server
+    assert list_remote() != []  # warm the cache
+
+    def reset(*args, **kwargs):
+        raise ConnectionResetError(104, "Connection reset by peer")
+
+    monkeypatch.setattr(urllib.request, "urlopen", reset)
+    assert list_remote() != []  # served from cache
+    with pytest.raises(GalleryError, match="unreachable"):
+        fetch_cached(url.replace("/index.json", "/never.json"))
+
+
 def test_server_error_falls_back_to_cache(gallery_server):
     srv, url = gallery_server
     assert list_remote() != []  # warm the cache

@@ -1,0 +1,73 @@
+"""The three listed backbones of the sequence template, pinned: what their
+tiny configurations compute on a fixed seeded batch must stay what the commit
+before the fourth backbone computed."""
+
+import json
+
+import jax
+import numpy as np
+import pytest
+
+from predictionio_tpu.models import seq_backbone as bb
+
+VOCAB, L = 50, 64
+
+
+@pytest.fixture(scope="module")
+def batch():
+    """Two packed rows of L + 1 slots: three histories and padding in the
+    first, one history that fills the second."""
+    rng = np.random.default_rng(2)
+    rows = rng.integers(0, VOCAB, size=(2, L + 1)).astype(np.int32)
+    segs = np.zeros((2, L + 1), np.int32)
+    for sid, (lo, hi) in {1: (0, 20), 2: (20, 57), 3: (57, 62)}.items():
+        segs[0, lo:hi] = sid
+    segs[1, :] = 1
+    return rows, segs
+
+
+PINNED = json.loads("""
+{"qwen3next-tiny": {"loss": 3.9226794242858887, "grad_norm": {"embed": 42.86202103688412,
+  "final_norm.w": 0.014612602865828718, "head": 0.714324359798024, "periods.ffn": 1.1973556206567664,
+  "periods.full": 0.08691547574913118, "periods.linear": 7.205409834054159,
+  "periods.norm_in": 0.7365154657629039, "periods.norm_post": 0.22681090023504363}},
+ "joyai-flash-tiny": {"loss": 5.116833209991455, "grad_norm": {"dense.ffn": 0.1786237141053716,
+  "dense.full": 0.35678529079261606, "dense.norm_in": 0.05811747142576252,
+  "dense.norm_post": 0.03350126319130029, "embed": 2.5350993431535684,
+  "final_norm.w": 0.01301136403281458, "head": 0.7155275038543362, "mtp.block": 0.03028197180117545,
+  "mtp.eh_proj": 0.02868857754427902, "mtp.enorm": 0.004046483760910491,
+  "mtp.hnorm": 0.00423206835364789, "mtp.norm": 0.004297533724349888,
+  "periods.ffn": 0.11208062946786075, "periods.full": 0.052811122277361754,
+  "periods.norm_in": 0.006166508100601532, "periods.norm_post": 0.017627226551916005}},
+ "lfm2-tiny": {"loss": 3.956284284591675, "grad_norm": {"dense.conv": 0.5102702058199934,
+  "dense.ffn": 0.2573889067213364, "dense.norm_in": 0.08048597162057211,
+  "dense.norm_post": 0.04305908614364948, "embed": 4.210059824918104,
+  "final_norm.w": 0.01595513999631072, "periods.conv": 0.22144379809589704,
+  "periods.ffn": 0.07585715419251786, "periods.full": 0.09164016955651524,
+  "periods.norm_in": 0.036699178116064254, "periods.norm_post": 0.01332038945281503}}}
+""")
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_a_listed_configuration_computes_what_it_did_before_this_backbone(name, batch):
+    """The loss and the gradient's norm by group of the three listed
+    backbones at their tiny sizes, on this file's seeded batch and weights
+    drawn from seed 0, as commit fb37b61 (PR 37, before ``ssm`` layers, the
+    multipliers and ``positions: none``) computed them on the CPU. A change
+    to ``_attention_mixer``, ``_layer``, ``logits_of``, ``swiglu`` or the
+    loss that moves a listed configuration fails here and not on the
+    driver's chip. 1e-4: float32 sums in another order read 1e-6; a
+    multiplier applied where it is 1 by default moves every number."""
+    cfg = bb.BackboneConfig.load(name)
+    params = bb.init_params(cfg, VOCAB, L, 0)
+    (loss, _), grads = jax.jit(jax.value_and_grad(
+        lambda mp, r, s: bb.loss_fn(cfg, mp, r, s), has_aux=True))(params, *batch)
+    squares = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(grads)[0]:
+        key = ".".join(str(k.key) for k in path[:2])
+        squares[key] = squares.get(key, 0.0) + float(np.sum(np.asarray(leaf, np.float64) ** 2))
+    want = PINNED[name]
+    assert float(loss) == pytest.approx(want["loss"], rel=1e-5)
+    assert sorted(squares) == sorted(want["grad_norm"])
+    for key, value in want["grad_norm"].items():
+        assert np.sqrt(squares[key]) == pytest.approx(value, rel=1e-4), key
