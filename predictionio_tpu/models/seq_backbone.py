@@ -54,7 +54,7 @@ import numpy as np
 from ..ops.attention import attention
 from ..ops.deltanet import gated_deltanet
 from ..ops.moe import expert_layer, swiglu
-from ..ops.shortconv import short_conv
+from ..ops.shortconv import conv_kind, short_conv
 from ..ops.ssd import mamba2
 
 #: a public file's word for a layer's mixer -> the kind the parameters are
@@ -863,3 +863,22 @@ def step_routers(cfg: BackboneConfig, before: Dict, after: Dict, counters: Dict)
             if not cfg.router_trains:
                 after = put(after, path + ("router",), ffn["router"])
     return after
+
+
+def conv_kinds(cfg: BackboneConfig, length: int) -> Dict[str, str]:
+    """What runs the short convolutions of the mixers over rows of
+    ``length`` slots, as ``{"conv": "pallas"}`` or ``"xla"``
+    (``ops.shortconv.conv_kind`` at the channels each mixer convolves, where
+    they start in its wide projection and the dtype of its taps); nothing for
+    a backbone without one."""
+    d, qk = cfg.hidden_size, cfg.linear_num_key_heads * cfg.linear_key_head_dim
+    inner = cfg.mamba_n_heads * cfg.mamba_d_head
+    chains = {  # channels, taps, their dtype, where the parts start
+        "linear": (2 * qk + cfg.linear_num_value_heads * cfg.linear_value_head_dim,
+                   cfg.linear_conv_kernel_dim, "float32", (0,)),
+        "ssm": (inner + 2 * cfg.mamba_d_state, cfg.mamba_d_conv, "float32", (inner,)),
+        "conv": (d, cfg.conv_L_cache, cfg.gate_dtype, (0, d, 2 * d)),
+    }
+    ran = {conv_kind(channels, length, dtype, offsets, taps=taps)
+           for kind, (channels, taps, dtype, offsets) in chains.items() if kind in cfg.kinds}
+    return {"conv": "+".join(sorted(ran))} if ran else {}

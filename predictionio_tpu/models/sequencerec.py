@@ -463,7 +463,7 @@ class SeqRecAlgorithm(Algorithm):
         p = self.params
         cfg = p.backbone_config()
         tags = {"backbone": p.backbone or "toy", "steps": p.steps,
-                "layers": cfg.num_hidden_layers, **_delta_rule_walk(cfg), **_ssd_scan(cfg),
+                "layers": cfg.num_hidden_layers, **_mechanisms(cfg, pd.seq_len),
                 "mixers": " ".join(f"{name}:{n}" for name, n in cfg.mixers().items())}
         # the job's root span: under no server it starts a trace of its own
         with span("train", tags):
@@ -509,7 +509,7 @@ class SeqRecAlgorithm(Algorithm):
         with span("train.wait_device"):
             jax.block_until_ready(model_params)
         stats = {"fill": pd.fill, "steps": p.steps, "tokens_per_step": batch * pd.seq_len,
-                 "mixers": cfg.mixers(), **_delta_rule_walk(cfg), **_ssd_scan(cfg)}
+                 "mixers": cfg.mixers(), **_mechanisms(cfg, pd.seq_len)}
         if counters:
             stats.update(jax.tree_util.tree_map(np.asarray, counters))
         with span("train.fetch"):
@@ -570,6 +570,15 @@ class SeqRecAlgorithm(Algorithm):
 
     def query_class(self):
         return Query
+
+
+def _mechanisms(cfg: bb.BackboneConfig, length: int) -> Dict[str, str]:
+    """The counters that say which form of a mixer's inner loops a job over
+    rows of ``length`` slots runs: ``delta_rule_walk``, ``ssd_scan``, ``conv``,
+    each only where the backbone has such a mixer. (Defined below the
+    trainer: the Pallas kernels' serialized bodies record the source lines of
+    the frames above them, and a line added there misses the compile cache.)"""
+    return {**_delta_rule_walk(cfg), **_ssd_scan(cfg), **bb.conv_kinds(cfg, length)}
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))

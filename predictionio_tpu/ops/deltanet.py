@@ -30,7 +30,7 @@ the tiles allow (``dk`` and ``dv`` multiples of 128, the chunk a multiple of
 Packed rows: ``seg`` gives each slot the id of its history (one contiguous
 run per id). A history's first token resets the state, which the chunked
 form does by masking the decay between slots of different histories; the
-short convolution (:func:`.shortconv.causal_conv`) reads zero where a tap would
+short convolution (:func:`.shortconv.conv_chain`) reads zero where a tap would
 reach into the neighbour.
 
 Precision: gates ``alpha`` (as ``g = log alpha`` and its running sums) and
@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .shortconv import causal_conv
+from .shortconv import conv_chain
 
 _HI = jax.lax.Precision.HIGHEST
 _BLOCK = 16  # diagonal blocks solved row by row; the rest by products
@@ -338,7 +338,7 @@ def gated_deltanet(p: Dict, x, seg, *, key_heads: int, value_heads: int, key_dim
     def prepare(qkvz, ba, seg, conv_w, a_log, dt_bias):
         rows = qkvz.shape[0]
         with jax.named_scope("seq.deltanet.conv"):
-            qkv = jax.nn.silu(causal_conv(qkvz[..., :n_qkv].astype(f32), conv_w, seg))
+            qkv = conv_chain(qkvz, conv_w, seg, channels=n_qkv, silu=True)
             q = qkv[..., : hk * dk].reshape(rows, length, hk, dk)
             k = qkv[..., hk * dk: 2 * hk * dk].reshape(rows, length, hk, dk)
             v = qkv[..., 2 * hk * dk:].reshape(rows, length, hv, dv)

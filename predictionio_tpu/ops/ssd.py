@@ -33,7 +33,7 @@ its first slot, its last, or anywhere between.
 
 :func:`mamba2` is the mixer around the scan: in-projection to ``[z | x B
 C]`` and ``dt``, a depthwise causal convolution with a bias over ``x B C``
-(a tap in another history reads zero: :func:`.shortconv.causal_conv`),
+(a tap in another history reads zero: :func:`.shortconv.conv_chain`),
 SiLU, the scan, the skip ``D * u``, the gated RMS norm ``rms(y *
 silu(z)) * w`` over the whole inner width, out-projection.
 
@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from .deltanet import _HI, _with_state
-from .shortconv import causal_conv
+from .shortconv import conv_chain
 
 
 def _segsum(x):
@@ -158,8 +158,8 @@ def mamba2(p: Dict, x, seg, *, heads: int, head_dim: int, state: int, eps: float
         zxbc = jnp.dot(x.astype(cd), p["w_in"].astype(cd), preferred_element_type=f32).astype(cd)
         dt_raw = jnp.dot(x.astype(f32), p["w_dt"], precision=_HI)
     with jax.named_scope("seq.ssm.conv"):
-        xbc = jax.nn.silu(
-            causal_conv(zxbc[..., inner:].astype(f32), p["conv_w"], seg) + p["conv_b"])
+        xbc = conv_chain(zxbc, p["conv_w"], seg, channels=zxbc.shape[-1] - inner, at=inner,
+                         bias=p["conv_b"], silu=True)
         u = xbc[..., :inner].reshape(bsz, length, heads, head_dim).astype(cd)
         b, c = (xbc[..., inner + i * state: inner + (i + 1) * state].astype(cd) for i in (0, 1))
         dt = jax.nn.softplus(dt_raw + p["dt_bias"])

@@ -389,6 +389,33 @@ def as_tpu(monkeypatch):
 # rows of 8,192 slots, Qwen3-Next-80B-A3B widths, bfloat16 products
 SEQ_L = 8192
 
+#: temporaries of the three step programs with a short convolution as commit
+#: 90f0b5c (PR 39) compiled them, its XLA chain of pads and selects in them
+_TEMPORARIES_BEFORE_THE_KERNEL = {
+    "qwen3next": 6_815_364_096, "lfm2": 5_393_792_512 + 2 * 8192 * 2048 * 4,
+    "granite4h": 4_765_453_312}
+
+
+def _conv_under(compiled, scope: str, name: str = ""):
+    """The short convolution of a compiled step, read from its text: the
+    Pallas kernel's calls under ``scope`` (forward, the layer's
+    recomputation, backward: three a layer) and no ``pad`` of a float32
+    [rows, slots, C] array along its slots there, the shifted copies the XLA
+    chain made of every tap (DeltaNet's cotangents of q, k, v are still put
+    side by side by pads along the channels); temporaries no larger than
+    before the kernel, but for the float32 ``y`` LFM2's chain now hands out
+    (XLA's chain rounded it to bfloat16 in its last pass: PERF.md section 6);
+    without ``name``, a layer alone: no temporaries are compared."""
+    under = [line for line in compiled.as_text().splitlines() if scope in line]
+    calls = [line for line in under if 'custom_call_target="tpu_custom_call"' in line]
+    pads = [line for line in under
+            if re.search(r"= f32\[\d+,\d+,\d+\]\S* pad\(.*padding=\d+_\d+x(?!0_0x)", line)]
+    assert not pads, pads[0][:300]
+    if name:
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                <= _TEMPORARIES_BEFORE_THE_KERNEL[name])
+    return len(calls)
+
 
 @pytest.mark.parametrize("walk,state", [
     ("scan", "float32"), ("pallas", "float32"), ("pallas", "bfloat16")])
@@ -634,16 +661,18 @@ def test_qwen3next_step_two_rows_of_8k_fits_the_chip(one_chip, as_tpu):
     finally:
         step.clear_cache()  # the job's own program object, kept by ``_programs``
     _report("qwen3next step", compiled)
-    assert sequencerec._delta_rule_walk(cfg) == {"delta_rule_walk": "pallas"}
-    assert "tpu_custom_call" in compiled.as_text()
+    assert sequencerec._mechanisms(cfg, SEQ_L) == {"delta_rule_walk": "pallas", "conv": "pallas"}
+    # the period's three DeltaNet layers stacked in one scan, a row at a time
+    assert _conv_under(compiled, "seq.deltanet.conv", "qwen3next") >= 3
 
 
 # -- and at the shapes of train-lfm2-packed8k: LFM2-24B-A2B widths, gated short
 # convolutions beside grouped-query attention on heads of 64, 8 of 64 experts
-def test_short_conv_layer_two_rows_of_8k(one_chip):
+def test_short_conv_layer_two_rows_of_8k(one_chip, as_tpu):
     """One sparse layer whose mixer is the gated short convolution, as the
     step runs it (recomputed in the backward pass): three taps over 2,048
-    channels, router over 64, 8 held experts of 1,536 and no shared one."""
+    channels in the Pallas kernel, router over 64, 8 held experts of 1,536
+    and no shared one."""
     from predictionio_tpu.models import seq_backbone as bb
 
     cfg = bb.BackboneConfig.load("lfm2-24b-a2b-ep8")
@@ -665,9 +694,12 @@ def test_short_conv_layer_two_rows_of_8k(one_chip):
     stats = _report("short-convolution sparse layer", compiled)
     assert "ragged" in compiled.as_text()  # the grouped products: the compiler's own kernel
     assert stats.temp_size_in_bytes < 6 * 2**30
+    # forward, the layer's recomputation, backward; the chain's own checkpoint
+    # recomputes nothing, because the kernel's backward pass asks for no output
+    assert _conv_under(compiled, "seq.shortconv.conv") == 3
 
 
-def test_lfm2_step_two_rows_of_8k_fits_the_chip(one_chip):
+def test_lfm2_step_two_rows_of_8k_fits_the_chip(one_chip, as_tpu):
     """The whole optimizer step of ``train-lfm2-packed8k`` (2 rows of 8,193
     slots, 469 M parameters with their AdamW moments, donated) as the job
     compiles it: arguments 5.63 GB, temporaries 6.94 GB when this was
@@ -688,12 +720,14 @@ def test_lfm2_step_two_rows_of_8k_fits_the_chip(one_chip):
     stats = _report("lfm2 step", compiled)
     assert stats.argument_size_in_bytes + stats.temp_size_in_bytes < 15 * 2**30
     assert cfg.mixers() == {"gqa": 1, "shortconv": 4}
+    assert sequencerec._mechanisms(cfg, SEQ_L) == {"conv": "pallas"}
+    assert _conv_under(compiled, "seq.shortconv.conv", "lfm2") >= 3
 
 
 # -- and at the shapes of train-granite4h-packed: granite-4.0-h-micro widths, nine
 # Mamba-2 layers (64 heads of 64 on a state of 128, chunks of 256) beside one
 # grouped-query attention layer, ONE row of 8,192 slots a step
-def test_mamba2_layer_one_row_of_8k(one_chip):
+def test_mamba2_layer_one_row_of_8k(one_chip, as_tpu):
     """One Mamba-2 layer with its dense SwiGLU as the step runs it
     (recomputed in the backward pass): the [256, 256] matrices a head and
     chunk are its largest arrays, and the scan keeps none of them across
@@ -719,9 +753,10 @@ def test_mamba2_layer_one_row_of_8k(one_chip):
     stats = _report("mamba-2 layer", compiled)
     assert stats.temp_size_in_bytes < 3 * 2**30
     assert "while" not in compiled.as_text().split("ENTRY")[1]  # no loop over slots or chunks
+    assert _conv_under(compiled, "seq.ssm.conv") == 3
 
 
-def test_granite4h_step_one_row_of_8k_fits_the_chip(one_chip):
+def test_granite4h_step_one_row_of_8k_fits_the_chip(one_chip, as_tpu):
     """The whole optimizer step of ``train-granite4h-packed`` (1 row of
     8,193 slots, 772 M parameters with their AdamW moments, donated) as the
     job compiles it: arguments 9.27 GB, temporaries 4.77 GB when this was
@@ -743,4 +778,54 @@ def test_granite4h_step_one_row_of_8k_fits_the_chip(one_chip):
     stats = _report("granite4h step", compiled)
     assert stats.argument_size_in_bytes + stats.temp_size_in_bytes <= 15.75 * 2**30
     assert cfg.mixers() == {"gqa": 1, "mamba2": 9}
-    assert sequencerec._ssd_scan(cfg) == {"ssd_scan": "xla"}
+    assert sequencerec._mechanisms(cfg, SEQ_L) == {"ssd_scan": "xla", "conv": "pallas"}
+    assert _conv_under(compiled, "seq.ssm.conv", "granite4h") >= 3
+
+
+@pytest.mark.parametrize("case", ["deltanet", "mamba2", "lfm2"])
+def test_short_convolution_kernel_at_the_cells_shapes(one_chip, as_tpu, case):
+    """The convolution chain alone, forward and backward, at the three
+    callers' shapes (a row of 8,192 slots of the wide bfloat16 projection):
+    the chip's compiler takes the tiles ``ops.shortconv._tile`` picks, the
+    unaligned reads of the staged slots and the VMEM they ask for."""
+    from predictionio_tpu.ops import shortconv as sc
+
+    rows, width, channels, taps, pieces = {
+        "deltanet": (1, 12288, 8192, 4, dict(silu=True)),
+        "mamba2": (1, 8448, 4352, 4, dict(at=4096, silu=True, bias=True)),
+        "lfm2": (2, 6144, 2048, 3, dict(at=4096, gate_in=0, gate_out=2048)),
+    }[case]
+    with_bias = pieces.pop("bias", False)
+
+    def loss(src, w, bias, seg):
+        y = sc.conv_chain(src, w, seg, channels=channels, bias=bias if with_bias else None,
+                          interpret=False, **pieces)
+        return jnp.sum(y * y)
+
+    avals = (_sds(one_chip, (rows, SEQ_L, width), jnp.bfloat16),
+             _sds(one_chip, (taps, channels), jnp.float32),
+             _sds(one_chip, (channels,), jnp.float32), _sds(one_chip, (rows, SEQ_L), jnp.int32))
+    for program in (loss, jax.grad(loss, argnums=(0, 1, 2))):
+        assert 'custom_call_target="tpu_custom_call"' in _compile(program, *avals).as_text()
+
+
+def test_joyai_step_has_no_short_convolution(one_chip, as_tpu):
+    """``train-joyai-long8k``'s step runs no convolution: its lowered text
+    names no ``seq.<mixer>.conv`` scope and its job counts no ``conv`` (the text's hash
+    against the parent's is in ``CHANGES.md``)."""
+    from predictionio_tpu.models import seq_backbone as bb
+    from predictionio_tpu.models import sequencerec
+
+    cfg = bb.BackboneConfig.load("joyai-flash-48b-a3b-ep16")
+    opt_init, step, _ = sequencerec._programs(cfg, 3e-4, None, "auto")
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: bb.init_params(cfg, 16160, SEQ_L, 0)))
+    rows = _sds(one_chip, (2, SEQ_L + 1), jnp.int32)
+    try:
+        text = step.lower(params, on_chip(jax.eval_shape(opt_init, params)), rows, rows).as_text()
+    finally:
+        step.clear_cache()
+    assert "tpu_custom_call" in text and not re.search(r"seq\.\w+\.conv", text)
+    assert sequencerec._mechanisms(cfg, SEQ_L) == {}
+

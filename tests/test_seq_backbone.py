@@ -748,7 +748,7 @@ def test_pio_train_and_predict_with_the_backbone_configuration(tmp_path, monkeyp
     assert model.losses[-1] < model.losses[0]
     assert 0.0 < model.stats["fill"] <= 1.0
     # heads of 16 on the CPU: not the kernel's tiles
-    assert model.stats["delta_rule_walk"] == "scan"
+    assert model.stats["delta_rule_walk"] == "scan" and model.stats["conv"] == "xla"
     answer = SeqRecAlgorithm(algo_params).predict(
         model, Query(recent_items=("i0", "i1", "i2"), num=3))
     assert len(answer.item_scores) == 3
@@ -792,7 +792,7 @@ def test_a_job_counts_the_layer_steps_that_overflowed(forced, monkeypatch):
     tags = roots[-1]["tags"]
     assert tags["layer_steps"] == 24 and tags["overflow_layer_steps"] == stats["overflow_layer_steps"]
     assert tags["passes_by_step"].split() == [str(n) for n in stats["passes_by_step"].max(axis=(1, 2))]
-    assert tags["delta_rule_walk"] == "scan"
+    assert tags["delta_rule_walk"] == "scan" and tags["conv"] == "xla"
 
 
 def test_the_two_copies_of_the_reference_are_one_text():

@@ -416,9 +416,11 @@ def test_pio_train_and_predict_with_the_backbone_configuration(tmp_path, monkeyp
     stats = model.stats
     assert stats["mixers"] == {"gqa": 2, "mamba2": 6} and stats["ssd_scan"] == "xla"
     assert stats["tokens_per_step"] == 32 and "delta_rule_walk" not in stats
+    assert stats["conv"] == "xla"  # the CPU: the XLA form of the chain
     assert not [name for name in stats if "expert" in name or "router" in name or "passes" in name]
     roots = [s for s in default_tracer().store.dump() if s["name"] == "train" and s["parentId"] is None]
     assert roots[-1]["tags"]["mixers"] == "gqa:2 mamba2:6" and roots[-1]["tags"]["ssd_scan"] == "xla"
+    assert roots[-1]["tags"]["conv"] == "xla"
     answer = SeqRecAlgorithm(algo_params).predict(model, Query(recent_items=("i0", "i1", "i2"), num=3))
     scores = [s.score for s in answer.item_scores]
     assert len(scores) == 3 and scores == sorted(scores, reverse=True)

@@ -381,13 +381,14 @@ def test_pio_train_and_predict_with_the_backbone_configuration(tmp_path, monkeyp
     assert model.losses[-1] < model.losses[0]
     stats = model.stats
     assert stats["mixers"] == {"gqa": 2, "shortconv": 7} and "delta_rule_walk" not in stats
+    assert stats["conv"] == "xla"  # the CPU: the XLA form of the chain
     assert stats["router_tokens_by_step"].shape == (30, 2, 4, 8)
     bias = np.zeros((2, 4, 8), np.float32)
     for counts in stats["router_tokens_by_step"]:
         bias = ref.bias_step(bias, counts, model.config.router_bias_rate)
     np.testing.assert_allclose(model.params["periods"]["ffn"]["router_bias"], bias, atol=1e-6)
     roots = [s for s in default_tracer().store.dump() if s["name"] == "train" and s["parentId"] is None]
-    assert roots[-1]["tags"]["mixers"] == "gqa:2 shortconv:7"
+    assert roots[-1]["tags"]["mixers"] == "gqa:2 shortconv:7" and roots[-1]["tags"]["conv"] == "xla"
     answer = SeqRecAlgorithm(algo_params).predict(model, Query(recent_items=("i0", "i1", "i2"), num=3))
     scores = [s.score for s in answer.item_scores]
     assert len(scores) == 3 and scores == sorted(scores, reverse=True)

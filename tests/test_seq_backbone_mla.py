@@ -493,6 +493,7 @@ def test_pio_train_and_predict_with_the_backbone_configuration(tmp_path, monkeyp
     assert model.config.attention == "mla" and model.config.experts_held == (2, 3)
     assert model.losses[-1] < model.losses[0]
     stats = model.stats
+    assert "conv" not in stats and "delta_rule_walk" not in stats  # no mixer with a convolution
     assert stats["mtp_loss_by_step"].shape == (30,) and stats["mtp_loss_by_step"][-1] < stats["mtp_loss_by_step"][0]
     assert stats["router_tokens_by_step"].shape == (30, 2, 1, 8)
     assert stats["mtp_router_tokens_by_step"].shape == (30, 8)

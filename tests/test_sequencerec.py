@@ -245,3 +245,47 @@ def test_a_second_job_reuses_the_programs_and_trains_the_same():
         cfg, algo.params.learning_rate, None, "auto")[1]
     for key in ("embed", "pos"):
         np.testing.assert_array_equal(np.asarray(first[key]), np.asarray(second[key]))
+
+
+@pytest.mark.parametrize("backbone,backend,want", [
+    ("qwen3next-80b-a3b-ep16", "tpu", {"delta_rule_walk": "pallas", "conv": "pallas"}),
+    ("lfm2-24b-a2b-ep8", "tpu", {"conv": "pallas"}),
+    ("granite4h-micro-vp8", "tpu", {"ssd_scan": "xla", "conv": "pallas"}),
+    ("joyai-flash-48b-a3b-ep16", "tpu", {}),
+    ("qwen3next-80b-a3b-ep16", "cpu", {"delta_rule_walk": "scan", "conv": "xla"}),
+    ("lfm2-24b-a2b-ep8", "cpu", {"conv": "xla"}),
+    ("granite4h-micro-vp8", "cpu", {"ssd_scan": "xla", "conv": "xla"}),
+    ("qwen3next-tiny", "tpu", {"delta_rule_walk": "scan", "conv": "pallas"}),  # 128 channels
+    ("lfm2-tiny", "tpu", {"conv": "xla"}),
+    ("granite4h-tiny", "tpu", {"ssd_scan": "xla", "conv": "xla"}),
+])
+def test_the_counters_that_say_which_form_of_a_mixer_runs(monkeypatch, backbone, backend, want):
+    """``delta_rule_walk``, ``ssd_scan`` and ``conv`` in ``SeqRecModel.stats``
+    and on the job's ``train`` span: read from the widths, the row's length
+    and the backend, each only where the backbone has such a mixer; the
+    cells' configurations run the convolution's kernel on a TPU, channels
+    that are no lane tiles (64 and 160 of the toy widths) and the CPU the XLA
+    form."""
+    import jax
+
+    from predictionio_tpu.models import seq_backbone as bb
+    from predictionio_tpu.models import sequencerec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = bb.BackboneConfig.load(backbone)
+    assert sequencerec._mechanisms(cfg, 8192) == want
+    assert bb.conv_kinds(cfg, 8192) == {k: v for k, v in want.items() if k == "conv"}
+
+
+def test_a_row_that_is_no_whole_halo_blocks_runs_the_xla_form(monkeypatch):
+    import jax
+
+    from predictionio_tpu.models import seq_backbone as bb
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = bb.BackboneConfig.load("lfm2-24b-a2b-ep8")
+    assert bb.conv_kinds(cfg, 8192) == {"conv": "pallas"}
+    assert bb.conv_kinds(cfg, 8200) == {"conv": "xla"}
+    import dataclasses
+
+    assert bb.conv_kinds(dataclasses.replace(cfg, gate_dtype="bfloat16"), 8192) == {"conv": "xla"}
