@@ -3,12 +3,18 @@ of a configuration.
 
 A configuration gives the layer pattern (``layer_types``: a mixer a layer,
 ``full_attention`` or ``attention``, ``linear_attention`` = gated DeltaNet,
-``conv`` = the gated short convolution, or ``mamba`` = the Mamba-2
-state-space mixer, ``ssm`` here; or ``full_attention_interval``: every n-th
-layer is softmax attention, the others gated DeltaNet; 1 = all attention),
+``conv`` = the gated short convolution, ``mamba`` = the Mamba-2
+state-space mixer, ``ssm`` here, ``mamba1`` = the Mamba-1 selective scan,
+``sliding_attention`` = attention inside ``sliding_window`` slots, ``gmu`` =
+a gated memory unit that reads the scan output of the last ``mamba1`` layer
+below it, or ``cross_attention`` = queries of its own onto the keys and
+values of the last ``full_attention`` layer below it; or
+``full_attention_interval``: every n-th layer is softmax attention, the
+others gated DeltaNet; 1 = all attention),
 the attention (``gqa``: grouped heads of one width; ``mla``:
 latent attention, queries and keys/values projected down, normed and up
-again, a rotary part of the key that all heads share), the head and
+again, a rotary part of the key that all heads share; ``differential``:
+grouped heads in pairs, two softmaxes a pair and their difference), the head and
 feed-forward widths, the norm (``rms`` with scale ``1 + w``, or ``layer``),
 the positions (``rotary`` on part of a head, a ``learned`` table, or
 ``none``: the mixers' own order is all the model knows of it), Granite's four
@@ -51,16 +57,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.attention import attention
+from ..ops.attention import attention, tiles_skipped_by_window
 from ..ops.deltanet import gated_deltanet
 from ..ops.moe import expert_layer, swiglu
+from ..ops.selscan import gated_memory, mamba1
 from ..ops.shortconv import conv_kind, short_conv
 from ..ops.ssd import mamba2
 
 #: a public file's word for a layer's mixer -> the kind the parameters are
 #: stacked under
 _KINDS = {"full_attention": "full", "attention": "full", "linear_attention": "linear",
-          "conv": "conv", "mamba": "ssm"}
+          "conv": "conv", "mamba": "ssm", "mamba1": "mamba1", "sliding_attention": "swa",
+          "gmu": "gmu", "cross_attention": "cross"}
+#: what a layer of a kind hands to the layers above it in its period (of what
+#: its mixer returns beside its output), and what a layer of a kind reads of
+#: that: the newest below it
+_HANDS = {"mamba1": ("m",), "full": ("k", "v")}
+_READS = {"gmu": ("m",), "cross": ("k", "v")}
+#: the kinds of layer a decoder-hybrid-decoder adds (``_hybrid_mixer`` runs them)
+_HYBRID = ("mamba1", "swa", "gmu", "cross")
+#: a reading kind -> the kind that hands it what it reads, in a file's word, and what that is
+_PRODUCERS = {"gmu": ("mamba1", "mamba1", "a scan output"),
+              "cross": ("full", "full_attention", "keys and values")}
 
 CONF_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
@@ -91,6 +109,16 @@ class BackboneConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    #: differential attention (arXiv:2410.05258) on grouped heads: query and
+    #: key heads in pairs, a softmax a member, the second taken from the
+    #: first ``lambda`` times (four learned vectors a layer and a start that
+    #: follows the layer's depth), an RMS norm over a pair's value of twice
+    #: the head; the form of ``sliding_attention`` and ``cross_attention``
+    differential: bool = False
+    #: slots a ``sliding_attention`` layer sees: itself and the ones before
+    sliding_window: int = 0
+    #: the depth of this file's first layer in the published model
+    layer_index_offset: int = 0
     #: the single device's attention kernel: "xla" (the blockwise loop,
     #: which skips the tiles between histories) or "splash" (JAX's Pallas
     #: kernel where it can run: ``ops.attention.attention``)
@@ -100,6 +128,7 @@ class BackboneConfig:
     positions: str = "learned"  # "learned" | "rotary" | "none"
     norm: str = "layer"  # "layer" | "rms"
     rms_norm_eps: float = 1e-6
+    layer_norm_eps: float = 1e-6
     linear_num_key_heads: int = 0
     linear_num_value_heads: int = 0
     linear_key_head_dim: int = 0
@@ -115,6 +144,11 @@ class BackboneConfig:
     mamba_d_state: int = 0
     mamba_d_conv: int = 0
     mamba_n_groups: int = 0
+    #: the Mamba-1 mixer (``layer_types`` ``mamba1``) has ``mamba_expand`` x
+    #: ``hidden_size`` channels with ``mamba_d_state`` numbers of state each,
+    #: ``mamba_d_conv`` taps and a step projected through ``mamba_dt_rank``
+    mamba_expand: int = 0
+    mamba_dt_rank: int = 0
     #: what the attention scores are multiplied by; None = 1 / sqrt(head_dim)
     attention_multiplier: Optional[float] = None
     #: on the embedding; on what a mixer and a feed-forward add to the
@@ -172,7 +206,8 @@ class BackboneConfig:
 
     @property
     def kinds(self) -> Tuple[str, ...]:
-        """The mixer of every layer: ``full``, ``linear``, ``conv`` or ``ssm``."""
+        """The mixer of every layer: ``full``, ``linear``, ``conv``, ``ssm``,
+        ``mamba1``, ``swa``, ``gmu`` or ``cross``."""
         if self.layer_types:
             return tuple(_KINDS[t] for t in self.layer_types)
         p = self.full_attention_interval
@@ -208,11 +243,11 @@ class BackboneConfig:
 
     def mixers(self) -> Dict[str, int]:
         """Layers by the mixer they run: ``deltanet``, ``shortconv``,
-        ``mamba2``, and ``gqa`` or ``mla`` (the prediction module's block
-        counts too)."""
+        ``mamba2``, ``mamba1``, ``swa``, ``gmu``, ``cross``, and ``gqa`` or
+        ``mla`` (the prediction module's block counts too)."""
         names = {"linear": "deltanet", "conv": "shortconv", "ssm": "mamba2",
                  "full": self.attention}
-        found = [names[k] for k in self.kinds] + [self.attention] * self.num_nextn_predict_layers
+        found = [names.get(k, k) for k in self.kinds] + [self.attention] * self.num_nextn_predict_layers
         return {name: found.count(name) for name in sorted(set(found))}
 
     @classmethod
@@ -285,6 +320,7 @@ class BackboneConfig:
                                  "and none on its projections")
         if merged.get("attention_bias"):
             raise ValueError("the attention projections carry no bias here")
+        _check_hybrid(cfg, merged)
         if cfg.positions not in ("learned", "rotary", "none"):
             raise ValueError(f"positions {cfg.positions!r}: learned, rotary or none")
         if cfg.num_nextn_predict_layers not in (0, 1):
@@ -348,12 +384,25 @@ def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
         }
         if cfg.attn_gate or cfg.qk_norm:
             full.update(q_norm=((hd,), "zero"), k_norm=((hd,), "zero"))
+        if cfg.differential:
+            full.update({name: ((hd,), "lambda") for name in _LAMBDAS}, subln=((2 * hd,), "one"))
     hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
     mh, ns = cfg.mamba_n_heads, cfg.mamba_d_state
     inner = mh * cfg.mamba_d_head
+    wide, rank = cfg.mamba_expand * d, cfg.mamba_dt_rank
     mixers = {
         "full": full,
+        "swa": full,
+        "cross": {name: spec for name, spec in full.items() if name not in ("w_k", "w_v")},
+        "mamba1": {
+            "w_in": ((d, 2 * wide), "w"), "conv_w": ((cfg.mamba_d_conv, wide), "w"),
+            "conv_b": ((wide,), "zero"), "w_x": ((wide, rank + 2 * ns), "w"),
+            "w_dt": ((rank, wide), "w"), "dt_bias": ((wide,), "dt_bias"),
+            "A_log": ((wide, ns), "a_log_range"), "D": ((wide,), "one"),
+            "w_out": ((wide, d), "w"),
+        },
+        "gmu": {"w_1": ((d, wide), "w"), "w_2": ((wide, d), "w")},
         "linear": {
             "w_qkvz": ((d, 2 * hk * dk + 2 * hv * dv), "w"), "w_ba": ((d, 2 * hv), "w"),
             "conv_w": ((cfg.linear_conv_kernel_dim, 2 * hk * dk + hv * dv), "w"),
@@ -438,6 +487,11 @@ def _draw_program(cfg: BackboneConfig, vocab: int, max_positions: int):
                 leaf = 0.02 * jax.random.normal(k, shape, jnp.float32)
             elif kind == "a_log":  # A uniform in [1, 16), as the public implementation has it
                 leaf = jnp.log(jax.random.uniform(k, shape, jnp.float32, 1.0, 16.0))
+            elif kind == "a_log_range":  # A = 1 ... N along the state of every channel (Mamba-1)
+                leaf = jnp.log(jnp.broadcast_to(
+                    jnp.arange(1, shape[-1] + 1, dtype=jnp.float32), shape))
+            elif kind == "lambda":  # the four vectors of a differential layer's lambda
+                leaf = 0.1 * jax.random.normal(k, shape, jnp.float32)
             elif kind == "dt_bias":  # softplus^-1 of dt, dt log-uniform in [1e-3, 1e-1]
                 dt = jnp.exp(jax.random.uniform(
                     k, shape, jnp.float32, np.log(1e-3), np.log(1e-1)))
@@ -460,13 +514,18 @@ def layers_of(params: Dict, cfg: BackboneConfig) -> Dict:
     prediction module is ``mtp``; ``testing/lfm2_moe_reference.py``, whose
     mixers are ``conv`` and ``full`` and whose head is its embedding;
     ``testing/granite4h_reference.py``, whose mixers are ``ssm`` and
-    ``full`` and whose every layer carries ``mlp``).
+    ``full`` and whose every layer carries ``mlp``;
+    ``testing/phi4flash_reference.py``, whose mixers are ``mamba1``, ``swa``,
+    ``full``, ``gmu`` and ``cross`` and whose norms are ``{"g", "b"}``).
     Works on any pytree of the parameters' structure: gradients too."""
     full_key = "attn" if cfg.attention == "mla" else "full"
     ffn_key = "moe" if cfg.ffn == "moe" else "mlp"
 
+    def norm(p):  # an RMS norm is its one leaf, a LayerNorm its two
+        return p["w"] if "w" in p else p
+
     def block(blk, kind="full", ffn_key=ffn_key):
-        return {"input_norm": blk["norm_in"]["w"], "post_norm": blk["norm_post"]["w"],
+        return {"input_norm": norm(blk["norm_in"]), "post_norm": norm(blk["norm_post"]),
                 ffn_key: blk["ffn"], full_key if kind == "full" else kind: blk[kind]}
 
     layers = []
@@ -481,7 +540,7 @@ def layers_of(params: Dict, cfg: BackboneConfig) -> Dict:
                    for name in ("norm_in", "norm_post", "ffn")}
             blk[kind] = _mixer_of(cfg, at_n, j)
             layers.append(block(blk, kind))
-    out = {"embed": params["embed"], "final_norm": params["final_norm"]["w"], "layers": layers}
+    out = {"embed": params["embed"], "final_norm": norm(params["final_norm"]), "layers": layers}
     if "head" in params:
         out["head"] = params["head"]
     if "mtp" in params:
@@ -510,7 +569,7 @@ def _norm(cfg: BackboneConfig, p: Dict, x):
             1.0 + p["w"])
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + 1e-6) * p["g"] + p["b"]
+    return (x - mu) * jax.lax.rsqrt(var + cfg.layer_norm_eps) * p["g"] + p["b"]
 
 
 def positions_of(seg):
@@ -651,10 +710,13 @@ def _add(cfg: BackboneConfig, x, y):
 
 
 def _layer(cfg: BackboneConfig, kind: str, mesh, schedule, x, seg, pos,
-           norm_in, mixer, norm_post, ffn):
+           norm_in, mixer, norm_post, ffn, given=None, *, depth: int = 0):
     h = _norm(cfg, norm_in, x)
     ran = {}
-    if kind == "full" and cfg.attention == "mla":
+    if kind in _HYBRID or (kind == "full" and cfg.differential):
+        mixed, ran = _hybrid_mixer(cfg, kind, mixer, h, seg, mesh, schedule, given, depth)
+        x = _add(cfg, x, mixed)
+    elif kind == "full" and cfg.attention == "mla":
         with jax.named_scope("seq.attn"):
             mixed, ran = _latent_mixer(cfg, mixer, h, seg, pos, mesh, schedule)
             x = _add(cfg, x, mixed)
@@ -687,11 +749,14 @@ def _layer(cfg: BackboneConfig, kind: str, mesh, schedule, x, seg, pos,
     return _add(cfg, x, y), counters, ran
 
 
-def _layer_fn(cfg: BackboneConfig, kind: str, mesh, schedule):
-    """One layer whose mixer is of ``kind`` (``full``, ``linear``, ``conv``,
-    ``ssm``) as ``(x, seg, pos, norm_in, mixer, norm_post, ffn) -> x, counters,
-    ran``, recomputed in the backward pass."""
-    return jax.checkpoint(lambda *a: _layer(cfg, kind, mesh, schedule, *a))
+def _layer_fn(cfg: BackboneConfig, kind: str, mesh, schedule, depth: int = 0):
+    """One layer whose mixer is of ``kind`` (``BackboneConfig.kinds``) as ``(x,
+    seg, pos, norm_in, mixer, norm_post, ffn) -> x, counters, ran``, recomputed
+    in the backward pass; a layer that reads what a layer below handed on
+    (``_READS``) takes that, a dict, as one more argument. ``depth``: the
+    layer's index in the published model, which a differential layer's
+    ``lambda`` starts from."""
+    return jax.checkpoint(lambda *a: _layer(cfg, kind, mesh, schedule, *a, depth=depth))
 
 
 def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
@@ -704,6 +769,9 @@ def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
     ``ops.deltanet.gated_deltanet``; latent attention's q, k, v and o; the
     short convolution's ``bcx`` and ``y``: ``ops.shortconv.short_conv``; the
     state-space scan's ``u``, ``B``, ``C``, ``dt`` and ``y``: ``ops.ssd.mamba2``;
+    the selective scan's ``c``, ``dt``, ``B``, ``C``, ``y`` and ``m``:
+    ``ops.selscan.mamba1``, and beside them the first differential layer's
+    ``q``, ``k``, ``v``, ``lam`` and ``o``: ``_differential_mixer``;
     stacked [periods, B, ...]; empty where no mixer of a period does)."""
     pos = positions_of(seg)
     with jax.named_scope("seq.embed"):
@@ -725,14 +793,19 @@ def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
         x, _, _ = layer_of[kind](x, seg, pos, d["norm_in"], d[kind], d["norm_post"], d["ffn"])
 
     def one_period(x, per):
-        counters, first_ran = [], {}
+        counters, first_ran, given = [], {}, {}
         for j, kind in enumerate(cfg.period_kinds):
             pick = lambda tree, j=j: jax.tree_util.tree_map(lambda a: a[j], tree)  # noqa: E731
             mixer = _mixer_of(cfg, per, j)
-            x, c, ran = layer_of[kind](
-                x, seg, pos, pick(per["norm_in"]), mixer, pick(per["norm_post"]), pick(per["ffn"]))
+            layer = layer_of[kind] if not cfg.differential else _layer_fn(
+                cfg, kind, mesh, schedule, cfg.layer_index_offset + cfg.first_k_dense_replace + j)
+            reads = ({name: given[name] for name in _READS[kind]},) if kind in _READS else ()
+            x, c, ran = layer(x, seg, pos, pick(per["norm_in"]), mixer, pick(per["norm_post"]),
+                              pick(per["ffn"]), *reads)
+            given.update({name: ran[name] for name in _HANDS.get(kind, ()) if name in ran})
             counters.append(c)
-            first_ran = first_ran or ran
+            # every name from the first mixer of the period that gives it
+            first_ran = {**ran, **first_ran}
         stacked = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *counters)
         return x, (stacked, first_ran)
 
@@ -877,8 +950,144 @@ def conv_kinds(cfg: BackboneConfig, length: int) -> Dict[str, str]:
         "linear": (2 * qk + cfg.linear_num_value_heads * cfg.linear_value_head_dim,
                    cfg.linear_conv_kernel_dim, "float32", (0,)),
         "ssm": (inner + 2 * cfg.mamba_d_state, cfg.mamba_d_conv, "float32", (inner,)),
+        "mamba1": (cfg.mamba_expand * d, cfg.mamba_d_conv, "float32", (0,)),
         "conv": (d, cfg.conv_L_cache, cfg.gate_dtype, (0, d, 2 * d)),
     }
     ran = {conv_kind(channels, length, dtype, offsets, taps=taps)
            for kind, (channels, taps, dtype, offsets) in chains.items() if kind in cfg.kinds}
     return {"conv": "+".join(sorted(ran))} if ran else {}
+
+
+# -- the decoder-hybrid-decoder's mixers (below the frames the Pallas kernels
+# record: PERF.md section 7) --------------------------------------------------
+#: the four learned vectors of a differential layer's lambda
+_LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+
+
+def _check_hybrid(cfg: BackboneConfig, merged: Dict) -> None:
+    """What ``from_dict`` asks of a configuration with ``mamba1``,
+    ``sliding_attention``, ``gmu`` or ``cross_attention`` layers or with
+    differential attention, each refusal in words."""
+    kinds = cfg.kinds
+    words = {kind: word for word, kind in _KINDS.items()}
+    for i, kind in enumerate(cfg.period_kinds):
+        if kind in _PRODUCERS:
+            producer, word, product = _PRODUCERS[kind]
+            if producer not in cfg.period_kinds[:i]:
+                raise ValueError(
+                    f"layer {cfg.first_k_dense_replace + i} is a {words[kind]} layer and no "
+                    f"{word} layer below it in its period hands it {product}")
+    hybrid = set(_HYBRID) & set(kinds)
+    if hybrid & set(kinds[:cfg.first_k_dense_replace]):
+        raise ValueError("the leading dense layers hand nothing on: mamba1, sliding_attention, "
+                         "gmu and cross_attention layers belong to the periods")
+    if {"swa", "cross"} & hybrid and not cfg.differential:
+        raise ValueError("sliding_attention and cross_attention layers run differential "
+                         "attention here: the backbone group has to say differential")
+    if cfg.differential:
+        if cfg.attention != "gqa" or cfg.attn_gate or cfg.qk_norm or cfg.positions == "rotary":
+            raise ValueError("differential attention here is grouped-query attention without "
+                             "a gate, a norm on q and k or rotary positions")
+        if cfg.num_attention_heads % 2 or cfg.num_key_value_heads % 2 or (
+                cfg.num_attention_heads % cfg.num_key_value_heads):
+            raise ValueError("differential attention pairs up query heads and key heads: "
+                             "both counts even, the first a multiple of the second")
+        if cfg.n_periods != 1 or cfg.num_nextn_predict_layers:
+            raise ValueError("a differential layer's lambda starts from its depth: the layers "
+                             "after the dense ones have to be ONE period, with no prediction module")
+    if "swa" in kinds and cfg.sliding_window <= 0:
+        raise ValueError("sliding_attention layers need sliding_window: no default is assumed")
+    if "mamba1" in kinds:
+        sizes = ("mamba_d_state", "mamba_d_conv", "mamba_expand", "mamba_dt_rank")
+        missing = [name for name in sizes if not getattr(cfg, name)]
+        if missing:
+            raise ValueError(f"mamba1 layers need {', '.join(missing)}: no default is assumed")
+        if not merged.get("mamba_conv_bias", True) or merged.get("mamba_proj_bias"):
+            raise ValueError("the Mamba-1 mixer here has a bias on its convolution "
+                             "and none on its projections")
+
+
+def lambda_init(depth: int) -> float:
+    """Where a differential layer's lambda starts, by the layer's depth."""
+    return 0.8 - 0.6 * float(np.exp(-0.3 * depth))
+
+
+def _differential_mixer(cfg: BackboneConfig, p: Dict, x, seg, mesh, schedule, depth: int,
+                        window: int = 0, given: Optional[Dict] = None):
+    """Differential attention on grouped heads: query heads (2p, 2p + 1) are
+    pair p, key heads (2c, 2c + 1) key pair c, value heads (2c, 2c + 1) side
+    by side ONE value of twice the head; query pair p reads key pair ``p //
+    (pairs a key pair)``. Per pair ``(softmax(q1 k1) - lambda softmax(q2 k2))
+    v``, an RMS norm over the value's width, ``1 - lambda_init``, ``W_o``.
+    Both softmaxes are ONE call of the attention core: the members lie along
+    its head axis, first of all pairs, then second, over the values twice.
+    ``given`` (a cross-attention layer): the ``k`` and ``v`` of the full
+    layer below, as that layer's call of this function returned them. Also
+    returns the ``q``, ``k``, ``v`` the core was handed [B, 2 pairs, L, .],
+    ``lam`` and ``o``, the difference before the norm [B, pairs, L, 2 hd]."""
+    b, l, _ = x.shape
+    h, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    cd, f32 = _dt(cfg.compute_dtype), jnp.float32
+    xc = x.astype(cd)
+
+    def members_first(t, heads):  # [B, L, heads * hd] -> [B, member, pair, L, hd] -> [B, heads, L, hd]
+        return t.reshape(b, l, heads // 2, 2, hd).transpose(0, 3, 2, 1, 4).reshape(b, heads, l, hd)
+
+    q = members_first(jnp.dot(xc, p["w_q"].astype(cd), preferred_element_type=f32).astype(cd), h)
+    if given is None:
+        k = members_first(
+            jnp.dot(xc, p["w_k"].astype(cd), preferred_element_type=f32).astype(cd), hkv)
+        v = jnp.dot(xc, p["w_v"].astype(cd), preferred_element_type=f32).astype(cd)
+        v = v.reshape(b, l, hkv // 2, 2 * hd).transpose(0, 2, 1, 3)
+        v = jnp.concatenate([v, v], axis=1)  # each member's softmax over the pair's one value
+    else:
+        k, v = given["k"], given["v"]
+    start = lambda_init(depth)
+    lam = (jnp.exp(jnp.sum(p["lambda_q1"] * p["lambda_k1"]))
+           - jnp.exp(jnp.sum(p["lambda_q2"] * p["lambda_k2"])) + start)
+    with jax.named_scope("seq.attn.swa.core" if window else "seq.attn.core"):
+        both = attention(q, k, v, mesh=mesh, causal=True, schedule=schedule, segment_ids=seg,
+                         block=cfg.attn_block, window=window).astype(f32)
+        o = both[:, : h // 2] - lam * both[:, h // 2:]  # [B, pairs, L, 2 hd]
+    normed = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.rms_norm_eps) * (
+        p["subln"] * (1.0 - start))
+    out = jnp.dot(normed.transpose(0, 2, 1, 3).reshape(b, l, h * hd).astype(cd),
+                  p["w_o"].astype(cd), preferred_element_type=f32)
+    return out, {"q": q, "k": k, "v": v, "lam": lam, "o": o}
+
+
+def _hybrid_mixer(cfg: BackboneConfig, kind: str, p: Dict, h, seg, mesh, schedule,
+                  given: Optional[Dict], depth: int):
+    """The mixers of a decoder-hybrid-decoder, ``h`` the layer's normed
+    input: a Mamba-1 layer, a gated memory unit on the scan output ``m`` a
+    Mamba-1 layer below handed on, or differential attention (full; inside a
+    window: ``seq.attn.swa`` around the layer, its core ``seq.attn.swa.core``;
+    or queries alone onto the ``k``, ``v`` a full layer below handed on)."""
+    if kind == "mamba1":
+        with jax.named_scope("seq.mamba"):
+            return mamba1(
+                p, h, seg, state=cfg.mamba_d_state, dt_rank=cfg.mamba_dt_rank, chunk=cfg.chunk,
+                compute_dtype=_dt(cfg.compute_dtype), state_dtype=_dt(cfg.state_dtype),
+                gate_dtype=_dt(cfg.gate_dtype))
+    if kind == "gmu":
+        with jax.named_scope("seq.gmu"):
+            return gated_memory(p, h, given["m"], compute_dtype=_dt(cfg.compute_dtype)), {}
+    with jax.named_scope("seq.attn"):
+        if kind == "swa":
+            with jax.named_scope("seq.attn.swa"):
+                return _differential_mixer(cfg, p, h, seg, mesh, schedule, depth,
+                                           window=cfg.sliding_window)
+        return _differential_mixer(cfg, p, h, seg, mesh, schedule, depth,
+                                   given=given if kind == "cross" else None)
+
+
+def window_tiles(cfg: BackboneConfig, length: int) -> Dict[str, int]:
+    """``attn_tiles_skipped_by_window``: the tiles of the blockwise attention
+    loop that the window alone leaves out, over the sliding layers of one
+    forward pass of a row of ``length`` slots; nothing for a backbone without
+    such a layer."""
+    layers = cfg.kinds.count("swa")
+    if not layers:
+        return {}
+    return {"attn_tiles_skipped_by_window":
+            layers * tiles_skipped_by_window(length, cfg.attn_block, cfg.sliding_window)}

@@ -47,6 +47,7 @@ from ..controller import (
     Preparator,
 )
 from ..obs.trace import span
+from ..ops import selscan
 from ..ops.deltanet import walk_kind
 from ..ops.scoring import top_k_for_vectors
 from ..ops.ssd import scan_kind
@@ -574,11 +575,15 @@ class SeqRecAlgorithm(Algorithm):
 
 def _mechanisms(cfg: bb.BackboneConfig, length: int) -> Dict[str, str]:
     """The counters that say which form of a mixer's inner loops a job over
-    rows of ``length`` slots runs: ``delta_rule_walk``, ``ssd_scan``, ``conv``,
-    each only where the backbone has such a mixer. (Defined below the
+    rows of ``length`` slots runs: ``delta_rule_walk``, ``ssd_scan``,
+    ``selective_scan`` ("pallas" or "xla": ``ops.selscan.scan_kind``), ``conv``,
+    and ``attn_tiles_skipped_by_window`` (``seq_backbone.window_tiles``), each
+    only where the backbone has such a mixer. (Defined below the
     trainer: the Pallas kernels' serialized bodies record the source lines of
     the frames above them, and a line added there misses the compile cache.)"""
-    return {**_delta_rule_walk(cfg), **_ssd_scan(cfg), **bb.conv_kinds(cfg, length)}
+    selective = {"selective_scan": selscan.scan_kind()} if "mamba1" in cfg.kinds else {}
+    return {**_delta_rule_walk(cfg), **_ssd_scan(cfg), **selective,
+            **bb.conv_kinds(cfg, length), **bb.window_tiles(cfg, length)}
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))

@@ -86,13 +86,15 @@ def _grouped_and_padded(q, k, v, segment_ids, bq: int, bk: int):
     return qg, k, v, seg_q, seg_k
 
 
-def _block_pairs(lq: int, lk: int, bq: int, bk: int, causal: bool):
+def _block_pairs(lq: int, lk: int, bq: int, bk: int, causal: bool, window: int = 0):
     """Index arrays (i, j) of every Q block i and KV block j that can hold
     a kept score: all of them, or under a causal mask those on or below the
-    diagonal."""
+    diagonal, less, under a ``window``, those whose nearest pair of slots
+    already lies a window apart."""
     pairs = [
         (i, j) for i in range(lq // bq) for j in range(lk // bk)
-        if not causal or j * bk <= (i + 1) * bq - 1
+        if (not causal or j * bk <= (i + 1) * bq - 1)
+        and (not window or i * bq - ((j + 1) * bk - 1) < window)
     ]
     return (jnp.asarray([p[0] for p in pairs], jnp.int32),
             jnp.asarray([p[1] for p in pairs], jnp.int32))
@@ -113,8 +115,9 @@ def _pair_meets(seg_q, seg_k, i, j, bq, bk):
     return jnp.any((sq.max(1) >= sk.min(1)) & (sq.min(1) <= sk.max(1)))
 
 
-def _pair_tile(q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, after):
-    """Scores of Q block i against KV block j and the mask of those kept.
+def _pair_tile(q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, after, window=0):
+    """Scores of Q block i against KV block j and the mask of those kept
+    (under a ``window`` a slot keeps itself and the ``window - 1`` before it).
     q [B, Hkv, G, Lq, D], k [B, Hkv, Lk, D], seg [B, L]. ``after`` is a
     value of this iteration's carry: the barrier makes the tile wait for
     it, or the compiler computes every pair's tile ahead of the loop and
@@ -129,13 +132,16 @@ def _pair_tile(q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, after):
     keep = jnp.broadcast_to(k_pos[None, :] < lk, (bq, bk))
     if causal:
         keep = keep & (q_pos[:, None] >= k_pos[None, :])
+    if window:
+        keep = keep & (q_pos[:, None] - k_pos[None, :] < window)
     sq = jax.lax.dynamic_slice_in_dim(seg_q, i * bq, bq, axis=1)
     sk = jax.lax.dynamic_slice_in_dim(seg_k, j * bk, bk, axis=1)
     keep = keep[None] & (sq[:, :, None] == sk[:, None, :])  # [B, bq, bk]
     return qi, kj, s, keep[:, None, None], after
 
 
-def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype=jnp.float32):
+def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype=jnp.float32,
+                   window=0):
     """Online softmax over the block pairs; returns o (q's dtype, v's
     width) and the log-sum-exp of every row [B, Hkv, G, Lq] (float32). The
     running maximum, sum and output are kept in ``stats_dtype`` between
@@ -143,7 +149,7 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype=jnp.fl
     b, hkv, g, lq, d = q.shape
     f32 = jnp.float32
     scale = 1.0 / np.sqrt(d)
-    ii, jj = _block_pairs(lq, k.shape[2], bq, bk, causal)
+    ii, jj = _block_pairs(lq, k.shape[2], bq, bk, causal, window)
 
     def body(t, carry):
         i, j = ii[t], jj[t]
@@ -152,7 +158,7 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype=jnp.fl
             m, l, o = carry
             mi = jax.lax.dynamic_slice_in_dim(m, i * bq, bq, axis=3)
             qi, kj, s, keep, mi = _pair_tile(
-                q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, mi)
+                q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, mi, window)
             mi = mi.astype(f32)
             li = jax.lax.dynamic_slice_in_dim(l, i * bq, bq, axis=3).astype(f32)
             oi = jax.lax.dynamic_slice_in_dim(o, i * bq, bq, axis=3).astype(f32)
@@ -180,12 +186,12 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype=jnp.fl
     return (o.astype(f32) / l[..., None]).astype(q.dtype), m.astype(f32) + jnp.log(l)
 
 
-def _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk):
+def _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk, window=0):
     """The flash backward pass: scores are recomputed tile by tile from q,
     k and the saved log-sum-exp; the score matrix is never a residual."""
     b, hkv, g, lq, d = q.shape
     scale = 1.0 / np.sqrt(d)
-    ii, jj = _block_pairs(lq, k.shape[2], bq, bk, causal)
+    ii, jj = _block_pairs(lq, k.shape[2], bq, bk, causal, window)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
 
     def body(t, carry):
@@ -195,7 +201,7 @@ def _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk):
             dq, dk, dv = carry
             dq_old = jax.lax.dynamic_slice_in_dim(dq, i * bq, bq, axis=3)
             qi, kj, s, keep, dq_old = _pair_tile(
-                q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, dq_old)
+                q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, dq_old, window)
             vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=2)
             doi = jax.lax.dynamic_slice_in_dim(do, i * bq, bq, axis=3)
             lsei = jax.lax.dynamic_slice_in_dim(lse, i * bq, bq, axis=3)
@@ -227,26 +233,28 @@ def _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype):
-    return _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype, window):
+    return _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype, window)[0]
 
 
-def _flash_vjp_fwd(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype):
-    o, lse = _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype)
+def _flash_vjp_fwd(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype, window):
+    o, lse = _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype, window)
     return o, (q, k, v, seg_q, seg_k, o, lse)
 
 
-def _flash_vjp_bwd(causal, bq, bk, lk, stats_dtype, res, do):
+def _flash_vjp_bwd(causal, bq, bk, lk, stats_dtype, window, res, do):
     q, k, v, seg_q, seg_k, o, lse = res
-    dq, dk, dv = _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk)
+    dq, dk, dv = _flash_backward(
+        q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk, window)
     return dq, dk, dv, None, None
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_k", "block_q", "stats_dtype"))
+@functools.partial(
+    jax.jit, static_argnames=("causal", "block_k", "block_q", "stats_dtype", "window"))
 def flash_attention(
     q: jax.Array,  # [B, H, Lq, D]
     k: jax.Array,  # [B, Hkv, Lk, D], H a multiple of Hkv
@@ -256,9 +264,14 @@ def flash_attention(
     segment_ids: Optional[jax.Array] = None,  # [B, L]: packed rows, Lq == Lk
     block_q: Optional[int] = None,
     stats_dtype: str = "float32",
+    window: int = 0,
 ) -> jax.Array:
     """Blockwise attention with online softmax (single device): [B, H, Lq,
     Dv]. Scores are scaled by ``1 / sqrt(D)``, the width q and k share.
+    With a ``window`` (under ``causal``) a slot attends to itself and the
+    ``window - 1`` slots before it: tiles wholly outside it are left out of
+    the loop (:func:`tiles_skipped_by_window`), the ones that straddle its
+    edge are masked inside; 0 = no window.
 
     Query heads share key/value heads in groups (``H / Hkv`` each). With
     ``segment_ids`` a slot attends only to slots of its own id (histories
@@ -266,6 +279,8 @@ def flash_attention(
     as are, under ``causal``, the tiles above the diagonal. Differentiable:
     the backward pass recomputes each tile from q, k and the rows'
     log-sum-exp, so neither direction holds an [L, L] matrix."""
+    if window and not causal:
+        raise ValueError("a window is one of slots before a slot: causal attention only")
     b, h, lq, d = q.shape
     lk = k.shape[2]
     bk = min(block_k, lk)
@@ -273,8 +288,18 @@ def flash_attention(
     qg, k, v, seg_q, seg_k = _grouped_and_padded(q, k, v, segment_ids, bq, bk)
     seg_q = jnp.pad(seg_q, ((0, 0), (0, -lq % bq)), mode="edge")
     seg_k = jnp.pad(seg_k, ((0, 0), (0, -lk % bk)), mode="edge")
-    o = _flash(qg, k, v, seg_q, seg_k, causal, bq, bk, lk, jnp.dtype(stats_dtype))
+    o = _flash(qg, k, v, seg_q, seg_k, causal, bq, bk, lk, jnp.dtype(stats_dtype), window)
     return o[:, :, :, :lq].reshape(b, h, lq, v.shape[-1])
+
+
+def tiles_skipped_by_window(length: int, block: int, window: int) -> int:
+    """Tiles of one causal pass of :func:`flash_attention` over rows of
+    ``length`` slots that the ``window`` alone leaves out of the loop, from
+    the static pair lists (0 without a window)."""
+    blk = min(block, length)
+    padded = length + -length % blk
+    return (len(_block_pairs(padded, padded, blk, blk, True)[0])
+            - len(_block_pairs(padded, padded, blk, blk, True, window)[0]))
 
 
 #: tile edge of the Pallas kernel, forward and backward: the best of 512,
@@ -455,6 +480,7 @@ def attention(
     block: int = 512,
     stats_dtype: str = "float32",
     kernel: str = "xla",
+    window: int = 0,
 ) -> jax.Array:
     """Dispatch: single-device flash when no mesh / 1-device axis; otherwise
     ring (default) or Ulysses (``schedule="ulysses"``, when heads divide).
@@ -463,16 +489,19 @@ def attention(
     ``stats_dtype`` says. ``kernel="splash"`` asks the single device for
     :func:`splash_attention` in place of the XLA loop; it is given where it
     can run (a TPU, packed rows of whole blocks, one key/value head a query
-    head, float32 statistics) and the XLA loop elsewhere."""
+    head, float32 statistics) and the XLA loop elsewhere. A ``window``
+    (:func:`flash_attention`) is the XLA loop's on a single device."""
     if mesh is None or axis not in mesh.shape or mesh.shape[axis] == 1:
-        if (kernel == "splash" and causal and segment_ids is not None
+        if (kernel == "splash" and causal and segment_ids is not None and not window
                 and _splash_fits(q, k, stats_dtype)):
             return splash_attention(q, k, v, segment_ids)
         if kernel not in ("xla", "splash"):
             raise ValueError(f"unknown attention kernel {kernel!r}")
         return flash_attention(
             q, k, v, causal=causal, block_k=block, segment_ids=segment_ids,
-            stats_dtype=stats_dtype)
+            stats_dtype=stats_dtype, window=window)
+    if window:
+        raise ValueError("the sharded attention schedules know no window")
     if k.shape[1] != q.shape[1]:
         k = jnp.repeat(k, q.shape[1] // k.shape[1], axis=1)
         v = jnp.repeat(v, q.shape[1] // v.shape[1], axis=1)

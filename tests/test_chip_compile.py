@@ -782,6 +782,80 @@ def test_granite4h_step_one_row_of_8k_fits_the_chip(one_chip, as_tpu):
     assert _conv_under(compiled, "seq.ssm.conv", "granite4h") >= 3
 
 
+# -- and at the shapes of train-phi4flash-long8k: Phi-4-mini-flash widths, two
+# Mamba-1 selective scans (5,120 channels on a state of 16), differential
+# attention inside a window of 512, in full and as cross-attention, a gated
+# memory unit, ONE row of 8,192 slots a step
+def test_selective_scan_one_row_of_8k(one_chip):
+    """The selective scan alone, forward and backward, at the cell's shape:
+    neither direction keeps a state a slot (8,192 x 5,120 x 16 floats would be
+    2.7 GB): a block's states are the backward pass's largest array."""
+    from predictionio_tpu.ops.selscan import selective_scan
+
+    def loss(x, dt, a, b, c, seg):
+        return selective_scan(x, dt, a, b, c, seg, chunk=64).sum()
+
+    wide = _sds(one_chip, (1, SEQ_L, 5120), jnp.float32)
+    state = _sds(one_chip, (1, SEQ_L, 16), jnp.float32)
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), _sds(one_chip, (1, SEQ_L, 5120), jnp.bfloat16),
+        wide, _sds(one_chip, (5120, 16), jnp.float32), state, state,
+        _sds(one_chip, (1, SEQ_L), jnp.int32))
+    stats = _report("selective scan", compiled)
+    assert stats.temp_size_in_bytes < 2 * 2**30
+
+
+def test_windowed_attention_one_row_of_8k(one_chip):
+    """Differential attention's core as one call of the blockwise loop (40
+    member heads on 20 key heads of 64, values of 128) under the window of
+    512, forward and backward; the window is in the loop's trip count: 31
+    tiles where the full layer walks 136."""
+    from predictionio_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v, seg, window):
+        return flash_attention(q, k, v, causal=True, block_k=512, segment_ids=seg,
+                               window=window).astype(jnp.float32).sum()
+
+    avals = (_sds(one_chip, (1, 40, SEQ_L, 64), jnp.bfloat16),
+             _sds(one_chip, (1, 20, SEQ_L, 64), jnp.bfloat16),
+             _sds(one_chip, (1, 20, SEQ_L, 128), jnp.bfloat16), _sds(one_chip, (1, SEQ_L), jnp.int32))
+    texts = {window: _compile(jax.grad(functools.partial(loss, window=window), argnums=(0, 1, 2)),
+                              *avals).as_text() for window in (512, 0)}
+    assert "s32[31]" in texts[512] and "s32[136]" not in texts[512]
+    assert "s32[136]" in texts[0] and "s32[31]" not in texts[0]
+
+
+def test_phi4flash_step_one_row_of_8k_fits_the_chip(one_chip, as_tpu):
+    """The whole optimizer step of ``train-phi4flash-long8k`` (1 row of 8,193
+    slots, 697 M parameters with their AdamW moments, donated) as the job
+    compiles it: arguments 8.36 GB, temporaries 6.50 GB when this was written
+    (``PERF.md`` section 4); the chip's 15.75 GiB hold both."""
+    from predictionio_tpu.models import seq_backbone as bb
+    from predictionio_tpu.models import sequencerec
+
+    cfg = bb.BackboneConfig.load("phi4-mini-flash-vp8")
+    opt_init, step, _ = sequencerec._programs(cfg, 3e-4, None, "auto")
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: bb.init_params(cfg, 25008, SEQ_L, 0)))
+    assert sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(params)) == 697_073_792
+    rows = _sds(one_chip, (1, SEQ_L + 1), jnp.int32)
+    try:
+        compiled = _compile(step, params, on_chip(jax.eval_shape(opt_init, params)), rows, rows)
+    finally:
+        step.clear_cache()  # the job's own program object, kept by ``_programs``
+    stats = _report("phi4flash step", compiled)
+    assert stats.argument_size_in_bytes + stats.temp_size_in_bytes <= 15.75 * 2**30
+    assert cfg.mixers() == {"cross": 1, "gmu": 1, "gqa": 1, "mamba1": 2, "swa": 1}
+    assert sequencerec._mechanisms(cfg, SEQ_L) == {
+        "selective_scan": "xla", "conv": "pallas", "attn_tiles_skipped_by_window": 105}
+    # both Mamba-1 layers run the shared convolution kernel: forward, the
+    # layer's recomputation, backward
+    assert _conv_under(compiled, "seq.mamba.conv") >= 6
+    text = compiled.as_text()
+    assert "seq.attn.swa.core" in text and "seq.gmu" in text and "seq.attn.core" in text
+
+
 @pytest.mark.parametrize("case", ["deltanet", "mamba2", "lfm2"])
 def test_short_convolution_kernel_at_the_cells_shapes(one_chip, as_tpu, case):
     """The convolution chain alone, forward and backward, at the three

@@ -355,7 +355,9 @@ def test_the_cut_is_the_first_period_of_the_uncut_model_and_a_slice_of_its_head(
     ({"mamba_expand": 4}, "mamba_expand"), ({"mamba_conv_bias": False}, "bias"),
     ({"mamba_proj_bias": True}, "bias"), ({"attention_bias": True}, "bias"),
     ({"backbone": {**TINY["backbone"], "positions": "alibi"}}, "positions"),
-    ({"layer_types": ["mamba"] * 7 + ["sliding_attention"]}, "unknown"),
+    ({"layer_types": ["mamba"] * 7 + ["chunked_attention"]}, "unknown"),
+    # known since the fifth backbone, and differential attention's alone
+    ({"layer_types": ["mamba"] * 7 + ["sliding_attention"]}, "differential"),
 ])
 def test_configurations_the_backbone_cannot_run_are_refused_with_a_message(bad, says):
     conf = {k: v for k, v in {**TINY, **bad}.items() if v is not None}

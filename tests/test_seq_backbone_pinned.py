@@ -1,6 +1,7 @@
-"""The three listed backbones of the sequence template, pinned: what their
+"""The four listed backbones of the sequence template, pinned: what their
 tiny configurations compute on a fixed seeded batch must stay what the commit
-before the fourth backbone computed."""
+before the next backbone computed (``granite4h-tiny``: commit 32bdee9, the
+parent of the fifth backbone's PR)."""
 
 import json
 
@@ -44,16 +45,22 @@ PINNED = json.loads("""
   "dense.norm_post": 0.04305908614364948, "embed": 4.210059824918104,
   "final_norm.w": 0.01595513999631072, "periods.conv": 0.22144379809589704,
   "periods.ffn": 0.07585715419251786, "periods.full": 0.09164016955651524,
-  "periods.norm_in": 0.036699178116064254, "periods.norm_post": 0.01332038945281503}}}
+  "periods.norm_in": 0.036699178116064254, "periods.norm_post": 0.01332038945281503}},
+ "granite4h-tiny": {"loss": 3.9133141040802, "grad_norm": {"embed": 0.4779150043923512,
+  "final_norm.w": 0.0017570282807558048, "periods.ffn": 0.04354429097027376,
+  "periods.full": 0.005497379137975552, "periods.norm_in": 0.009530143908606488,
+  "periods.norm_post": 0.007683603375504363, "periods.ssm": 0.06981819830080853}}}
 """)
 
 
 @pytest.mark.parametrize("name", sorted(PINNED))
 def test_a_listed_configuration_computes_what_it_did_before_this_backbone(name, batch):
-    """The loss and the gradient's norm by group of the three listed
-    backbones at their tiny sizes, on this file's seeded batch and weights
-    drawn from seed 0, as commit fb37b61 (PR 37, before ``ssm`` layers, the
-    multipliers and ``positions: none``) computed them on the CPU. A change
+    """The loss and the gradient's norm by group of the listed backbones at
+    their tiny sizes, on this file's seeded batch and weights drawn from seed
+    0, as commit fb37b61 (PR 37, before ``ssm`` layers, the multipliers and
+    ``positions: none``) computed the first three on the CPU and commit 32bdee9
+    (PR 40, before ``mamba1``, ``gmu``, windows and cross-attention) the
+    fourth. A change
     to ``_attention_mixer``, ``_layer``, ``logits_of``, ``swiglu`` or the
     loss that moves a listed configuration fails here and not on the
     driver's chip. 1e-4: float32 sums in another order read 1e-6; a

@@ -258,6 +258,12 @@ def test_a_second_job_reuses_the_programs_and_trains_the_same():
     ("qwen3next-tiny", "tpu", {"delta_rule_walk": "scan", "conv": "pallas"}),  # 128 channels
     ("lfm2-tiny", "tpu", {"conv": "xla"}),
     ("granite4h-tiny", "tpu", {"ssd_scan": "xla", "conv": "xla"}),
+    ("phi4-mini-flash-vp8", "tpu",
+     {"selective_scan": "xla", "conv": "pallas", "attn_tiles_skipped_by_window": 105}),
+    ("phi4-mini-flash-vp8", "cpu",
+     {"selective_scan": "xla", "conv": "xla", "attn_tiles_skipped_by_window": 105}),
+    ("phi4-mini-flash-tiny", "tpu",  # 128 channels; rows of 8,192 in tiles of 16 under a window of 16
+     {"selective_scan": "xla", "conv": "pallas", "attn_tiles_skipped_by_window": 512 * 513 // 2 - 1023}),
 ])
 def test_the_counters_that_say_which_form_of_a_mixer_runs(monkeypatch, backbone, backend, want):
     """``delta_rule_walk``, ``ssd_scan`` and ``conv`` in ``SeqRecModel.stats``
