@@ -756,7 +756,7 @@ def _layer_fn(cfg: BackboneConfig, kind: str, mesh, schedule, depth: int = 0):
     (``_READS``) takes that, a dict, as one more argument. ``depth``: the
     layer's index in the published model, which a differential layer's
     ``lambda`` starts from."""
-    return jax.checkpoint(lambda *a: _layer(cfg, kind, mesh, schedule, *a, depth=depth))
+    return jax.checkpoint(lambda *a: _layer(cfg, kind, mesh, schedule, *a, depth=depth), policy=_KEPT.get(kind))
 
 
 def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
@@ -1091,3 +1091,9 @@ def window_tiles(cfg: BackboneConfig, length: int) -> Dict[str, int]:
         return {}
     return {"attn_tiles_skipped_by_window":
             layers * tiles_skipped_by_window(length, cfg.attn_block, cfg.sliding_window)}
+
+
+#: what a layer's recomputation does not make again, by the layer's kind: the
+#: selective scan's output and the states its backward pass starts from
+#: (``ops.selscan``); every other layer keeps nothing, as before
+_KEPT = {"mamba1": jax.checkpoint_policies.save_only_these_names("selscan")}

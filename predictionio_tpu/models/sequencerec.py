@@ -581,7 +581,7 @@ def _mechanisms(cfg: bb.BackboneConfig, length: int) -> Dict[str, str]:
     only where the backbone has such a mixer. (Defined below the
     trainer: the Pallas kernels' serialized bodies record the source lines of
     the frames above them, and a line added there misses the compile cache.)"""
-    selective = {"selective_scan": selscan.scan_kind()} if "mamba1" in cfg.kinds else {}
+    selective = _selective_scan(cfg, length) if "mamba1" in cfg.kinds else {}
     return {**_delta_rule_walk(cfg), **_ssd_scan(cfg), **selective,
             **bb.conv_kinds(cfg, length), **bb.window_tiles(cfg, length)}
 
@@ -601,3 +601,12 @@ def engine_factory() -> Engine:
         {"transformer": SeqRecAlgorithm, "": SeqRecAlgorithm},
         {"": FirstServing},
     )
+
+
+def _selective_scan(cfg: bb.BackboneConfig, length: int) -> Dict[str, str]:
+    """What walks the Mamba-1 layers' selective scan over rows of ``length``
+    slots ("pallas" or "xla": ``ops.selscan.scan_kind`` at the mixer's
+    channels, state width and dtypes)."""
+    return {"selective_scan": selscan.scan_kind(
+        cfg.mamba_expand * cfg.hidden_size, cfg.mamba_d_state, length,
+        cfg.state_dtype, cfg.gate_dtype)}

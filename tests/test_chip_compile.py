@@ -786,14 +786,22 @@ def test_granite4h_step_one_row_of_8k_fits_the_chip(one_chip, as_tpu):
 # Mamba-1 selective scans (5,120 channels on a state of 16), differential
 # attention inside a window of 512, in full and as cross-attention, a gated
 # memory unit, ONE row of 8,192 slots a step
-def test_selective_scan_one_row_of_8k(one_chip):
+@pytest.mark.parametrize("kind", ["pallas", "xla"])
+def test_selective_scan_one_row_of_8k(one_chip, as_tpu, kind):
     """The selective scan alone, forward and backward, at the cell's shape:
-    neither direction keeps a state a slot (8,192 x 5,120 x 16 floats would be
-    2.7 GB): a block's states are the backward pass's largest array."""
-    from predictionio_tpu.ops.selscan import selective_scan
+    the kernel pair (the state in VMEM; what the backward pass keeps is the
+    state entering every grid step, 10 MB) and XLA's loops (the control
+    build's bfloat16 state takes them: a block's states are its backward
+    pass's largest array). Neither keeps a state a slot (8,192 x 5,120 x 16
+    floats would be 2.7 GB)."""
+    from predictionio_tpu.ops import selscan
+
+    low = {} if kind == "pallas" else dict(state_dtype=jnp.bfloat16, gate_dtype=jnp.bfloat16)
+    assert selscan.scan_kind(5120, 16, SEQ_L, *low.values()) == kind
 
     def loss(x, dt, a, b, c, seg):
-        return selective_scan(x, dt, a, b, c, seg, chunk=64).sum()
+        with jax.named_scope("seq.mamba.scan"):
+            return selscan.selective_scan(x, dt, a, b, c, seg, chunk=64, **low).sum()
 
     wide = _sds(one_chip, (1, SEQ_L, 5120), jnp.float32)
     state = _sds(one_chip, (1, SEQ_L, 16), jnp.float32)
@@ -801,8 +809,13 @@ def test_selective_scan_one_row_of_8k(one_chip):
         jax.grad(loss, argnums=(0, 1, 2, 3, 4)), _sds(one_chip, (1, SEQ_L, 5120), jnp.bfloat16),
         wide, _sds(one_chip, (5120, 16), jnp.float32), state, state,
         _sds(one_chip, (1, SEQ_L), jnp.int32))
-    stats = _report("selective scan", compiled)
-    assert stats.temp_size_in_bytes < 2 * 2**30
+    stats = _report(f"selective scan ({kind})", compiled)
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line and "seq.mamba.scan" in line]
+    # the kernel: forward and backward; its temporaries are the arrays laid
+    # out as [L, 40, 128] and B's and C's cotangents lane by lane
+    assert len(calls) == (2 if kind == "pallas" else 0)
+    assert stats.temp_size_in_bytes < (0.6 if kind == "pallas" else 2) * 2**30
 
 
 def test_windowed_attention_one_row_of_8k(one_chip):
@@ -828,8 +841,8 @@ def test_windowed_attention_one_row_of_8k(one_chip):
 def test_phi4flash_step_one_row_of_8k_fits_the_chip(one_chip, as_tpu):
     """The whole optimizer step of ``train-phi4flash-long8k`` (1 row of 8,193
     slots, 697 M parameters with their AdamW moments, donated) as the job
-    compiles it: arguments 8.36 GB, temporaries 6.50 GB when this was written
-    (``PERF.md`` section 4); the chip's 15.75 GiB hold both."""
+    compiles it: arguments 8.36 GB, temporaries 6.16 GB (6.50 before the scan's
+    kernel: ``PERF.md`` section 4); the chip's 15.75 GiB hold both."""
     from predictionio_tpu.models import seq_backbone as bb
     from predictionio_tpu.models import sequencerec
 
@@ -848,11 +861,19 @@ def test_phi4flash_step_one_row_of_8k_fits_the_chip(one_chip, as_tpu):
     assert stats.argument_size_in_bytes + stats.temp_size_in_bytes <= 15.75 * 2**30
     assert cfg.mixers() == {"cross": 1, "gmu": 1, "gqa": 1, "mamba1": 2, "swa": 1}
     assert sequencerec._mechanisms(cfg, SEQ_L) == {
-        "selective_scan": "xla", "conv": "pallas", "attn_tiles_skipped_by_window": 105}
+        "selective_scan": "pallas", "conv": "pallas", "attn_tiles_skipped_by_window": 105}
     # both Mamba-1 layers run the shared convolution kernel: forward, the
     # layer's recomputation, backward
     assert _conv_under(compiled, "seq.mamba.conv") >= 6
     text = compiled.as_text()
+    # and the scan's kernel pair: forward and backward, and NO forward call in
+    # the layer's recomputation (the layer keeps y and the states entering the
+    # grid steps: ``seq_backbone._KEPT``). Temporaries 6.16 GB with what is
+    # kept (5.74 GB without), under the 6.50 GB of XLA's loops (PERF.md section 4)
+    scans = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line and "seq.mamba.scan" in line]
+    assert len(scans) == 4
+    assert stats.temp_size_in_bytes < 6.3e9
     assert "seq.attn.swa.core" in text and "seq.gmu" in text and "seq.attn.core" in text
 
 

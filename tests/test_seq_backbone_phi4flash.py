@@ -323,7 +323,7 @@ def test_chunk_and_block_are_no_part_of_the_result(chunk, block):
     x, dt, a_log, b, c, segs = _scan_inputs(np.random.default_rng(11), BOUNDARIES["mid-chunk"])
     want = ref.selective_scan_of(x[0], dt[0], b[0], c[0], a_log, segs[0])
     got = selective_scan(x, dt, -jnp.exp(a_log), b, c, segs, chunk=chunk, block=block)
-    assert rel(got[0], want) < 1e-5 and scan_kind() == "xla"
+    assert rel(got[0], want) < 1e-5 and scan_kind(24, 4, 64) == "xla"
 
 
 def test_a_fast_decay_overflows_nothing():

@@ -259,10 +259,10 @@ def test_a_second_job_reuses_the_programs_and_trains_the_same():
     ("lfm2-tiny", "tpu", {"conv": "xla"}),
     ("granite4h-tiny", "tpu", {"ssd_scan": "xla", "conv": "xla"}),
     ("phi4-mini-flash-vp8", "tpu",
-     {"selective_scan": "xla", "conv": "pallas", "attn_tiles_skipped_by_window": 105}),
+     {"selective_scan": "pallas", "conv": "pallas", "attn_tiles_skipped_by_window": 105}),
     ("phi4-mini-flash-vp8", "cpu",
      {"selective_scan": "xla", "conv": "xla", "attn_tiles_skipped_by_window": 105}),
-    ("phi4-mini-flash-tiny", "tpu",  # 128 channels; rows of 8,192 in tiles of 16 under a window of 16
+    ("phi4-mini-flash-tiny", "tpu",  # 128 channels on a state of 4; rows of 8,192 in tiles of 16 under a window of 16
      {"selective_scan": "xla", "conv": "pallas", "attn_tiles_skipped_by_window": 512 * 513 // 2 - 1023}),
 ])
 def test_the_counters_that_say_which_form_of_a_mixer_runs(monkeypatch, backbone, backend, want):
@@ -295,3 +295,22 @@ def test_a_row_that_is_no_whole_halo_blocks_runs_the_xla_form(monkeypatch):
     import dataclasses
 
     assert bb.conv_kinds(dataclasses.replace(cfg, gate_dtype="bfloat16"), 8192) == {"conv": "xla"}
+
+
+@pytest.mark.parametrize("change,length,want", [
+    ({}, 8192, "pallas"),
+    ({"state_dtype": "bfloat16", "gate_dtype": "bfloat16"}, 8192, "xla"),  # the cell's control build
+    ({"gate_dtype": "bfloat16"}, 8192, "xla"),
+    ({}, 8200, "xla"),  # no whole strips
+])
+def test_the_selective_scan_counter_follows_the_dtypes_and_the_row(monkeypatch, change, length, want):
+    import dataclasses
+
+    import jax
+
+    from predictionio_tpu.models import seq_backbone as bb
+    from predictionio_tpu.models import sequencerec
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(bb.BackboneConfig.load("phi4-mini-flash-vp8"), **change)
+    assert sequencerec._mechanisms(cfg, length)["selective_scan"] == want
