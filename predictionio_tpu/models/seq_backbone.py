@@ -1095,5 +1095,15 @@ def window_tiles(cfg: BackboneConfig, length: int) -> Dict[str, int]:
 
 #: what a layer's recomputation does not make again, by the layer's kind: the
 #: selective scan's output and the states its backward pass starts from
-#: (``ops.selscan``); every other layer keeps nothing, as before
-_KEPT = {"mamba1": jax.checkpoint_policies.save_only_these_names("selscan")}
+#: (``ops.selscan``), the state-space scan's likewise where its kernel runs
+#: (``ops.ssd``); every other layer keeps nothing, as before
+_KEPT = {"mamba1": jax.checkpoint_policies.save_only_these_names("selscan"),
+         "ssm": jax.checkpoint_policies.save_only_these_names("ssd")}
+
+
+def ssd_shape(cfg: BackboneConfig, length: int):
+    """What ``ops.ssd.scan_kind`` asks of a Mamba-2 layer over rows of
+    ``length`` slots: heads, head and state widths, length, chunk, the
+    state's and the gates' dtypes."""
+    return (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, length, cfg.chunk,
+            _dt(cfg.state_dtype), _dt(cfg.gate_dtype))

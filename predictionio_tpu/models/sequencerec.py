@@ -437,11 +437,11 @@ def _delta_rule_walk(cfg: bb.BackboneConfig) -> Dict[str, str]:
         cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.chunk)}
 
 
-def _ssd_scan(cfg: bb.BackboneConfig) -> Dict[str, str]:
+def _ssd_scan(cfg: bb.BackboneConfig, length: int = 1) -> Dict[str, str]:
     """What runs the step's Mamba-2 layers' state-space scan ("pallas" or
-    "xla": ``ops.ssd.scan_kind``); nothing for a backbone without such
-    layers."""
-    return {"ssd_scan": scan_kind()} if "ssm" in cfg.kinds else {}
+    "xla": ``ops.ssd.scan_kind`` at the mixer's widths, chunk and dtypes);
+    nothing for a backbone without such layers."""
+    return {"ssd_scan": scan_kind(*bb.ssd_shape(cfg, length))} if "ssm" in cfg.kinds else {}
 
 
 class SeqRecAlgorithm(Algorithm):
@@ -582,7 +582,7 @@ def _mechanisms(cfg: bb.BackboneConfig, length: int) -> Dict[str, str]:
     trainer: the Pallas kernels' serialized bodies record the source lines of
     the frames above them, and a line added there misses the compile cache.)"""
     selective = _selective_scan(cfg, length) if "mamba1" in cfg.kinds else {}
-    return {**_delta_rule_walk(cfg), **_ssd_scan(cfg), **selective,
+    return {**_delta_rule_walk(cfg), **_ssd_scan(cfg, length), **selective,
             **bb.conv_kinds(cfg, length), **bb.window_tiles(cfg, length)}
 
 

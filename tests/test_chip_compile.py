@@ -727,11 +727,52 @@ def test_lfm2_step_two_rows_of_8k_fits_the_chip(one_chip, as_tpu):
 # -- and at the shapes of train-granite4h-packed: granite-4.0-h-micro widths, nine
 # Mamba-2 layers (64 heads of 64 on a state of 128, chunks of 256) beside one
 # grouped-query attention layer, ONE row of 8,192 slots a step
+#: the scan's kernel a Mamba-2 layer and step: forward once (the layer's
+#: recomputation keeps its outputs) and backward once
+_SCAN_CALLS_A_LAYER = 2
+
+
+@pytest.mark.parametrize("kind", ["pallas", "xla"])
+def test_ssd_scan_one_row_of_8k(one_chip, as_tpu, kind):
+    """The state-space scan alone, forward and backward, at the cell's shape:
+    the kernel pair (a chunk's [256, 256] matrices and the states in VMEM;
+    what the backward pass keeps is the state entering every chunk, 67 MB)
+    and XLA's batch products (the control build's bfloat16 state takes
+    them: a float32 [256, 256] matrix a head and chunk is 537 MB)."""
+    from predictionio_tpu.ops import ssd
+
+    low = {} if kind == "pallas" else dict(state_dtype=jnp.bfloat16, gate_dtype=jnp.bfloat16)
+    assert ssd.scan_kind(64, 64, 128, SEQ_L, 256, *low.values()) == kind
+
+    def loss(u, dt, a, b, c, seg):
+        with jax.named_scope("seq.ssm.scan"):
+            return ssd.ssd_scan(u, dt, a, b, c, seg, chunk=256, compute_dtype=jnp.bfloat16,
+                                **low).sum()
+
+    state = _sds(one_chip, (1, SEQ_L, 128), jnp.bfloat16)
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), _sds(one_chip, (1, SEQ_L, 64, 64), jnp.bfloat16),
+        _sds(one_chip, (1, SEQ_L, 64), jnp.float32), _sds(one_chip, (64,), jnp.float32),
+        state, state, _sds(one_chip, (1, SEQ_L), jnp.int32))
+    stats = _report(f"state-space scan ({kind})", compiled)
+    text = compiled.as_text().splitlines()
+    calls = [line for line in text
+             if 'custom_call_target="tpu_custom_call"' in line and "seq.ssm.scan" in line]
+    matrices = [line for line in text if re.search(r"= f32\[[\d,]*256,256\]", line)]
+    # the kernel: forward and backward, and no [256, 256] matrix a head and
+    # chunk in HBM; its temporaries are y's cotangent, the kept states and
+    # what XLA lays out a slot and head
+    assert len(calls) == (2 if kind == "pallas" else 0)
+    assert bool(matrices) == (kind == "xla")
+    assert stats.temp_size_in_bytes < (0.4 if kind == "pallas" else 2) * 2**30
+
+
 def test_mamba2_layer_one_row_of_8k(one_chip, as_tpu):
     """One Mamba-2 layer with its dense SwiGLU as the step runs it
-    (recomputed in the backward pass): the [256, 256] matrices a head and
-    chunk are its largest arrays, and the scan keeps none of them across
-    its own backward pass."""
+    (recomputed in the backward pass; the scan's kernel pair keeps ``y`` and
+    the states entering the chunks over that recomputation): the [256, 256]
+    matrices a head and chunk, the largest arrays of XLA's form, exist in
+    VMEM alone."""
     from predictionio_tpu.models import seq_backbone as bb
 
     cfg = bb.BackboneConfig.load("granite4h-micro-vp8")
@@ -751,16 +792,22 @@ def test_mamba2_layer_one_row_of_8k(one_chip, as_tpu):
         jax.grad(loss, argnums=(0, 1), has_aux=True), block,
         _sds(one_chip, (1, SEQ_L, 2048), jnp.float32), _sds(one_chip, (1, SEQ_L), jnp.int32))
     stats = _report("mamba-2 layer", compiled)
-    assert stats.temp_size_in_bytes < 3 * 2**30
-    assert "while" not in compiled.as_text().split("ENTRY")[1]  # no loop over slots or chunks
+    assert stats.temp_size_in_bytes < 1.5 * 2**30  # 1.96 GB with XLA's form
+    text = compiled.as_text()
+    assert "while" not in text.split("ENTRY")[1]  # the walk over chunks is the kernel's grid
+    assert not re.search(r"= f32\[[\d,]*256,256\]", text)
+    scan = [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line and "seq.ssm.scan" in line]
+    assert len(scan) == _SCAN_CALLS_A_LAYER
     assert _conv_under(compiled, "seq.ssm.conv") == 3
 
 
 def test_granite4h_step_one_row_of_8k_fits_the_chip(one_chip, as_tpu):
     """The whole optimizer step of ``train-granite4h-packed`` (1 row of
     8,193 slots, 772 M parameters with their AdamW moments, donated) as the
-    job compiles it: arguments 9.27 GB, temporaries 4.77 GB when this was
-    written (``PERF.md`` section 4); the chip's 15.75 GiB hold both."""
+    job compiles it: arguments 9.27 GB, temporaries 4.77 GB with XLA's form
+    of the scan (``PERF.md`` section 4); the chip's 15.75 GiB hold both, and
+    half a GiB more."""
     from predictionio_tpu.models import seq_backbone as bb
     from predictionio_tpu.models import sequencerec
 
@@ -776,10 +823,13 @@ def test_granite4h_step_one_row_of_8k_fits_the_chip(one_chip, as_tpu):
     finally:
         step.clear_cache()  # the job's own program object, kept by ``_programs``
     stats = _report("granite4h step", compiled)
-    assert stats.argument_size_in_bytes + stats.temp_size_in_bytes <= 15.75 * 2**30
+    assert stats.argument_size_in_bytes + stats.temp_size_in_bytes <= 15.25 * 2**30
     assert cfg.mixers() == {"gqa": 1, "mamba2": 9}
-    assert sequencerec._mechanisms(cfg, SEQ_L) == {"ssd_scan": "xla", "conv": "pallas"}
-    assert _conv_under(compiled, "seq.ssm.conv", "granite4h") >= 3
+    assert sequencerec._mechanisms(cfg, SEQ_L) == {"ssd_scan": "pallas", "conv": "pallas"}
+    assert _conv_under(compiled, "seq.ssm.conv") >= 3
+    scan = [line for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line and "seq.ssm.scan" in line]
+    assert len(scan) == 9 * _SCAN_CALLS_A_LAYER
 
 
 # -- and at the shapes of train-phi4flash-long8k: Phi-4-mini-flash widths, two

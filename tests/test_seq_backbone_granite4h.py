@@ -266,6 +266,19 @@ def test_the_control_build_fails_ssd_err_and_the_sound_build_passes(build, passe
     assert (err < 6e-3) == passes and (passes or err > 1.2e-2), err
 
 
+@pytest.mark.parametrize("build,form", [("float32", "pallas"), ("bfloat16", "xla")])
+def test_on_a_tpu_the_control_builds_call_is_the_xla_form_and_the_sound_builds_the_kernel(
+        monkeypatch, build, form):
+    """The same call at the cell's widths with the backend answered as a
+    TPU: what the control build hands over (bfloat16 state and gates) takes
+    XLA's batch products, so it goes on failing ``ssd_err`` whatever the
+    kernel does."""
+    from predictionio_tpu.ops.ssd import scan_kind
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert scan_kind(64, 64, 128, 8192, 256, jnp.dtype(build), jnp.dtype(build)) == form
+
+
 def test_the_mixer_is_the_references_on_histories_shorter_than_its_taps():
     rng = np.random.default_rng(5)
     d, heads, width, state = 12, 3, 4, 5

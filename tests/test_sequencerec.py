@@ -250,7 +250,7 @@ def test_a_second_job_reuses_the_programs_and_trains_the_same():
 @pytest.mark.parametrize("backbone,backend,want", [
     ("qwen3next-80b-a3b-ep16", "tpu", {"delta_rule_walk": "pallas", "conv": "pallas"}),
     ("lfm2-24b-a2b-ep8", "tpu", {"conv": "pallas"}),
-    ("granite4h-micro-vp8", "tpu", {"ssd_scan": "xla", "conv": "pallas"}),
+    ("granite4h-micro-vp8", "tpu", {"ssd_scan": "pallas", "conv": "pallas"}),
     ("joyai-flash-48b-a3b-ep16", "tpu", {}),
     ("qwen3next-80b-a3b-ep16", "cpu", {"delta_rule_walk": "scan", "conv": "xla"}),
     ("lfm2-24b-a2b-ep8", "cpu", {"conv": "xla"}),
