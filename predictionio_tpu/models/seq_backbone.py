@@ -1,14 +1,9 @@
 """The sequence recommender's backbone: a decoder block that is a function
 of a configuration.
 
-A configuration gives the layer pattern (``layer_types``: a mixer a layer,
-``full_attention`` or ``attention``, ``linear_attention`` = gated DeltaNet,
-``conv`` = the gated short convolution, ``mamba`` = the Mamba-2
-state-space mixer, ``ssm`` here, ``mamba1`` = the Mamba-1 selective scan,
-``sliding_attention`` = attention inside ``sliding_window`` slots, ``gmu`` =
-a gated memory unit that reads the scan output of the last ``mamba1`` layer
-below it, or ``cross_attention`` = queries of its own onto the keys and
-values of the last ``full_attention`` layer below it; or
+A configuration gives the layer pattern (``layer_types``: a mixer a layer, in
+the ``words`` of the table ``_MIXERS`` below, the one place that knows a kind
+of mixer: its words, parameters, op, scope, counters and refusals; or
 ``full_attention_interval``: every n-th layer is softmax attention, the
 others gated DeltaNet; 1 = all attention),
 the attention (``gqa``: grouped heads of one width; ``mla``:
@@ -47,38 +42,24 @@ softmax statistics in between tiles.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import json
 import os
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import deltanet, selscan, shortconv, ssd
 from ..ops.attention import attention, tiles_skipped_by_window
 from ..ops.deltanet import gated_deltanet
 from ..ops.moe import expert_layer, swiglu
 from ..ops.selscan import gated_memory, mamba1
-from ..ops.shortconv import conv_kind, short_conv
+from ..ops.shortconv import short_conv
 from ..ops.ssd import mamba2
-
-#: a public file's word for a layer's mixer -> the kind the parameters are
-#: stacked under
-_KINDS = {"full_attention": "full", "attention": "full", "linear_attention": "linear",
-          "conv": "conv", "mamba": "ssm", "mamba1": "mamba1", "sliding_attention": "swa",
-          "gmu": "gmu", "cross_attention": "cross"}
-#: what a layer of a kind hands to the layers above it in its period (of what
-#: its mixer returns beside its output), and what a layer of a kind reads of
-#: that: the newest below it
-_HANDS = {"mamba1": ("m",), "full": ("k", "v")}
-_READS = {"gmu": ("m",), "cross": ("k", "v")}
-#: the kinds of layer a decoder-hybrid-decoder adds (``_hybrid_mixer`` runs them)
-_HYBRID = ("mamba1", "swa", "gmu", "cross")
-#: a reading kind -> the kind that hands it what it reads, in a file's word, and what that is
-_PRODUCERS = {"gmu": ("mamba1", "mamba1", "a scan output"),
-              "cross": ("full", "full_attention", "keys and values")}
 
 CONF_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
@@ -90,7 +71,7 @@ class BackboneConfig:
     hidden_size: int = 64
     num_hidden_layers: int = 2
     full_attention_interval: int = 1
-    #: the mixer of every layer in a public file's words (``_KINDS``); empty
+    #: the mixer of every layer in a public file's words (``_MIXERS``); empty
     #: = ``full_attention_interval`` decides, and leading dense layers are
     #: full attention
     layer_types: Tuple[str, ...] = ()
@@ -206,10 +187,9 @@ class BackboneConfig:
 
     @property
     def kinds(self) -> Tuple[str, ...]:
-        """The mixer of every layer: ``full``, ``linear``, ``conv``, ``ssm``,
-        ``mamba1``, ``swa``, ``gmu`` or ``cross``."""
+        """The mixer of every layer: a key of ``_MIXERS``."""
         if self.layer_types:
-            return tuple(_KINDS[t] for t in self.layer_types)
+            return tuple(_KIND_OF[t] for t in self.layer_types)
         p = self.full_attention_interval
         one = ("linear",) * (p - 1) + ("full",)
         k = self.first_k_dense_replace
@@ -242,12 +222,11 @@ class BackboneConfig:
         return 0 if (kind, held) == ("full", 1) else held
 
     def mixers(self) -> Dict[str, int]:
-        """Layers by the mixer they run: ``deltanet``, ``shortconv``,
-        ``mamba2``, ``mamba1``, ``swa``, ``gmu``, ``cross``, and ``gqa`` or
-        ``mla`` (the prediction module's block counts too)."""
-        names = {"linear": "deltanet", "conv": "shortconv", "ssm": "mamba2",
-                 "full": self.attention}
-        found = [names.get(k, k) for k in self.kinds] + [self.attention] * self.num_nextn_predict_layers
+        """Layers by the ``name`` of the mixer they run (``_MIXERS``; softmax
+        attention counts as ``gqa`` or ``mla``, the prediction module's block
+        too)."""
+        found = [_MIXERS[k].name or self.attention for k in self.kinds]
+        found += [self.attention] * self.num_nextn_predict_layers
         return {name: found.count(name) for name in sorted(set(found))}
 
     @classmethod
@@ -290,7 +269,7 @@ class BackboneConfig:
                 merged["n_shared_experts"] * merged["moe_intermediate_size"])
         cfg = cls(**values)
         if cfg.layer_types:
-            unknown = sorted(set(cfg.layer_types) - set(_KINDS))
+            unknown = sorted(set(cfg.layer_types) - set(_KIND_OF))
             if unknown:
                 raise ValueError(f"layer_types names mixers unknown here: {unknown}")
             if len(cfg.layer_types) != cfg.num_hidden_layers:
@@ -302,25 +281,9 @@ class BackboneConfig:
             raise ValueError(
                 f"{cfg.num_hidden_layers} layers less {cfg.first_k_dense_replace} dense ones "
                 f"are not whole periods of {cfg.full_attention_interval}")
-        if merged.get("conv_bias"):
-            raise ValueError("the short convolution and its projections carry no bias here")
-        if "ssm" in cfg.kinds:
-            sizes = ("mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_d_conv")
-            missing = [name for name in sizes if not getattr(cfg, name)]
-            if missing:
-                raise ValueError(f"mamba layers need {', '.join(missing)}: no default is assumed")
-            if cfg.mamba_n_groups != 1:
-                raise ValueError(f"mamba_n_groups is {cfg.mamba_n_groups or 'not given'}: the "
-                                 "state-space scan here shares B and C among all heads (one group)")
-            if "mamba_expand" in merged and (
-                    merged["mamba_expand"] * cfg.hidden_size != cfg.mamba_n_heads * cfg.mamba_d_head):
-                raise ValueError("mamba_expand x hidden_size is not mamba_n_heads x mamba_d_head")
-            if not merged.get("mamba_conv_bias", True) or merged.get("mamba_proj_bias"):
-                raise ValueError("the Mamba-2 mixer here has a bias on its convolution "
-                                 "and none on its projections")
-        if merged.get("attention_bias"):
-            raise ValueError("the attention projections carry no bias here")
-        _check_hybrid(cfg, merged)
+        for kind, mixer in _MIXERS.items():  # each mixer's own refusals
+            if kind in cfg.kinds:
+                mixer.check(cfg, merged)
         if cfg.positions not in ("learned", "rotary", "none"):
             raise ValueError(f"positions {cfg.positions!r}: learned, rotary or none")
         if cfg.num_nextn_predict_layers not in (0, 1):
@@ -356,9 +319,9 @@ def _is_spec(x) -> bool:
 
 def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
     """name -> (shape, kind): 'w' a matrix (fan-in = second-to-last axis),
-    'zero', 'one', 'embed', or a kind of its own. A period's mixers are
-    stacked by kind, [periods, layers of that kind in a period, ...]
-    (``BackboneConfig.stacked``)."""
+    'zero', 'one', 'embed', or a kind of its own. A mixer's are its record's
+    (``_MIXERS``); a period's mixers are stacked by kind, [periods, layers of
+    that kind in a period, ...] (``BackboneConfig.stacked``)."""
     d, p, n = cfg.hidden_size, cfg.period, cfg.n_periods
     norm = {"w": ((d,), "zero")} if cfg.norm == "rms" else {
         "g": ((d,), "one"), "b": ((d,), "zero")}
@@ -367,58 +330,6 @@ def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
         return jax.tree_util.tree_map(
             lambda leaf: (tuple(axes) + leaf[0], leaf[1]), tree, is_leaf=_is_spec)
 
-    h, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    if cfg.attention == "mla":
-        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
-        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        full = {
-            "w_qa": ((d, rq), "w"), "q_norm": ((rq,), "zero"), "w_qb": ((rq, h * (dn + dr)), "w"),
-            "w_kva": ((d, rkv + dr), "w"), "kv_norm": ((rkv,), "zero"),
-            "w_kvb": ((rkv, h * (dn + dv)), "w"), "w_o": ((h * dv, d), "w"),
-        }
-    else:
-        full = {
-            "w_q": ((d, h * hd * (2 if cfg.attn_gate else 1)), "w"),
-            "w_k": ((d, hkv * hd), "w"), "w_v": ((d, hkv * hd), "w"),
-            "w_o": ((h * hd, d), "w"),
-        }
-        if cfg.attn_gate or cfg.qk_norm:
-            full.update(q_norm=((hd,), "zero"), k_norm=((hd,), "zero"))
-        if cfg.differential:
-            full.update({name: ((hd,), "lambda") for name in _LAMBDAS}, subln=((2 * hd,), "one"))
-    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
-    mh, ns = cfg.mamba_n_heads, cfg.mamba_d_state
-    inner = mh * cfg.mamba_d_head
-    wide, rank = cfg.mamba_expand * d, cfg.mamba_dt_rank
-    mixers = {
-        "full": full,
-        "swa": full,
-        "cross": {name: spec for name, spec in full.items() if name not in ("w_k", "w_v")},
-        "mamba1": {
-            "w_in": ((d, 2 * wide), "w"), "conv_w": ((cfg.mamba_d_conv, wide), "w"),
-            "conv_b": ((wide,), "zero"), "w_x": ((wide, rank + 2 * ns), "w"),
-            "w_dt": ((rank, wide), "w"), "dt_bias": ((wide,), "dt_bias"),
-            "A_log": ((wide, ns), "a_log_range"), "D": ((wide,), "one"),
-            "w_out": ((wide, d), "w"),
-        },
-        "gmu": {"w_1": ((d, wide), "w"), "w_2": ((wide, d), "w")},
-        "linear": {
-            "w_qkvz": ((d, 2 * hk * dk + 2 * hv * dv), "w"), "w_ba": ((d, 2 * hv), "w"),
-            "conv_w": ((cfg.linear_conv_kernel_dim, 2 * hk * dk + hv * dv), "w"),
-            "A_log": ((hv,), "a_log"), "dt_bias": ((hv,), "dt_bias"),
-            "o_norm": ((dv,), "one"), "w_out": ((hv * dv, d), "w"),
-        },
-        "conv": {"w_in": ((d, 3 * d), "w"), "conv_w": ((cfg.conv_L_cache, d), "w"),
-                 "w_out": ((d, d), "w")},
-        "ssm": {
-            "w_in": ((d, 2 * inner + 2 * ns), "w"), "w_dt": ((d, mh), "w"),
-            "conv_w": ((cfg.mamba_d_conv, inner + 2 * ns), "w"),
-            "conv_b": ((inner + 2 * ns,), "zero"),
-            "A_log": ((mh,), "a_log"), "dt_bias": ((mh,), "dt_bias"), "D": ((mh,), "one"),
-            "norm": ((inner,), "one"), "w_out": ((inner, d), "w"),
-        },
-    }
     m = cfg.intermediate_size
     gated = {"wg": ((d, m), "w"), "wu": ((d, m), "w"), "wd": ((m, d), "w")}
     if cfg.ffn == "moe":
@@ -444,18 +355,19 @@ def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
         "ffn": lead(ffn, n, p),
     }
     for kind in set(cfg.period_kinds):
-        held = cfg.stacked(kind)
-        periods[kind] = lead(mixers[kind], n, held) if held else lead(mixers[kind], n)
+        held, mixer = cfg.stacked(kind), _MIXERS[kind].shapes(cfg)
+        periods[kind] = lead(mixer, n, held) if held else lead(mixer, n)
     shapes = {"embed": ((vocab, d), "embed"), "final_norm": norm, "periods": periods}
     if cfg.first_k_dense_replace:
         kind = cfg.kinds[0]
         shapes["dense"] = lead(
-            {kind: mixers[kind], "norm_in": norm, "norm_post": norm, "ffn": gated},
+            {kind: _MIXERS[kind].shapes(cfg), "norm_in": norm, "norm_post": norm, "ffn": gated},
             cfg.first_k_dense_replace)
     if cfg.num_nextn_predict_layers:
         shapes["mtp"] = {
             "enorm": norm, "hnorm": norm, "eh_proj": ((2 * d, d), "w"), "norm": norm,
-            "block": {"full": full, "norm_in": norm, "norm_post": norm, "ffn": ffn},
+            "block": {"full": _MIXERS["full"].shapes(cfg), "norm_in": norm, "norm_post": norm,
+                      "ffn": ffn},
         }
     if not cfg.tie_word_embeddings:
         shapes["head"] = ((vocab, d), "embed")
@@ -508,16 +420,12 @@ def _draw_program(cfg: BackboneConfig, vocab: int, max_positions: int):
 
 def layers_of(params: Dict, cfg: BackboneConfig) -> Dict:
     """The parameters unstacked into a list of per-layer dicts, in the
-    layout of the plain references (``testing/qwen3_next_reference.py``;
-    with latent attention ``testing/joyai_flash_reference.py``, whose
-    mixer is ``attn``, whose dense layers carry ``mlp`` and whose
-    prediction module is ``mtp``; ``testing/lfm2_moe_reference.py``, whose
-    mixers are ``conv`` and ``full`` and whose head is its embedding;
-    ``testing/granite4h_reference.py``, whose mixers are ``ssm`` and
-    ``full`` and whose every layer carries ``mlp``;
-    ``testing/phi4flash_reference.py``, whose mixers are ``mamba1``, ``swa``,
-    ``full``, ``gmu`` and ``cross`` and whose norms are ``{"g", "b"}``).
-    Works on any pytree of the parameters' structure: gradients too."""
+    layout of the plain references (``testing/*_reference.py``): a layer's
+    mixer under its kind (a key of ``_MIXERS``; latent attention under
+    ``attn``), its feed-forward under ``moe`` or ``mlp`` (a leading dense
+    layer's always ``mlp``), an RMS norm as its one leaf and a LayerNorm as
+    ``{"g", "b"}``, the prediction module under ``mtp``. Works on any pytree
+    of the parameters' structure: gradients too."""
     full_key = "attn" if cfg.attention == "mla" else "full"
     ffn_key = "moe" if cfg.ffn == "moe" else "mlp"
 
@@ -562,14 +470,189 @@ def _mixer_of(cfg: BackboneConfig, per: Dict, j: int):
     return jax.tree_util.tree_map(lambda a: a[nth], per[kind])
 
 
-# -- the block --------------------------------------------------------------
+# -- the mixers -------------------------------------------------------------
+def _unit(x, eps: float):
+    """x over its root mean square along the last axis: an RMS norm before its scale."""
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+
+
 def _norm(cfg: BackboneConfig, p: Dict, x):
     if cfg.norm == "rms":
-        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + cfg.rms_norm_eps) * (
-            1.0 + p["w"])
+        return _unit(x, cfg.rms_norm_eps) * (1.0 + p["w"])
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + cfg.layer_norm_eps) * p["g"] + p["b"]
+
+
+def _dot(cfg: BackboneConfig, x, w):
+    """x w: both in ``compute_dtype``, the sum in float32."""
+    cd = _dt(cfg.compute_dtype)
+    return jnp.dot(x.astype(cd), w.astype(cd), preferred_element_type=jnp.float32)
+
+
+def _dtypes(cfg: BackboneConfig) -> Dict:
+    return dict(compute_dtype=_dt(cfg.compute_dtype), state_dtype=_dt(cfg.state_dtype),
+                gate_dtype=_dt(cfg.gate_dtype))
+
+
+# gated DeltaNet (``ops.deltanet``)
+def _deltanet_shapes(cfg: BackboneConfig) -> Dict:
+    d, hk, hv = cfg.hidden_size, cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    return {
+        "w_qkvz": ((d, 2 * hk * dk + 2 * hv * dv), "w"), "w_ba": ((d, 2 * hv), "w"),
+        "conv_w": ((cfg.linear_conv_kernel_dim, 2 * hk * dk + hv * dv), "w"),
+        "A_log": ((hv,), "a_log"), "dt_bias": ((hv,), "dt_bias"),
+        "o_norm": ((dv,), "one"), "w_out": ((hv * dv, d), "w"),
+    }
+
+
+def _deltanet_widths(cfg: BackboneConfig) -> Dict:
+    return dict(key_heads=cfg.linear_num_key_heads, value_heads=cfg.linear_num_value_heads,
+                key_dim=cfg.linear_key_head_dim, value_dim=cfg.linear_value_head_dim,
+                eps=cfg.rms_norm_eps, chunk=cfg.chunk, **_dtypes(cfg))
+
+
+# the gated short convolution (``ops.shortconv``)
+def _shortconv_shapes(cfg: BackboneConfig) -> Dict:
+    d = cfg.hidden_size
+    return {"w_in": ((d, 3 * d), "w"), "conv_w": ((cfg.conv_L_cache, d), "w"),
+            "w_out": ((d, d), "w")}
+
+
+def _shortconv_widths(cfg: BackboneConfig) -> Dict:
+    return dict(compute_dtype=_dt(cfg.compute_dtype), gate_dtype=_dt(cfg.gate_dtype))
+
+
+def _shortconv_check(cfg: BackboneConfig, merged: Dict) -> None:
+    if merged.get("conv_bias"):
+        raise ValueError("the short convolution and its projections carry no bias here")
+
+
+# Mamba-2 (``ops.ssd``) and Mamba-1 (``ops.selscan``)
+def _mamba_check(cfg: BackboneConfig, merged: Dict, word: str, name: str, sizes) -> None:
+    missing = [size for size in sizes if not getattr(cfg, size)]
+    if missing:
+        raise ValueError(f"{word} layers need {', '.join(missing)}: no default is assumed")
+    if not merged.get("mamba_conv_bias", True) or merged.get("mamba_proj_bias"):
+        raise ValueError(f"the {name} mixer here has a bias on its convolution "
+                         "and none on its projections")
+
+
+def _mamba2_shapes(cfg: BackboneConfig) -> Dict:
+    d, mh, ns = cfg.hidden_size, cfg.mamba_n_heads, cfg.mamba_d_state
+    inner = mh * cfg.mamba_d_head
+    return {
+        "w_in": ((d, 2 * inner + 2 * ns), "w"), "w_dt": ((d, mh), "w"),
+        "conv_w": ((cfg.mamba_d_conv, inner + 2 * ns), "w"),
+        "conv_b": ((inner + 2 * ns,), "zero"),
+        "A_log": ((mh,), "a_log"), "dt_bias": ((mh,), "dt_bias"), "D": ((mh,), "one"),
+        "norm": ((inner,), "one"), "w_out": ((inner, d), "w"),
+    }
+
+
+def _mamba2_widths(cfg: BackboneConfig) -> Dict:
+    return dict(heads=cfg.mamba_n_heads, head_dim=cfg.mamba_d_head, state=cfg.mamba_d_state,
+                eps=cfg.rms_norm_eps, chunk=cfg.chunk, **_dtypes(cfg))
+
+
+def _mamba2_check(cfg: BackboneConfig, merged: Dict) -> None:
+    _mamba_check(cfg, merged, "mamba", "Mamba-2",
+                 ("mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_d_conv"))
+    if cfg.mamba_n_groups != 1:
+        raise ValueError(f"mamba_n_groups is {cfg.mamba_n_groups or 'not given'}: the "
+                         "state-space scan here shares B and C among all heads (one group)")
+    if "mamba_expand" in merged and (
+            merged["mamba_expand"] * cfg.hidden_size != cfg.mamba_n_heads * cfg.mamba_d_head):
+        raise ValueError("mamba_expand x hidden_size is not mamba_n_heads x mamba_d_head")
+
+
+def _mamba1_shapes(cfg: BackboneConfig) -> Dict:
+    d, ns, rank = cfg.hidden_size, cfg.mamba_d_state, cfg.mamba_dt_rank
+    wide = cfg.mamba_expand * d
+    return {
+        "w_in": ((d, 2 * wide), "w"), "conv_w": ((cfg.mamba_d_conv, wide), "w"),
+        "conv_b": ((wide,), "zero"), "w_x": ((wide, rank + 2 * ns), "w"),
+        "w_dt": ((rank, wide), "w"), "dt_bias": ((wide,), "dt_bias"),
+        "A_log": ((wide, ns), "a_log_range"), "D": ((wide,), "one"),
+        "w_out": ((wide, d), "w"),
+    }
+
+
+def _mamba1_widths(cfg: BackboneConfig) -> Dict:
+    return dict(state=cfg.mamba_d_state, dt_rank=cfg.mamba_dt_rank, chunk=cfg.chunk,
+                **_dtypes(cfg))
+
+
+def _mamba1_check(cfg: BackboneConfig, merged: Dict) -> None:
+    _in_a_period(cfg, "mamba1")
+    _mamba_check(cfg, merged, "mamba1", "Mamba-1",
+                 ("mamba_d_state", "mamba_d_conv", "mamba_expand", "mamba_dt_rank"))
+
+
+# what a decoder-hybrid-decoder's upper layers read of the layers below them
+def _in_a_period(cfg: BackboneConfig, kind: str) -> None:
+    if kind in cfg.kinds[:cfg.first_k_dense_replace]:
+        raise ValueError("the leading dense layers hand nothing on: mamba1, sliding_attention, "
+                         "gmu and cross_attention layers belong to the periods")
+
+
+def _handed(cfg: BackboneConfig, kind: str, product: str) -> None:
+    """No layer of ``kind`` is in a leading dense layer's place, and each has,
+    below it in its period, a layer of the kind that hands on what it reads
+    (``product``, in words)."""
+    mine = _MIXERS[kind]
+    by = next(k for k, m in _MIXERS.items() if set(mine.reads) <= set(m.hands))
+    for i, k in enumerate(cfg.period_kinds):
+        if k == kind and by not in cfg.period_kinds[:i]:
+            raise ValueError(
+                f"layer {cfg.first_k_dense_replace + i} is a {mine.words[0]} layer and no "
+                f"{_MIXERS[by].words[0]} layer below it in its period hands it {product}")
+    _in_a_period(cfg, kind)
+
+
+def _gmu(cfg: BackboneConfig, p: Dict, h, seg, pos, given, depth, mesh, schedule):
+    """A gated memory unit on the scan output ``m`` that the last Mamba-1 layer
+    below it handed on."""
+    return gated_memory(p, h, given["m"], compute_dtype=_dt(cfg.compute_dtype)), {}
+
+
+# softmax attention (``ops.attention``): grouped, latent or differential; whole,
+# inside a window, or onto the keys and values of the full layer below
+#: the four learned vectors of a differential layer's lambda
+_LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+
+
+def _attention_shapes(cfg: BackboneConfig) -> Dict:
+    d, h, hkv, hd = (cfg.hidden_size, cfg.num_attention_heads, cfg.num_key_value_heads,
+                     cfg.head_dim)
+    if cfg.attention == "mla":
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        return {
+            "w_qa": ((d, rq), "w"), "q_norm": ((rq,), "zero"), "w_qb": ((rq, h * (dn + dr)), "w"),
+            "w_kva": ((d, rkv + dr), "w"), "kv_norm": ((rkv,), "zero"),
+            "w_kvb": ((rkv, h * (dn + dv)), "w"), "w_o": ((h * dv, d), "w"),
+        }
+    full = {
+        "w_q": ((d, h * hd * (2 if cfg.attn_gate else 1)), "w"),
+        "w_k": ((d, hkv * hd), "w"), "w_v": ((d, hkv * hd), "w"),
+        "w_o": ((h * hd, d), "w"),
+    }
+    if cfg.attn_gate or cfg.qk_norm:
+        full.update(q_norm=((hd,), "zero"), k_norm=((hd,), "zero"))
+    if cfg.differential:
+        full.update({name: ((hd,), "lambda") for name in _LAMBDAS}, subln=((2 * hd,), "one"))
+    return full
+
+
+def _cross_shapes(cfg: BackboneConfig) -> Dict:
+    return {name: spec for name, spec in _attention_shapes(cfg).items()
+            if name not in ("w_k", "w_v")}
+
+
+def _window_widths(cfg: BackboneConfig) -> Dict:
+    return dict(window=cfg.sliding_window)
 
 
 def positions_of(seg):
@@ -602,11 +685,7 @@ def _rope_pairs(t, pos, theta: float):
     return jnp.stack([a * cos - b * sin, b * cos + a * sin], -1).reshape(t.shape)
 
 
-def _rms(x, w, eps: float):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (1.0 + w)
-
-
-def _latent_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule):
+def _latent_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, given, depth, mesh, schedule):
     """Latent attention. Queries: down to ``q_lora_rank``, RMS norm, up to
     H x (nope | rope). Keys and values: down to ``kv_lora_rank`` + rope,
     RMS norm of the latent part, up to H x (nope | value); the rope part
@@ -618,17 +697,13 @@ def _latent_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule):
     h, rkv = cfg.num_attention_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     cd, f32, eps = _dt(cfg.compute_dtype), jnp.float32, cfg.rms_norm_eps
-
-    def dot(t, w):
-        return jnp.dot(t.astype(cd), w.astype(cd), preferred_element_type=f32)
-
     with jax.named_scope("seq.attn.latent"):
         # the wide projections are kept in the compute dtype, as in the other mixers
-        q = dot(_rms(dot(x, p["w_qa"]), p["q_norm"], eps), p["w_qb"]).astype(cd)
-        q = q.reshape(b, l, h, dn + dr)
-        kva = dot(x, p["w_kva"])
+        q = _dot(cfg, _unit(_dot(cfg, x, p["w_qa"]), eps) * (1.0 + p["q_norm"]), p["w_qb"])
+        q = q.astype(cd).reshape(b, l, h, dn + dr)
+        kva = _dot(cfg, x, p["w_kva"])
         k_rope = _rope_pairs(kva[:, :, None, rkv:], pos, cfg.rope_theta)
-        kv = dot(_rms(kva[..., :rkv], p["kv_norm"], eps), p["w_kvb"]).astype(cd)
+        kv = _dot(cfg, _unit(kva[..., :rkv], eps) * (1.0 + p["kv_norm"]), p["w_kvb"]).astype(cd)
         kv = kv.reshape(b, l, h, dn + dv).astype(f32)
         q_rope = _rope_pairs(q[..., dn:].astype(f32), pos, cfg.rope_theta)
         q = jnp.concatenate([q[..., :dn], q_rope.astype(cd)], -1)
@@ -645,27 +720,23 @@ def _latent_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule):
     with jax.named_scope("seq.attn.core"):
         o = attention(q, k, v, mesh=mesh, causal=True, schedule=schedule, segment_ids=seg,
                       block=cfg.attn_block, stats_dtype=cfg.state_dtype, kernel=cfg.attn_kernel)
-    out = dot(o.transpose(0, 2, 1, 3).reshape(b, l, h * dv), p["w_o"])
-    return out + dot(v_mean.reshape(b, 1, h * dv), p["w_o"]), {"q": q, "k": k, "v": v, "o": o}
+    out = _dot(cfg, o.transpose(0, 2, 1, 3).reshape(b, l, h * dv), p["w_o"])
+    return out + _dot(cfg, v_mean.reshape(b, 1, h * dv), p["w_o"]), {"q": q, "k": k, "v": v, "o": o}
 
 
-def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule):
+def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, given, depth, mesh, schedule):
     b, l, _ = x.shape
     h, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     cd, f32 = _dt(cfg.compute_dtype), jnp.float32
     xc = x.astype(cd)
     # the wide projections are kept in the compute dtype, as in the DeltaNet mixer
-    qg = jnp.dot(xc, p["w_q"].astype(cd), preferred_element_type=f32).astype(cd)
+    qg = _dot(cfg, xc, p["w_q"]).astype(cd)
     q = qg[..., : h * hd].reshape(b, l, h, hd).astype(f32)
-    k = jnp.dot(xc, p["w_k"].astype(cd), preferred_element_type=f32).reshape(b, l, hkv, hd)
-    v = jnp.dot(xc, p["w_v"].astype(cd), preferred_element_type=f32).reshape(b, l, hkv, hd)
+    k = _dot(cfg, xc, p["w_k"]).reshape(b, l, hkv, hd)
+    v = _dot(cfg, xc, p["w_v"]).reshape(b, l, hkv, hd)
     if "q_norm" in p:
-        eps = cfg.rms_norm_eps
-
-        def head_norm(t, w):
-            return t * jax.lax.rsqrt(jnp.mean(t * t, -1, keepdims=True) + eps) * (1.0 + w)
-
-        q, k = head_norm(q, p["q_norm"]), head_norm(k, p["k_norm"])
+        q = _unit(q, cfg.rms_norm_eps) * (1.0 + p["q_norm"])
+        k = _unit(k, cfg.rms_norm_eps) * (1.0 + p["k_norm"])
     if cfg.positions == "rotary":
         rot = int(cfg.partial_rotary_factor * hd)
         q, k = _rope(q, pos, rot, cfg.rope_theta), _rope(k, pos, rot, cfg.rope_theta)
@@ -681,27 +752,236 @@ def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, mesh, schedule):
     o = o.transpose(0, 2, 1, 3).reshape(b, l, h * hd).astype(f32)
     if cfg.attn_gate:
         o = o * jax.nn.sigmoid(qg[..., h * hd:].astype(f32))
-    return jnp.dot(o.astype(cd), p["w_o"].astype(cd), preferred_element_type=f32)
+    return _dot(cfg, o, p["w_o"]), {}
 
 
+def lambda_init(depth: int) -> float:
+    """Where a differential layer's lambda starts, by the layer's depth."""
+    return 0.8 - 0.6 * float(np.exp(-0.3 * depth))
+
+
+def _differential_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, given, depth, mesh, schedule,
+                        *, window: int = 0):
+    """Differential attention on grouped heads: query heads (2p, 2p + 1) are
+    pair p, key heads (2c, 2c + 1) key pair c, value heads (2c, 2c + 1) side
+    by side ONE value of twice the head; query pair p reads key pair ``p //
+    (pairs a key pair)``. Per pair ``(softmax(q1 k1) - lambda softmax(q2 k2))
+    v``, an RMS norm over the value's width, ``1 - lambda_init``, ``W_o``.
+    Both softmaxes are ONE call of the attention core: the members lie along
+    its head axis, first of all pairs, then second, over the values twice.
+    ``given`` (a cross-attention layer's, else empty): the ``k`` and ``v`` of
+    the full layer below, as that layer's call of this function returned them.
+    ``window``: a sliding layer's (``_window_widths``).
+    Also returns the ``q``, ``k``, ``v`` the core was handed [B, 2 pairs, L, .],
+    ``lam`` and ``o``, the difference before the norm [B, pairs, L, 2 hd]."""
+    b, l, _ = x.shape
+    h, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    cd, f32 = _dt(cfg.compute_dtype), jnp.float32
+    xc = x.astype(cd)
+
+    def members_first(t, heads):  # [B, L, heads * hd] -> [B, member, pair, L, hd] -> [B, heads, L, hd]
+        return t.reshape(b, l, heads // 2, 2, hd).transpose(0, 3, 2, 1, 4).reshape(b, heads, l, hd)
+
+    q = members_first(_dot(cfg, xc, p["w_q"]).astype(cd), h)
+    if not given:
+        k = members_first(_dot(cfg, xc, p["w_k"]).astype(cd), hkv)
+        v = _dot(cfg, xc, p["w_v"]).astype(cd)
+        v = v.reshape(b, l, hkv // 2, 2 * hd).transpose(0, 2, 1, 3)
+        v = jnp.concatenate([v, v], axis=1)  # each member's softmax over the pair's one value
+    else:
+        k, v = given["k"], given["v"]
+    start = lambda_init(depth)
+    lam = (jnp.exp(jnp.sum(p["lambda_q1"] * p["lambda_k1"]))
+           - jnp.exp(jnp.sum(p["lambda_q2"] * p["lambda_k2"])) + start)
+    with jax.named_scope("seq.attn.swa.core" if window else "seq.attn.core"):
+        both = attention(q, k, v, mesh=mesh, causal=True, schedule=schedule, segment_ids=seg,
+                         block=cfg.attn_block, window=window).astype(f32)
+        o = both[:, : h // 2] - lam * both[:, h // 2:]  # [B, pairs, L, 2 hd]
+    normed = _unit(o, cfg.rms_norm_eps) * (p["subln"] * (1.0 - start))
+    out = _dot(cfg, normed.transpose(0, 2, 1, 3).reshape(b, l, h * hd), p["w_o"])
+    return out, {"q": q, "k": k, "v": v, "lam": lam, "o": o}
+
+
+def _full(cfg: BackboneConfig, *layer):
+    """The attention the configuration names: differential, latent or grouped."""
+    mixer = _differential_mixer if cfg.differential else (
+        _latent_mixer if cfg.attention == "mla" else _attention_mixer)
+    return mixer(cfg, *layer)
+
+
+def _swa(cfg: BackboneConfig, *layer):
+    return _differential_mixer(cfg, *layer, **_window_widths(cfg))
+
+
+def _window_forms(cfg: BackboneConfig, length: int) -> Dict:
+    """The tiles of the blockwise attention loop that the window alone leaves
+    out, over the sliding layers of one forward pass of a row of ``length`` slots."""
+    return {"attn_tiles_skipped_by_window": cfg.kinds.count("swa") * tiles_skipped_by_window(
+        length, cfg.attn_block, **_window_widths(cfg))}
+
+
+def _attention_check(cfg: BackboneConfig, merged: Dict) -> None:
+    if merged.get("attention_bias"):
+        raise ValueError("the attention projections carry no bias here")
+    if not cfg.differential:
+        return
+    if cfg.attention != "gqa" or cfg.attn_gate or cfg.qk_norm or cfg.positions == "rotary":
+        raise ValueError("differential attention here is grouped-query attention without "
+                         "a gate, a norm on q and k or rotary positions")
+    if cfg.num_attention_heads % 2 or cfg.num_key_value_heads % 2 or (
+            cfg.num_attention_heads % cfg.num_key_value_heads):
+        raise ValueError("differential attention pairs up query heads and key heads: "
+                         "both counts even, the first a multiple of the second")
+    if cfg.n_periods != 1 or cfg.num_nextn_predict_layers:
+        raise ValueError("a differential layer's lambda starts from its depth: the layers "
+                         "after the dense ones have to be ONE period, with no prediction module")
+
+
+def _differential_check(cfg: BackboneConfig, merged: Dict) -> None:
+    if not cfg.differential:
+        raise ValueError("sliding_attention and cross_attention layers run differential "
+                         "attention here: the backbone group has to say differential")
+    _attention_check(cfg, merged)
+
+
+def _swa_check(cfg: BackboneConfig, merged: Dict) -> None:
+    _in_a_period(cfg, "swa")
+    _differential_check(cfg, merged)
+    if cfg.sliding_window <= 0:
+        raise ValueError("sliding_attention layers need sliding_window: no default is assumed")
+
+
+def _cross_check(cfg: BackboneConfig, merged: Dict) -> None:
+    _handed(cfg, "cross", "keys and values")
+    _differential_check(cfg, merged)
+
+
+# -- the table ----------------------------------------------------------------
+class _Mixer(NamedTuple):
+    """Everything the backbone knows about one kind of mixer. ``words``: the
+    public files' words for it in ``layer_types``. ``name``: what
+    ``BackboneConfig.mixers`` counts it as; empty = ``cfg.attention``.
+    ``scope``: the named scopes around the mixer and its residual addition,
+    outermost first; the benchmark's per-layer metrics read these names.
+    ``shapes(cfg)``: its parameters' ``(shape, kind)`` tree (``_shapes``).
+    ``run(cfg, p, h, seg, pos, given, depth, mesh, schedule) -> (y, ran)``: ``h``
+    the layer's normed input, ``given`` what it ``reads``, ``depth`` the layer's
+    index in the published model, ``ran`` what its inner loops were handed and
+    gave; a function of this module that finds its op in the module's globals
+    when it is called, so a test that replaces the op there runs the
+    replacement. ``widths(cfg)``: the keyword arguments its op takes from the
+    configuration; what ``run`` hands the op is what ``forms`` asks the op's
+    module about. ``forms(cfg, length)``: the counters that say which form of
+    its inner loops runs over rows of ``length`` slots. ``hands``: of ``ran``,
+    what the layers above it in its period may read; ``reads``: what it reads
+    of that, the newest below it. ``kept``: what its layer's recomputation
+    keeps and does not make again, a name that ``checkpoint_name`` gave: the
+    scan's output and the states its backward pass starts from, where the
+    scan's kernel runs. ``check(cfg, merged)``: raises its refusals of a
+    configuration in words (``merged``: the public file's keys and the
+    ``backbone`` group's)."""
+    words: Tuple[str, ...]
+    name: str
+    scope: Tuple[str, ...]
+    shapes: Callable
+    run: Callable
+    widths: Callable = lambda cfg: {}
+    forms: Callable = lambda cfg, length: {}
+    hands: Tuple[str, ...] = ()
+    reads: Tuple[str, ...] = ()
+    kept: Optional[str] = None
+    check: Callable = lambda cfg, merged: None
+
+
+def _of_op(op: Callable, forms: Callable, shapes: Callable, widths: Callable, **fields) -> _Mixer:
+    """The record of a mixer that is one op of ``ops/``: ``op()(p, h, seg,
+    **widths(cfg))`` (``op`` a thunk: the op is looked up when a layer is
+    traced), and the ``forms`` of the op's own module, asked with the
+    parameters' shapes and the same keyword arguments."""
+    def run(cfg, p, h, seg, pos, given, depth, mesh, schedule):
+        return op()(p, h, seg, **widths(cfg))
+
+    def forms_of(cfg, length):
+        plain = {name: shape for name, (shape, _) in shapes(cfg).items()}
+        return forms(plain, length, **widths(cfg))
+
+    return _Mixer(shapes=shapes, widths=widths, run=run, forms=forms_of, **fields)
+
+
+#: kind (what the parameters are stacked under) -> its record
+_MIXERS: Dict[str, _Mixer] = {
+    # gated DeltaNet
+    "linear": _of_op(
+        lambda: gated_deltanet, deltanet.forms, _deltanet_shapes, _deltanet_widths,
+        words=("linear_attention",), name="deltanet", scope=("seq.deltanet",)),
+    # the gated short convolution
+    "conv": _of_op(
+        lambda: short_conv, shortconv.forms, _shortconv_shapes, _shortconv_widths,
+        words=("conv",), name="shortconv", scope=("seq.shortconv",), check=_shortconv_check),
+    # the Mamba-2 state-space mixer
+    "ssm": _of_op(
+        lambda: mamba2, ssd.forms, _mamba2_shapes, _mamba2_widths,
+        words=("mamba",), name="mamba2", scope=("seq.ssm",), kept="ssd", check=_mamba2_check),
+    # the Mamba-1 selective scan
+    "mamba1": _of_op(
+        lambda: mamba1, selscan.forms, _mamba1_shapes, _mamba1_widths,
+        words=("mamba1",), name="mamba1", scope=("seq.mamba",), hands=("m",), kept="selscan",
+        check=_mamba1_check),
+    # a gated memory unit that reads the scan output of the last ``mamba1`` layer below it
+    "gmu": _Mixer(
+        words=("gmu",), name="gmu", scope=("seq.gmu",), run=_gmu, reads=("m",),
+        shapes=lambda cfg: {"w_1": ((cfg.hidden_size, cfg.mamba_expand * cfg.hidden_size), "w"),
+                            "w_2": ((cfg.mamba_expand * cfg.hidden_size, cfg.hidden_size), "w")},
+        check=lambda cfg, merged: _handed(cfg, "gmu", "a scan output")),
+    # softmax attention over the whole history: grouped, latent or differential
+    "full": _Mixer(
+        words=("full_attention", "attention"), name="", scope=("seq.attn",),
+        shapes=_attention_shapes, run=_full, hands=("k", "v"), check=_attention_check),
+    # differential attention inside ``sliding_window`` slots: itself and the ones before
+    "swa": _Mixer(
+        words=("sliding_attention",), name="swa", scope=("seq.attn", "seq.attn.swa"),
+        shapes=_attention_shapes, widths=_window_widths, run=_swa, forms=_window_forms,
+        check=_swa_check),
+    # queries of its own onto the keys and values of the last ``full`` layer below it
+    "cross": _Mixer(
+        words=("cross_attention",), name="cross", scope=("seq.attn",), shapes=_cross_shapes,
+        run=_differential_mixer, reads=("k", "v"), check=_cross_check),
+}
+_KIND_OF = {word: kind for kind, mixer in _MIXERS.items() for word in mixer.words}
+#: the recomputation policy that keeps a name, ONE object a name: layers whose
+#: policies are two objects share none of their inner functions in the lowered step
+_POLICIES = {mixer.kept: jax.checkpoint_policies.save_only_these_names(mixer.kept)
+             for mixer in _MIXERS.values() if mixer.kept}
+
+
+def mechanisms(cfg: BackboneConfig, length: int) -> Dict:
+    """The counters that say which form of its mixers' inner loops a job over
+    rows of ``length`` slots runs: every record's ``forms`` over the kinds the
+    backbone has, ``conv`` the forms its mixers' short convolutions run in,
+    sorted and joined by ``+``."""
+    found = [mixer.forms(cfg, length) for kind, mixer in _MIXERS.items() if kind in cfg.kinds]
+    convs = {forms.pop("conv") for forms in found if "conv" in forms}
+    merged = {name: form for forms in found for name, form in forms.items()}
+    return {**merged, **({"conv": "+".join(sorted(convs))} if convs else {})}
+
+
+# -- the block --------------------------------------------------------------
 def _ffn(cfg: BackboneConfig, p: Dict, x):
     """The feed-forward its parameters describe: routed experts, a SwiGLU
     or the GELU pair."""
-    cd, f32 = _dt(cfg.compute_dtype), jnp.float32
     if "router" in p:
         b, l, d = x.shape
         with jax.named_scope("seq.moe"):
             y, counters = expert_layer(
                 p, x.reshape(b * l, d), first=cfg.experts_held[0],
                 top_k=cfg.num_experts_per_tok, norm_topk=cfg.norm_topk_prob,
-                compute_dtype=cd, scoring=cfg.scoring_func, scale=cfg.routed_scaling_factor,
-                norm_eps=cfg.norm_topk_eps)
+                compute_dtype=_dt(cfg.compute_dtype), scoring=cfg.scoring_func,
+                scale=cfg.routed_scaling_factor, norm_eps=cfg.norm_topk_eps)
         return y.reshape(b, l, d), counters
     if "wg" in p:
         with jax.named_scope("seq.ffn"):
-            return swiglu(p, x, cd), {}
-    hidden = jax.nn.gelu(jnp.dot(x.astype(cd), p["mlp_in"].astype(cd), preferred_element_type=f32))
-    return jnp.dot(hidden.astype(cd), p["mlp_out"].astype(cd), preferred_element_type=f32), {}
+            return swiglu(p, x, _dt(cfg.compute_dtype)), {}
+    return _dot(cfg, jax.nn.gelu(_dot(cfg, x, p["mlp_in"])), p["mlp_out"]), {}
 
 
 def _add(cfg: BackboneConfig, x, y):
@@ -711,68 +991,37 @@ def _add(cfg: BackboneConfig, x, y):
 
 def _layer(cfg: BackboneConfig, kind: str, mesh, schedule, x, seg, pos,
            norm_in, mixer, norm_post, ffn, given=None, *, depth: int = 0):
+    record = _MIXERS[kind]
     h = _norm(cfg, norm_in, x)
-    ran = {}
-    if kind in _HYBRID or (kind == "full" and cfg.differential):
-        mixed, ran = _hybrid_mixer(cfg, kind, mixer, h, seg, mesh, schedule, given, depth)
+    with contextlib.ExitStack() as scopes:
+        for name in record.scope:
+            scopes.enter_context(jax.named_scope(name))
+        mixed, ran = record.run(cfg, mixer, h, seg, pos, given, depth, mesh, schedule)
         x = _add(cfg, x, mixed)
-    elif kind == "full" and cfg.attention == "mla":
-        with jax.named_scope("seq.attn"):
-            mixed, ran = _latent_mixer(cfg, mixer, h, seg, pos, mesh, schedule)
-            x = _add(cfg, x, mixed)
-    elif kind == "full":
-        with jax.named_scope("seq.attn"):
-            x = _add(cfg, x, _attention_mixer(cfg, mixer, h, seg, pos, mesh, schedule))
-    elif kind == "conv":
-        with jax.named_scope("seq.shortconv"):
-            mixed, ran = short_conv(mixer, h, seg, compute_dtype=_dt(cfg.compute_dtype),
-                                    gate_dtype=_dt(cfg.gate_dtype))
-            x = _add(cfg, x, mixed)
-    elif kind == "ssm":
-        with jax.named_scope("seq.ssm"):
-            mixed, ran = mamba2(
-                mixer, h, seg, heads=cfg.mamba_n_heads, head_dim=cfg.mamba_d_head,
-                state=cfg.mamba_d_state, eps=cfg.rms_norm_eps, chunk=cfg.chunk,
-                compute_dtype=_dt(cfg.compute_dtype), state_dtype=_dt(cfg.state_dtype),
-                gate_dtype=_dt(cfg.gate_dtype))
-            x = _add(cfg, x, mixed)
-    else:
-        with jax.named_scope("seq.deltanet"):
-            mixed, ran = gated_deltanet(
-                mixer, h, seg, key_heads=cfg.linear_num_key_heads,
-                value_heads=cfg.linear_num_value_heads, key_dim=cfg.linear_key_head_dim,
-                value_dim=cfg.linear_value_head_dim, eps=cfg.rms_norm_eps, chunk=cfg.chunk,
-                compute_dtype=_dt(cfg.compute_dtype), state_dtype=_dt(cfg.state_dtype),
-                gate_dtype=_dt(cfg.gate_dtype))
-            x = _add(cfg, x, mixed)
     y, counters = _ffn(cfg, ffn, _norm(cfg, norm_post, x))
     return _add(cfg, x, y), counters, ran
 
 
 def _layer_fn(cfg: BackboneConfig, kind: str, mesh, schedule, depth: int = 0):
-    """One layer whose mixer is of ``kind`` (``BackboneConfig.kinds``) as ``(x,
-    seg, pos, norm_in, mixer, norm_post, ffn) -> x, counters, ran``, recomputed
-    in the backward pass; a layer that reads what a layer below handed on
-    (``_READS``) takes that, a dict, as one more argument. ``depth``: the
-    layer's index in the published model, which a differential layer's
-    ``lambda`` starts from."""
-    return jax.checkpoint(lambda *a: _layer(cfg, kind, mesh, schedule, *a, depth=depth), policy=_KEPT.get(kind))
+    """One layer whose mixer is of ``kind`` (a key of ``_MIXERS``) as ``(x, seg,
+    pos, norm_in, mixer, norm_post, ffn, given) -> x, counters, ran``, recomputed
+    in the backward pass but for what its record says is ``kept``; ``given``, a
+    dict: what the layer reads of the layers below it. ``depth``: the layer's
+    index in the published model, which a differential layer's ``lambda``
+    starts from."""
+    return jax.checkpoint(lambda *a: _layer(cfg, kind, mesh, schedule, *a, depth=depth),
+                          policy=_POLICIES.get(_MIXERS[kind].kept))
 
 
 def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
                   schedule: str = "auto"):
     """tokens, seg [B, L] -> the residual stream after the last layer
     [B, L, D] (float32, before the final norm); the expert layers'
-    counters, stacked [periods, layers of a period, ...]; and what the
-    first mixer of each period that says so handed its inner kernel and got
-    back (the delta rule's q, k, v, g, beta and o:
-    ``ops.deltanet.gated_deltanet``; latent attention's q, k, v and o; the
-    short convolution's ``bcx`` and ``y``: ``ops.shortconv.short_conv``; the
-    state-space scan's ``u``, ``B``, ``C``, ``dt`` and ``y``: ``ops.ssd.mamba2``;
-    the selective scan's ``c``, ``dt``, ``B``, ``C``, ``y`` and ``m``:
-    ``ops.selscan.mamba1``, and beside them the first differential layer's
-    ``q``, ``k``, ``v``, ``lam`` and ``o``: ``_differential_mixer``;
-    stacked [periods, B, ...]; empty where no mixer of a period does)."""
+    counters, stacked [periods, layers of a period, ...]; and ``ran``: what
+    the first mixer of each period that gives a name handed its inner loops
+    and got back, as its op or mixer function documents it (the ``run`` of
+    its record in ``_MIXERS``), stacked [periods, B, ...]; empty where no
+    mixer of a period gives any."""
     pos = positions_of(seg)
     with jax.named_scope("seq.embed"):
         x = params["embed"][tokens]
@@ -786,23 +1035,31 @@ def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
                     f"table ({table.shape[0]} positions: trained with a shorter seq_len)")
             x = x + table[pos]
 
-    layer_of = {kind: _layer_fn(cfg, kind, mesh, schedule) for kind in set(cfg.kinds)}
+    # A layer knows its place in the published model only where a mixer starts
+    # from it (a differential layer's lambda); layers of one kind that do not
+    # are ONE function, traced and lowered once.
+    @functools.cache
+    def layer_of(kind, depth):
+        return _layer_fn(cfg, kind, mesh, schedule, depth)
+
+    def depth(j):
+        return cfg.layer_index_offset + j if cfg.differential else 0
+
     for j in range(cfg.first_k_dense_replace):
         kind = cfg.kinds[0]
         d = jax.tree_util.tree_map(lambda a, j=j: a[j], params["dense"])
-        x, _, _ = layer_of[kind](x, seg, pos, d["norm_in"], d[kind], d["norm_post"], d["ffn"])
+        x, _, _ = layer_of(kind, depth(j))(
+            x, seg, pos, d["norm_in"], d[kind], d["norm_post"], d["ffn"])
 
     def one_period(x, per):
         counters, first_ran, given = [], {}, {}
         for j, kind in enumerate(cfg.period_kinds):
             pick = lambda tree, j=j: jax.tree_util.tree_map(lambda a: a[j], tree)  # noqa: E731
-            mixer = _mixer_of(cfg, per, j)
-            layer = layer_of[kind] if not cfg.differential else _layer_fn(
-                cfg, kind, mesh, schedule, cfg.layer_index_offset + cfg.first_k_dense_replace + j)
-            reads = ({name: given[name] for name in _READS[kind]},) if kind in _READS else ()
+            record, mixer = _MIXERS[kind], _mixer_of(cfg, per, j)
+            layer = layer_of(kind, depth(cfg.first_k_dense_replace + j))
             x, c, ran = layer(x, seg, pos, pick(per["norm_in"]), mixer, pick(per["norm_post"]),
-                              pick(per["ffn"]), *reads)
-            given.update({name: ran[name] for name in _HANDS.get(kind, ()) if name in ran})
+                              pick(per["ffn"]), {name: given[name] for name in record.reads})
+            given.update({name: ran[name] for name in record.hands if name in ran})
             counters.append(c)
             # every name from the first mixer of the period that gives it
             first_ran = {**ran, **first_ran}
@@ -813,6 +1070,7 @@ def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
     return x, counters, ran
 
 
+# -- the loss ---------------------------------------------------------------
 def head_of(params: Dict):
     return params["head"] if "head" in params else params["embed"]
 
@@ -820,11 +1078,9 @@ def head_of(params: Dict):
 def logits_of(cfg: BackboneConfig, params: Dict, hidden, norm: Optional[Dict] = None):
     """hidden [..., D] (before the final norm, or before ``norm``, the
     prediction module's own) -> logits [..., V], float32."""
-    cd = _dt(cfg.compute_dtype)
     with jax.named_scope("seq.head"):
         h = _norm(cfg, params["final_norm"] if norm is None else norm, hidden)
-        logits = jnp.dot(h.astype(cd), head_of(params).T.astype(cd),
-                         preferred_element_type=jnp.float32)
+        logits = _dot(cfg, h.astype(_dt(cfg.compute_dtype)), head_of(params).T)
         return logits if cfg.logits_scaling == 1.0 else logits / cfg.logits_scaling
 
 
@@ -873,11 +1129,10 @@ def mtp_hidden(cfg: BackboneConfig, params: Dict, hidden, next_tokens, seg, mesh
     W_eh``, then one block of the model's own kind (its routed experts
     too). hidden [B, L, D] as :func:`hidden_states` gives it -> [B, L, D]
     (before the module's own last norm) and the block's counters."""
-    m, cd = params["mtp"], _dt(cfg.compute_dtype)
+    m = params["mtp"]
     both = jnp.concatenate([_norm(cfg, m["enorm"], params["embed"][next_tokens]),
                             _norm(cfg, m["hnorm"], hidden)], -1)
-    x = jnp.dot(both.astype(cd), m["eh_proj"].astype(cd), preferred_element_type=jnp.float32)
-    blk = m["block"]
+    x, blk = _dot(cfg, both, m["eh_proj"]), m["block"]
     x, counters, _ = _layer_fn(cfg, "full", mesh, schedule)(
         x, seg, positions_of(seg), blk["norm_in"], blk["full"], blk["norm_post"], blk["ffn"])
     return x, counters
@@ -905,6 +1160,7 @@ def loss_fn(cfg: BackboneConfig, params: Dict, rows, segs, mesh=None,
     return loss, (hidden, counters, ran)
 
 
+# -- the routers' step --------------------------------------------------------
 def step_routers(cfg: BackboneConfig, before: Dict, after: Dict, counters: Dict) -> Dict:
     """``after`` (the parameters an optimizer step made of ``before``) with
     what that step does not decide about the routers put right. Every
@@ -936,174 +1192,3 @@ def step_routers(cfg: BackboneConfig, before: Dict, after: Dict, counters: Dict)
             if not cfg.router_trains:
                 after = put(after, path + ("router",), ffn["router"])
     return after
-
-
-def conv_kinds(cfg: BackboneConfig, length: int) -> Dict[str, str]:
-    """What runs the short convolutions of the mixers over rows of
-    ``length`` slots, as ``{"conv": "pallas"}`` or ``"xla"``
-    (``ops.shortconv.conv_kind`` at the channels each mixer convolves, where
-    they start in its wide projection and the dtype of its taps); nothing for
-    a backbone without one."""
-    d, qk = cfg.hidden_size, cfg.linear_num_key_heads * cfg.linear_key_head_dim
-    inner = cfg.mamba_n_heads * cfg.mamba_d_head
-    chains = {  # channels, taps, their dtype, where the parts start
-        "linear": (2 * qk + cfg.linear_num_value_heads * cfg.linear_value_head_dim,
-                   cfg.linear_conv_kernel_dim, "float32", (0,)),
-        "ssm": (inner + 2 * cfg.mamba_d_state, cfg.mamba_d_conv, "float32", (inner,)),
-        "mamba1": (cfg.mamba_expand * d, cfg.mamba_d_conv, "float32", (0,)),
-        "conv": (d, cfg.conv_L_cache, cfg.gate_dtype, (0, d, 2 * d)),
-    }
-    ran = {conv_kind(channels, length, dtype, offsets, taps=taps)
-           for kind, (channels, taps, dtype, offsets) in chains.items() if kind in cfg.kinds}
-    return {"conv": "+".join(sorted(ran))} if ran else {}
-
-
-# -- the decoder-hybrid-decoder's mixers (below the frames the Pallas kernels
-# record: PERF.md section 7) --------------------------------------------------
-#: the four learned vectors of a differential layer's lambda
-_LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
-
-
-def _check_hybrid(cfg: BackboneConfig, merged: Dict) -> None:
-    """What ``from_dict`` asks of a configuration with ``mamba1``,
-    ``sliding_attention``, ``gmu`` or ``cross_attention`` layers or with
-    differential attention, each refusal in words."""
-    kinds = cfg.kinds
-    words = {kind: word for word, kind in _KINDS.items()}
-    for i, kind in enumerate(cfg.period_kinds):
-        if kind in _PRODUCERS:
-            producer, word, product = _PRODUCERS[kind]
-            if producer not in cfg.period_kinds[:i]:
-                raise ValueError(
-                    f"layer {cfg.first_k_dense_replace + i} is a {words[kind]} layer and no "
-                    f"{word} layer below it in its period hands it {product}")
-    hybrid = set(_HYBRID) & set(kinds)
-    if hybrid & set(kinds[:cfg.first_k_dense_replace]):
-        raise ValueError("the leading dense layers hand nothing on: mamba1, sliding_attention, "
-                         "gmu and cross_attention layers belong to the periods")
-    if {"swa", "cross"} & hybrid and not cfg.differential:
-        raise ValueError("sliding_attention and cross_attention layers run differential "
-                         "attention here: the backbone group has to say differential")
-    if cfg.differential:
-        if cfg.attention != "gqa" or cfg.attn_gate or cfg.qk_norm or cfg.positions == "rotary":
-            raise ValueError("differential attention here is grouped-query attention without "
-                             "a gate, a norm on q and k or rotary positions")
-        if cfg.num_attention_heads % 2 or cfg.num_key_value_heads % 2 or (
-                cfg.num_attention_heads % cfg.num_key_value_heads):
-            raise ValueError("differential attention pairs up query heads and key heads: "
-                             "both counts even, the first a multiple of the second")
-        if cfg.n_periods != 1 or cfg.num_nextn_predict_layers:
-            raise ValueError("a differential layer's lambda starts from its depth: the layers "
-                             "after the dense ones have to be ONE period, with no prediction module")
-    if "swa" in kinds and cfg.sliding_window <= 0:
-        raise ValueError("sliding_attention layers need sliding_window: no default is assumed")
-    if "mamba1" in kinds:
-        sizes = ("mamba_d_state", "mamba_d_conv", "mamba_expand", "mamba_dt_rank")
-        missing = [name for name in sizes if not getattr(cfg, name)]
-        if missing:
-            raise ValueError(f"mamba1 layers need {', '.join(missing)}: no default is assumed")
-        if not merged.get("mamba_conv_bias", True) or merged.get("mamba_proj_bias"):
-            raise ValueError("the Mamba-1 mixer here has a bias on its convolution "
-                             "and none on its projections")
-
-
-def lambda_init(depth: int) -> float:
-    """Where a differential layer's lambda starts, by the layer's depth."""
-    return 0.8 - 0.6 * float(np.exp(-0.3 * depth))
-
-
-def _differential_mixer(cfg: BackboneConfig, p: Dict, x, seg, mesh, schedule, depth: int,
-                        window: int = 0, given: Optional[Dict] = None):
-    """Differential attention on grouped heads: query heads (2p, 2p + 1) are
-    pair p, key heads (2c, 2c + 1) key pair c, value heads (2c, 2c + 1) side
-    by side ONE value of twice the head; query pair p reads key pair ``p //
-    (pairs a key pair)``. Per pair ``(softmax(q1 k1) - lambda softmax(q2 k2))
-    v``, an RMS norm over the value's width, ``1 - lambda_init``, ``W_o``.
-    Both softmaxes are ONE call of the attention core: the members lie along
-    its head axis, first of all pairs, then second, over the values twice.
-    ``given`` (a cross-attention layer): the ``k`` and ``v`` of the full
-    layer below, as that layer's call of this function returned them. Also
-    returns the ``q``, ``k``, ``v`` the core was handed [B, 2 pairs, L, .],
-    ``lam`` and ``o``, the difference before the norm [B, pairs, L, 2 hd]."""
-    b, l, _ = x.shape
-    h, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    cd, f32 = _dt(cfg.compute_dtype), jnp.float32
-    xc = x.astype(cd)
-
-    def members_first(t, heads):  # [B, L, heads * hd] -> [B, member, pair, L, hd] -> [B, heads, L, hd]
-        return t.reshape(b, l, heads // 2, 2, hd).transpose(0, 3, 2, 1, 4).reshape(b, heads, l, hd)
-
-    q = members_first(jnp.dot(xc, p["w_q"].astype(cd), preferred_element_type=f32).astype(cd), h)
-    if given is None:
-        k = members_first(
-            jnp.dot(xc, p["w_k"].astype(cd), preferred_element_type=f32).astype(cd), hkv)
-        v = jnp.dot(xc, p["w_v"].astype(cd), preferred_element_type=f32).astype(cd)
-        v = v.reshape(b, l, hkv // 2, 2 * hd).transpose(0, 2, 1, 3)
-        v = jnp.concatenate([v, v], axis=1)  # each member's softmax over the pair's one value
-    else:
-        k, v = given["k"], given["v"]
-    start = lambda_init(depth)
-    lam = (jnp.exp(jnp.sum(p["lambda_q1"] * p["lambda_k1"]))
-           - jnp.exp(jnp.sum(p["lambda_q2"] * p["lambda_k2"])) + start)
-    with jax.named_scope("seq.attn.swa.core" if window else "seq.attn.core"):
-        both = attention(q, k, v, mesh=mesh, causal=True, schedule=schedule, segment_ids=seg,
-                         block=cfg.attn_block, window=window).astype(f32)
-        o = both[:, : h // 2] - lam * both[:, h // 2:]  # [B, pairs, L, 2 hd]
-    normed = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.rms_norm_eps) * (
-        p["subln"] * (1.0 - start))
-    out = jnp.dot(normed.transpose(0, 2, 1, 3).reshape(b, l, h * hd).astype(cd),
-                  p["w_o"].astype(cd), preferred_element_type=f32)
-    return out, {"q": q, "k": k, "v": v, "lam": lam, "o": o}
-
-
-def _hybrid_mixer(cfg: BackboneConfig, kind: str, p: Dict, h, seg, mesh, schedule,
-                  given: Optional[Dict], depth: int):
-    """The mixers of a decoder-hybrid-decoder, ``h`` the layer's normed
-    input: a Mamba-1 layer, a gated memory unit on the scan output ``m`` a
-    Mamba-1 layer below handed on, or differential attention (full; inside a
-    window: ``seq.attn.swa`` around the layer, its core ``seq.attn.swa.core``;
-    or queries alone onto the ``k``, ``v`` a full layer below handed on)."""
-    if kind == "mamba1":
-        with jax.named_scope("seq.mamba"):
-            return mamba1(
-                p, h, seg, state=cfg.mamba_d_state, dt_rank=cfg.mamba_dt_rank, chunk=cfg.chunk,
-                compute_dtype=_dt(cfg.compute_dtype), state_dtype=_dt(cfg.state_dtype),
-                gate_dtype=_dt(cfg.gate_dtype))
-    if kind == "gmu":
-        with jax.named_scope("seq.gmu"):
-            return gated_memory(p, h, given["m"], compute_dtype=_dt(cfg.compute_dtype)), {}
-    with jax.named_scope("seq.attn"):
-        if kind == "swa":
-            with jax.named_scope("seq.attn.swa"):
-                return _differential_mixer(cfg, p, h, seg, mesh, schedule, depth,
-                                           window=cfg.sliding_window)
-        return _differential_mixer(cfg, p, h, seg, mesh, schedule, depth,
-                                   given=given if kind == "cross" else None)
-
-
-def window_tiles(cfg: BackboneConfig, length: int) -> Dict[str, int]:
-    """``attn_tiles_skipped_by_window``: the tiles of the blockwise attention
-    loop that the window alone leaves out, over the sliding layers of one
-    forward pass of a row of ``length`` slots; nothing for a backbone without
-    such a layer."""
-    layers = cfg.kinds.count("swa")
-    if not layers:
-        return {}
-    return {"attn_tiles_skipped_by_window":
-            layers * tiles_skipped_by_window(length, cfg.attn_block, cfg.sliding_window)}
-
-
-#: what a layer's recomputation does not make again, by the layer's kind: the
-#: selective scan's output and the states its backward pass starts from
-#: (``ops.selscan``), the state-space scan's likewise where its kernel runs
-#: (``ops.ssd``); every other layer keeps nothing, as before
-_KEPT = {"mamba1": jax.checkpoint_policies.save_only_these_names("selscan"),
-         "ssm": jax.checkpoint_policies.save_only_these_names("ssd")}
-
-
-def ssd_shape(cfg: BackboneConfig, length: int):
-    """What ``ops.ssd.scan_kind`` asks of a Mamba-2 layer over rows of
-    ``length`` slots: heads, head and state widths, length, chunk, the
-    state's and the gates' dtypes."""
-    return (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, length, cfg.chunk,
-            _dt(cfg.state_dtype), _dt(cfg.gate_dtype))

@@ -47,10 +47,7 @@ from ..controller import (
     Preparator,
 )
 from ..obs.trace import span
-from ..ops import selscan
-from ..ops.deltanet import walk_kind
 from ..ops.scoring import top_k_for_vectors
-from ..ops.ssd import scan_kind
 from ..storage import BiMap, EventFilter, get_registry
 from . import seq_backbone as bb
 
@@ -427,23 +424,6 @@ def _programs(cfg: bb.BackboneConfig, learning_rate: float, mesh, schedule: str)
     return jax.jit(opt.init), jax.jit(step, donate_argnums=(0, 1)), loss_and_grad
 
 
-def _delta_rule_walk(cfg: bb.BackboneConfig) -> Dict[str, str]:
-    """Which walk the step's gated-DeltaNet layers run ("pallas" or "scan":
-    ``ops.deltanet.walk_kind``, what the rule itself asks where the step is
-    traced); nothing for a backbone without such layers."""
-    if "linear" not in cfg.kinds:
-        return {}
-    return {"delta_rule_walk": walk_kind(
-        cfg.linear_key_head_dim, cfg.linear_value_head_dim, cfg.chunk)}
-
-
-def _ssd_scan(cfg: bb.BackboneConfig, length: int = 1) -> Dict[str, str]:
-    """What runs the step's Mamba-2 layers' state-space scan ("pallas" or
-    "xla": ``ops.ssd.scan_kind`` at the mixer's widths, chunk and dtypes);
-    nothing for a backbone without such layers."""
-    return {"ssd_scan": scan_kind(*bb.ssd_shape(cfg, length))} if "ssm" in cfg.kinds else {}
-
-
 class SeqRecAlgorithm(Algorithm):
     """Next-item trainer over packed histories (optax AdamW)."""
 
@@ -464,7 +444,7 @@ class SeqRecAlgorithm(Algorithm):
         p = self.params
         cfg = p.backbone_config()
         tags = {"backbone": p.backbone or "toy", "steps": p.steps,
-                "layers": cfg.num_hidden_layers, **_mechanisms(cfg, pd.seq_len),
+                "layers": cfg.num_hidden_layers, **bb.mechanisms(cfg, pd.seq_len),
                 "mixers": " ".join(f"{name}:{n}" for name, n in cfg.mixers().items())}
         # the job's root span: under no server it starts a trace of its own
         with span("train", tags):
@@ -510,7 +490,7 @@ class SeqRecAlgorithm(Algorithm):
         with span("train.wait_device"):
             jax.block_until_ready(model_params)
         stats = {"fill": pd.fill, "steps": p.steps, "tokens_per_step": batch * pd.seq_len,
-                 "mixers": cfg.mixers(), **_mechanisms(cfg, pd.seq_len)}
+                 "mixers": cfg.mixers(), **bb.mechanisms(cfg, pd.seq_len)}
         if counters:
             stats.update(jax.tree_util.tree_map(np.asarray, counters))
         with span("train.fetch"):
@@ -573,19 +553,6 @@ class SeqRecAlgorithm(Algorithm):
         return Query
 
 
-def _mechanisms(cfg: bb.BackboneConfig, length: int) -> Dict[str, str]:
-    """The counters that say which form of a mixer's inner loops a job over
-    rows of ``length`` slots runs: ``delta_rule_walk``, ``ssd_scan``,
-    ``selective_scan`` ("pallas" or "xla": ``ops.selscan.scan_kind``), ``conv``,
-    and ``attn_tiles_skipped_by_window`` (``seq_backbone.window_tiles``), each
-    only where the backbone has such a mixer. (Defined below the
-    trainer: the Pallas kernels' serialized bodies record the source lines of
-    the frames above them, and a line added there misses the compile cache.)"""
-    selective = _selective_scan(cfg, length) if "mamba1" in cfg.kinds else {}
-    return {**_delta_rule_walk(cfg), **_ssd_scan(cfg, length), **selective,
-            **bb.conv_kinds(cfg, length), **bb.window_tiles(cfg, length)}
-
-
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _encode_and_select(cfg: bb.BackboneConfig, k: int, params, tokens, seg):
     hidden, *_ = bb.hidden_states(cfg, params, tokens, seg)
@@ -601,12 +568,3 @@ def engine_factory() -> Engine:
         {"transformer": SeqRecAlgorithm, "": SeqRecAlgorithm},
         {"": FirstServing},
     )
-
-
-def _selective_scan(cfg: bb.BackboneConfig, length: int) -> Dict[str, str]:
-    """What walks the Mamba-1 layers' selective scan over rows of ``length``
-    slots ("pallas" or "xla": ``ops.selscan.scan_kind`` at the mixer's
-    channels, state width and dtypes)."""
-    return {"selective_scan": selscan.scan_kind(
-        cfg.mamba_expand * cfg.hidden_size, cfg.mamba_d_state, length,
-        cfg.state_dtype, cfg.gate_dtype)}

@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .shortconv import conv_chain
+from .shortconv import chain_kind, conv_chain
 
 _HI = jax.lax.Precision.HIGHEST
 _BLOCK = 16  # diagonal blocks solved row by row; the rest by products
@@ -306,6 +306,21 @@ def gated_delta_rule(q, k, v, g, beta, seg, chunk: int = 64,
     return o[:, :length]
 
 
+def _chain(key_heads: int, value_heads: int, key_dim: int, value_dim: int) -> Dict:
+    """:func:`conv_chain`'s arguments: q, k and v, the wide projection's first columns."""
+    return dict(channels=2 * key_heads * key_dim + value_heads * value_dim, silu=True)
+
+
+def forms(shapes: Dict, length: int, *, key_heads: int, value_heads: int, key_dim: int,
+          value_dim: int, chunk: int = 64, **_) -> Dict[str, str]:
+    """``delta_rule_walk`` (:func:`walk_kind`) and ``conv`` ("pallas" or "xla"):
+    what :func:`gated_deltanet` runs over rows of ``length`` slots, ``shapes``
+    its parameters' and the keyword arguments its own."""
+    return {"delta_rule_walk": walk_kind(key_dim, value_dim, chunk),
+            "conv": chain_kind(length, shapes["conv_w"][0],
+                               **_chain(key_heads, value_heads, key_dim, value_dim))}
+
+
 def gated_deltanet(p: Dict, x, seg, *, key_heads: int, value_heads: int, key_dim: int,
                    value_dim: int, eps: float, chunk: int = 64,
                    compute_dtype=jnp.float32, state_dtype=jnp.float32,
@@ -328,7 +343,8 @@ def gated_deltanet(p: Dict, x, seg, *, key_heads: int, value_heads: int, key_dim
     bsz, length, _ = x.shape
     hk, hv, dk, dv = key_heads, value_heads, key_dim, value_dim
     cd, f32 = compute_dtype, jnp.float32
-    n_qkv = 2 * hk * dk + hv * dv
+    chain = _chain(hk, hv, dk, dv)
+    n_qkv = chain["channels"]
     with jax.named_scope("seq.deltanet.proj"):
         qkvz = jnp.dot(x.astype(cd), p["w_qkvz"].astype(cd), preferred_element_type=f32).astype(cd)
         # the gates' own inputs stay float32: alpha feeds an exponential
@@ -338,7 +354,7 @@ def gated_deltanet(p: Dict, x, seg, *, key_heads: int, value_heads: int, key_dim
     def prepare(qkvz, ba, seg, conv_w, a_log, dt_bias):
         rows = qkvz.shape[0]
         with jax.named_scope("seq.deltanet.conv"):
-            qkv = conv_chain(qkvz, conv_w, seg, channels=n_qkv, silu=True)
+            qkv = conv_chain(qkvz, conv_w, seg, **chain)
             q = qkv[..., : hk * dk].reshape(rows, length, hk, dk)
             k = qkv[..., hk * dk: 2 * hk * dk].reshape(rows, length, hk, dk)
             v = qkv[..., 2 * hk * dk:].reshape(rows, length, hv, dv)

@@ -83,7 +83,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .deltanet import _HI
-from .shortconv import conv_chain
+from .shortconv import chain_kind, conv_chain
 
 #: slots of a block: what the backward pass keeps of one is its slots' states,
 #: block x C x N floats (168 MB at 512 x 5,120 x 16) in the XLA form, in HBM;
@@ -421,6 +421,21 @@ def _walk_rows(x, dt, a, b, c, seg, interpret: bool):
     return y.reshape(bsz, length, ch)
 
 
+def _chain(inner: int) -> Dict:
+    """:func:`conv_chain`'s arguments: x~, the wide projection's first ``inner`` columns."""
+    return dict(channels=inner, at=0, silu=True)
+
+
+def forms(shapes: Dict, length: int, *, state: int, state_dtype=jnp.float32,
+          gate_dtype=jnp.float32, **_) -> Dict[str, str]:
+    """``selective_scan`` (:func:`scan_kind`) and ``conv`` ("pallas" or "xla"):
+    what :func:`mamba1` runs over rows of ``length`` slots, ``shapes`` its
+    parameters' and the keyword arguments its own."""
+    inner = shapes["w_out"][0]
+    return {"selective_scan": scan_kind(inner, state, length, state_dtype, gate_dtype),
+            "conv": chain_kind(length, shapes["conv_w"][0], **_chain(inner))}
+
+
 def mamba1(p: Dict, x, seg, *, state: int, dt_rank: int, chunk: int = 64,
            compute_dtype=jnp.float32, state_dtype=jnp.float32,
            gate_dtype=jnp.float32) -> Tuple[jax.Array, Dict]:
@@ -441,8 +456,7 @@ def mamba1(p: Dict, x, seg, *, state: int, dt_rank: int, chunk: int = 64,
         # the wide projection is kept in the compute dtype, as in the other mixers
         xz = jnp.dot(x.astype(cd), p["w_in"].astype(cd), preferred_element_type=f32).astype(cd)
     with jax.named_scope("seq.mamba.conv"):
-        c = conv_chain(xz, p["conv_w"], seg, channels=inner, at=0, bias=p["conv_b"],
-                       silu=True).astype(cd)
+        c = conv_chain(xz, p["conv_w"], seg, bias=p["conv_b"], **_chain(inner)).astype(cd)
     with jax.named_scope("seq.mamba.proj"):
         dbc = jnp.dot(c, p["w_x"].astype(cd), preferred_element_type=f32)
         b, cc = (dbc[..., dt_rank + i * state: dt_rank + (i + 1) * state] for i in (0, 1))

@@ -70,11 +70,11 @@ class _Chain(NamedTuple):
     convolution's input and the two gates start (``None``: no such gate),
     how many channels each has, whether a SiLU follows the taps."""
     channels: int
-    at: int
-    gate_in: Optional[int]
-    gate_out: Optional[int]
-    silu: bool
-    interpret: bool
+    at: int = 0
+    gate_in: Optional[int] = None
+    gate_out: Optional[int] = None
+    silu: bool = False
+    interpret: bool = False
 
     @property
     def parts(self) -> Tuple[int, ...]:
@@ -101,6 +101,14 @@ def conv_kind(channels: int, length: int, dtype, offsets=(0,), interpret: bool =
     return "pallas" if tiles and f32 and (interpret or jax.default_backend() == "tpu") else "xla"
 
 
+def chain_kind(length: int, taps: int, *, dtype=jnp.float32, **chain) -> str:
+    """:func:`conv_kind` of what :func:`conv_chain` runs with the keyword
+    arguments ``chain`` (and ``dtype``) and ``taps`` taps over rows of ``length``
+    slots: what the chain asks itself, and what a mixer's ``forms`` asks for it."""
+    spec = _Chain(**chain)
+    return conv_kind(spec.channels, length, dtype, spec.parts, spec.interpret, taps)
+
+
 def conv_chain(src, w, seg, *, channels: int, at: int = 0, bias=None,
                gate_in: Optional[int] = None, gate_out: Optional[int] = None,
                silu: bool = False, dtype=jnp.float32, interpret: bool = False):
@@ -110,7 +118,7 @@ def conv_chain(src, w, seg, *, channels: int, at: int = 0, bias=None,
     left out, as are a ``bias`` [channels] that is ``None`` and the SiLU);
     w [K, channels], seg [B, L]. One kernel or XLA's chain: :func:`conv_kind`."""
     spec = _Chain(channels, at, gate_in, gate_out, silu, interpret)
-    if conv_kind(channels, src.shape[1], dtype, spec.parts, interpret, w.shape[0]) == "pallas":
+    if chain_kind(src.shape[1], w.shape[0], dtype=dtype, **spec._asdict()) == "pallas":
         return _chain(spec, src, w.astype(dtype), bias, seg)
 
     def part(start):
@@ -425,18 +433,30 @@ def _chain_bwd(spec, kept, dy):
 _chain.defvjp(_chain_fwd, _chain_bwd)
 
 
+def _gated(width: int, gate_dtype) -> Dict:
+    """:func:`conv_chain`'s arguments for [B | C | x~], ``width`` columns."""
+    d = width // 3
+    return dict(channels=d, at=2 * d, gate_in=0, gate_out=d, dtype=gate_dtype)
+
+
 def gated_conv(bcx, conv_w, seg, gate_dtype=jnp.float32, interpret: bool = False):
     """``C * conv(B * x~)`` of ``bcx`` = [B | C | x~] [rows, L, 3 D], in
     ``gate_dtype``; recomputed in the backward pass from ``bcx`` alone."""
-    d = bcx.shape[-1] // 3
-
     @jax.checkpoint
     def chain(bcx, conv_w):
         with jax.named_scope("seq.shortconv.conv"):
-            return conv_chain(bcx, conv_w, seg, channels=d, at=2 * d, gate_in=0, gate_out=d,
-                              dtype=gate_dtype, interpret=interpret)
+            return conv_chain(bcx, conv_w, seg, interpret=interpret,
+                              **_gated(bcx.shape[-1], gate_dtype))
 
     return chain(bcx, conv_w)
+
+
+def forms(shapes: Dict, length: int, *, gate_dtype=jnp.float32, **_) -> Dict[str, str]:
+    """``conv``: what runs the chain of :func:`short_conv` ("pallas" or "xla")
+    over rows of ``length`` slots, ``shapes`` its parameters' and the keyword
+    arguments its own."""
+    return {"conv": chain_kind(length, shapes["conv_w"][0],
+                               **_gated(shapes["w_in"][1], gate_dtype))}
 
 
 def short_conv(p: Dict, x, seg, *, compute_dtype=jnp.float32,

@@ -94,7 +94,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .deltanet import _HI, _with_state
-from .shortconv import conv_chain
+from .shortconv import chain_kind, conv_chain
 
 _LANES = 128
 _HEADS = 8  # heads of a grid step of the kernel (their slots' work is unrolled in its body)
@@ -540,6 +540,22 @@ def _walk_bwd(static, saved, dy):
 _walk.defvjp(_walk_fwd, _walk_bwd)
 
 
+def _chain(width: int, inner: int) -> Dict:
+    """:func:`conv_chain`'s arguments: x, B and C, what of the wide projection's
+    ``width`` columns follows z's ``inner``."""
+    return dict(channels=width - inner, at=inner, silu=True)
+
+
+def forms(shapes: Dict, length: int, *, heads: int, head_dim: int, state: int, chunk: int = 256,
+          state_dtype=jnp.float32, gate_dtype=jnp.float32, **_) -> Dict[str, str]:
+    """``ssd_scan`` (:func:`scan_kind`) and ``conv`` ("pallas" or "xla"): what
+    :func:`mamba2` runs over rows of ``length`` slots, ``shapes`` its
+    parameters' and the keyword arguments its own."""
+    return {"ssd_scan": scan_kind(heads, head_dim, state, length, chunk, state_dtype, gate_dtype),
+            "conv": chain_kind(length, shapes["conv_w"][0],
+                               **_chain(shapes["w_in"][1], heads * head_dim))}
+
+
 def mamba2(p: Dict, x, seg, *, heads: int, head_dim: int, state: int, eps: float,
            chunk: int = 256, compute_dtype=jnp.float32, state_dtype=jnp.float32,
            gate_dtype=jnp.float32) -> Tuple[jax.Array, Dict]:
@@ -562,8 +578,8 @@ def mamba2(p: Dict, x, seg, *, heads: int, head_dim: int, state: int, eps: float
         zxbc = jnp.dot(x.astype(cd), p["w_in"].astype(cd), preferred_element_type=f32).astype(cd)
         dt_raw = jnp.dot(x.astype(f32), p["w_dt"], precision=_HI)
     with jax.named_scope("seq.ssm.conv"):
-        xbc = conv_chain(zxbc, p["conv_w"], seg, channels=zxbc.shape[-1] - inner, at=inner,
-                         bias=p["conv_b"], silu=True)
+        xbc = conv_chain(zxbc, p["conv_w"], seg, bias=p["conv_b"],
+                         **_chain(zxbc.shape[-1], inner))
         u = xbc[..., :inner].reshape(bsz, length, heads, head_dim).astype(cd)
         b, c = (xbc[..., inner + i * state: inner + (i + 1) * state].astype(cd) for i in (0, 1))
         dt = jax.nn.softplus(dt_raw + p["dt_bias"])

@@ -661,7 +661,7 @@ def test_qwen3next_step_two_rows_of_8k_fits_the_chip(one_chip, as_tpu):
     finally:
         step.clear_cache()  # the job's own program object, kept by ``_programs``
     _report("qwen3next step", compiled)
-    assert sequencerec._mechanisms(cfg, SEQ_L) == {"delta_rule_walk": "pallas", "conv": "pallas"}
+    assert bb.mechanisms(cfg, SEQ_L) == {"delta_rule_walk": "pallas", "conv": "pallas"}
     # the period's three DeltaNet layers stacked in one scan, a row at a time
     assert _conv_under(compiled, "seq.deltanet.conv", "qwen3next") >= 3
 
@@ -720,7 +720,7 @@ def test_lfm2_step_two_rows_of_8k_fits_the_chip(one_chip, as_tpu):
     stats = _report("lfm2 step", compiled)
     assert stats.argument_size_in_bytes + stats.temp_size_in_bytes < 15 * 2**30
     assert cfg.mixers() == {"gqa": 1, "shortconv": 4}
-    assert sequencerec._mechanisms(cfg, SEQ_L) == {"conv": "pallas"}
+    assert bb.mechanisms(cfg, SEQ_L) == {"conv": "pallas"}
     assert _conv_under(compiled, "seq.shortconv.conv", "lfm2") >= 3
 
 
@@ -825,7 +825,7 @@ def test_granite4h_step_one_row_of_8k_fits_the_chip(one_chip, as_tpu):
     stats = _report("granite4h step", compiled)
     assert stats.argument_size_in_bytes + stats.temp_size_in_bytes <= 15.25 * 2**30
     assert cfg.mixers() == {"gqa": 1, "mamba2": 9}
-    assert sequencerec._mechanisms(cfg, SEQ_L) == {"ssd_scan": "pallas", "conv": "pallas"}
+    assert bb.mechanisms(cfg, SEQ_L) == {"ssd_scan": "pallas", "conv": "pallas"}
     assert _conv_under(compiled, "seq.ssm.conv") >= 3
     scan = [line for line in compiled.as_text().splitlines()
             if 'custom_call_target="tpu_custom_call"' in line and "seq.ssm.scan" in line]
@@ -910,7 +910,7 @@ def test_phi4flash_step_one_row_of_8k_fits_the_chip(one_chip, as_tpu):
     stats = _report("phi4flash step", compiled)
     assert stats.argument_size_in_bytes + stats.temp_size_in_bytes <= 15.75 * 2**30
     assert cfg.mixers() == {"cross": 1, "gmu": 1, "gqa": 1, "mamba1": 2, "swa": 1}
-    assert sequencerec._mechanisms(cfg, SEQ_L) == {
+    assert bb.mechanisms(cfg, SEQ_L) == {
         "selective_scan": "pallas", "conv": "pallas", "attn_tiles_skipped_by_window": 105}
     # both Mamba-1 layers run the shared convolution kernel: forward, the
     # layer's recomputation, backward
@@ -918,7 +918,7 @@ def test_phi4flash_step_one_row_of_8k_fits_the_chip(one_chip, as_tpu):
     text = compiled.as_text()
     # and the scan's kernel pair: forward and backward, and NO forward call in
     # the layer's recomputation (the layer keeps y and the states entering the
-    # grid steps: ``seq_backbone._KEPT``). Temporaries 6.16 GB with what is
+    # grid steps: ``kept`` in the table ``seq_backbone._MIXERS``). Temporaries 6.16 GB with what is
     # kept (5.74 GB without), under the 6.50 GB of XLA's loops (PERF.md section 4)
     scans = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line and "seq.mamba.scan" in line]
@@ -972,5 +972,5 @@ def test_joyai_step_has_no_short_convolution(one_chip, as_tpu):
     finally:
         step.clear_cache()
     assert "tpu_custom_call" in text and not re.search(r"seq\.\w+\.conv", text)
-    assert sequencerec._mechanisms(cfg, SEQ_L) == {}
+    assert bb.mechanisms(cfg, SEQ_L) == {}
 

@@ -869,3 +869,107 @@ def test_rows_past_the_last_group_do_not_reach_a_token(monkeypatch, untraced_pas
     assert planted
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(clean)):
         assert np.isfinite(np.asarray(a)).all() and rel(a, b) < 1e-5
+
+
+# -- the table of mixers ------------------------------------------------------
+#: a configuration in which every kind of mixer can stand as the third layer
+#: of one period: a Mamba-1 layer and a full differential layer below it hand
+#: on what the reading kinds read
+EVERY = {
+    "hidden_size": 64, "num_hidden_layers": 3, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "head_dim": 16, "sliding_window": 16, "intermediate_size": 96,
+    "linear_num_key_heads": 2, "linear_num_value_heads": 4, "linear_key_head_dim": 16,
+    "linear_value_head_dim": 16, "conv_L_cache": 3,
+    "mamba_n_heads": 4, "mamba_d_head": 32, "mamba_n_groups": 1, "mamba_d_state": 4,
+    "mamba_d_conv": 4, "mamba_expand": 2, "mamba_dt_rank": 4,
+    "backbone": {"differential": True, "norm": "layer", "positions": "none", "ffn": "swiglu",
+                 "chunk": 8, "attn_block": 16, "loss_block": 32},
+}
+
+
+@pytest.mark.parametrize("kind", sorted(bb._MIXERS))
+def test_every_record_of_the_table_is_complete_and_its_words_are_accepted(kind):
+    record = bb._MIXERS[kind]
+    assert record.words and record.scope and all(s.startswith("seq.") for s in record.scope)
+    assert record.run.__module__ == bb.__name__  # a function of the module: it finds its op there
+    if record.reads:  # some kind hands on what it reads
+        assert any(set(record.reads) <= set(other.hands) for other in bb._MIXERS.values())
+    for word in record.words:
+        cfg = bb.BackboneConfig.from_dict(
+            {**EVERY, "layer_types": ["mamba1", "full_attention", word]})
+        assert cfg.kinds[-1] == kind and cfg.stacked(kind) in (0, 1, 2)
+        assert cfg.mixers()[record.name or cfg.attention] >= 1
+        assert all(bb._is_spec(spec) for spec in record.shapes(cfg).values()) and record.shapes(cfg)
+        assert isinstance(record.forms(cfg, L), dict) and isinstance(record.widths(cfg), dict)
+        # and the layer runs with the parameters its record names (traced, not compiled)
+        params = jax.eval_shape(lambda: bb.init_params(cfg, VOCAB, L, 0))
+        rows = jax.ShapeDtypeStruct((2, L), jnp.int32)
+        hidden, _, ran = jax.eval_shape(
+            lambda p, t, s: bb.hidden_states(cfg, p, t, s), params, rows, rows)
+        assert hidden.shape == (2, L, 64) and set(record.hands) <= set(ran)
+
+
+def test_a_word_the_table_does_not_know_is_refused():
+    with pytest.raises(ValueError, match=r"layer_types names mixers unknown here: \['window'\]"):
+        bb.BackboneConfig.from_dict({**EVERY, "layer_types": ["mamba1", "full_attention", "window"]})
+
+
+def _pallas_calls(jaxpr, above=""):
+    """The name stacks of the ``pallas_call`` equations of a jaxpr and of the
+    jaxprs its equations hold (a scan's body, a checkpoint's, a custom VJP's
+    primal function), each under the stack of the equation that holds it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        stack = f"{above}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "pallas_call":
+            found.append(stack)
+        for value in eqn.params.values():
+            for held in (value if isinstance(value, (tuple, list)) else (value,)):
+                held = getattr(held, "jaxpr", held)  # a closed jaxpr's
+                if hasattr(held, "eqns"):
+                    found += _pallas_calls(held, stack)
+    return found
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Code that asks for the backend is told "tpu"; the delta rule, whose jit
+    keeps a trace made under one answer, is traced anew on both sides."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    deltanet.gated_delta_rule.clear_cache()
+    yield
+    deltanet.gated_delta_rule.clear_cache()
+
+
+@pytest.mark.parametrize("name,rows", [
+    ("qwen3next-80b-a3b-ep16", 2), ("joyai-flash-48b-a3b-ep16", 2), ("lfm2-24b-a2b-ep8", 2),
+    ("granite4h-micro-vp8", 1), ("phi4-mini-flash-vp8", 1)])
+def test_the_counters_say_pallas_exactly_where_the_step_holds_a_kernel(as_tpu, name, rows):
+    """On a TPU, over the cells' rows of 8,192 slots: the forward pass traced
+    on abstract arguments holds a ``pallas_call`` under a mixer's ``.conv`` or
+    ``.scan`` scope exactly where that mixer's ``forms`` (what ``mechanisms``
+    merges into the counters the benchmark prints) say ``pallas``, and none
+    where they say ``xla`` or ``scan``; as shipped, and in the cells' control
+    build (bfloat16 state and gates), which asks most kernels for the other form."""
+    import dataclasses
+
+    shipped = bb.BackboneConfig.load(name)
+    seen = set()
+    for cfg in (shipped, dataclasses.replace(shipped, state_dtype="bfloat16", gate_dtype="bfloat16")):
+        params = jax.eval_shape(lambda: bb.init_params(cfg, 1024, 8192, 0))
+        tokens = jax.ShapeDtypeStruct((rows, 8192), jnp.int32)
+        jaxpr = jax.make_jaxpr(lambda p, t, s: bb.hidden_states(cfg, p, t, s)[0])(
+            params, tokens, tokens)
+        kernels = _pallas_calls(jaxpr.jaxpr)
+        merged = bb.mechanisms(cfg, 8192)
+        for kind in set(cfg.kinds):
+            record = bb._MIXERS[kind]
+            for counter, form in record.forms(cfg, 8192).items():
+                if not isinstance(form, str):
+                    continue  # a count of tiles, no form
+                scope = f"{record.scope[0]}.{'conv' if counter == 'conv' else 'scan'}"
+                assert any(scope in stack for stack in kernels) == (form == "pallas"), (counter, form)
+                assert form in merged[counter].split("+")
+                seen.add(form)
+    # every backbone with a counter of a form met a kernel (JoyAI has none)
+    assert ("pallas" in seen) == any(isinstance(v, str) for v in bb.mechanisms(shipped, 8192).values())

@@ -387,9 +387,9 @@ def test_a_backbone_without_a_router_steps_and_counts_nothing_of_one(cfg, params
     assert bb.step_routers(dataclasses.replace(cfg, router_trains=False, router_bias=True),
                            params, stepped, {}) is stepped
     assert sequencerec._pass_counts({"fill": 1.0}) == {}
-    assert sequencerec._ssd_scan(cfg) == {"ssd_scan": "xla"}
-    assert sequencerec._delta_rule_walk(cfg) == {}
-    assert sequencerec._ssd_scan(bb.BackboneConfig.load("lfm2-tiny")) == {}
+    assert bb.mechanisms(cfg, 1)["ssd_scan"] == "xla"
+    assert "delta_rule_walk" not in bb.mechanisms(cfg, 1)
+    assert "ssd_scan" not in bb.mechanisms(bb.BackboneConfig.load("lfm2-tiny"), 1)
 
 
 # -- the normal path --------------------------------------------------------

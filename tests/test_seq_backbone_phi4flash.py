@@ -487,14 +487,14 @@ def test_configurations_the_backbone_cannot_run_are_refused_with_a_message(bad, 
 
 
 def test_the_job_counts_its_mixers_its_scan_and_the_tiles_the_window_skipped(cfg):
-    assert sequencerec._mechanisms(cfg, L) == {
+    assert bb.mechanisms(cfg, L) == {
         "selective_scan": "xla", "conv": "xla", "attn_tiles_skipped_by_window": 10 - 7}
     # a backbone without a sliding layer counts no such tiles, one without Mamba-1 no such scan
     for name in ("granite4h-tiny", "lfm2-tiny", "qwen3next-tiny", "joyai-flash-tiny"):
-        found = sequencerec._mechanisms(bb.BackboneConfig.load(name), L)
+        found = bb.mechanisms(bb.BackboneConfig.load(name), L)
         assert "attn_tiles_skipped_by_window" not in found and "selective_scan" not in found
     shipped = bb.BackboneConfig.load("phi4-mini-flash-vp8")
-    assert bb.window_tiles(shipped, 8192) == {"attn_tiles_skipped_by_window": 105}
+    assert bb._MIXERS["swa"].forms(shipped, 8192) == {"attn_tiles_skipped_by_window": 105}
 
 
 def test_pio_train_and_predict_with_the_backbone_configuration():

@@ -1,7 +1,9 @@
-"""The four listed backbones of the sequence template, pinned: what their
+"""The five listed backbones of the sequence template, pinned: what their
 tiny configurations compute on a fixed seeded batch must stay what the commit
 before the next backbone computed (``granite4h-tiny``: commit 32bdee9, the
-parent of the fifth backbone's PR)."""
+parent of the fifth backbone's PR), or, for the fifth, the commit before the
+backbone's mixers became one table (``phi4-mini-flash-tiny``: commit d0ce42b,
+PR 43)."""
 
 import json
 
@@ -49,7 +51,13 @@ PINNED = json.loads("""
  "granite4h-tiny": {"loss": 3.9133141040802, "grad_norm": {"embed": 0.4779150043923512,
   "final_norm.w": 0.0017570282807558048, "periods.ffn": 0.04354429097027376,
   "periods.full": 0.005497379137975552, "periods.norm_in": 0.009530143908606488,
-  "periods.norm_post": 0.007683603375504363, "periods.ssm": 0.06981819830080853}}}
+  "periods.norm_post": 0.007683603375504363, "periods.ssm": 0.06981819830080853}},
+ "phi4-mini-flash-tiny": {"loss": 3.934260606765747, "grad_norm": {"embed": 3.74777341084151,
+  "final_norm.b": 0.012061838697027814, "final_norm.g": 0.01484294619753881,
+  "periods.cross": 0.006096933379104272, "periods.ffn": 0.36072661833735686,
+  "periods.full": 0.018284754473628158, "periods.gmu": 0.014815461261404308,
+  "periods.mamba1": 0.5202352792829213, "periods.norm_in": 0.10984904188512179,
+  "periods.norm_post": 0.08390504050254491, "periods.swa": 0.04723355729407075}}}
 """)
 
 
@@ -60,8 +68,8 @@ def test_a_listed_configuration_computes_what_it_did_before_this_backbone(name, 
     0, as commit fb37b61 (PR 37, before ``ssm`` layers, the multipliers and
     ``positions: none``) computed the first three on the CPU and commit 32bdee9
     (PR 40, before ``mamba1``, ``gmu``, windows and cross-attention) the
-    fourth. A change
-    to ``_attention_mixer``, ``_layer``, ``logits_of``, ``swiglu`` or the
+    fourth, and commit d0ce42b (PR 43, before the table of mixers) the fifth. A
+    change to a mixer of ``seq_backbone``, ``_layer``, ``logits_of``, ``swiglu`` or the
     loss that moves a listed configuration fails here and not on the
     driver's chip. 1e-4: float32 sums in another order read 1e-6; a
     multiplier applied where it is 1 by default moves every number."""

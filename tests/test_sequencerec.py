@@ -275,12 +275,13 @@ def test_the_counters_that_say_which_form_of_a_mixer_runs(monkeypatch, backbone,
     import jax
 
     from predictionio_tpu.models import seq_backbone as bb
-    from predictionio_tpu.models import sequencerec
 
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     cfg = bb.BackboneConfig.load(backbone)
-    assert sequencerec._mechanisms(cfg, 8192) == want
-    assert bb.conv_kinds(cfg, 8192) == {k: v for k, v in want.items() if k == "conv"}
+    assert bb.mechanisms(cfg, 8192) == want
+    # and every convolving mixer's own record says the form ``conv`` joins
+    convs = {bb._MIXERS[kind].forms(cfg, 8192).get("conv") for kind in set(cfg.kinds)} - {None}
+    assert "+".join(sorted(convs)) == want.get("conv", "")
 
 
 def test_a_row_that_is_no_whole_halo_blocks_runs_the_xla_form(monkeypatch):
@@ -289,12 +290,19 @@ def test_a_row_that_is_no_whole_halo_blocks_runs_the_xla_form(monkeypatch):
     from predictionio_tpu.models import seq_backbone as bb
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    from predictionio_tpu.ops import shortconv
+
     cfg = bb.BackboneConfig.load("lfm2-24b-a2b-ep8")
-    assert bb.conv_kinds(cfg, 8192) == {"conv": "pallas"}
-    assert bb.conv_kinds(cfg, 8200) == {"conv": "xla"}
+    assert bb.mechanisms(cfg, 8192) == {"conv": "pallas"}
+    assert bb.mechanisms(cfg, 8200) == {"conv": "xla"}
     import dataclasses
 
-    assert bb.conv_kinds(dataclasses.replace(cfg, gate_dtype="bfloat16"), 8192) == {"conv": "xla"}
+    assert bb.mechanisms(dataclasses.replace(cfg, gate_dtype="bfloat16"), 8192) == {"conv": "xla"}
+    # the op's own answer, from its parameters' shapes and its keyword arguments
+    shapes = {"w_in": (2048, 6144), "conv_w": (3, 2048), "w_out": (2048, 2048)}
+    assert shortconv.forms(shapes, 8192, gate_dtype="float32") == {"conv": "pallas"}
+    assert shortconv.forms(shapes, 8200, gate_dtype="float32") == {"conv": "xla"}
+    assert shortconv.forms(shapes, 8192, gate_dtype="bfloat16") == {"conv": "xla"}
 
 
 @pytest.mark.parametrize("change,length,want", [
@@ -309,8 +317,7 @@ def test_the_selective_scan_counter_follows_the_dtypes_and_the_row(monkeypatch, 
     import jax
 
     from predictionio_tpu.models import seq_backbone as bb
-    from predictionio_tpu.models import sequencerec
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = dataclasses.replace(bb.BackboneConfig.load("phi4-mini-flash-vp8"), **change)
-    assert sequencerec._mechanisms(cfg, length)["selective_scan"] == want
+    assert bb.mechanisms(cfg, length)["selective_scan"] == want

@@ -15,7 +15,6 @@ import numpy as np
 import pytest
 
 from predictionio_tpu.models import seq_backbone as bb
-from predictionio_tpu.models import sequencerec
 from predictionio_tpu.ops import shortconv as sc
 from predictionio_tpu.ops import ssd
 from predictionio_tpu.testing import granite4h_reference as ref
@@ -238,10 +237,13 @@ def test_the_control_builds_configuration_asks_for_the_xla_form(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = bb.BackboneConfig.load("granite4h-micro-vp8")
     control = dataclasses.replace(cfg, state_dtype="bfloat16", gate_dtype="bfloat16")
-    assert ssd.scan_kind(*bb.ssd_shape(cfg, 8192)) == "pallas"
-    assert ssd.scan_kind(*bb.ssd_shape(control, 8192)) == "xla"
-    assert sequencerec._ssd_scan(cfg, 8192) == {"ssd_scan": "pallas"}
-    assert sequencerec._ssd_scan(control, 8192) == {"ssd_scan": "xla"}
+    shapes = {"w_in": (2048, 8448), "conv_w": (4, 4352)}
+    widths = dict(heads=64, head_dim=64, state=128, chunk=256)
+    assert ssd.forms(shapes, 8192, **widths)["ssd_scan"] == "pallas"
+    assert ssd.forms(shapes, 8192, **widths, state_dtype="bfloat16",
+                     gate_dtype="bfloat16")["ssd_scan"] == "xla"
+    assert bb.mechanisms(cfg, 8192)["ssd_scan"] == "pallas"
+    assert bb.mechanisms(control, 8192)["ssd_scan"] == "xla"
 
 
 def test_the_mixer_on_a_tpu_runs_the_kernel_and_gives_what_the_xla_form_gives(monkeypatch):
