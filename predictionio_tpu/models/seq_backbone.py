@@ -53,8 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import deltanet, selscan, shortconv, ssd
-from ..ops.attention import attention, tiles_skipped_by_window
+from ..ops import deltanet, dsa, selscan, shortconv, ssd
+from ..ops.attention import attention, chosen_attention, tiles_skipped_by_window
 from ..ops.deltanet import gated_deltanet
 from ..ops.moe import expert_layer, swiglu
 from ..ops.selscan import gated_memory, mamba1
@@ -100,6 +100,20 @@ class BackboneConfig:
     sliding_window: int = 0
     #: the depth of this file's first layer in the published model
     layer_index_offset: int = 0
+    #: learned sparse attention (``layer_types`` ``sparse_attention``; a public
+    #: file's ``sa_config``): a lightning indexer of ``index_n_heads`` heads of
+    #: ``index_head_dim`` on ONE index key a slot scores every (query, key)
+    #: pair, and a query's grouped heads read the ``index_topk`` causal keys of
+    #: its history with the largest scores (``ops.dsa``). The indexer reads the
+    #: layer's normed input behind a ``stop_gradient`` and learns from its own
+    #: loss alone, the KL of the main heads' attention over the chosen keys
+    #: from the scores' softmax over them, summed over the layers with weight
+    #: 1; rotary on the first half of its head; its head-weighted sum in
+    #: ``index_dtype``. 0 = not given.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_dtype: str = "float32"
     #: the single device's attention kernel: "xla" (the blockwise loop,
     #: which skips the tiles between histories) or "splash" (JAX's Pallas
     #: kernel where it can run: ``ops.attention.attention``)
@@ -243,7 +257,8 @@ class BackboneConfig:
     def from_dict(cls, d: Dict) -> "BackboneConfig":
         names = {f.name for f in dataclasses.fields(cls)}
         merged = {**d, **d.get("backbone", {})}
-        values = {k: v for k, v in merged.items() if k in names}
+        # (a public file's null says nothing: the default stands)
+        values = {k: v for k, v in merged.items() if k in names and v is not None}
         for name in ("experts_held", "layer_types"):
             if name in values:
                 values[name] = tuple(values[name])
@@ -263,6 +278,13 @@ class BackboneConfig:
             values["intermediate_size"] = merged["shared_intermediate_size"]
         if "rope_theta" in merged.get("rope_parameters", {}):
             values.setdefault("rope_theta", merged["rope_parameters"]["rope_theta"])
+        for theirs, ours in (("indexer_num_heads", "index_n_heads"),
+                             ("indexer_head_dim", "index_head_dim"), ("topk", "index_topk")):
+            if theirs in merged.get("sa_config", {}):
+                values.setdefault(ours, merged["sa_config"][theirs])
+        if "sa_config" in merged:  # every layer of such a file is a sparse-attention layer
+            values.setdefault("layer_types",
+                              ("sparse_attention",) * values.get("num_hidden_layers", 0))
         if "n_shared_experts" in merged:
             values.setdefault(
                 "shared_expert_intermediate_size",
@@ -476,12 +498,16 @@ def _unit(x, eps: float):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
 
 
+def _layer_norm(x, g, b, eps: float):
+    mu = x.mean(-1, keepdims=True)
+    var = ((x - mu) ** 2).mean(-1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * g + b
+
+
 def _norm(cfg: BackboneConfig, p: Dict, x):
     if cfg.norm == "rms":
         return _unit(x, cfg.rms_norm_eps) * (1.0 + p["w"])
-    mu = x.mean(-1, keepdims=True)
-    var = ((x - mu) ** 2).mean(-1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + cfg.layer_norm_eps) * p["g"] + p["b"]
+    return _layer_norm(x, p["g"], p["b"], cfg.layer_norm_eps)
 
 
 def _dot(cfg: BackboneConfig, x, w):
@@ -724,7 +750,11 @@ def _latent_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, given, depth, mesh,
     return out + _dot(cfg, v_mean.reshape(b, 1, h * dv), p["w_o"]), {"q": q, "k": k, "v": v, "o": o}
 
 
-def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, given, depth, mesh, schedule):
+def _grouped_heads(cfg: BackboneConfig, p: Dict, x, pos):
+    """The grouped heads of a layer's normed input ``x``: q [B, L, H, hd], k, v
+    [B, L, Hkv, hd] float32 (projected, normed a head where the layer has the
+    norms, turned by position; :func:`_for_the_core` makes them the core's), and
+    what ``w_q`` gave [B, L, .], whose second half is the gate where there is one."""
     b, l, _ = x.shape
     h, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     cd, f32 = _dt(cfg.compute_dtype), jnp.float32
@@ -743,16 +773,110 @@ def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, given, depth, me
     if cfg.attention_multiplier is not None:
         # the core scales by 1 / sqrt(hd): q carries the rest
         q = q * (cfg.attention_multiplier * hd ** 0.5)
+    return q, k, v, qg
+
+
+def _for_the_core(cfg: BackboneConfig, *heads):
+    """[B, L, H, hd] -> [B, H, L, hd] in the compute dtype (inside the core's
+    scope: the cast and the transpose are seconds of the core's)."""
+    return tuple(t.astype(_dt(cfg.compute_dtype)).transpose(0, 2, 1, 3) for t in heads)
+
+
+def _attention_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, given, depth, mesh, schedule):
+    b, l, _ = x.shape
+    h, hd = cfg.num_attention_heads, cfg.head_dim
+    q, k, v, qg = _grouped_heads(cfg, p, x, pos)
     with jax.named_scope("seq.attn.core"):
-        o = attention(
-            q.astype(cd).transpose(0, 2, 1, 3), k.astype(cd).transpose(0, 2, 1, 3),
-            v.astype(cd).transpose(0, 2, 1, 3), mesh=mesh, causal=True,
-            schedule=schedule, segment_ids=seg, block=cfg.attn_block,
-        )
-    o = o.transpose(0, 2, 1, 3).reshape(b, l, h * hd).astype(f32)
+        o = attention(*_for_the_core(cfg, q, k, v), mesh=mesh, causal=True, schedule=schedule,
+                      segment_ids=seg, block=cfg.attn_block)
+    o = o.transpose(0, 2, 1, 3).reshape(b, l, h * hd).astype(jnp.float32)
     if cfg.attn_gate:
-        o = o * jax.nn.sigmoid(qg[..., h * hd:].astype(f32))
+        o = o * jax.nn.sigmoid(qg[..., h * hd:].astype(jnp.float32))
     return _dot(cfg, o, p["w_o"]), {}
+
+
+def _sparse_shapes(cfg: BackboneConfig) -> Dict:
+    d, j, di = cfg.hidden_size, cfg.index_n_heads, cfg.index_head_dim
+    return {**_attention_shapes(cfg),  # and the indexer's: three projections, its key's LayerNorm
+            "w_iq": ((d, j * di), "w"), "w_ik": ((d, di), "w"), "w_iw": ((d, j), "w"),
+            "ik_g": ((di,), "one"), "ik_b": ((di,), "zero")}
+
+
+def _sparse_widths(cfg: BackboneConfig) -> Dict:
+    return dict(topk=cfg.index_topk, block=cfg.attn_block, sum_dtype=_dt(cfg.index_dtype))
+
+
+def _index_heads(cfg: BackboneConfig, p: Dict, x, pos):
+    """The lightning indexer's inputs from a layer's normed input ``x``: J
+    query heads iq [B, L, J, d] and ONE key a slot ik [B, L, d] (a LayerNorm
+    over it), both turned by position on the first half of the head (the
+    DeepSeek-V3.2 report's proportion), in the compute dtype; and the heads' weights iw [B, L, J]
+    float32, ``J ** -0.5 * d ** -0.5`` folded in."""
+    b, l, _ = x.shape
+    j, di = cfg.index_n_heads, cfg.index_head_dim
+    cd = _dt(cfg.compute_dtype)
+    rot = di // 2
+    iq = _dot(cfg, x, p["w_iq"]).reshape(b, l, j, di)
+    ik = _layer_norm(_dot(cfg, x, p["w_ik"]), p["ik_g"], p["ik_b"], cfg.layer_norm_eps)
+    iq = _rope(iq, pos, rot, cfg.rope_theta)
+    ik = _rope(ik[:, :, None, :], pos, rot, cfg.rope_theta)[:, :, 0]
+    iw = _dot(cfg, x, p["w_iw"]) * (j ** -0.5 * di ** -0.5)
+    return iq.astype(cd), ik.astype(cd), iw
+
+
+def _sparse_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, given, depth, mesh, schedule):
+    """Grouped-query attention that reads only the keys a learned indexer
+    picks (``ops.dsa``). The indexer sees the layer's normed input behind a
+    ``stop_gradient``; the choice has no gradient; the indexer's loss holds the
+    main heads' weights constant. Also returns, beside the layer's counters
+    (``index_loss``, the mean KL of the real slots, and the ``kept_pairs`` among
+    their ``causal_pairs`` inside histories), what a check of the choice
+    needs: the indexer's inputs ``iq``, ``ik``, ``iw``, one strip of scores as
+    the choice saw them (``index``, strip ``index_at``), the chosen mask packed
+    8 keys a byte (``chosen`` [B, L, ceil(L / 8)] uint8, key ``8 w + bit`` at
+    bit ``bit``), and the first key head's group as the core was handed it and what
+    it gave: ``q``, ``o`` [B, G, L, hd], ``k``, ``v`` [B, 1, L, hd]."""
+    if mesh is not None:
+        raise ValueError("sparse attention runs on a single device: no sharded schedule "
+                         "takes a mask that the step computes")
+    b, l, _ = x.shape
+    h, hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    q, k, v, _ = _grouped_heads(cfg, p, x, pos)
+    widths = _sparse_widths(cfg)
+    topk = widths.pop("topk")
+    with jax.named_scope("seq.attn.index"):
+        iq, ik, iw = _index_heads(cfg, p, jax.lax.stop_gradient(x), pos)
+    # the scopes of the choice are the op's own: seq.attn.index around a
+    # strip's scores, seq.attn.select around its threshold and mask
+    chosen, kept, causal, sample, at = dsa.select(
+        *jax.lax.stop_gradient((iq, ik, iw)), seg, topk=topk, **widths)
+    with jax.named_scope("seq.attn.core"):
+        q, k, v = _for_the_core(cfg, q, k, v)
+        o, lse = chosen_attention(q, k, v, chosen, seg, block=cfg.attn_block)
+    with jax.named_scope("seq.attn.index_loss"):
+        loss, slots = dsa.index_loss(iq, ik, iw, q, k, lse, seg, chosen, **widths)
+    out = _dot(cfg, o.transpose(0, 2, 1, 3).reshape(b, l, h * hd).astype(jnp.float32), p["w_o"])
+    g = h // hkv
+    return out, {
+        "index_loss": loss / jnp.maximum(slots, 1), "kept_pairs": kept.sum(),
+        "causal_pairs": causal.sum(), "iq": iq, "ik": ik, "iw": iw, "index": sample,
+        "index_at": at, "q": q[:, :g], "k": k[:, :1], "v": v[:, :1], "o": o[:, :g],
+        "chosen": jnp.packbits(chosen, axis=-1, bitorder="little"),
+    }
+
+
+def _sparse_check(cfg: BackboneConfig, merged: Dict) -> None:
+    _in_a_period(cfg, "dsa")
+    _attention_check(cfg, merged)
+    missing = [size for size in ("index_n_heads", "index_head_dim", "index_topk")
+               if not getattr(cfg, size)]
+    if missing:
+        raise ValueError(f"sparse_attention layers need {', '.join(missing)} "
+                         "(a public file's sa_config): no default is assumed")
+    if merged.get("sa_config", {}).get("indexer_num_kv_heads", 1) != 1:
+        raise ValueError("the indexer here has ONE index key a slot (indexer_num_kv_heads 1)")
+    if cfg.attention != "gqa" or cfg.differential or cfg.attn_gate:
+        raise ValueError("sparse attention here is grouped-query attention without a gate")
 
 
 def lambda_init(depth: int) -> float:
@@ -877,8 +1001,10 @@ class _Mixer(NamedTuple):
     of that, the newest below it. ``kept``: what its layer's recomputation
     keeps and does not make again, a name that ``checkpoint_name`` gave: the
     scan's output and the states its backward pass starts from, where the
-    scan's kernel runs. ``check(cfg, merged)``: raises its refusals of a
-    configuration in words (``merged``: the public file's keys and the
+    scan's kernel runs. ``counts``: of ``ran``, the layer's own counters, which
+    leave the layer beside its feed-forward's (a loss of the mixer's own among
+    them: ``loss_fn`` adds it). ``check(cfg, merged)``: raises its refusals of
+    a configuration in words (``merged``: the public file's keys and the
     ``backbone`` group's)."""
     words: Tuple[str, ...]
     name: str
@@ -890,6 +1016,7 @@ class _Mixer(NamedTuple):
     hands: Tuple[str, ...] = ()
     reads: Tuple[str, ...] = ()
     kept: Optional[str] = None
+    counts: Tuple[str, ...] = ()
     check: Callable = lambda cfg, merged: None
 
 
@@ -937,6 +1064,11 @@ _MIXERS: Dict[str, _Mixer] = {
     "full": _Mixer(
         words=("full_attention", "attention"), name="", scope=("seq.attn",),
         shapes=_attention_shapes, run=_full, hands=("k", "v"), check=_attention_check),
+    # grouped-query attention over the keys a lightning indexer picks
+    "dsa": _Mixer(
+        words=("sparse_attention",), name="dsa", scope=("seq.attn",), shapes=_sparse_shapes,
+        widths=_sparse_widths, run=_sparse_mixer,
+        counts=("index_loss", "kept_pairs", "causal_pairs"), check=_sparse_check),
     # differential attention inside ``sliding_window`` slots: itself and the ones before
     "swa": _Mixer(
         words=("sliding_attention",), name="swa", scope=("seq.attn", "seq.attn.swa"),
@@ -999,6 +1131,8 @@ def _layer(cfg: BackboneConfig, kind: str, mesh, schedule, x, seg, pos,
         mixed, ran = record.run(cfg, mixer, h, seg, pos, given, depth, mesh, schedule)
         x = _add(cfg, x, mixed)
     y, counters = _ffn(cfg, ffn, _norm(cfg, norm_post, x))
+    if record.counts:
+        counters = {**counters, **{name: ran.pop(name) for name in record.counts}}
     return _add(cfg, x, y), counters, ran
 
 
@@ -1063,7 +1197,9 @@ def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
             counters.append(c)
             # every name from the first mixer of the period that gives it
             first_ran = {**ran, **first_ran}
-        stacked = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *counters)
+        # a counter by the layers of the period that count it, in their order
+        names = sorted({name for c in counters for name in c})
+        stacked = {name: jnp.stack([c[name] for c in counters if name in c]) for name in names}
         return x, (stacked, first_ran)
 
     x, (counters, ran) = jax.lax.scan(one_period, x, params["periods"])
@@ -1141,13 +1277,17 @@ def mtp_hidden(cfg: BackboneConfig, params: Dict, hidden, next_tokens, seg, mesh
 def loss_fn(cfg: BackboneConfig, params: Dict, rows, segs, mesh=None,
             schedule: str = "auto"):
     """The training loss of one batch of packed rows, and (aux) the final
-    hidden states, the counters and what the first mixers ran on. With a
+    hidden states, the counters and what the first mixers ran on. With
+    sparse-attention layers the loss is next-item + the sum of the layers'
+    ``index_loss`` (the counters carry it a layer). With a
     prediction module the loss is next-item + ``mtp_loss_weight`` x the
     module's; the counters then carry ``mtp_loss`` and its block's own as
     ``mtp_<name>``, and the third aux ``mtp_hidden``."""
     tokens, seg, targets, valid = split_rows(rows, segs)
     hidden, counters, ran = hidden_states(cfg, params, tokens, seg, mesh, schedule)
     loss = next_item_loss(cfg, params, hidden, targets, valid)
+    if "index_loss" in counters:  # the indexers' own loss, every sparse-attention layer's
+        loss = loss + counters["index_loss"].sum()
     if cfg.num_nextn_predict_layers:
         with jax.named_scope("seq.mtp"):
             next_tokens, targets, valid = split_rows_mtp(rows, segs)

@@ -267,6 +267,9 @@ class SeqRecAlgorithmParams(Params):
     #: packed rows of seq_len positions in one optimizer step
     batch_size: int = 64
     learning_rate: float = 1e-3
+    #: optimizer steps over which the rate rises in a line to ``learning_rate``:
+    #: step k of them (from 0) runs at (k + 1) / warmup_steps of it. 0 = none
+    warmup_steps: int = 0
     seed: int = 0
     #: attention schedule: "flash" (single device), "ring", "ulysses",
     #: or "auto" (ring when the ctx mesh has a seq axis of size > 1)
@@ -387,7 +390,24 @@ def make_loss_and_grad(cfg: bb.BackboneConfig, mesh=None, schedule: str = "auto"
 #: passes an expert layer's step needed [periods, layers] (the module's
 #: block: a scalar; more than one: it took the overflow branch, ``ops.moe``)
 _BY_STEP = ("expert_tokens", "router_tokens", "mtp_expert_tokens", "mtp_router_tokens",
-            "mtp_loss", "passes", "mtp_passes")
+            "mtp_loss", "passes", "mtp_passes", "index_loss", "kept_pairs", "causal_pairs")
+
+
+def _choice_counts(counters: Dict) -> Dict[str, float]:
+    """From a step's counters where the backbone has sparse-attention layers:
+    ``index_loss`` (the indexers' loss summed over the layers) and
+    ``dsa_kept_pairs_pct`` (the pairs the choice kept over the causal pairs
+    inside histories, all layers); nothing elsewhere. Counters stacked over a
+    job's steps give the last step's loss and the whole job's share (a step's
+    own follows its rows: a history that fills a row keeps a quarter, eight
+    short ones keep all)."""
+    if "kept_pairs" not in counters:
+        return {}
+    loss, kept, causal = (np.asarray(a, np.float64) for a in jax.device_get(
+        [counters[name] for name in ("index_loss", "kept_pairs", "causal_pairs")]))
+    last = loss[-1] if loss.ndim > 2 else loss
+    return {"index_loss": float(last.sum()),
+            "dsa_kept_pairs_pct": float(100.0 * kept.sum() / max(causal.sum(), 1.0))}
 
 
 def _pass_counts(stats: Dict) -> Dict[str, object]:
@@ -404,11 +424,15 @@ def _pass_counts(stats: Dict) -> Dict[str, object]:
 
 
 @functools.lru_cache(maxsize=8)
-def _programs(cfg: bb.BackboneConfig, learning_rate: float, mesh, schedule: str):
+def _programs(cfg: bb.BackboneConfig, learning_rate: float, mesh, schedule: str,
+              warmup_steps: int = 0):
     """The jitted programs of a job, made once per configuration: a second
     job of the same shape compiles nothing."""
     import optax
 
+    if warmup_steps > 1:
+        learning_rate = optax.linear_schedule(
+            learning_rate / warmup_steps, learning_rate, warmup_steps - 1)
     opt = optax.adamw(learning_rate)
     loss_and_grad = make_loss_and_grad(cfg, mesh, schedule)
 
@@ -438,7 +462,12 @@ class SeqRecAlgorithm(Algorithm):
         (params -> state), the donated ``step`` ((params, state, rows,
         segs) -> params, state, loss, counters) and the loss-and-gradient
         function the step is built from (:func:`make_loss_and_grad`)."""
-        return _programs(cfg, self.params.learning_rate, None, "auto")
+        return self._programs_of(cfg, None, "auto")
+
+    def _programs_of(self, cfg: bb.BackboneConfig, mesh, schedule: str):
+        # (the cache's key is the call as written: no warm-up, no fifth argument)
+        warm = (self.params.warmup_steps,) if self.params.warmup_steps else ()
+        return _programs(cfg, self.params.learning_rate, mesh, schedule, *warm)
 
     def train(self, ctx, pd: PreparedData) -> SeqRecModel:
         p = self.params
@@ -466,7 +495,7 @@ class SeqRecAlgorithm(Algorithm):
     def _run_steps(self, pd, cfg, batches, batch, mesh, schedule) -> SeqRecModel:
         p = self.params
         vocab = len(pd.item_map)
-        opt_init, step, _ = _programs(cfg, p.learning_rate, mesh, schedule)
+        opt_init, step, _ = self._programs_of(cfg, mesh, schedule)
         with span("seqrec.init"):
             model_params = bb.init_params(cfg, vocab, pd.seq_len, p.seed)
             opt_state = opt_init(model_params)
@@ -474,7 +503,8 @@ class SeqRecAlgorithm(Algorithm):
         for i in range(p.steps):
             with span("seqrec.input", {"i": i}):
                 rows, segs = batches.next()
-            with span("seqrec.step", {"i": i}):
+            tags = {"i": i}
+            with span("seqrec.step", tags):
                 model_params, opt_state, loss, counters = step(
                     model_params, opt_state, rows, segs)
                 losses.append(loss)
@@ -485,8 +515,10 @@ class SeqRecAlgorithm(Algorithm):
                 # host waits for step i - 1, so the span is a step long and
                 # the device is never left waiting for the host
                 if before is not None:
-                    jax.block_until_ready(before)
-                before = loss
+                    jax.block_until_ready(before[0])
+                    # what the step the span waited for counted of its choice
+                    tags.update(_choice_counts(before[1]))
+                before = loss, counters
         with span("train.wait_device"):
             jax.block_until_ready(model_params)
         stats = {"fill": pd.fill, "steps": p.steps, "tokens_per_step": batch * pd.seq_len,
@@ -498,6 +530,8 @@ class SeqRecAlgorithm(Algorithm):
             host_losses = np.asarray(jax.device_get(losses), np.float32)
             for name, values in by_step.items():  # [steps, ...]
                 stats[name + "_by_step"] = np.stack(jax.device_get(values))
+            # the last step's loss, the whole job's kept share
+            stats.update(_choice_counts({name: stats[name + "_by_step"] for name in by_step}))
             counts = _pass_counts(stats)
             stats.update({name: counts[name] for name in counts if name != "passes_by_step"})
             if cfg.router_bias and cfg.ffn == "moe":

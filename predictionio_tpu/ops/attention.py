@@ -115,9 +115,12 @@ def _pair_meets(seg_q, seg_k, i, j, bq, bk):
     return jnp.any((sq.max(1) >= sk.min(1)) & (sq.min(1) <= sk.max(1)))
 
 
-def _pair_tile(q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, after, window=0):
+def _pair_tile(q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, after, window=0,
+               chosen=None):
     """Scores of Q block i against KV block j and the mask of those kept
-    (under a ``window`` a slot keeps itself and the ``window - 1`` before it).
+    (under a ``window`` a slot keeps itself and the ``window - 1`` before it;
+    with ``chosen`` [B, Lq, Lk] bool, a mask the step made from its data, only
+    the pairs that holds true).
     q [B, Hkv, G, Lq, D], k [B, Hkv, Lk, D], seg [B, L]. ``after`` is a
     value of this iteration's carry: the barrier makes the tile wait for
     it, or the compiler computes every pair's tile ahead of the loop and
@@ -137,11 +140,13 @@ def _pair_tile(q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, after, windo
     sq = jax.lax.dynamic_slice_in_dim(seg_q, i * bq, bq, axis=1)
     sk = jax.lax.dynamic_slice_in_dim(seg_k, j * bk, bk, axis=1)
     keep = keep[None] & (sq[:, :, None] == sk[:, None, :])  # [B, bq, bk]
+    if chosen is not None:
+        keep = keep & jax.lax.dynamic_slice(chosen, (0, i * bq, j * bk), (keep.shape[0], bq, bk))
     return qi, kj, s, keep[:, None, None], after
 
 
 def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype=jnp.float32,
-                   window=0):
+                   window=0, chosen=None):
     """Online softmax over the block pairs; returns o (q's dtype, v's
     width) and the log-sum-exp of every row [B, Hkv, G, Lq] (float32). The
     running maximum, sum and output are kept in ``stats_dtype`` between
@@ -158,7 +163,7 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype=jnp.fl
             m, l, o = carry
             mi = jax.lax.dynamic_slice_in_dim(m, i * bq, bq, axis=3)
             qi, kj, s, keep, mi = _pair_tile(
-                q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, mi, window)
+                q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, mi, window, chosen)
             mi = mi.astype(f32)
             li = jax.lax.dynamic_slice_in_dim(l, i * bq, bq, axis=3).astype(f32)
             oi = jax.lax.dynamic_slice_in_dim(o, i * bq, bq, axis=3).astype(f32)
@@ -186,7 +191,8 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, bq, bk, lk, stats_dtype=jnp.fl
     return (o.astype(f32) / l[..., None]).astype(q.dtype), m.astype(f32) + jnp.log(l)
 
 
-def _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk, window=0):
+def _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk, window=0,
+                    chosen=None):
     """The flash backward pass: scores are recomputed tile by tile from q,
     k and the saved log-sum-exp; the score matrix is never a residual."""
     b, hkv, g, lq, d = q.shape
@@ -201,7 +207,7 @@ def _flash_backward(q, k, v, seg_q, seg_k, o, lse, do, causal, bq, bk, lk, windo
             dq, dk, dv = carry
             dq_old = jax.lax.dynamic_slice_in_dim(dq, i * bq, bq, axis=3)
             qi, kj, s, keep, dq_old = _pair_tile(
-                q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, dq_old, window)
+                q, k, seg_q, seg_k, i, j, bq, bk, lk, causal, scale, dq_old, window, chosen)
             vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=2)
             doi = jax.lax.dynamic_slice_in_dim(do, i * bq, bq, axis=3)
             lsei = jax.lax.dynamic_slice_in_dim(lse, i * bq, bq, axis=3)
@@ -253,6 +259,29 @@ def _flash_vjp_bwd(causal, bq, bk, lk, stats_dtype, window, res, do):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _flash_chosen(q, k, v, seg_q, seg_k, chosen, bq, bk, lk, stats_dtype):
+    """:func:`_flash` under a causal mask and ``chosen``; also hands back the
+    rows' log-sum-exp [B, Hkv, G, Lq], which no gradient flows through (it is
+    for a pass that holds the attention weights constant)."""
+    return _flash_forward(q, k, v, seg_q, seg_k, True, bq, bk, lk, stats_dtype, 0, chosen)
+
+
+def _flash_chosen_fwd(q, k, v, seg_q, seg_k, chosen, bq, bk, lk, stats_dtype):
+    o, lse = _flash_forward(q, k, v, seg_q, seg_k, True, bq, bk, lk, stats_dtype, 0, chosen)
+    return (o, lse), (q, k, v, seg_q, seg_k, chosen, o, lse)
+
+
+def _flash_chosen_bwd(bq, bk, lk, stats_dtype, res, cotangents):
+    q, k, v, seg_q, seg_k, chosen, o, lse = res
+    dq, dk, dv = _flash_backward(
+        q, k, v, seg_q, seg_k, o, lse, cotangents[0], True, bq, bk, lk, 0, chosen)
+    return dq, dk, dv, None, None, None
+
+
+_flash_chosen.defvjp(_flash_chosen_fwd, _flash_chosen_bwd)
+
+
 @functools.partial(
     jax.jit, static_argnames=("causal", "block_k", "block_q", "stats_dtype", "window"))
 def flash_attention(
@@ -300,6 +329,50 @@ def tiles_skipped_by_window(length: int, block: int, window: int) -> int:
     padded = length + -length % blk
     return (len(_block_pairs(padded, padded, blk, blk, True)[0])
             - len(_block_pairs(padded, padded, blk, blk, True, window)[0]))
+
+
+@functools.partial(jax.jit, static_argnames=("block", "stats_dtype"))
+def chosen_attention(
+    q: jax.Array,  # [B, H, L, D]
+    k: jax.Array,  # [B, Hkv, L, D]
+    v: jax.Array,  # [B, Hkv, L, Dv]
+    chosen: jax.Array,  # [B, L, L] bool: the keys (last axis) each query reads
+    segment_ids: Optional[jax.Array] = None,
+    block: int = 512,
+    stats_dtype: str = "float32",
+):
+    """:func:`flash_attention` (causal, inside histories) over the pairs that
+    ``chosen``, a mask the step itself made from its data, holds true: the
+    blockwise loop and its VJP run every tile the position masks leave and
+    apply ``chosen`` inside it. Returns o [B, H, L, Dv] and the log-sum-exp of
+    every row over its chosen keys [B, H, L] (float32; no gradient flows
+    through it: it is what :func:`chosen_weights_tile` turns scores back into
+    weights with). A query without a chosen key gives zeros."""
+    b, h, lq, _ = q.shape
+    blk = min(block, lq)
+    qg, k, v, seg_q, seg_k = _grouped_and_padded(q, k, v, segment_ids, blk, blk)
+    pad = -lq % blk
+    seg_q = jnp.pad(seg_q, ((0, 0), (0, pad)), mode="edge")
+    chosen = jnp.pad(chosen, ((0, 0), (0, pad), (0, pad)))
+    o, lse = _flash_chosen(qg, k, v, seg_q, seg_q, chosen, blk, blk, lq, jnp.dtype(stats_dtype))
+    return o[:, :, :, :lq].reshape(b, h, lq, v.shape[-1]), lse[:, :, :, :lq].reshape(b, h, lq)
+
+
+def chosen_weights_tile(qg, k, lse, seg, chosen, i, j, block: int, after):
+    """The attention weights of :func:`chosen_attention` again, for a second
+    pass that holds them constant: of Q block i over KV block j, summed over
+    all heads and divided by their number, [B, block, block] float32 (a
+    query's sum to 1 over its chosen keys), and the tile's mask [B, block,
+    block]. ``qg`` [B, Hkv, G, L, D], ``k`` [B, Hkv, L, D], ``lse`` [B, Hkv,
+    G, L] as the first pass gave it, L whole blocks; ``after`` as in
+    :func:`_pair_tile`."""
+    heads = qg.shape[1] * qg.shape[2]
+    qi, kj, s, keep, after = _pair_tile(
+        qg, k, seg, seg, i, j, block, block, k.shape[2], True, 1.0 / np.sqrt(qg.shape[-1]),
+        after, 0, chosen)
+    lsei = jax.lax.dynamic_slice_in_dim(lse, i * block, block, axis=3)
+    p = jnp.where(keep, jnp.exp(s - lsei[..., None]), 0.0)
+    return p.sum((1, 2)) / heads, keep[:, 0, 0], after
 
 
 #: tile edge of the Pallas kernel, forward and backward: the best of 512,

@@ -5,6 +5,7 @@ attention — the mesh changes the communication pattern, never the math.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -147,3 +148,107 @@ def test_dispatch_on_one_device(qkv):
     for mesh in (one, other):
         got = attention(q, k[:, :2], v[:, :2], mesh=mesh, block=16)
         np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-4, atol=2e-5)
+
+
+# -- a mask the step computes (learned sparse attention) ----------------------
+def _chosen_case(length, seed=0, heads=4, kv_heads=2, hd=8, keep_share=0.5):
+    """Seeded q, k, v, packed histories and a random chosen mask that always
+    holds the diagonal (every query keeps itself, as a choice of its best keys
+    among its causal ones always leaves it one)."""
+    rng = np.random.default_rng(seed)
+    q = rng.normal(size=(2, heads, length, hd)).astype(np.float32)
+    k = rng.normal(size=(2, kv_heads, length, hd)).astype(np.float32)
+    v = rng.normal(size=(2, kv_heads, length, hd)).astype(np.float32)
+    seg = np.ones((2, length), np.int32)
+    seg[0, length // 3:] = 2
+    seg[0, -2:] = 0
+    chosen = (rng.random((2, length, length)) < keep_share) | np.eye(length, dtype=bool)[None]
+    idx = np.arange(length)
+    kept = chosen & (idx[:, None] >= idx[None, :])[None] & (seg[:, :, None] == seg[:, None, :])
+    return q, k, v, seg, chosen, kept
+
+
+def _dense_chosen(q, k, v, kept):
+    """Masked softmax over the full score matrix: o [B, H, L, hd], the weights
+    [B, H, L, L]."""
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    w = jax.nn.softmax(jnp.where(kept[:, None], s, -1e30), axis=-1)
+    w = jnp.where(kept[:, None], w, 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", w, v), w
+
+
+@pytest.mark.parametrize("length,block", [(48, 16), (40, 16), (24, 64)])
+def test_the_loop_under_a_chosen_mask_is_the_dense_masked_softmax(length, block):
+    """``chosen_attention``: forward, the rows' log-sum-exp and the VJP against
+    a dense masked softmax, rows of whole tiles and not, one tile a row."""
+    from predictionio_tpu.ops.attention import chosen_attention
+
+    q, k, v, seg, chosen, kept = _chosen_case(length)
+    probe = np.random.default_rng(9).normal(size=q.shape).astype(np.float32)
+
+    def ours(q, k, v):
+        o, lse = chosen_attention(q, k, v, jnp.asarray(chosen), jnp.asarray(seg), block=block)
+        return jnp.sum(o * probe), (o, lse)
+
+    def dense(q, k, v):
+        o, _ = _dense_chosen(q, k, v, jnp.asarray(kept))
+        return jnp.sum(o * probe), o
+
+    (_, (o, lse)), grads = jax.value_and_grad(ours, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, want_o), want_grads = jax.value_and_grad(dense, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(o, want_o, atol=2e-5)
+    for got, want in zip(grads, want_grads):
+        np.testing.assert_allclose(got, want, atol=5e-5)
+    group = q.shape[1] // k.shape[1]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, group, axis=1)) / np.sqrt(q.shape[-1])
+    want_lse = jax.nn.logsumexp(jnp.where(kept[:, None], s, -np.inf), axis=-1)
+    np.testing.assert_allclose(lse, want_lse, atol=2e-5)
+
+
+def test_no_gradient_flows_through_the_log_sum_exp_that_is_handed_back():
+    from predictionio_tpu.ops.attention import chosen_attention
+
+    q, k, v, seg, chosen, _ = _chosen_case(32)
+    grads = jax.grad(lambda q, k, v: chosen_attention(
+        q, k, v, jnp.asarray(chosen), jnp.asarray(seg), block=16)[1].sum(), argnums=(0, 1, 2))(q, k, v)
+    assert all(float(jnp.abs(g).max()) == 0.0 for g in grads)
+
+
+@pytest.mark.parametrize("length,block", [(48, 16), (40, 16)])
+def test_the_indexers_loss_is_the_kl_over_the_chosen_keys(length, block):
+    """``ops.dsa.index_loss`` (the second pass over the tiles) against the KL
+    written out on full matrices: the head-summed weights of the main heads
+    over the chosen keys, held constant, from the softmax of the index scores
+    over the same keys; value and the gradient onto the indexer's inputs, and
+    none onto q and k."""
+    from predictionio_tpu.ops import dsa
+    from predictionio_tpu.ops.attention import chosen_attention
+
+    q, k, v, seg, chosen, kept = _chosen_case(length, seed=3)
+    rng = np.random.default_rng(4)
+    iq = rng.normal(size=(2, length, 3, 4)).astype(np.float32)
+    ik = rng.normal(size=(2, length, 4)).astype(np.float32)
+    iw = rng.normal(size=(2, length, 3)).astype(np.float32)
+    _, lse = chosen_attention(q, k, v, jnp.asarray(chosen), jnp.asarray(seg), block=block)
+
+    def ours(iq, ik, iw, q, k):
+        total, slots = dsa.index_loss(iq, ik, iw, q, k, lse, jnp.asarray(seg), jnp.asarray(chosen),
+                                      block=block)
+        return total / slots
+
+    def dense(iq, ik, iw):
+        scores = dsa.index_scores(iq, ik, iw)
+        p = _dense_chosen(q, k, v, jnp.asarray(kept))[1].mean(1)
+        log_soft = jax.nn.log_softmax(jnp.where(kept, scores, -1e30), axis=-1)
+        kl = jnp.where(kept & (p > 0), p * (jnp.log(jnp.where(p > 0, p, 1.0)) - log_soft), 0.0).sum(-1)
+        real = jnp.asarray(seg > 0)
+        return jnp.where(real, kl, 0.0).sum() / real.sum()
+
+    got, grads = jax.value_and_grad(ours, argnums=(0, 1, 2, 3, 4))(iq, ik, iw, q, k)
+    want, want_grads = jax.value_and_grad(dense, argnums=(0, 1, 2))(iq, ik, iw)
+    assert float(got) == pytest.approx(float(want), rel=1e-5) and float(want) > 0.1
+    for g, w in zip(grads[:3], want_grads):
+        np.testing.assert_allclose(g, w, atol=2e-6)
+    assert float(jnp.abs(grads[3]).max()) == 0.0 and float(jnp.abs(grads[4]).max()) == 0.0

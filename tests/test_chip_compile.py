@@ -974,3 +974,38 @@ def test_joyai_step_has_no_short_convolution(one_chip, as_tpu):
     assert "tpu_custom_call" in text and not re.search(r"seq\.\w+\.conv", text)
     assert bb.mechanisms(cfg, SEQ_L) == {}
 
+
+
+def test_keye_step_one_row_of_16k_fits_the_chip(one_chip, as_tpu):
+    """The whole optimizer step of ``train-keye-long16k`` (1 row of 16,385
+    slots, six sparse-attention layers, 659 M parameters with their AdamW
+    moments, donated) as the job compiles it. The chip's compiler takes it (it
+    refuses a program that does not fit the chip's memory), and its buffer
+    assignment reads 15.04 GiB of the chip's 15.75: arguments 7.91 GB, which
+    the outputs alias, and 8.25 GB of temporaries, the gradient among them
+    (``memory_analysis()`` counts 12.96 GB of temporaries for this program, the
+    aliased outputs' share among them). This reading decides how many layers the
+    cut keeps (``conf/backbones/keye-vl2-30b-a3b-ep8.json``: six; five had it
+    not fit); on the chip the step runs (``PERF.md`` section 4)."""
+    from predictionio_tpu.models import seq_backbone as bb
+    from predictionio_tpu.models import sequencerec
+
+    length = 16384
+    cfg = bb.BackboneConfig.load("keye-vl2-30b-a3b-ep8")
+    opt_init, step, _ = sequencerec._programs(cfg, 3e-4, None, "auto")
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: bb.init_params(cfg, 18992, length, 0)))
+    assert sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(params)) == 659_190_016
+    rows = _sds(one_chip, (1, length + 1), jnp.int32)
+    try:
+        compiled = _compile(step, params, on_chip(jax.eval_shape(opt_init, params)), rows, rows)
+    finally:
+        step.clear_cache()  # the job's own program object, kept by ``_programs``
+    stats = _report("keye step", compiled)
+    assert (stats.argument_size_in_bytes + stats.temp_size_in_bytes
+            - stats.alias_size_in_bytes) <= 15.75 * 2**30
+    assert cfg.mixers() == {"dsa": 6}
+    text = compiled.as_text()
+    for scope in ("seq.attn.index", "seq.attn.select", "seq.attn.core", "seq.attn.index_loss"):
+        assert scope in text

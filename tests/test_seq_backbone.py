@@ -886,6 +886,11 @@ EVERY = {
                  "chunk": 8, "attn_block": 16, "loss_block": 32},
 }
 
+#: what a kind needs that ``EVERY`` cannot carry for all: sparse attention is
+#: grouped-query attention and refuses a differential file
+OWN = {"dsa": {"sa_config": {"indexer_num_heads": 2, "indexer_head_dim": 8, "topk": 8},
+               "backbone": {**EVERY["backbone"], "differential": False}}}
+
 
 @pytest.mark.parametrize("kind", sorted(bb._MIXERS))
 def test_every_record_of_the_table_is_complete_and_its_words_are_accepted(kind):
@@ -896,7 +901,7 @@ def test_every_record_of_the_table_is_complete_and_its_words_are_accepted(kind):
         assert any(set(record.reads) <= set(other.hands) for other in bb._MIXERS.values())
     for word in record.words:
         cfg = bb.BackboneConfig.from_dict(
-            {**EVERY, "layer_types": ["mamba1", "full_attention", word]})
+            {**EVERY, **OWN.get(kind, {}), "layer_types": ["mamba1", "full_attention", word]})
         assert cfg.kinds[-1] == kind and cfg.stacked(kind) in (0, 1, 2)
         assert cfg.mixers()[record.name or cfg.attention] >= 1
         assert all(bb._is_spec(spec) for spec in record.shapes(cfg).values()) and record.shapes(cfg)

@@ -1,9 +1,12 @@
-"""The five listed backbones of the sequence template, pinned: what their
+"""The listed backbones of the sequence template, pinned: what their
 tiny configurations compute on a fixed seeded batch must stay what the commit
 before the next backbone computed (``granite4h-tiny``: commit 32bdee9, the
 parent of the fifth backbone's PR), or, for the fifth, the commit before the
 backbone's mixers became one table (``phi4-mini-flash-tiny``: commit d0ce42b,
-PR 43)."""
+PR 43), or, for the sixth, what its own PR computed (``keye-vl2-tiny``: PR 45,
+with exactly ``topk`` keys a query);
+and the five older ones' step programs must lower to the text the sixth's
+parent lowered."""
 
 import json
 
@@ -57,7 +60,11 @@ PINNED = json.loads("""
   "periods.cross": 0.006096933379104272, "periods.ffn": 0.36072661833735686,
   "periods.full": 0.018284754473628158, "periods.gmu": 0.014815461261404308,
   "periods.mamba1": 0.5202352792829213, "periods.norm_in": 0.10984904188512179,
-  "periods.norm_post": 0.08390504050254491, "periods.swa": 0.04723355729407075}}}
+  "periods.norm_post": 0.08390504050254491, "periods.swa": 0.04723355729407075}},
+ "keye-vl2-tiny": {"loss": 4.133016109466553, "grad_norm": {"embed": 0.8558340625832418,
+  "final_norm.w": 0.01721364968225186, "head": 0.7197617383071163, "periods.dsa": 0.9331903167737758,
+  "periods.ffn": 0.05705573140272603, "periods.norm_in": 0.012798044946150227,
+  "periods.norm_post": 0.0009832543026727114}}}
 """)
 
 
@@ -68,7 +75,9 @@ def test_a_listed_configuration_computes_what_it_did_before_this_backbone(name, 
     0, as commit fb37b61 (PR 37, before ``ssm`` layers, the multipliers and
     ``positions: none``) computed the first three on the CPU and commit 32bdee9
     (PR 40, before ``mamba1``, ``gmu``, windows and cross-attention) the
-    fourth, and commit d0ce42b (PR 43, before the table of mixers) the fifth. A
+    fourth, commit d0ce42b (PR 43, before the table of mixers) the fifth, and
+    PR 45 itself the sixth (sparse attention; the reference holds it: here it is
+    held against a later change). A
     change to a mixer of ``seq_backbone``, ``_layer``, ``logits_of``, ``swiglu`` or the
     loss that moves a listed configuration fails here and not on the
     driver's chip. 1e-4: float32 sums in another order read 1e-6; a
@@ -86,3 +95,75 @@ def test_a_listed_configuration_computes_what_it_did_before_this_backbone(name, 
     assert sorted(squares) == sorted(want["grad_norm"])
     for key, value in want["grad_norm"].items():
         assert np.sqrt(squares[key]) == pytest.approx(value, rel=1e-4), key
+
+
+#: sha256 (first 16 hex digits) of the lowered text of the donated optimizer step
+#: (``sequencerec._programs``) of every listed backbone at its tiny size, two
+#: rows of 65 slots over 50 items, as commit 690be00 (PR 44, before sparse
+#: attention and the chosen mask of ``ops/attention.py``) lowered it here
+LOWERED = {
+    "qwen3next-tiny": "8647b3ccf9e03740", "joyai-flash-tiny": "ca7aadf33217cfca",
+    "lfm2-tiny": "8a0a78b7521aab03", "granite4h-tiny": "af44eb0d1a6e2910",
+    "phi4-mini-flash-tiny": "11204e7d06802878"}
+
+
+@pytest.mark.parametrize("name", sorted(LOWERED))
+def test_a_listed_configuration_lowers_to_the_text_it_did_before_this_backbone(name):
+    """The step program of a listed backbone is, letter for letter, what the
+    commit before the sixth backbone lowered: the chosen mask, its second
+    custom VJP and the shared projections' new function change nothing that a
+    configuration without sparse-attention layers traces. A later PR that
+    changes a listed step on purpose writes the new digests here and says so
+    (``python -c`` of this test's four lines on the parent gives the old ones)."""
+    import hashlib
+
+    from predictionio_tpu.models import sequencerec
+
+    cfg = bb.BackboneConfig.load(name)
+    opt_init, step, _ = sequencerec._programs(cfg, 3e-4, None, "auto")
+    params = jax.eval_shape(lambda: bb.init_params(cfg, VOCAB, L, 0))
+    rows = jax.ShapeDtypeStruct((2, L + 1), np.int32)
+    try:
+        text = step.lower(params, jax.eval_shape(opt_init, params), rows, rows).as_text()
+    finally:
+        step.clear_cache()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == LOWERED[name]
+
+
+#: sha256 (first 16 hex digits) over the same step's equations, each as "name
+#: stack/primitive" with how often it occurs, of those under a ``seq.`` scope,
+#: through every sub-jaxpr: as commit 690be00 traced them. The lowered text
+#: above carries no name stack, so a cast or a transpose that moves out of
+#: ``seq.attn.core`` (seconds that a per-layer metric divides by) shows here alone
+SCOPED = {
+    "qwen3next-tiny": "d3cddef1dda60909", "joyai-flash-tiny": "84122237e9b90538",
+    "lfm2-tiny": "c0849603f5aef9f0", "granite4h-tiny": "2d28a1e6607553c0",
+    "phi4-mini-flash-tiny": "d655df2c744808e3"}
+
+
+def _scoped(jaxpr, under=""):
+    for eqn in jaxpr.eqns:
+        stack = under + "/" + str(eqn.source_info.name_stack)
+        yield stack + "/" + eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scoped(sub, stack)
+
+
+@pytest.mark.parametrize("name", sorted(SCOPED))
+def test_a_listed_configuration_keeps_every_operation_under_its_scope(name):
+    """What the device-trace metrics read is part of the yardstick: the
+    operations of a listed backbone's step stand under the named scopes they
+    stood under before the sixth backbone shared its projections."""
+    import collections
+    import hashlib
+
+    from predictionio_tpu.models import sequencerec
+
+    cfg = bb.BackboneConfig.load(name)
+    opt_init, step, _ = sequencerec._programs(cfg, 3e-4, None, "auto")
+    params = jax.eval_shape(lambda: bb.init_params(cfg, VOCAB, L, 0))
+    rows = jax.ShapeDtypeStruct((2, L + 1), np.int32)
+    closed = jax.make_jaxpr(step)(params, jax.eval_shape(opt_init, params), rows, rows)
+    count = collections.Counter(s for s in _scoped(closed.jaxpr) if "seq." in s)
+    lines = sorted(f"{k} {v}" for k, v in count.items())
+    assert hashlib.sha256("\n".join(lines).encode()).hexdigest()[:16] == SCOPED[name]

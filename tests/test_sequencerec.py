@@ -247,6 +247,42 @@ def test_a_second_job_reuses_the_programs_and_trains_the_same():
         np.testing.assert_array_equal(np.asarray(first[key]), np.asarray(second[key]))
 
 
+@pytest.mark.parametrize("warm", [4, 1, 0])
+def test_a_warm_up_raises_the_rate_in_a_line(warm):
+    """``warmup_steps`` W: step k of a job (from 0) runs at (k + 1) / W of
+    ``learning_rate`` and every step from W - 1 on at all of it. AdamW's step is
+    its rate times what the moments and the parameters give, so from the same
+    state the first step moves every leaf by 1 / W of what it moves without."""
+    import jax
+    import numpy as np
+    import optax
+
+    from predictionio_tpu.models import seq_backbone as bb
+    from predictionio_tpu.models.sequencerec import SeqRecAlgorithm, SeqRecAlgorithmParams
+
+    def first_move(**kw):
+        algo = SeqRecAlgorithm(SeqRecAlgorithmParams(
+            d_model=16, n_heads=2, n_layers=1, learning_rate=1e-2, **kw))
+        cfg = algo.params.backbone_config()
+        opt_init, step, _ = algo.programs(cfg)
+        params = bb.init_params(cfg, 9, 8, 5)
+        rows = np.arange(18, dtype=np.int32).reshape(2, 9) % 9
+        before = jax.tree_util.tree_map(np.asarray, params)
+        state = opt_init(params)
+        after, state, *_ = step(params, state, rows, np.ones_like(rows))
+        moved = jax.tree_util.tree_map(lambda a, b: np.asarray(a) - b, after, before)
+        return moved, state
+
+    plain, _ = first_move()
+    warmed, state = first_move(warmup_steps=warm)
+    for a, b in zip(jax.tree_util.tree_leaves(warmed), jax.tree_util.tree_leaves(plain)):
+        np.testing.assert_allclose(a, b / max(warm, 1), rtol=1e-4, atol=1e-9)
+    if warm > 1:
+        rate = optax.linear_schedule(1e-2 / warm, 1e-2, warm - 1)
+        assert [float(rate(k)) for k in (0, 1, warm - 1, warm + 5)] == pytest.approx(
+            [1e-2 / warm, 2e-2 / warm, 1e-2, 1e-2])
+
+
 @pytest.mark.parametrize("backbone,backend,want", [
     ("qwen3next-80b-a3b-ep16", "tpu", {"delta_rule_walk": "pallas", "conv": "pallas"}),
     ("lfm2-24b-a2b-ep8", "tpu", {"conv": "pallas"}),
