@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import deltanet, dsa, selscan, shortconv, ssd
+from ..ops import chosen_core, deltanet, dsa, selscan, shortconv, ssd
 from ..ops.attention import attention, chosen_attention, tiles_skipped_by_window
 from ..ops.deltanet import gated_deltanet
 from ..ops.moe import expert_layer, swiglu
@@ -865,6 +865,10 @@ def _sparse_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, given, depth, mesh,
     }
 
 
+def _sparse_forms(cfg: BackboneConfig, length: int) -> Dict:
+    return chosen_core.forms(cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, length)
+
+
 def _sparse_check(cfg: BackboneConfig, merged: Dict) -> None:
     _in_a_period(cfg, "dsa")
     _attention_check(cfg, merged)
@@ -1067,7 +1071,7 @@ _MIXERS: Dict[str, _Mixer] = {
     # grouped-query attention over the keys a lightning indexer picks
     "dsa": _Mixer(
         words=("sparse_attention",), name="dsa", scope=("seq.attn",), shapes=_sparse_shapes,
-        widths=_sparse_widths, run=_sparse_mixer,
+        widths=_sparse_widths, run=_sparse_mixer, forms=_sparse_forms,
         counts=("index_loss", "kept_pairs", "causal_pairs"), check=_sparse_check),
     # differential attention inside ``sliding_window`` slots: itself and the ones before
     "swa": _Mixer(

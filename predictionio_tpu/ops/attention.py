@@ -36,6 +36,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from jax import shard_map
 
+from . import chosen_core
+
 SEQ_AXIS = "seq"
 
 _NEG_BIG = -1e30  # additive mask value (finite: keeps fully-masked rows NaN-free)
@@ -347,8 +349,13 @@ def chosen_attention(
     apply ``chosen`` inside it. Returns o [B, H, L, Dv] and the log-sum-exp of
     every row over its chosen keys [B, H, L] (float32; no gradient flows
     through it: it is what :func:`chosen_weights_tile` turns scores back into
-    weights with). A query without a chosen key gives zeros."""
+    weights with). A query without a chosen key gives zeros. Where
+    :func:`.chosen_core.core_kind` says so (a TPU, heads and values of whole
+    lane tiles, rows of whole kernel tiles) the core is that module's Pallas
+    kernel pair, whose tile is its own; the loop everywhere else."""
     b, h, lq, _ = q.shape
+    if chosen_core.core_kind(h, k.shape[1], q.shape[-1], v.shape[-1], lq, stats_dtype) == "pallas":
+        return chosen_core.chosen_core(q, k, v, chosen, segment_ids)
     blk = min(block, lq)
     qg, k, v, seg_q, seg_k = _grouped_and_padded(q, k, v, segment_ids, blk, blk)
     pad = -lq % blk

@@ -375,14 +375,19 @@ def test_sharded_half_step_2x2(topo):
 @pytest.fixture
 def as_tpu(monkeypatch):
     """Code that asks for the backend is told "tpu" (here it is the CPU),
-    and the delta rule, whose jit keeps a trace made under one answer for
-    the next caller with the same shapes, is traced anew on both sides."""
+    and the delta rule and the sparse-attention core, whose jits keep a trace
+    made under one answer for the next caller with the same shapes, are traced
+    anew on both sides."""
+    from predictionio_tpu.ops.attention import chosen_attention
     from predictionio_tpu.ops.deltanet import gated_delta_rule
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    gated_delta_rule.clear_cache()
+    kept = (gated_delta_rule, chosen_attention)  # jits that ask for the backend while traced
+    for fn in kept:
+        fn.clear_cache()
     yield
-    gated_delta_rule.clear_cache()
+    for fn in kept:
+        fn.clear_cache()
 
 
 # -- the sequence backbone's programs at the shapes of train-qwen3next-packed8k:
@@ -976,15 +981,51 @@ def test_joyai_step_has_no_short_convolution(one_chip, as_tpu):
 
 
 
+def test_chosen_core_one_row_of_16k(one_chip, as_tpu):
+    """The sparse-attention core alone, forward and backward, at the cell's
+    shape (32 heads of 128 on 4 key heads, one row of 16,384 slots, a mask of
+    [16384, 16384]): ``chosen_attention`` is the kernel pair of
+    ``ops/chosen_core.py``, two custom calls under the caller's scope (the
+    backward one holds a key head's whole ``dk`` and ``dv`` in VMEM: 33.5 MB of
+    its 100), and what XLA makes beside them is the mask a byte a pair (268 MB,
+    once a pass), ``delta`` and the tables."""
+    from predictionio_tpu.ops import chosen_core
+    from predictionio_tpu.ops.attention import chosen_attention
+
+    length = 16384
+    assert chosen_core.core_kind(32, 4, 128, 128, length) == "pallas"
+
+    def loss(q, k, v, chosen, seg):
+        with jax.named_scope("seq.attn.core"):
+            o, lse = chosen_attention(q, k, v, chosen, seg)
+        return o.astype(jnp.float32).sum() + jax.lax.stop_gradient(lse).sum()
+
+    heads = lambda n: _sds(one_chip, (1, n, length, 128), jnp.bfloat16)  # noqa: E731
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), heads(32), heads(4), heads(4),
+        _sds(one_chip, (1, length, length), jnp.bool_), _sds(one_chip, (1, length), jnp.int32))
+    stats = _report("chosen core", compiled)
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line and "seq.attn.core" in line]
+    assert len(calls) == 2 and "chosen_core_forward" in calls[0] + calls[1]
+    assert "chosen_core_backward" in calls[0] + calls[1]
+    # the mask a byte a pair for each kernel, o and the cotangents; no float32 copy of q
+    assert stats.temp_size_in_bytes < 1.0 * 2**30
+
+
 def test_keye_step_one_row_of_16k_fits_the_chip(one_chip, as_tpu):
     """The whole optimizer step of ``train-keye-long16k`` (1 row of 16,385
     slots, six sparse-attention layers, 659 M parameters with their AdamW
     moments, donated) as the job compiles it. The chip's compiler takes it (it
-    refuses a program that does not fit the chip's memory), and its buffer
-    assignment reads 15.04 GiB of the chip's 15.75: arguments 7.91 GB, which
-    the outputs alias, and 8.25 GB of temporaries, the gradient among them
-    (``memory_analysis()`` counts 12.96 GB of temporaries for this program, the
-    aliased outputs' share among them). This reading decides how many layers the
+    refuses a program that does not fit the chip's memory). With the cores on
+    the XLA loop (PR 45) its buffer assignment read 15.04 GiB of the chip's
+    15.75: arguments 7.91 GB, which the outputs alias, and 8.25 GB of
+    temporaries, the gradient among them (``memory_analysis()`` counted 12.96
+    GB of temporaries, the aliased outputs' share among them). With the cores
+    on the kernel pair of ``ops/chosen_core.py`` (PR 46) ``memory_analysis()``
+    counts 11.88 GB: the loop's three float32 carries of q's size go, a byte a
+    pair of mask comes, and the step stands about 1.0 GiB lower, near 14.0 GiB.
+    The first reading decides how many layers the
     cut keeps (``conf/backbones/keye-vl2-30b-a3b-ep8.json``: six; five had it
     not fit); on the chip the step runs (``PERF.md`` section 4)."""
     from predictionio_tpu.models import seq_backbone as bb
@@ -1006,6 +1047,11 @@ def test_keye_step_one_row_of_16k_fits_the_chip(one_chip, as_tpu):
     assert (stats.argument_size_in_bytes + stats.temp_size_in_bytes
             - stats.alias_size_in_bytes) <= 15.75 * 2**30
     assert cfg.mixers() == {"dsa": 6}
+    assert bb.mechanisms(cfg, length)["chosen_core"] == "pallas"
     text = compiled.as_text()
     for scope in ("seq.attn.index", "seq.attn.select", "seq.attn.core", "seq.attn.index_loss"):
         assert scope in text
+    # the core is the kernel pair: its custom calls lie under the core's scope
+    core = [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line and "seq.attn.core" in line]
+    assert core and all("chosen_core_" in line for line in core)

@@ -938,21 +938,26 @@ def _pallas_calls(jaxpr, above=""):
 
 @pytest.fixture
 def as_tpu(monkeypatch):
-    """Code that asks for the backend is told "tpu"; the delta rule, whose jit
-    keeps a trace made under one answer, is traced anew on both sides."""
+    """Code that asks for the backend is told "tpu"; the delta rule and the
+    sparse-attention core, whose jits keep a trace made under one answer, are
+    traced anew on both sides."""
+    from predictionio_tpu.ops.attention import chosen_attention
+
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    deltanet.gated_delta_rule.clear_cache()
+    for fn in (deltanet.gated_delta_rule, chosen_attention):
+        fn.clear_cache()
     yield
-    deltanet.gated_delta_rule.clear_cache()
+    for fn in (deltanet.gated_delta_rule, chosen_attention):
+        fn.clear_cache()
 
 
 @pytest.mark.parametrize("name,rows", [
     ("qwen3next-80b-a3b-ep16", 2), ("joyai-flash-48b-a3b-ep16", 2), ("lfm2-24b-a2b-ep8", 2),
-    ("granite4h-micro-vp8", 1), ("phi4-mini-flash-vp8", 1)])
+    ("granite4h-micro-vp8", 1), ("phi4-mini-flash-vp8", 1), ("keye-vl2-30b-a3b-ep8", 1)])
 def test_the_counters_say_pallas_exactly_where_the_step_holds_a_kernel(as_tpu, name, rows):
     """On a TPU, over the cells' rows of 8,192 slots: the forward pass traced
-    on abstract arguments holds a ``pallas_call`` under a mixer's ``.conv`` or
-    ``.scan`` scope exactly where that mixer's ``forms`` (what ``mechanisms``
+    on abstract arguments holds a ``pallas_call`` under a mixer's ``.conv``,
+    ``.scan`` or ``.core`` scope exactly where that mixer's ``forms`` (what ``mechanisms``
     merges into the counters the benchmark prints) say ``pallas``, and none
     where they say ``xla`` or ``scan``; as shipped, and in the cells' control
     build (bfloat16 state and gates), which asks most kernels for the other form."""
@@ -972,7 +977,8 @@ def test_the_counters_say_pallas_exactly_where_the_step_holds_a_kernel(as_tpu, n
             for counter, form in record.forms(cfg, 8192).items():
                 if not isinstance(form, str):
                     continue  # a count of tiles, no form
-                scope = f"{record.scope[0]}.{'conv' if counter == 'conv' else 'scan'}"
+                part = {"conv": "conv", "chosen_core": "core"}.get(counter, "scan")
+                scope = f"{record.scope[0]}.{part}"
                 assert any(scope in stack for stack in kernels) == (form == "pallas"), (counter, form)
                 assert form in merged[counter].split("+")
                 seen.add(form)

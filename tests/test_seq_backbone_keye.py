@@ -357,7 +357,8 @@ def test_the_layout_comes_from_sa_config(cfg, both):
     assert cfg.layer_types == ("sparse_attention",) * 2 and cfg.period_kinds == ("dsa",)
     assert (cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk) == (4, 8, TOPK)
     assert cfg.mixers() == {"dsa": 2}
-    assert bb.mechanisms(cfg, L) == {}  # one form of the choice: nothing to say
+    # the choice has one form; the core two: toy heads on the CPU take the XLA loop
+    assert bb.mechanisms(cfg, L) == {"chosen_core": "xla"}
     layer = both["grads"]["layers"][0]
     assert sorted(layer) == ["dsa", "input_norm", "moe", "post_norm"]
     assert set(ref.INDEXER) < set(layer["dsa"]) and len(layer["dsa"]) == 6 + len(ref.INDEXER)
@@ -400,7 +401,7 @@ def test_pio_train_and_predict_with_the_backbone_configuration(tmp_path, monkeyp
     model.sanity_check()
     assert model.losses[-1] < model.losses[0]
     stats = model.stats
-    assert stats["mixers"] == {"dsa": 2}
+    assert stats["mixers"] == {"dsa": 2} and stats["chosen_core"] == "xla"
     assert 50.0 < stats["dsa_kept_pairs_pct"] < 100.0  # the whole job: histories of up to 62 ids, 24 kept
     assert stats["index_loss_by_step"].shape == (30, 2, 1)
     assert stats["index_loss"] == pytest.approx(float(stats["index_loss_by_step"][-1].sum()), rel=1e-6)
