@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import chosen_core, deltanet, dsa, selscan, shortconv, ssd
+from ..ops import chosen_core, deltanet, dsa, indexer_kl, selscan, shortconv, ssd
 from ..ops.attention import attention, chosen_attention, tiles_skipped_by_window
 from ..ops.deltanet import gated_deltanet
 from ..ops.moe import expert_layer, swiglu
@@ -866,7 +866,10 @@ def _sparse_mixer(cfg: BackboneConfig, p: Dict, x, seg, pos, given, depth, mesh,
 
 
 def _sparse_forms(cfg: BackboneConfig, length: int) -> Dict:
-    return chosen_core.forms(cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, length)
+    heads = (cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim)
+    return {**chosen_core.forms(*heads, length),
+            **indexer_kl.forms(*heads, cfg.index_n_heads, cfg.index_head_dim, length,
+                               _dt(cfg.index_dtype))}
 
 
 def _sparse_check(cfg: BackboneConfig, merged: Dict) -> None:
@@ -1005,7 +1008,8 @@ class _Mixer(NamedTuple):
     of that, the newest below it. ``kept``: what its layer's recomputation
     keeps and does not make again, a name that ``checkpoint_name`` gave: the
     scan's output and the states its backward pass starts from, where the
-    scan's kernel runs. ``counts``: of ``ran``, the layer's own counters, which
+    scan's kernel runs; the two numbers a query of the indexers' loss, where
+    its kernel does. ``counts``: of ``ran``, the layer's own counters, which
     leave the layer beside its feed-forward's (a loss of the mixer's own among
     them: ``loss_fn`` adds it). ``check(cfg, merged)``: raises its refusals of
     a configuration in words (``merged``: the public file's keys and the
@@ -1071,7 +1075,7 @@ _MIXERS: Dict[str, _Mixer] = {
     # grouped-query attention over the keys a lightning indexer picks
     "dsa": _Mixer(
         words=("sparse_attention",), name="dsa", scope=("seq.attn",), shapes=_sparse_shapes,
-        widths=_sparse_widths, run=_sparse_mixer, forms=_sparse_forms,
+        widths=_sparse_widths, run=_sparse_mixer, forms=_sparse_forms, kept=indexer_kl.KEPT,
         counts=("index_loss", "kept_pairs", "causal_pairs"), check=_sparse_check),
     # differential attention inside ``sliding_window`` slots: itself and the ones before
     "swa": _Mixer(

@@ -39,6 +39,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from . import indexer_kl
 from .attention import _block_pairs, _grouped_and_padded, _opaque, _pair_meets, chosen_weights_tile
 
 #: bits of the threshold a bisection pass decides (each pass reads the strip's
@@ -258,17 +259,25 @@ def index_loss(iq, ik, iw, q, k, lse, seg, chosen, *, block: int = 512,
     """The indexer's loss over a batch of packed rows: the KL of every real
     query (seg > 0) summed [scalar], and how many they are; their quotient is
     the mean. ``q`` [B, H, L, D], ``k`` [B, Hkv, L, D] and ``lse`` [B, H, L]:
-    what ``chosen_attention`` was handed and gave, held constant here."""
+    what ``chosen_attention`` was handed and gave, held constant here. Where
+    :func:`.indexer_kl.loss_kind` says so (a TPU, main heads of whole lane
+    tiles, rows of whole kernel tiles, a float32 head-weighted sum) the
+    per-query KL is that module's Pallas kernel pair, whose tile is the
+    core's; the loop over the tiles everywhere else."""
     b, h, length, _ = q.shape
-    blk = min(block, length)
-    pad = -length % blk
-    qg, k, _, seg_p, _ = _grouped_and_padded(q, k, k, seg, blk, blk)
-    seg_p = jnp.pad(seg_p, ((0, 0), (0, pad)), mode="edge")
-    lse = jnp.pad(lse.reshape(qg.shape[:3] + (length,)), ((0, 0),) * 3 + ((0, pad),))
-    along = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))  # noqa: E731
-    chosen = jnp.pad(chosen, ((0, 0), (0, pad), (0, pad)))
-    qg, k, lse = jax.lax.stop_gradient((qg, k, lse))
-    per_query = _kl(along(iq), along(ik), along(iw), qg, k, lse, seg_p, chosen, blk,
-                    jnp.dtype(sum_dtype))[:, :length]
+    if indexer_kl.loss_kind(h, k.shape[1], q.shape[-1], iq.shape[2], iq.shape[3], length,
+                            sum_dtype) == "pallas":
+        per_query = indexer_kl.kl(iq, ik, iw, *jax.lax.stop_gradient((q, k, lse)), seg, chosen)
+    else:
+        blk = min(block, length)
+        pad = -length % blk
+        qg, k, _, seg_p, _ = _grouped_and_padded(q, k, k, seg, blk, blk)
+        seg_p = jnp.pad(seg_p, ((0, 0), (0, pad)), mode="edge")
+        lse = jnp.pad(lse.reshape(qg.shape[:3] + (length,)), ((0, 0),) * 3 + ((0, pad),))
+        along = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))  # noqa: E731
+        chosen = jnp.pad(chosen, ((0, 0), (0, pad), (0, pad)))
+        qg, k, lse = jax.lax.stop_gradient((qg, k, lse))
+        per_query = _kl(along(iq), along(ik), along(iw), qg, k, lse, seg_p, chosen, blk,
+                        jnp.dtype(sum_dtype))[:, :length]
     real = seg > 0
     return jnp.where(real, per_query, 0.0).sum(), real.sum()

@@ -375,14 +375,16 @@ def test_sharded_half_step_2x2(topo):
 @pytest.fixture
 def as_tpu(monkeypatch):
     """Code that asks for the backend is told "tpu" (here it is the CPU),
-    and the delta rule and the sparse-attention core, whose jits keep a trace
-    made under one answer for the next caller with the same shapes, are traced
-    anew on both sides."""
+    and the delta rule, the sparse-attention core and the indexers' loss, whose
+    jits keep a trace made under one answer for the next caller with the same
+    shapes, are traced anew on both sides."""
     from predictionio_tpu.ops.attention import chosen_attention
     from predictionio_tpu.ops.deltanet import gated_delta_rule
+    from predictionio_tpu.ops.dsa import index_loss
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    kept = (gated_delta_rule, chosen_attention)  # jits that ask for the backend while traced
+    # jits that ask for the backend while traced
+    kept = (gated_delta_rule, chosen_attention, index_loss)
     for fn in kept:
         fn.clear_cache()
     yield
@@ -1025,6 +1027,10 @@ def test_keye_step_one_row_of_16k_fits_the_chip(one_chip, as_tpu):
     on the kernel pair of ``ops/chosen_core.py`` (PR 46) ``memory_analysis()``
     counts 11.88 GB: the loop's three float32 carries of q's size go, a byte a
     pair of mask comes, and the step stands about 1.0 GiB lower, near 14.0 GiB.
+    With the indexers' loss on the kernel pair of ``ops/indexer_kl.py`` (PR 48)
+    it counts 11.92 GB: the loop's float32 carries (``d_iq`` [16384, 16, 64],
+    ``d_ik``, ``d_iw`` and three [16384]) go, the kernels' own mask a byte a
+    pair and the kept forward's two [16384] a layer come.
     The first reading decides how many layers the
     cut keeps (``conf/backbones/keye-vl2-30b-a3b-ep8.json``: six; five had it
     not fit); on the chip the step runs (``PERF.md`` section 4)."""
@@ -1047,7 +1053,7 @@ def test_keye_step_one_row_of_16k_fits_the_chip(one_chip, as_tpu):
     assert (stats.argument_size_in_bytes + stats.temp_size_in_bytes
             - stats.alias_size_in_bytes) <= 15.75 * 2**30
     assert cfg.mixers() == {"dsa": 6}
-    assert bb.mechanisms(cfg, length)["chosen_core"] == "pallas"
+    assert bb.mechanisms(cfg, length) == {"chosen_core": "pallas", "index_kl": "pallas"}
     text = compiled.as_text()
     for scope in ("seq.attn.index", "seq.attn.select", "seq.attn.core", "seq.attn.index_loss"):
         assert scope in text
@@ -1055,3 +1061,11 @@ def test_keye_step_one_row_of_16k_fits_the_chip(one_chip, as_tpu):
     core = [line for line in text.splitlines()
             if 'custom_call_target="tpu_custom_call"' in line and "seq.attn.core" in line]
     assert core and all("chosen_core_" in line for line in core)
+    # and so is the indexers' loss: forward once (kept over the layer's recomputation) and
+    # backward once a layer, under the loss's scope, with no loop over the tiles left there
+    loss = [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line and "seq.attn.index_loss" in line]
+    assert sorted(line.split()[0].lstrip("%").split(".")[0] for line in loss) == [
+        "index_kl_backward", "index_kl_forward"]
+    assert not [line for line in text.splitlines()
+                if " while(" in line and "seq.attn.index_loss" in line]

@@ -938,16 +938,17 @@ def _pallas_calls(jaxpr, above=""):
 
 @pytest.fixture
 def as_tpu(monkeypatch):
-    """Code that asks for the backend is told "tpu"; the delta rule and the
-    sparse-attention core, whose jits keep a trace made under one answer, are
-    traced anew on both sides."""
+    """Code that asks for the backend is told "tpu"; the delta rule, the
+    sparse-attention core and the indexers' loss, whose jits keep a trace made
+    under one answer, are traced anew on both sides."""
     from predictionio_tpu.ops.attention import chosen_attention
+    from predictionio_tpu.ops.dsa import index_loss
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    for fn in (deltanet.gated_delta_rule, chosen_attention):
+    for fn in (deltanet.gated_delta_rule, chosen_attention, index_loss):
         fn.clear_cache()
     yield
-    for fn in (deltanet.gated_delta_rule, chosen_attention):
+    for fn in (deltanet.gated_delta_rule, chosen_attention, index_loss):
         fn.clear_cache()
 
 
@@ -957,7 +958,7 @@ def as_tpu(monkeypatch):
 def test_the_counters_say_pallas_exactly_where_the_step_holds_a_kernel(as_tpu, name, rows):
     """On a TPU, over the cells' rows of 8,192 slots: the forward pass traced
     on abstract arguments holds a ``pallas_call`` under a mixer's ``.conv``,
-    ``.scan`` or ``.core`` scope exactly where that mixer's ``forms`` (what ``mechanisms``
+    ``.scan``, ``.core`` or ``.index_loss`` scope exactly where that mixer's ``forms`` (what ``mechanisms``
     merges into the counters the benchmark prints) say ``pallas``, and none
     where they say ``xla`` or ``scan``; as shipped, and in the cells' control
     build (bfloat16 state and gates), which asks most kernels for the other form."""
@@ -977,7 +978,8 @@ def test_the_counters_say_pallas_exactly_where_the_step_holds_a_kernel(as_tpu, n
             for counter, form in record.forms(cfg, 8192).items():
                 if not isinstance(form, str):
                     continue  # a count of tiles, no form
-                part = {"conv": "conv", "chosen_core": "core"}.get(counter, "scan")
+                part = {"conv": "conv", "chosen_core": "core",
+                        "index_kl": "index_loss"}.get(counter, "scan")
                 scope = f"{record.scope[0]}.{part}"
                 assert any(scope in stack for stack in kernels) == (form == "pallas"), (counter, form)
                 assert form in merged[counter].split("+")

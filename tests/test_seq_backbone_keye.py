@@ -357,8 +357,9 @@ def test_the_layout_comes_from_sa_config(cfg, both):
     assert cfg.layer_types == ("sparse_attention",) * 2 and cfg.period_kinds == ("dsa",)
     assert (cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk) == (4, 8, TOPK)
     assert cfg.mixers() == {"dsa": 2}
-    # the choice has one form; the core two: toy heads on the CPU take the XLA loop
-    assert bb.mechanisms(cfg, L) == {"chosen_core": "xla"}
+    # the choice has one form; the core and the indexers' loss two: toy heads on the CPU take
+    # the XLA loops
+    assert bb.mechanisms(cfg, L) == {"chosen_core": "xla", "index_kl": "xla"}
     layer = both["grads"]["layers"][0]
     assert sorted(layer) == ["dsa", "input_norm", "moe", "post_norm"]
     assert set(ref.INDEXER) < set(layer["dsa"]) and len(layer["dsa"]) == 6 + len(ref.INDEXER)
