@@ -4,6 +4,10 @@ of a configuration.
 A configuration gives the layer pattern (``layer_types``: a mixer a layer, in
 the ``words`` of the table ``_MIXERS`` below, the one place that knows a kind
 of mixer: its words, parameters, op, scope, counters and refusals; or
+``hybrid_override_pattern``: a letter a layer, each layer ONE part, ``x <- x +
+part(norm(x))``, the part a mixer or the expert layer, which is then a record
+of the table like any mixer (``ffn`` is ``none``: no second norm, no
+feed-forward behind the mixer); or
 ``full_attention_interval``: every n-th layer is softmax attention, the
 others gated DeltaNet; 1 = all attention),
 the attention (``gqa``: grouped heads of one width; ``mla``:
@@ -17,8 +21,9 @@ multipliers (on the embedding, on both residual additions of a layer, on the
 attention scores in place of ``1 / sqrt(head)``, and the divisor of the
 logits; all 1 or absent elsewhere), the feed-forward kind (``moe``: routed
 experts of which this share holds a range, plus a shared expert where the
-file gives one; ``swiglu``; or ``gelu``), how many leading layers are dense
-instead (``first_k_dense_replace``, ``num_dense_layers``), whether a
+file gives one, each a SwiGLU or, ``expert_act`` ``relu2``, the ungated pair
+``W_d relu(W_u h)^2``; ``swiglu``; ``gelu``; or ``none``), how many leading
+layers are dense instead (``first_k_dense_replace``, ``num_dense_layers``), whether a
 multi-token-prediction module follows the last layer, and whether the head
 is the embedding. The keys are those of the public models' ``config.json``;
 what such a file does not state (norm, positions, the range of experts
@@ -132,8 +137,9 @@ class BackboneConfig:
     #: taps of the gated short convolution (``layer_types`` ``conv``)
     conv_L_cache: int = 3
     #: the Mamba-2 mixer (``layer_types`` ``mamba``): heads, their width, the
-    #: state's width a head, the taps of its convolution, and the groups
-    #: that share B and C (one: nothing else runs here). 0 = not given.
+    #: state's width a head, the taps of its convolution, and the groups of
+    #: neighbouring heads that share B and C, each with a gated norm of its
+    #: own (one: all heads share them). 0 = not given.
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
     mamba_d_state: int = 0
@@ -151,7 +157,11 @@ class BackboneConfig:
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
-    ffn: str = "gelu"  # "gelu" | "swiglu" | "moe"
+    #: the feed-forward behind every mixer; "none": a layer is ONE part (a
+    #: ``hybrid_override_pattern``), its one norm ``norm_in``
+    ffn: str = "gelu"  # "gelu" | "swiglu" | "moe" | "none"
+    #: an expert's feed-forward, routed and shared alike (``ops.moe.ACTS``)
+    expert_act: str = "swiglu"  # "swiglu" | "relu2"
     #: leading layers, outside the periods, whose feed-forward is a SwiGLU
     #: of ``intermediate_size`` whatever ``ffn`` says
     first_k_dense_replace: int = 0
@@ -212,7 +222,8 @@ class BackboneConfig:
     @property
     def period_kinds(self) -> Tuple[str, ...]:
         """One period: the shortest run of mixers that the layers after the
-        leading dense ones are whole repeats of."""
+        leading dense ones are whole repeats of (all of them, where they
+        repeat nothing: a cut of a pattern that is not periodic is one period)."""
         rest = self.kinds[self.first_k_dense_replace:]
         for p in range(1, len(rest) + 1):
             if len(rest) % p == 0 and rest == rest[:p] * (len(rest) // p):
@@ -266,11 +277,29 @@ class BackboneConfig:
         if merged.get("topk_method") == "noaux_tc" or merged.get("use_expert_bias"):
             values.setdefault("router_bias", True)
         for theirs, ours in (("num_dense_layers", "first_k_dense_replace"),
-                             ("norm_eps", "rms_norm_eps")):
+                             ("norm_eps", "rms_norm_eps"),
+                             # a ``nemotron_h`` file's words
+                             ("layer_norm_epsilon", "rms_norm_eps"),
+                             ("mamba_num_heads", "mamba_n_heads"),
+                             ("mamba_head_dim", "mamba_d_head"),
+                             ("ssm_state_size", "mamba_d_state"), ("conv_kernel", "mamba_d_conv"),
+                             ("n_groups", "mamba_n_groups"),
+                             ("moe_shared_expert_intermediate_size",
+                              "shared_expert_intermediate_size")):
             if theirs in merged:
                 values.setdefault(ours, merged[theirs])
-        if "chunk" not in merged.get("backbone", {}) and "mamba_chunk_size" in merged:
-            values["chunk"] = merged["mamba_chunk_size"]
+        for theirs in ("mamba_chunk_size", "chunk_size"):
+            if "chunk" not in merged.get("backbone", {}) and theirs in merged:
+                values["chunk"] = merged[theirs]
+        if merged.get("mlp_hidden_act") == "relu2":
+            values.setdefault("expert_act", "relu2")
+        if "hybrid_override_pattern" in merged:  # a letter a layer, each layer one part
+            pattern = merged["hybrid_override_pattern"]
+            if "-" in pattern:
+                raise ValueError("hybrid_override_pattern has '-', a dense MLP as a layer of its "
+                                 "own: the layers here are mixers and expert layers")
+            values.setdefault("layer_types", tuple(pattern))
+            values.setdefault("ffn", "none")
         if merged.get("position_embedding_type") == "nope":
             values.setdefault("positions", "none")
         # a dense Granite's feed-forward is its "shared" SwiGLU
@@ -308,6 +337,9 @@ class BackboneConfig:
                 mixer.check(cfg, merged)
         if cfg.positions not in ("learned", "rotary", "none"):
             raise ValueError(f"positions {cfg.positions!r}: learned, rotary or none")
+        if cfg.ffn == "none" and (cfg.first_k_dense_replace or cfg.num_nextn_predict_layers):
+            raise ValueError("layers of one part have no dense feed-forward to lead with "
+                             "and no prediction module")
         if cfg.num_nextn_predict_layers not in (0, 1):
             raise ValueError("one multi-token-prediction module at most")
         if cfg.attention == "mla" and not (
@@ -355,27 +387,14 @@ def _shapes(cfg: BackboneConfig, vocab: int, max_positions: int) -> Dict:
     m = cfg.intermediate_size
     gated = {"wg": ((d, m), "w"), "wu": ((d, m), "w"), "wd": ((m, d), "w")}
     if cfg.ffn == "moe":
-        f, fs = cfg.moe_intermediate_size, cfg.shared_expert_intermediate_size
-        count = cfg.experts_held[1]
-        ffn = {
-            "router": ((d, cfg.router_width), "w"),
-            "experts": {"wg": ((count, d, f), "w"), "wu": ((count, d, f), "w"),
-                        "wd": ((count, f, d), "w")},
-        }
-        if fs:
-            ffn["shared"] = {"wg": ((d, fs), "w"), "wu": ((d, fs), "w"), "wd": ((fs, d), "w")}
-            if cfg.shared_expert_gate:
-                ffn["shared_gate"] = ((d,), "w_vec")
-        if cfg.router_bias:
-            ffn["router_bias"] = ((cfg.router_width,), "zero")
+        ffn = _experts_shapes(cfg)
     elif cfg.ffn == "swiglu":
         ffn = gated
     else:
         ffn = {"mlp_in": ((d, m), "w"), "mlp_out": ((m, d), "w")}
-    periods = {
-        "norm_in": lead(norm, n, p), "norm_post": lead(norm, n, p),
-        "ffn": lead(ffn, n, p),
-    }
+    periods = {"norm_in": lead(norm, n, p)}
+    if cfg.ffn != "none":
+        periods.update(norm_post=lead(norm, n, p), ffn=lead(ffn, n, p))
     for kind in set(cfg.period_kinds):
         held, mixer = cfg.stacked(kind), _MIXERS[kind].shapes(cfg)
         periods[kind] = lead(mixer, n, held) if held else lead(mixer, n)
@@ -445,7 +464,9 @@ def layers_of(params: Dict, cfg: BackboneConfig) -> Dict:
     layout of the plain references (``testing/*_reference.py``): a layer's
     mixer under its kind (a key of ``_MIXERS``; latent attention under
     ``attn``), its feed-forward under ``moe`` or ``mlp`` (a leading dense
-    layer's always ``mlp``), an RMS norm as its one leaf and a LayerNorm as
+    layer's always ``mlp``), its norms under ``input_norm`` and ``post_norm``
+    (a layer of one part: its part under its kind, its one norm under
+    ``norm``), an RMS norm as its one leaf and a LayerNorm as
     ``{"g", "b"}``, the prediction module under ``mtp``. Works on any pytree
     of the parameters' structure: gradients too."""
     full_key = "attn" if cfg.attention == "mla" else "full"
@@ -455,8 +476,11 @@ def layers_of(params: Dict, cfg: BackboneConfig) -> Dict:
         return p["w"] if "w" in p else p
 
     def block(blk, kind="full", ffn_key=ffn_key):
+        mixer = {full_key if kind == "full" else kind: blk[kind]}
+        if "ffn" not in blk:  # a layer of one part
+            return {"norm": norm(blk["norm_in"]), **mixer}
         return {"input_norm": norm(blk["norm_in"]), "post_norm": norm(blk["norm_post"]),
-                ffn_key: blk["ffn"], full_key if kind == "full" else kind: blk[kind]}
+                ffn_key: blk["ffn"], **mixer}
 
     layers = []
     for j in range(cfg.first_k_dense_replace):
@@ -467,7 +491,7 @@ def layers_of(params: Dict, cfg: BackboneConfig) -> Dict:
         at_n = jax.tree_util.tree_map(lambda leaf, n=n: leaf[n], per)
         for j, kind in enumerate(cfg.period_kinds):
             blk = {name: jax.tree_util.tree_map(lambda leaf, j=j: leaf[j], at_n[name])
-                   for name in ("norm_in", "norm_post", "ffn")}
+                   for name in ("norm_in", "norm_post", "ffn") if name in at_n}
             blk[kind] = _mixer_of(cfg, at_n, j)
             layers.append(block(blk, kind))
     out = {"embed": params["embed"], "final_norm": norm(params["final_norm"]), "layers": layers}
@@ -560,13 +584,15 @@ def _mamba_check(cfg: BackboneConfig, merged: Dict, word: str, name: str, sizes)
     missing = [size for size in sizes if not getattr(cfg, size)]
     if missing:
         raise ValueError(f"{word} layers need {', '.join(missing)}: no default is assumed")
-    if not merged.get("mamba_conv_bias", True) or merged.get("mamba_proj_bias"):
+    if (not merged.get("mamba_conv_bias", True) or not merged.get("use_conv_bias", True)
+            or merged.get("mamba_proj_bias") or merged.get("use_bias")):
         raise ValueError(f"the {name} mixer here has a bias on its convolution "
                          "and none on its projections")
 
 
 def _mamba2_shapes(cfg: BackboneConfig) -> Dict:
-    d, mh, ns = cfg.hidden_size, cfg.mamba_n_heads, cfg.mamba_d_state
+    d, mh = cfg.hidden_size, cfg.mamba_n_heads
+    ns = cfg.mamba_n_groups * cfg.mamba_d_state  # B's and C's columns: a group after the other
     inner = mh * cfg.mamba_d_head
     return {
         "w_in": ((d, 2 * inner + 2 * ns), "w"), "w_dt": ((d, mh), "w"),
@@ -579,15 +605,18 @@ def _mamba2_shapes(cfg: BackboneConfig) -> Dict:
 
 def _mamba2_widths(cfg: BackboneConfig) -> Dict:
     return dict(heads=cfg.mamba_n_heads, head_dim=cfg.mamba_d_head, state=cfg.mamba_d_state,
-                eps=cfg.rms_norm_eps, chunk=cfg.chunk, **_dtypes(cfg))
+                eps=cfg.rms_norm_eps, chunk=cfg.chunk, groups=cfg.mamba_n_groups, **_dtypes(cfg))
 
 
 def _mamba2_check(cfg: BackboneConfig, merged: Dict) -> None:
     _mamba_check(cfg, merged, "mamba", "Mamba-2",
                  ("mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_d_conv"))
-    if cfg.mamba_n_groups != 1:
-        raise ValueError(f"mamba_n_groups is {cfg.mamba_n_groups or 'not given'}: the "
-                         "state-space scan here shares B and C among all heads (one group)")
+    if cfg.mamba_n_groups < 1 or cfg.mamba_n_heads % cfg.mamba_n_groups:
+        raise ValueError(f"mamba_n_groups is {cfg.mamba_n_groups or 'not given'}: the groups "
+                         f"that share B and C are equal runs of the {cfg.mamba_n_heads} heads")
+    # (a ``granitemoehybrid`` file's key, whose inner width the public code makes from it;
+    # a ``nemotron_h`` file's ``expand`` is not read: there the inner width is heads x
+    # head width whatever ``expand`` x hidden says)
     if "mamba_expand" in merged and (
             merged["mamba_expand"] * cfg.hidden_size != cfg.mamba_n_heads * cfg.mamba_d_head):
         raise ValueError("mamba_expand x hidden_size is not mamba_n_heads x mamba_d_head")
@@ -987,10 +1016,66 @@ def _cross_check(cfg: BackboneConfig, merged: Dict) -> None:
     _differential_check(cfg, merged)
 
 
+# the expert layer (``ops.moe``): a layer's feed-forward, or a layer of its own
+def _experts_shapes(cfg: BackboneConfig) -> Dict:
+    d, f, fs = cfg.hidden_size, cfg.moe_intermediate_size, cfg.shared_expert_intermediate_size
+    count = cfg.experts_held[1]
+
+    def expert(width, *lead):  # an expert's matrices: the gate's only where it has a gate
+        mats = {"wg": (lead + (d, width), "w"), "wu": (lead + (d, width), "w"),
+                "wd": (lead + (width, d), "w")}
+        return mats if cfg.expert_act == "swiglu" else {k: mats[k] for k in ("wu", "wd")}
+
+    ffn = {"router": ((d, cfg.router_width), "w"), "experts": expert(f, count)}
+    if fs:
+        ffn["shared"] = expert(fs)
+        if cfg.shared_expert_gate:
+            ffn["shared_gate"] = ((d,), "w_vec")
+    if cfg.router_bias:
+        ffn["router_bias"] = ((cfg.router_width,), "zero")
+    return ffn
+
+
+def _experts_widths(cfg: BackboneConfig) -> Dict:
+    return dict(first=cfg.experts_held[0], top_k=cfg.num_experts_per_tok,
+                norm_topk=cfg.norm_topk_prob, compute_dtype=_dt(cfg.compute_dtype),
+                scoring=cfg.scoring_func, scale=cfg.routed_scaling_factor,
+                norm_eps=cfg.norm_topk_eps, act=cfg.expert_act)
+
+
+#: what an expert layer's step counts (``ops.moe.expert_layer``)
+_EXPERT_COUNTS = ("expert_tokens", "absent_weight", "dropped", "passes", "router_tokens")
+
+
+def _experts(cfg: BackboneConfig, p: Dict, h, seg, pos, given, depth, mesh, schedule):
+    """The expert layer as a layer of its own. Also returns, beside the step's
+    counters, what it was handed and gave: ``moe_in``, ``moe_out`` [B, L, D]."""
+    b, l, d = h.shape
+    y, counters = expert_layer(p, h.reshape(b * l, d), **_experts_widths(cfg))
+    y = y.reshape(b, l, d)
+    return y, {**counters, "moe_in": h, "moe_out": y}
+
+
+def _experts_check(cfg: BackboneConfig, merged: Dict) -> None:
+    if cfg.ffn != "none":
+        raise ValueError("an expert layer is a layer of its own only where every layer is one "
+                         "part (a hybrid_override_pattern); elsewhere it is the layers' ffn")
+    if merged.get("mlp_bias"):
+        raise ValueError("the experts' projections carry no bias here")
+    if merged.get("n_group", 1) != 1 or merged.get("topk_group", 1) != 1:
+        raise ValueError("the router here keeps the top k of all experts: no limit by "
+                         "groups of experts (n_group, topk_group)")
+    missing = [size for size in ("router_width", "num_experts_per_tok", "moe_intermediate_size")
+               if not getattr(cfg, size)] + ([] if cfg.experts_held[1] else ["experts_held"])
+    if missing:
+        raise ValueError(f"expert layers need {', '.join(missing)}: no default is assumed")
+
+
 # -- the table ----------------------------------------------------------------
 class _Mixer(NamedTuple):
     """Everything the backbone knows about one kind of mixer. ``words``: the
-    public files' words for it in ``layer_types``. ``name``: what
+    public files' words for it in ``layer_types``, and its letter in a
+    ``hybrid_override_pattern``. ``name``: what
     ``BackboneConfig.mixers`` counts it as; empty = ``cfg.attention``.
     ``scope``: the named scopes around the mixer and its residual addition,
     outermost first; the benchmark's per-layer metrics read these names.
@@ -1009,9 +1094,10 @@ class _Mixer(NamedTuple):
     keeps and does not make again, a name that ``checkpoint_name`` gave: the
     scan's output and the states its backward pass starts from, where the
     scan's kernel runs; the two numbers a query of the indexers' loss, where
-    its kernel does. ``counts``: of ``ran``, the layer's own counters, which
-    leave the layer beside its feed-forward's (a loss of the mixer's own among
-    them: ``loss_fn`` adds it). ``check(cfg, merged)``: raises its refusals of
+    its kernel does. ``counts``: of ``ran``, the layer's own counters (those of
+    them that this step counted), which leave the layer beside its
+    feed-forward's (a loss of the mixer's own among them: ``loss_fn`` adds
+    it). ``check(cfg, merged)``: raises its refusals of
     a configuration in words (``merged``: the public file's keys and the
     ``backbone`` group's)."""
     words: Tuple[str, ...]
@@ -1056,7 +1142,8 @@ _MIXERS: Dict[str, _Mixer] = {
     # the Mamba-2 state-space mixer
     "ssm": _of_op(
         lambda: mamba2, ssd.forms, _mamba2_shapes, _mamba2_widths,
-        words=("mamba",), name="mamba2", scope=("seq.ssm",), kept="ssd", check=_mamba2_check),
+        words=("mamba", "M"), name="mamba2", scope=("seq.ssm",), kept="ssd",
+        check=_mamba2_check),
     # the Mamba-1 selective scan
     "mamba1": _of_op(
         lambda: mamba1, selscan.forms, _mamba1_shapes, _mamba1_widths,
@@ -1070,7 +1157,7 @@ _MIXERS: Dict[str, _Mixer] = {
         check=lambda cfg, merged: _handed(cfg, "gmu", "a scan output")),
     # softmax attention over the whole history: grouped, latent or differential
     "full": _Mixer(
-        words=("full_attention", "attention"), name="", scope=("seq.attn",),
+        words=("full_attention", "attention", "*"), name="", scope=("seq.attn",),
         shapes=_attention_shapes, run=_full, hands=("k", "v"), check=_attention_check),
     # grouped-query attention over the keys a lightning indexer picks
     "dsa": _Mixer(
@@ -1086,6 +1173,11 @@ _MIXERS: Dict[str, _Mixer] = {
     "cross": _Mixer(
         words=("cross_attention",), name="cross", scope=("seq.attn",), shapes=_cross_shapes,
         run=_differential_mixer, reads=("k", "v"), check=_cross_check),
+    # the expert layer where it is a layer of its own (every layer one part) and no mixer's ffn
+    "moe": _Mixer(
+        words=("E",), name="moe", scope=("seq.moe",), shapes=_experts_shapes,
+        widths=_experts_widths, run=_experts, counts=_EXPERT_COUNTS, check=_experts_check,
+        forms=lambda cfg, length: {"expert_act": cfg.expert_act}),
 }
 _KIND_OF = {word: kind for kind, mixer in _MIXERS.items() for word in mixer.words}
 #: the recomputation policy that keeps a name, ONE object a name: layers whose
@@ -1112,11 +1204,7 @@ def _ffn(cfg: BackboneConfig, p: Dict, x):
     if "router" in p:
         b, l, d = x.shape
         with jax.named_scope("seq.moe"):
-            y, counters = expert_layer(
-                p, x.reshape(b * l, d), first=cfg.experts_held[0],
-                top_k=cfg.num_experts_per_tok, norm_topk=cfg.norm_topk_prob,
-                compute_dtype=_dt(cfg.compute_dtype), scoring=cfg.scoring_func,
-                scale=cfg.routed_scaling_factor, norm_eps=cfg.norm_topk_eps)
+            y, counters = expert_layer(p, x.reshape(b * l, d), **_experts_widths(cfg))
         return y.reshape(b, l, d), counters
     if "wg" in p:
         with jax.named_scope("seq.ffn"):
@@ -1138,15 +1226,19 @@ def _layer(cfg: BackboneConfig, kind: str, mesh, schedule, x, seg, pos,
             scopes.enter_context(jax.named_scope(name))
         mixed, ran = record.run(cfg, mixer, h, seg, pos, given, depth, mesh, schedule)
         x = _add(cfg, x, mixed)
-    y, counters = _ffn(cfg, ffn, _norm(cfg, norm_post, x))
+    counters = {}
+    if cfg.ffn != "none":  # the layer's second part
+        y, counters = _ffn(cfg, ffn, _norm(cfg, norm_post, x))
+        x = _add(cfg, x, y)
     if record.counts:
-        counters = {**counters, **{name: ran.pop(name) for name in record.counts}}
-    return _add(cfg, x, y), counters, ran
+        counters = {**counters, **{name: ran.pop(name) for name in record.counts if name in ran}}
+    return x, counters, ran
 
 
 def _layer_fn(cfg: BackboneConfig, kind: str, mesh, schedule, depth: int = 0):
     """One layer whose mixer is of ``kind`` (a key of ``_MIXERS``) as ``(x, seg,
-    pos, norm_in, mixer, norm_post, ffn, given) -> x, counters, ran``, recomputed
+    pos, norm_in, mixer, norm_post, ffn, given) -> x, counters, ran`` (``norm_post``
+    and ``ffn``: None where a layer is one part), recomputed
     in the backward pass but for what its record says is ``kept``; ``given``, a
     dict: what the layer reads of the layers below it. ``depth``: the layer's
     index in the published model, which a differential layer's ``lambda``
@@ -1196,11 +1288,12 @@ def hidden_states(cfg: BackboneConfig, params: Dict, tokens, seg, mesh=None,
     def one_period(x, per):
         counters, first_ran, given = [], {}, {}
         for j, kind in enumerate(cfg.period_kinds):
-            pick = lambda tree, j=j: jax.tree_util.tree_map(lambda a: a[j], tree)  # noqa: E731
+            # (a part the layers do not have: None)
+            pick = lambda name, j=j: jax.tree_util.tree_map(lambda a: a[j], per.get(name))  # noqa: E731
             record, mixer = _MIXERS[kind], _mixer_of(cfg, per, j)
             layer = layer_of(kind, depth(cfg.first_k_dense_replace + j))
-            x, c, ran = layer(x, seg, pos, pick(per["norm_in"]), mixer, pick(per["norm_post"]),
-                              pick(per["ffn"]), {name: given[name] for name in record.reads})
+            x, c, ran = layer(x, seg, pos, pick("norm_in"), mixer, pick("norm_post"),
+                              pick("ffn"), {name: given[name] for name in record.reads})
             given.update({name: ran[name] for name in record.hands if name in ran})
             counters.append(c)
             # every name from the first mixer of the period that gives it
@@ -1309,6 +1402,21 @@ def loss_fn(cfg: BackboneConfig, params: Dict, rows, segs, mesh=None,
 
 
 # -- the routers' step --------------------------------------------------------
+def expert_sites(cfg: BackboneConfig, params: Dict):
+    """Where the expert layers' parameters lie in ``params``, each path with
+    the counter that carries its routers' loads: the periods' feed-forwards
+    (and the prediction module's block's), or the periods' expert layers where
+    they are layers of their own; none without experts."""
+    if cfg.ffn == "moe":
+        return [(("periods", "ffn"), "router_tokens")] + (
+            [(("mtp", "block", "ffn"), "mtp_router_tokens")] if "mtp" in params else [])
+    return [(("periods", "moe"), "router_tokens")] if "moe" in cfg.period_kinds else []
+
+
+def at_path(tree: Dict, path):
+    return functools.reduce(lambda sub, key: sub[key], path, tree)
+
+
 def step_routers(cfg: BackboneConfig, before: Dict, after: Dict, counters: Dict) -> Dict:
     """``after`` (the parameters an optimizer step made of ``before``) with
     what that step does not decide about the routers put right. Every
@@ -1318,7 +1426,8 @@ def step_routers(cfg: BackboneConfig, before: Dict, after: Dict, counters: Dict)
     expert at exactly the mean is left where it is (sign 0). Where
     ``router_trains`` is off every router's matrix is ``before``'s. With
     neither, ``after`` as it is."""
-    if cfg.ffn != "moe" or (cfg.router_trains and not cfg.router_bias):
+    sites = expert_sites(cfg, after)
+    if not sites or (cfg.router_trains and not cfg.router_bias):
         return after
 
     def stepped(bias, tokens):
@@ -1328,12 +1437,9 @@ def step_routers(cfg: BackboneConfig, before: Dict, after: Dict, counters: Dict)
     def put(tree, path, leaf):
         return leaf if not path else {**tree, path[0]: put(tree[path[0]], path[1:], leaf)}
 
-    sites = [(("periods", "ffn"), "router_tokens")]
-    if "mtp" in after:
-        sites.append((("mtp", "block", "ffn"), "mtp_router_tokens"))
     with jax.named_scope("seq.router_bias"):
         for path, counted in sites:
-            ffn = functools.reduce(lambda tree, key: tree[key], path, before)
+            ffn = at_path(before, path)
             if cfg.router_bias:
                 after = put(after, path + ("router_bias",),
                             stepped(ffn["router_bias"], counters[counted]))

@@ -534,11 +534,11 @@ class SeqRecAlgorithm(Algorithm):
             stats.update(_choice_counts({name: stats[name + "_by_step"] for name in by_step}))
             counts = _pass_counts(stats)
             stats.update({name: counts[name] for name in counts if name != "passes_by_step"})
-            if cfg.router_bias and cfg.ffn == "moe":
-                blocks = [host_params["periods"]] + (
-                    [host_params["mtp"]["block"]] if "mtp" in host_params else [])
+            sites = bb.expert_sites(cfg, host_params)
+            if cfg.router_bias and sites:
                 stats["router_bias_abs_max"] = max(
-                    float(np.abs(blk["ffn"]["router_bias"]).max()) for blk in blocks)
+                    float(np.abs(bb.at_path(host_params, path)["router_bias"]).max())
+                    for path, _ in sites)
         return SeqRecModel(
             params=host_params, item_map=pd.item_map, user_recent=pd.user_recent,
             seq_len=pd.seq_len, config=cfg, losses=host_losses, stats=stats,

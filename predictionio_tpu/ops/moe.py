@@ -14,6 +14,10 @@ has one, the shared expert (behind a sigmoid gate where the layer has a
 ``shared_gate``), which every share computes alike. What the absent experts
 would add is left out; no code stands in for the chips that hold them.
 
+An expert, routed or shared, is one of two feed-forwards (``act``):
+``swiglu``, three matrices, ``W_d (silu(W_g h) * W_u h)``; or ``relu2``, two,
+``W_d relu(W_u h)^2``, no gate (the square is float32's, before the cast).
+
 No assignment is dropped. Shapes are static, so the sorted assignments are
 taken ``pass_rows`` at a time (default: twice this share's mean load), in
 as many passes as all of a step's assignments could need. The usual step
@@ -52,6 +56,19 @@ def swiglu(w: Dict, x, cd):
                    preferred_element_type=f32)
 
 
+def relu2(w: Dict, x, cd):
+    """``W_d relu(W_u x)^2``: the ungated feed-forward (``wu``, ``wd``)."""
+    f32 = jnp.float32
+    up = jnp.dot(x.astype(cd), w["wu"].astype(cd), preferred_element_type=f32)
+    return jnp.dot(jnp.square(jax.nn.relu(up)).astype(cd), w["wd"].astype(cd),
+                   preferred_element_type=f32)
+
+
+#: an expert's feed-forward by its name; the routed experts' passes run the
+#: same arithmetic as grouped products (:func:`_held_pass`)
+ACTS = {"swiglu": swiglu, "relu2": relu2}
+
+
 def route(x, router, top_k: int, norm_topk: bool = True, scoring: str = "softmax",
           bias=None, scale: float = 1.0, norm_eps: float = 1e-20):
     """x [T, D], router [D, E] -> expert ids [T, k] and weights [T, k]
@@ -75,7 +92,7 @@ def route(x, router, top_k: int, norm_topk: bool = True, scoring: str = "softmax
     return idx, top * scale
 
 
-def _held_pass(x, experts: Dict, take, group_sizes, weights, top_k: int, cd):
+def _held_pass(x, experts: Dict, take, group_sizes, weights, top_k: int, cd, act: str):
     """``take``: sorted assignments (token * k + slot) of one pass, the
     first ``group_sizes.sum()`` of them held. The rows past those lie past
     the last group, where the chip's grouped product writes NOTHING,
@@ -89,11 +106,13 @@ def _held_pass(x, experts: Dict, take, group_sizes, weights, top_k: int, cd):
     with jax.named_scope("seq.moe.dispatch"):
         xs = jnp.where(live, x.astype(cd)[tok], 0)
     with jax.named_scope("seq.moe.experts"):
-        gate = jax.lax.ragged_dot(xs, experts["wg"].astype(cd), group_sizes,
-                                  preferred_element_type=f32)
+        if act == "swiglu":
+            gate = jax.lax.ragged_dot(xs, experts["wg"].astype(cd), group_sizes,
+                                      preferred_element_type=f32)
         up = jax.lax.ragged_dot(xs, experts["wu"].astype(cd), group_sizes,
                                 preferred_element_type=f32)
-        hidden = jnp.where(live, jax.nn.silu(gate) * up, 0.0)
+        hidden = jnp.where(
+            live, jax.nn.silu(gate) * up if act == "swiglu" else jnp.square(jax.nn.relu(up)), 0.0)
         ys = jax.lax.ragged_dot(hidden.astype(cd), experts["wd"].astype(cd),
                                 group_sizes, preferred_element_type=f32)
     with jax.named_scope("seq.moe.combine"):
@@ -112,12 +131,12 @@ def _one_pass(static, x, experts, flat_w, order, group_sizes, start):
     tokens' sums and how many rows its mask let through. A jitted function
     (as its pull-back below), so that it is traced and lowered once for a
     shape, whichever layer, branch or direction asks for it."""
-    rows, _, top_k, cd = static
+    rows, _, top_k, cd, act = static
     take = jax.lax.dynamic_slice_in_dim(order, start, rows)
     # what of every group lies inside [start, start + rows)
     ends = jnp.cumsum(group_sizes)
     inside = jnp.clip(ends - start, 0, rows) - jnp.clip(ends - group_sizes - start, 0, rows)
-    return _held_pass(x, experts, take, inside, flat_w, top_k, cd)
+    return _held_pass(x, experts, take, inside, flat_w, top_k, cd, act)
 
 
 def _over_passes(static, n_held, one):
@@ -145,8 +164,8 @@ def _over_passes(static, n_held, one):
 def _passes(static, x, experts, flat_w, order, group_sizes):
     """Every held assignment through its experts, ``rows`` sorted
     assignments a pass: the tokens' sums [T, D] and the rows the passes'
-    masks let through. ``static``: ``rows``, ``passes``, ``top_k`` and the
-    compute dtype. Its gradient is the sum of the passes' own, each
+    masks let through. ``static``: ``rows``, ``passes``, ``top_k``, the
+    compute dtype and ``act``. Its gradient is the sum of the passes' own, each
     recomputed where it is pulled back: nothing is kept from the forward
     pass but the arguments, and no cotangent is carried through a pass that
     does not run."""
@@ -179,17 +198,20 @@ _passes.defvjp(_passes_fwd, _passes_bwd)
 
 def expert_layer(p: Dict, x, *, first: int, top_k: int, norm_topk: bool = True,
                  pass_rows: int = 0, compute_dtype=jnp.float32, scoring: str = "softmax",
-                 scale: float = 1.0, norm_eps: float = 1e-20) -> Tuple[jax.Array, Dict]:
+                 scale: float = 1.0, norm_eps: float = 1e-20,
+                 act: str = "swiglu") -> Tuple[jax.Array, Dict]:
     """x [T, D] (normed) -> y [T, D] float32 and the step's counters.
-    ``p``: ``router`` [D, E] and ``experts`` (``wg``, ``wu`` [.., D, F],
-    ``wd`` [.., F, D], with a leading [count] axis: the experts ``first ..
-    first + count - 1``); where the layer has them, ``shared`` (one expert
-    every token goes through), ``shared_gate`` [D] and ``router_bias`` [E]
-    (then the counters also give ``router_tokens`` [E]: the tokens of every
+    ``p``: ``router`` [D, E] and ``experts`` (``wg`` unless ``act`` is
+    ``relu2``, ``wu`` [.., D, F], ``wd`` [.., F, D], with a leading [count]
+    axis: the experts ``first .. first + count - 1``); where the layer has
+    them, ``shared`` (one expert every token goes through), ``shared_gate``
+    [D] and ``router_bias`` [E] (then the counters also give ``router_tokens`` [E]: the tokens of every
     expert, held or not)."""
+    if act not in ACTS:
+        raise ValueError(f"unknown expert activation {act!r}: {' or '.join(ACTS)}")
     x = jnp.asarray(x)
     tokens = x.shape[0]
-    count = p["experts"]["wg"].shape[0]
+    count = p["experts"]["wu"].shape[0]
     n_experts = p["router"].shape[1]
     cd = compute_dtype
     all_rows = tokens * min(top_k, count)  # a token's k experts are distinct
@@ -207,15 +229,15 @@ def expert_layer(p: Dict, x, *, first: int, top_k: int, norm_topk: bool = True,
         n_held = group_sizes.sum()
         flat_w = weights.reshape(-1)
 
-    y, combined = _passes((rows, passes, top_k, cd), x, p["experts"], flat_w, order, group_sizes)
+    y, combined = _passes((rows, passes, top_k, cd, act), x, p["experts"], flat_w, order, group_sizes)
     if "shared" in p:
         with jax.named_scope("seq.moe.shared"):
             if "shared_gate" in p:
                 gate = jax.nn.sigmoid(
                     jnp.dot(x.astype(jnp.float32), p["shared_gate"], precision=_HI))
-                y = y + gate[:, None] * swiglu(p["shared"], x, cd)
+                y = y + gate[:, None] * ACTS[act](p["shared"], x, cd)
             else:
-                y = y + swiglu(p["shared"], x, cd)
+                y = y + ACTS[act](p["shared"], x, cd)
     counters = {
         "expert_tokens": group_sizes,
         "absent_weight": jnp.where(held, 0.0, weights).sum() / (tokens * scale),

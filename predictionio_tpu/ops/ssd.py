@@ -6,15 +6,17 @@ a history's first slot, ``A = -exp(A_log)`` and ``dt`` the softplus'd step::
 
     S_t = exp(dt_t A) S_(t-1) + dt_t u_t (x) B_t;   y_t = S_t C_t
 
-``B_t`` and ``C_t`` [N] are shared by all heads (one group). No correction
-of the state by what it already holds: no triangular system, unlike the
-delta rule (:mod:`.deltanet`).
+``B_t`` and ``C_t`` [N] are shared by the heads of a group: ``groups`` groups
+of ``H / groups`` neighbouring heads, head i reading group ``i // (H /
+groups)`` (one group: all heads share them). No correction of the state by
+what it already holds: no triangular system, unlike the delta rule
+(:mod:`.deltanet`).
 
 :func:`ssd_scan` computes that in chunks of ``chunk`` slots (the state-space
 duality form). Inside a chunk slot i reads slot j <= i of its own history
 through ``exp(cum_i - cum_j) * (C_i . B_j)``, cum the running sum of ``dt A``
-inside the chunk; the scores ``C B^T`` are made once for all heads, the
-decay matrix a head. The decay is always the exponential of a DIFFERENCE
+inside the chunk; the scores ``C B^T`` are made once for the heads of a group,
+the decay matrix a head. The decay is always the exponential of a DIFFERENCE
 (never ``exp(cum_i) * exp(-cum_j)``), so nothing overflows however fast a
 head forgets. Every chunk writes ``sum_j e_last_j dt_j u_j (x) B_j`` to the
 state at its last slot (``e_last``: what of slot j is still there), and the
@@ -27,10 +29,12 @@ whole lane tiles, float32 state and gates): a Pallas kernel pair behind a
 custom VJP that walks a row's chunks in order, ``_HEADS`` heads a grid step,
 with the states of all heads in VMEM from chunk to chunk ([N, H P] float32,
 2 MB at 64 heads of 64 on a state of 128). ``u`` and ``y`` are read and
-written as they lie ([L, H P]: a lane tile holds two heads of 64); the
-scores and the mask (same history, not above the diagonal) are made once a
-chunk; a head's [C, C] decay matrix, its product with the scores and ``dt
-u`` exist in VMEM alone. What is per slot AND head (``dt``, the running sum,
+written as they lie ([L, H P]: a lane tile holds two heads of 64); a grid
+step's heads lie in ONE group, whose ``B`` and ``C`` it is handed by block
+index ([L, G N]: group g's N columns); the scores and the mask (same history,
+not above the diagonal) are made once a chunk and group; a head's [C, C]
+decay matrix, its product with the scores and ``dt u`` exist in VMEM
+alone. What is per slot AND head (``dt``, the running sum,
 ``e_in``, ``e_last``: 2 MB each a row of 8,192) is made by XLA
 (``seq.ssm.scan.prep``) and handed over with the slots on the sublanes and
 as rows. The forward kernel writes ``y`` and the state ENTERING every chunk
@@ -38,7 +42,7 @@ as rows. The forward kernel writes ``y`` and the state ENTERING every chunk
 beside the inputs. The backward kernel takes the chunks last to first
 carrying the state's cotangent, makes a head's matrices again (transposed:
 slot j on the sublanes) and writes the cotangents of ``u``, of ``B`` and
-``C`` (summed over all heads in VMEM) and, a slot and head, of ``dt``
+``C`` (summed over a group's heads in VMEM) and, a slot and head, of ``dt``
 (through ``dt u``), ``e_in`` and ``e_last`` (sums over a head's lanes, made
 as products with a 0/1 matrix) and of the running sum: the row sums less the
 column sums of ONE matrix a head, the decay times its cotangent, so that
@@ -53,7 +57,8 @@ the 0/1 matrix) the other is split into three bfloat16 terms, three passes
 where ``HIGHEST`` makes six.
 
 XLA's batch products (the CPU, toy widths, the control build's bfloat16
-state), with no loop over slots and none over chunks, in three phases after
+state), a group at a time (``vmap`` over the groups where there are several),
+with no loop over slots and none over chunks, in three phases after
 a preparation (``seq.ssm.scan.prep``: the layouts into chunks, ``dt u`` and
 the running sums). Local (``seq.ssm.scan.local``): the decay matrix a head
 and chunk times the scores, one batch product with ``dt u``, recomputed in
@@ -73,7 +78,8 @@ its first slot, its last, or anywhere between (in either form).
 C]`` and ``dt``, a depthwise causal convolution with a bias over ``x B C``
 (a tap in another history reads zero: :func:`.shortconv.conv_chain`),
 SiLU, the scan, the skip ``D * u``, the gated RMS norm ``rms(y *
-silu(z)) * w`` over the whole inner width, out-projection.
+silu(z)) * w`` over each group's share of the inner width (one group: the
+whole of it), out-projection.
 
 Precision (both forms): ``dt``, ``dt A`` and its running sums are
 ``gate_dtype`` (float32: they feed exponentials), the state ``state_dtype``
@@ -111,10 +117,12 @@ def _segsum(x):
     return jnp.cumsum(jnp.where(below, rows, 0), axis=-2)
 
 
-def ssd_scan(u, dt, a, b, c, seg, *, chunk: int = 256, compute_dtype=jnp.float32,
-             state_dtype=jnp.float32, gate_dtype=jnp.float32, interpret: bool = False):
+def ssd_scan(u, dt, a, b, c, seg, *, chunk: int = 256, groups: int = 1,
+             compute_dtype=jnp.float32, state_dtype=jnp.float32, gate_dtype=jnp.float32,
+             interpret: bool = False):
     """u [B, L, H, P], dt [B, L, H] (after the softplus), a [H] (negative),
-    b, c [B, L, N], seg [B, L] -> y [B, L, H, P] float32 (without the skip
+    b, c [B, L, G N] (group g's N numbers side by side; head i reads group ``i
+    // (H / G)``), seg [B, L] -> y [B, L, H, P] float32 (without the skip
     ``D * u``). The kernel's walk or XLA's batch products: :func:`scan_kind`."""
     bsz, length, heads, p = u.shape
     pad = -length % chunk
@@ -129,78 +137,96 @@ def ssd_scan(u, dt, a, b, c, seg, *, chunk: int = 256, compute_dtype=jnp.float32
         dtc = dt.astype(gate_dtype).reshape(bsz, n, chunk, heads)
         # log decay a slot, and its running sum inside the chunk (inclusive), [B, n, H, C]
         cum = jnp.cumsum(jnp.moveaxis(dtc * a.astype(gate_dtype), 2, 3), axis=-1)
-    if scan_kind(heads, p, b.shape[-1], length, chunk, state_dtype, gate_dtype,
-                 interpret) == "pallas":
+    if scan_kind(heads, p, b.shape[-1] // groups, length, chunk, state_dtype, gate_dtype,
+                 interpret, groups) == "pallas":
         with jax.named_scope("seq.ssm.scan.prep"):
             decays = _decays(cum, seg)
-        y = _walk((interpret, chunk), u.reshape(bsz, -1, heads * p), dtc, cum, *decays,
+        y = _walk((interpret, chunk, groups), u.reshape(bsz, -1, heads * p), dtc, cum, *decays,
                   b.astype(cd), c.astype(cd), seg)
         return y.reshape(bsz, length + pad, heads, p)[:, :length]
-    with jax.named_scope("seq.ssm.scan.prep"):
-        uc = u.reshape(bsz, n, chunk, heads, p)
-        bc, cc = (t.astype(cd).reshape(bsz, n, chunk, -1) for t in (b, c))
-        x = dtc[..., None] * uc.astype(gate_dtype)  # dt u, [B, n, C, H, P]
-    prev_last = jnp.pad(sc[:, :-1, -1], ((0, 0), (1, 0)), constant_values=-3)  # [B, n]
 
-    @jax.checkpoint
-    def local(cum, x, bc, cc):
-        with jax.named_scope("seq.ssm.scan.local"):
-            same = sc[..., :, None] == sc[..., None, :]  # [B, n, C, C]
-            reads = (same & jnp.tril(jnp.ones((chunk, chunk), bool)))[:, :, None]
-            diff = (cum[..., :, None] - cum[..., None, :]).astype(f32)
-            decay = jnp.exp(jnp.where(reads, diff, -jnp.inf))  # [B, n, H, C, C]
-            scores = jnp.einsum("bnis,bnjs->bnij", cc, bc, preferred_element_type=f32)
-            m = (scores[:, :, None] * decay).astype(cd)
-            return jnp.einsum("bnhij,bnjhp->bnihp", m, x.astype(cd), preferred_element_type=f32)
+    def one_group(u, dtc, cum, b, c):
+        """The heads that share ``b`` and ``c`` [B, L, N]: u [B, L, H, P], dtc [B,
+        n, C, H], cum [B, n, H, C] -> y [B, n, C, H, P]."""
+        with jax.named_scope("seq.ssm.scan.prep"):
+            uc = u.reshape(bsz, n, chunk, -1, p)
+            bc, cc = (t.astype(cd).reshape(bsz, n, chunk, -1) for t in (b, c))
+            x = dtc[..., None] * uc.astype(gate_dtype)  # dt u, [B, n, C, H, P]
+        prev_last = jnp.pad(sc[:, :-1, -1], ((0, 0), (1, 0)), constant_values=-3)  # [B, n]
 
-    y = local(cum, x, bc, cc)
+        @jax.checkpoint
+        def local(cum, x, bc, cc):
+            with jax.named_scope("seq.ssm.scan.local"):
+                same = sc[..., :, None] == sc[..., None, :]  # [B, n, C, C]
+                reads = (same & jnp.tril(jnp.ones((chunk, chunk), bool)))[:, :, None]
+                diff = (cum[..., :, None] - cum[..., None, :]).astype(f32)
+                decay = jnp.exp(jnp.where(reads, diff, -jnp.inf))  # [B, n, H, C, C]
+                scores = jnp.einsum("bnis,bnjs->bnij", cc, bc, preferred_element_type=f32)
+                m = (scores[:, :, None] * decay).astype(cd)
+                return jnp.einsum("bnhij,bnjhp->bnihp", m, x.astype(cd),
+                                  preferred_element_type=f32)
 
-    with jax.named_scope("seq.ssm.scan.state"):
-        # what of slot j is still there at the chunk's last slot
-        to_last = (sc == sc[..., -1:])[:, :, None]  # [B, n, 1, C]
-        e_last = jnp.exp(jnp.where(to_last, (cum[..., -1:] - cum).astype(f32), -jnp.inf))
-        xe = (x.astype(f32) * jnp.moveaxis(e_last, 2, 3)[..., None]).astype(cd)
-        wrote = jnp.einsum("bnjhp,bnjs->bnhps", xe, bc,
-                           preferred_element_type=f32).astype(state_dtype)
-        # the state after chunk i: what chunk m <= i wrote, decayed over the
-        # chunks between, unless a history ended in one of them
-        cont = sc[..., -1] == prev_last  # chunk k ends inside the history it was handed
-        breaks = jnp.cumsum(~cont, axis=-1)  # [B, n]
-        whole = (breaks[..., :, None] == breaks[..., None, :]) & jnp.tril(jnp.ones((n, n), bool))
-        total = jnp.moveaxis(cum[..., -1], 1, 2)  # [B, H, n]: a chunk's whole log decay
-        carry = jnp.exp(jnp.where(whole[:, None], _segsum(total).astype(f32), -jnp.inf))
-        after = _with_state("bhim,bmhps->bihps", carry, wrote).astype(state_dtype)
-        incoming = jnp.pad(after[:, :-1], ((0, 0), (1, 0)) + ((0, 0),) * 3)  # [B, n, H, P, N]
+        y = local(cum, x, bc, cc)
 
-    with jax.named_scope("seq.ssm.scan.out"):
-        carried = (sc == prev_last[..., None])[:, :, None]  # [B, n, 1, C]: sees the incoming state
-        e_in = jnp.exp(jnp.where(carried, cum.astype(f32), -jnp.inf))  # [B, n, H, C]
-        read = _with_state("bnis,bnhps->bnihp", cc, incoming)
-        y = y + read * jnp.moveaxis(e_in, 2, 3)[..., None]
+        with jax.named_scope("seq.ssm.scan.state"):
+            # what of slot j is still there at the chunk's last slot
+            to_last = (sc == sc[..., -1:])[:, :, None]  # [B, n, 1, C]
+            e_last = jnp.exp(jnp.where(to_last, (cum[..., -1:] - cum).astype(f32), -jnp.inf))
+            xe = (x.astype(f32) * jnp.moveaxis(e_last, 2, 3)[..., None]).astype(cd)
+            wrote = jnp.einsum("bnjhp,bnjs->bnhps", xe, bc,
+                               preferred_element_type=f32).astype(state_dtype)
+            # the state after chunk i: what chunk m <= i wrote, decayed over the
+            # chunks between, unless a history ended in one of them
+            cont = sc[..., -1] == prev_last  # chunk k ends inside the history it was handed
+            breaks = jnp.cumsum(~cont, axis=-1)  # [B, n]
+            whole = (breaks[..., :, None] == breaks[..., None, :]) & jnp.tril(
+                jnp.ones((n, n), bool))
+            total = jnp.moveaxis(cum[..., -1], 1, 2)  # [B, H, n]: a chunk's whole log decay
+            carry = jnp.exp(jnp.where(whole[:, None], _segsum(total).astype(f32), -jnp.inf))
+            after = _with_state("bhim,bmhps->bihps", carry, wrote).astype(state_dtype)
+            incoming = jnp.pad(after[:, :-1], ((0, 0), (1, 0)) + ((0, 0),) * 3)  # [B, n, H, P, N]
+
+        with jax.named_scope("seq.ssm.scan.out"):
+            carried = (sc == prev_last[..., None])[:, :, None]  # [B, n, 1, C]: sees the incoming state
+            e_in = jnp.exp(jnp.where(carried, cum.astype(f32), -jnp.inf))  # [B, n, H, C]
+            read = _with_state("bnis,bnhps->bnihp", cc, incoming)
+            return y + read * jnp.moveaxis(e_in, 2, 3)[..., None]
+
+    if groups == 1:
+        y = one_group(u, dtc, cum, b, c)
+    else:  # a group's heads are neighbours: [.., H, ..] -> [.., G, H / G, ..]
+        def split(t, axis):
+            return t.reshape(t.shape[:axis] + (groups, -1) + t.shape[axis + 1:])
+
+        y = jax.vmap(one_group, in_axes=(2, 3, 2, 2, 2), out_axes=3)(
+            split(u, 2), split(dtc, 3), split(cum, 2), split(b, 2), split(c, 2))
     return y.reshape(bsz, length + pad, heads, p)[:, :length]
 
 
-def _tile(heads: int, head_dim: int) -> int:
+def _tile(heads: int, head_dim: int, groups: int = 1) -> int:
     """Heads of a grid step: the most, up to ``_HEADS`` (and a quarter of a
     lane tile: the backward kernel's four sums a slot and head lie side by
-    side in one), that divide the heads and fill whole lane tiles; 0 where no
-    number does."""
+    side in one), that divide a group's heads and fill whole lane tiles; 0
+    where no number does."""
     return max((d for d in range(1, min(_HEADS, _LANES // 4) + 1)
-                if heads % d == 0 and d * head_dim % _LANES == 0), default=0)
+                if heads % groups == 0 and (heads // groups) % d == 0
+                and d * head_dim % _LANES == 0), default=0)
 
 
 def scan_kind(heads: int, head_dim: int, state: int, length: int, chunk: int,
-              state_dtype=jnp.float32, gate_dtype=jnp.float32, interpret: bool = False) -> str:
+              state_dtype=jnp.float32, gate_dtype=jnp.float32, interpret: bool = False,
+              groups: int = 1) -> str:
     """What implements :func:`ssd_scan` at these shapes: "pallas" (the
     kernel pair's walk over a row's chunks, a chunk's decay matrices and the
     state in VMEM; its backward pass keeps the state entering every chunk)
-    where a lane tile holds whole heads and a grid step's heads
-    whole lane tiles, the state's width and the chunk are whole lane tiles
+    where a lane tile holds whole heads and a grid step's heads, all of one
+    of the ``groups`` groups, whole lane tiles, the state's width and the chunk
+    are whole lane tiles
     (a row is padded to whole chunks in either form), state and gates
     float32 and the backend a TPU (``interpret``: or the kernel's
     interpreter, for tests); "xla" (batch products over all chunks that the
     compiler schedules) otherwise."""
-    whole = (head_dim > 0 and _LANES % head_dim == 0 and _tile(heads, head_dim) > 0
+    whole = (head_dim > 0 and _LANES % head_dim == 0 and _tile(heads, head_dim, groups) > 0
              and state % _LANES == 0 and chunk % _LANES == 0 and state > 0 and length > 0)
     f32 = all(jnp.dtype(t) == jnp.float32 for t in (state_dtype, gate_dtype))
     return "pallas" if whole and f32 and (interpret or jax.default_backend() == "tpu") else "xla"
@@ -271,11 +297,21 @@ def _shared(seg_col_ref, seg_row_ref, b_ref, c_ref, transposed: bool):
     return scores, jnp.where(reads, 0.0, -jnp.inf)
 
 
-def _forward_kernel(hb, p, u_ref, cols_ref, rows_ref, b_ref, c_ref, seg_col_ref, seg_row_ref,
-                    g_ref, y_ref, kept_ref, s_ref, scores_ref, bias_ref):
-    """One chunk for ``hb`` heads (the grid: rows, chunks in order, tiles of
-    heads). ``cols_ref`` [C, 4 hb]: ``dt``, the running sum, ``e_in``,
-    ``e_last`` a slot and head; ``rows_ref`` [hb, C]: the running sum;
+def _group_end(per_group: int, last: bool):
+    """Whether this grid step's tile of heads is its group's first (``last``:
+    its last), ``per_group`` tiles a group (0: one group, all the tiles)."""
+    tile = pl.program_id(2)
+    if not per_group:
+        return tile == pl.num_programs(2) - 1 if last else tile == 0
+    return tile % per_group == (per_group - 1 if last else 0)
+
+
+def _forward_kernel(hb, p, per_group, u_ref, cols_ref, rows_ref, b_ref, c_ref, seg_col_ref,
+                    seg_row_ref, g_ref, y_ref, kept_ref, s_ref, scores_ref, bias_ref):
+    """One chunk for ``hb`` heads of one group (the grid: rows, chunks in
+    order, tiles of heads; ``b_ref``, ``c_ref``: the group's). ``cols_ref``
+    [C, 4 hb]: ``dt``, the running sum, ``e_in``, ``e_last`` a slot and head;
+    ``rows_ref`` [hb, C]: the running sum;
     ``g_ref`` [1, hb P]: what of the incoming state reaches the next chunk;
     ``s_ref`` [tiles, N, hb P]: the states, transposed; ``kept_ref`` takes
     the tile's state entering the chunk."""
@@ -287,7 +323,7 @@ def _forward_kernel(hb, p, u_ref, cols_ref, rows_ref, b_ref, c_ref, seg_col_ref,
     def _():
         s_ref[tile] = jnp.zeros(s_ref.shape[1:], f32)
 
-    @pl.when(tile == 0)
+    @pl.when(_group_end(per_group, False))
     def _():
         scores_ref[...], bias_ref[...] = _shared(seg_col_ref, seg_row_ref, b_ref, c_ref, False)
 
@@ -309,21 +345,21 @@ def _forward_kernel(hb, p, u_ref, cols_ref, rows_ref, b_ref, c_ref, seg_col_ref,
         s_ref[tile, :, at] = g_ref[:, at] * s + wrote
 
 
-def _backward_kernel(hb, p, u_ref, cols_ref, rows_ref, b_ref, c_ref, seg_col_ref, seg_row_ref,
-                     g_ref, kept_ref, dy_ref, du_ref, sums_ref, reads_ref, db_ref, dc_ref, dg_ref,
-                     ds_ref, scores_ref, bias_ref, dscores_ref):
-    """One chunk backwards for ``hb`` heads (the grid: rows, chunks last to
-    first, tiles of heads). ``ds_ref`` [tiles, N, hb P] carries the
+def _backward_kernel(hb, p, per_group, u_ref, cols_ref, rows_ref, b_ref, c_ref, seg_col_ref,
+                     seg_row_ref, g_ref, kept_ref, dy_ref, du_ref, sums_ref, reads_ref, db_ref,
+                     dc_ref, dg_ref, ds_ref, scores_ref, bias_ref, dscores_ref):
+    """One chunk backwards for ``hb`` heads of one group (the grid: rows, chunks
+    last to first, tiles of heads). ``ds_ref`` [tiles, N, hb P] carries the
     cotangent of the state leaving the chunk. Writes the cotangent of ``u``;
     ``sums_ref`` [C, 4 hb], a slot and head: the cotangents of ``dt``
     (through ``dt u``), ``e_in`` and ``e_last``, and what is read OF slot j
     (a column sum of the head's decay matrix times its cotangent);
     ``reads_ref`` [hb, C]: what slot i reads (the row sum of the same
     matrix: the running sum's cotangent is the one less the other); B's and
-    C's cotangents, added up over the tiles of heads in the output's block;
+    C's cotangents, added up over the group's tiles of heads in the output's block;
     ``dg_ref`` [1, hb P]: the state entering the chunk times the cotangent
     of the state leaving it, lane by lane."""
-    tile, tiles = pl.program_id(2), pl.num_programs(2)
+    tile = pl.program_id(2)
     f32, cd = jnp.float32, b_ref.dtype
     high = cd == f32
 
@@ -331,7 +367,7 @@ def _backward_kernel(hb, p, u_ref, cols_ref, rows_ref, b_ref, c_ref, seg_col_ref
     def _():
         ds_ref[tile] = jnp.zeros(ds_ref.shape[1:], f32)
 
-    @pl.when(tile == 0)
+    @pl.when(_group_end(per_group, False))
     def _():
         scores_ref[...], bias_ref[...] = _shared(seg_col_ref, seg_row_ref, b_ref, c_ref, True)
         dscores_ref[...] = jnp.zeros_like(dscores_ref)
@@ -385,7 +421,7 @@ def _backward_kernel(hb, p, u_ref, cols_ref, rows_ref, b_ref, c_ref, seg_col_ref
     db_ref[...] += db
     dc_ref[...] += dc
 
-    @pl.when(tile == tiles - 1)
+    @pl.when(_group_end(per_group, True))
     def _():
         dscores = dscores_ref[...].astype(cd)  # [j, i]
         db_ref[...] += _dot(dscores, c_ref[...], (1, 0), high)
@@ -399,13 +435,15 @@ def _params(interpret: bool):
         interpret=interpret)
 
 
-def _specs(chunk: int, hb: int, width: int, state: int, at):
+def _specs(chunk: int, hb: int, width: int, state: int, at, per_group: int):
     """Block specs a grid step ``(row, i, tile)``, ``at(i)`` the chunk: of
     what both kernels read (u, the columns, the rows, B, C, the ids as a
     column and as a row, the state's decay), and of a chunk's slots by the
-    tile's lanes, of a state, of a chunk's slots by the state's width."""
+    tile's lanes, of a state, of a chunk's slots by the state's width: the
+    columns of the tile's group, ``per_group`` tiles a group (0: one group)."""
+    group = (lambda t: t // per_group) if per_group else (lambda t: 0)
     wide = pl.BlockSpec((None, chunk, width), lambda r, i, t: (r, at(i), t))
-    narrow = pl.BlockSpec((None, chunk, state), lambda r, i, t: (r, at(i), 0))
+    narrow = pl.BlockSpec((None, chunk, state), lambda r, i, t: (r, at(i), group(t)))
     reads = [wide,
              pl.BlockSpec((None, None, chunk, 4 * hb), lambda r, i, t: (r, t, at(i), 0)),
              pl.BlockSpec((None, None, None, hb, chunk), lambda r, i, t: (r, at(i), t, 0, 0)),
@@ -420,17 +458,18 @@ def _specs(chunk: int, hb: int, width: int, state: int, at):
 @functools.partial(jax.jit, static_argnums=(0,))
 def _forward(static, u, cols, rows, b, c, seg_col, seg_row, g):
     """u [B, L, H P], cols [B, tiles, L, 4 hb], rows [B, chunks, tiles, hb, C],
-    b, c [B, L, N], seg_col [B, L, 1], seg_row [B, chunks, 1, C], g [B,
+    b, c [B, L, G N], seg_col [B, L, 1], seg_row [B, chunks, 1, C], g [B,
     chunks, 1, H P] -> y as u lies (float32), the state entering every chunk
     [B, chunks, N, H P]. (A jitted function, as the backward pass is: a
     step calls each at one shape, and the body is traced once.)"""
-    interpret, chunk = static
+    interpret, chunk, groups = static
     bsz, length, inner = u.shape
-    tiles, hb, state = cols.shape[1], rows.shape[3], b.shape[-1]
+    tiles, hb, state = cols.shape[1], rows.shape[3], b.shape[-1] // groups
     n, width, f32 = length // chunk, inner // tiles, jnp.float32
-    reads, wide, kept, _ = _specs(chunk, hb, width, state, lambda i: i)
+    per_group = tiles // groups if groups > 1 else 0
+    reads, wide, kept, _ = _specs(chunk, hb, width, state, lambda i: i, per_group)
     return pl.pallas_call(
-        functools.partial(_forward_kernel, hb, width // hb),
+        functools.partial(_forward_kernel, hb, width // hb, per_group),
         grid=(bsz, n, tiles), in_specs=reads, out_specs=[wide, kept],
         out_shape=[jax.ShapeDtypeStruct(u.shape, f32),
                    jax.ShapeDtypeStruct((bsz, n, state, inner), f32)],
@@ -445,16 +484,17 @@ def _backward(static, u, cols, rows, b, c, seg_col, seg_row, g, kept, dy):
     """-> the cotangent of u (as it lies); a slot and head [B, tiles, L, 4
     hb]: the cotangents of ``dt`` (through ``dt u``), ``e_in``, ``e_last`` and
     what is read of the slot; what the slot reads, as rows [B, chunks, tiles,
-    hb, C]; the cotangents of b and c [B, L, N] float32; and that of the
+    hb, C]; the cotangents of b and c [B, L, G N] float32; and that of the
     state's decay, lane by lane [B, chunks, 1, H P]."""
-    interpret, chunk = static
+    interpret, chunk, groups = static
     bsz, length, inner = u.shape
-    tiles, hb, state = cols.shape[1], rows.shape[3], b.shape[-1]
+    tiles, hb, state = cols.shape[1], rows.shape[3], b.shape[-1] // groups
     n, width, f32 = length // chunk, inner // tiles, jnp.float32
+    per_group = tiles // groups if groups > 1 else 0
     back = lambda i: n - 1 - i  # noqa: E731
-    reads, wide, kept_spec, narrow = _specs(chunk, hb, width, state, back)
+    reads, wide, kept_spec, narrow = _specs(chunk, hb, width, state, back, per_group)
     return pl.pallas_call(
-        functools.partial(_backward_kernel, hb, width // hb),
+        functools.partial(_backward_kernel, hb, width // hb, per_group),
         grid=(bsz, n, tiles), in_specs=reads + [kept_spec, wide],
         out_specs=[wide, reads[1], reads[2], narrow, narrow, reads[-1]],
         out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype),
@@ -487,8 +527,9 @@ def _decays(cum, seg):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _walk(static, u, dt, cum, e_in, e_last, g, b, c, seg):
     """u [B, L, H P]; dt, e_in, e_last [B, chunks, C, H], cum [B, chunks, H,
-    C] and g [B, chunks, H] float32 (:func:`_decays`); b, c [B, L, N]; seg [B,
-    L]; L whole chunks -> y as u lies, float32."""
+    C] and g [B, chunks, H] float32 (:func:`_decays`); b, c [B, L, G N]; seg [B,
+    L]; L whole chunks -> y as u lies, float32. ``static``: whether the kernels
+    are interpreted, the chunk, the groups."""
     return _forward(static, *_operands(static, u, dt, cum, e_in, e_last, g, b, c, seg))[0]
 
 
@@ -498,10 +539,10 @@ def _operands(static, u, dt, cum, e_in, e_last, g, b, c, seg):
     ``e_last``), a tile of heads at a time [B, tiles, L, 4 hb]; the running
     sum as rows [B, chunks, tiles, hb, C]; the ids as a column and as rows;
     the state's decay over its head's lanes."""
-    _, chunk = static
+    _, chunk, groups = static
     bsz, length, inner = u.shape
     heads, n = dt.shape[-1], length // chunk
-    hb = _tile(heads, inner // heads)
+    hb = _tile(heads, inner // heads, groups)
     tiles = heads // hb
     with jax.named_scope("seq.ssm.scan.prep"):
         cols = jnp.stack([dt, jnp.moveaxis(cum, 2, 3), e_in, e_last], axis=3)  # [B, n, C, 4, H]
@@ -547,27 +588,32 @@ def _chain(width: int, inner: int) -> Dict:
 
 
 def forms(shapes: Dict, length: int, *, heads: int, head_dim: int, state: int, chunk: int = 256,
-          state_dtype=jnp.float32, gate_dtype=jnp.float32, **_) -> Dict[str, str]:
-    """``ssd_scan`` (:func:`scan_kind`) and ``conv`` ("pallas" or "xla"): what
-    :func:`mamba2` runs over rows of ``length`` slots, ``shapes`` its
-    parameters' and the keyword arguments its own."""
-    return {"ssd_scan": scan_kind(heads, head_dim, state, length, chunk, state_dtype, gate_dtype),
+          groups: int = 1, state_dtype=jnp.float32, gate_dtype=jnp.float32, **_) -> Dict:
+    """``ssd_scan`` (:func:`scan_kind`), ``conv`` ("pallas" or "xla") and, where
+    the heads read B and C by group, ``ssd_groups``: what :func:`mamba2` runs over
+    rows of ``length`` slots, ``shapes`` its parameters' and the keyword arguments
+    its own."""
+    return {"ssd_scan": scan_kind(heads, head_dim, state, length, chunk, state_dtype, gate_dtype,
+                                  groups=groups),
+            **({"ssd_groups": groups} if groups > 1 else {}),
             "conv": chain_kind(length, shapes["conv_w"][0],
                                **_chain(shapes["w_in"][1], heads * head_dim))}
 
 
 def mamba2(p: Dict, x, seg, *, heads: int, head_dim: int, state: int, eps: float,
-           chunk: int = 256, compute_dtype=jnp.float32, state_dtype=jnp.float32,
-           gate_dtype=jnp.float32) -> Tuple[jax.Array, Dict]:
+           chunk: int = 256, groups: int = 1, compute_dtype=jnp.float32,
+           state_dtype=jnp.float32, gate_dtype=jnp.float32) -> Tuple[jax.Array, Dict]:
     """The mixer of a Mamba-2 layer: x [B, L, D] (normed) -> [B, L, D]
-    float32. ``p``, with I = heads * head_dim: ``w_in`` [D, 2 I + 2 N] (the
-    columns ``[z | x | B | C]``), ``w_dt`` [D, H] (the published in-projection's
-    last H columns, a leaf of their own: they feed an exponential and are
-    multiplied in float32), ``conv_w`` [K, I + 2 N], ``conv_b`` [I + 2 N],
-    ``A_log``, ``dt_bias``, ``D`` [H], ``norm`` [I], ``w_out`` [I, D].
+    float32. ``p``, with I = heads * head_dim and G = ``groups``: ``w_in`` [D, 2 I
+    + 2 G N] (the columns ``[z | x | B | C]``, B and C a group after the other),
+    ``w_dt`` [D, H] (the published in-projection's last H columns, a leaf of
+    their own: they feed an exponential and are multiplied in float32),
+    ``conv_w`` [K, I + 2 G N], ``conv_b`` [I + 2 G N], ``A_log``, ``dt_bias``,
+    ``D`` [H], ``norm`` [I] (the gated norm's scale; the norm runs over each
+    group's I / G channels), ``w_out`` [I, D].
 
     Also returns what the scan was given and what it gave, as this call
-    computed them (``u`` [B, L, H, P], ``B``, ``C`` [B, L, N], ``dt`` [B, L,
+    computed them (``u`` [B, L, H, P], ``B``, ``C`` [B, L, G N], ``dt`` [B, L,
     H], ``y`` [B, L, H, P]): a caller that holds the scan that ran against
     the recurrence reads them."""
     bsz, length, _ = x.shape
@@ -581,15 +627,19 @@ def mamba2(p: Dict, x, seg, *, heads: int, head_dim: int, state: int, eps: float
         xbc = conv_chain(zxbc, p["conv_w"], seg, bias=p["conv_b"],
                          **_chain(zxbc.shape[-1], inner))
         u = xbc[..., :inner].reshape(bsz, length, heads, head_dim).astype(cd)
-        b, c = (xbc[..., inner + i * state: inner + (i + 1) * state].astype(cd) for i in (0, 1))
+        wide = groups * state
+        b, c = (xbc[..., inner + i * wide: inner + (i + 1) * wide].astype(cd) for i in (0, 1))
         dt = jax.nn.softplus(dt_raw + p["dt_bias"])
     with jax.named_scope("seq.ssm.scan"):
-        y = ssd_scan(u, dt, -jnp.exp(p["A_log"]), b, c, seg, chunk=chunk, compute_dtype=cd,
-                     state_dtype=state_dtype, gate_dtype=gate_dtype)
+        y = ssd_scan(u, dt, -jnp.exp(p["A_log"]), b, c, seg, chunk=chunk, groups=groups,
+                     compute_dtype=cd, state_dtype=state_dtype, gate_dtype=gate_dtype)
     with jax.named_scope("seq.ssm.norm"):
         skipped = y + p["D"][:, None] * u.astype(f32)
         gated = skipped.reshape(bsz, length, inner) * jax.nn.silu(zxbc[..., :inner].astype(f32))
-        o = gated * jax.lax.rsqrt(jnp.mean(gated * gated, -1, keepdims=True) + eps) * p["norm"]
+        if groups > 1:  # the norm's mean is a group's own
+            gated = gated.reshape(bsz, length, groups, inner // groups)
+        unit = gated * jax.lax.rsqrt(jnp.mean(gated * gated, -1, keepdims=True) + eps)
+        o = (unit.reshape(bsz, length, inner) if groups > 1 else unit) * p["norm"]
     with jax.named_scope("seq.ssm.out"):
         out = jnp.dot(o.astype(cd), p["w_out"].astype(cd), preferred_element_type=f32)
     return out, {"u": u, "B": b, "C": c, "dt": dt, "y": y}

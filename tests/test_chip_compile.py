@@ -1069,3 +1069,74 @@ def test_keye_step_one_row_of_16k_fits_the_chip(one_chip, as_tpu):
         "index_kl_backward", "index_kl_forward"]
     assert not [line for line in text.splitlines()
                 if " while(" in line and "seq.attn.index_loss" in line]
+
+
+# -- and at the shapes of train-nemotron3nano-packed8k: Nemotron-3-Nano widths, four
+# Mamba-2 layers (64 heads of 64 on a state of 128 in EIGHT groups, chunks of 128),
+# four expert layers of ungated experts and one grouped-query attention layer (32
+# heads on 2 of 128), every layer one part, TWO rows of 8,192 slots a step
+@pytest.mark.parametrize("chunk", [128, 256])
+def test_grouped_ssd_scan_two_rows_of_8k(one_chip, as_tpu, chunk):
+    """The state-space scan alone with eight groups of B and C, forward and
+    backward, at the cell's shape: a grid step's eight heads are one group,
+    whose 128 columns of the 1,024 it is handed by block index; at the
+    published chunk of 128 and at granite's 256."""
+    from predictionio_tpu.ops import ssd
+
+    assert ssd.scan_kind(64, 64, 128, SEQ_L, chunk, groups=8) == "pallas"
+    assert ssd._tile(64, 64, 8) == 8
+
+    def loss(u, dt, a, b, c, seg):
+        with jax.named_scope("seq.ssm.scan"):
+            return ssd.ssd_scan(u, dt, a, b, c, seg, chunk=chunk, groups=8,
+                                compute_dtype=jnp.bfloat16).sum()
+
+    state = _sds(one_chip, (2, SEQ_L, 8 * 128), jnp.bfloat16)
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), _sds(one_chip, (2, SEQ_L, 64, 64), jnp.bfloat16),
+        _sds(one_chip, (2, SEQ_L, 64), jnp.float32), _sds(one_chip, (64,), jnp.float32),
+        state, state, _sds(one_chip, (2, SEQ_L), jnp.int32))
+    stats = _report(f"grouped state-space scan (chunks of {chunk})", compiled)
+    text = compiled.as_text().splitlines()
+    calls = [line for line in text
+             if 'custom_call_target="tpu_custom_call"' in line and "seq.ssm.scan" in line]
+    assert len(calls) == 2
+    assert not [line for line in text if re.search(rf"= f32\[[\d,]*{chunk},{chunk}\]", line)]
+    # y's cotangent, the states entering the chunks (2 MB a chunk and row) and what XLA
+    # lays out a slot and head
+    assert stats.temp_size_in_bytes < (1.0 if chunk == 128 else 0.8) * 2**30
+
+
+def test_nemotronh_step_two_rows_of_8k_fits_the_chip(one_chip, as_tpu):
+    """The whole optimizer step of ``train-nemotron3nano-packed8k`` (2 rows of
+    8,193 slots, nine one-part layers, 667 M parameters with their AdamW
+    moments, donated) as the job compiles it: the chip's compiler takes it,
+    and arguments and temporaries together stay inside the chip's 15.75 GiB. The convolution (6,144 channels from column 4,096 of the
+    10,240 the wide projection has) and the grouped scan are their kernels."""
+    from predictionio_tpu.models import seq_backbone as bb
+    from predictionio_tpu.models import sequencerec
+
+    cfg = bb.BackboneConfig.load("nemotron3-nano-30b-a3b-ep16")
+    opt_init, step, _ = sequencerec._programs(cfg, 3e-4, None, "auto")
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: bb.init_params(cfg, 16384, SEQ_L, 0)))
+    assert sum(int(np.prod(s.shape)) for s in jax.tree_util.tree_leaves(params)) == 666_963_456
+    rows = _sds(one_chip, (2, SEQ_L + 1), jnp.int32)
+    try:
+        compiled = _compile(step, params, on_chip(jax.eval_shape(opt_init, params)), rows, rows)
+    finally:
+        step.clear_cache()  # the job's own program object, kept by ``_programs``
+    stats = _report("nemotronh step", compiled)
+    # (read here: arguments 8.00 GB, which the outputs alias, temporaries 7.65 GB)
+    assert stats.argument_size_in_bytes + stats.temp_size_in_bytes <= 15.75 * 2**30
+    assert cfg.mixers() == {"gqa": 1, "mamba2": 4, "moe": 4}
+    assert bb.mechanisms(cfg, SEQ_L) == {
+        "ssd_scan": "pallas", "ssd_groups": 8, "expert_act": "relu2", "conv": "pallas"}
+    assert _conv_under(compiled, "seq.ssm.conv") >= 3
+    text = compiled.as_text()
+    scan = [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line and "seq.ssm.scan" in line]
+    assert len(scan) == 4 * _SCAN_CALLS_A_LAYER
+    for scope in ("seq.moe.route", "seq.moe.experts", "seq.moe.shared", "seq.attn.core", "seq.head"):
+        assert scope in text

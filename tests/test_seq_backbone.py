@@ -889,7 +889,10 @@ EVERY = {
 #: what a kind needs that ``EVERY`` cannot carry for all: sparse attention is
 #: grouped-query attention and refuses a differential file
 OWN = {"dsa": {"sa_config": {"indexer_num_heads": 2, "indexer_head_dim": 8, "topk": 8},
-               "backbone": {**EVERY["backbone"], "differential": False}}}
+               "backbone": {**EVERY["backbone"], "differential": False}},
+       # an expert layer is a layer of its own where every layer is one part
+       "moe": {"router_width": 4, "num_experts_per_tok": 2, "moe_intermediate_size": 16,
+               "experts_held": [1, 2], "backbone": {**EVERY["backbone"], "ffn": "none"}}}
 
 
 @pytest.mark.parametrize("kind", sorted(bb._MIXERS))
@@ -904,7 +907,8 @@ def test_every_record_of_the_table_is_complete_and_its_words_are_accepted(kind):
             {**EVERY, **OWN.get(kind, {}), "layer_types": ["mamba1", "full_attention", word]})
         assert cfg.kinds[-1] == kind and cfg.stacked(kind) in (0, 1, 2)
         assert cfg.mixers()[record.name or cfg.attention] >= 1
-        assert all(bb._is_spec(spec) for spec in record.shapes(cfg).values()) and record.shapes(cfg)
+        specs = jax.tree_util.tree_leaves(record.shapes(cfg), is_leaf=bb._is_spec)
+        assert specs and all(bb._is_spec(spec) for spec in specs)
         assert isinstance(record.forms(cfg, L), dict) and isinstance(record.widths(cfg), dict)
         # and the layer runs with the parameters its record names (traced, not compiled)
         params = jax.eval_shape(lambda: bb.init_params(cfg, VOCAB, L, 0))
@@ -954,7 +958,8 @@ def as_tpu(monkeypatch):
 
 @pytest.mark.parametrize("name,rows", [
     ("qwen3next-80b-a3b-ep16", 2), ("joyai-flash-48b-a3b-ep16", 2), ("lfm2-24b-a2b-ep8", 2),
-    ("granite4h-micro-vp8", 1), ("phi4-mini-flash-vp8", 1), ("keye-vl2-30b-a3b-ep8", 1)])
+    ("granite4h-micro-vp8", 1), ("phi4-mini-flash-vp8", 1), ("keye-vl2-30b-a3b-ep8", 1),
+    ("nemotron3-nano-30b-a3b-ep16", 2)])
 def test_the_counters_say_pallas_exactly_where_the_step_holds_a_kernel(as_tpu, name, rows):
     """On a TPU, over the cells' rows of 8,192 slots: the forward pass traced
     on abstract arguments holds a ``pallas_call`` under a mixer's ``.conv``,

@@ -364,7 +364,7 @@ def test_the_cut_is_the_first_period_of_the_uncut_model_and_a_slice_of_its_head(
 @pytest.mark.parametrize("bad,says", [
     ({"mamba_n_heads": None}, "mamba_n_heads"), ({"mamba_d_head": None}, "mamba_d_head"),
     ({"mamba_d_state": None}, "mamba_d_state"), ({"mamba_d_conv": None}, "mamba_d_conv"),
-    ({"mamba_n_groups": None}, "mamba_n_groups"), ({"mamba_n_groups": 8}, "mamba_n_groups is 8"),
+    ({"mamba_n_groups": None}, "mamba_n_groups"), ({"mamba_n_groups": 3}, "mamba_n_groups is 3"),
     ({"mamba_expand": 4}, "mamba_expand"), ({"mamba_conv_bias": False}, "bias"),
     ({"mamba_proj_bias": True}, "bias"), ({"attention_bias": True}, "bias"),
     ({"backbone": {**TINY["backbone"], "positions": "alibi"}}, "positions"),

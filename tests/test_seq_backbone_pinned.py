@@ -4,9 +4,10 @@ before the next backbone computed (``granite4h-tiny``: commit 32bdee9, the
 parent of the fifth backbone's PR), or, for the fifth, the commit before the
 backbone's mixers became one table (``phi4-mini-flash-tiny``: commit d0ce42b,
 PR 43), or, for the sixth, what its own PR computed (``keye-vl2-tiny``: PR 45,
-with exactly ``topk`` keys a query);
+with exactly ``topk`` keys a query), or, for the seventh, what ITS own PR
+computed (``nemotron3-nano-tiny``: PR 49, layers of one part);
 and the five older ones' step programs must lower to the text the sixth's
-parent lowered."""
+parent lowered, the sixth's to what the seventh's parent lowered."""
 
 import json
 
@@ -64,7 +65,11 @@ PINNED = json.loads("""
  "keye-vl2-tiny": {"loss": 4.133016109466553, "grad_norm": {"embed": 0.8558340625832418,
   "final_norm.w": 0.01721364968225186, "head": 0.7197617383071163, "periods.dsa": 0.9331903167737758,
   "periods.ffn": 0.05705573140272603, "periods.norm_in": 0.012798044946150227,
-  "periods.norm_post": 0.0009832543026727114}}}
+  "periods.norm_post": 0.0009832543026727114}},
+ "nemotron3-nano-tiny": {"loss": 3.9308419227600098, "grad_norm": {"embed": 2.057600270499885,
+  "final_norm.w": 0.01512243031049317, "head": 0.677826406422071, "periods.full": 0.03878747338818655,
+  "periods.moe": 0.21130955257304626, "periods.norm_in": 0.05790962085522093,
+  "periods.ssm": 0.30691068655779696}}}
 """)
 
 
@@ -77,7 +82,7 @@ def test_a_listed_configuration_computes_what_it_did_before_this_backbone(name, 
     (PR 40, before ``mamba1``, ``gmu``, windows and cross-attention) the
     fourth, commit d0ce42b (PR 43, before the table of mixers) the fifth, and
     PR 45 itself the sixth (sparse attention; the reference holds it: here it is
-    held against a later change). A
+    held against a later change; the seventh likewise, PR 49). A
     change to a mixer of ``seq_backbone``, ``_layer``, ``logits_of``, ``swiglu`` or the
     loss that moves a listed configuration fails here and not on the
     driver's chip. 1e-4: float32 sums in another order read 1e-6; a
@@ -104,7 +109,9 @@ def test_a_listed_configuration_computes_what_it_did_before_this_backbone(name, 
 LOWERED = {
     "qwen3next-tiny": "8647b3ccf9e03740", "joyai-flash-tiny": "ca7aadf33217cfca",
     "lfm2-tiny": "8a0a78b7521aab03", "granite4h-tiny": "af44eb0d1a6e2910",
-    "phi4-mini-flash-tiny": "11204e7d06802878"}
+    "phi4-mini-flash-tiny": "11204e7d06802878",
+    # as commit e34b81f (PR 48, the seventh backbone's parent) lowered it
+    "keye-vl2-tiny": "9ecf115e83a29fe7"}
 
 
 @pytest.mark.parametrize("name", sorted(LOWERED))
@@ -138,7 +145,8 @@ def test_a_listed_configuration_lowers_to_the_text_it_did_before_this_backbone(n
 SCOPED = {
     "qwen3next-tiny": "d3cddef1dda60909", "joyai-flash-tiny": "84122237e9b90538",
     "lfm2-tiny": "c0849603f5aef9f0", "granite4h-tiny": "2d28a1e6607553c0",
-    "phi4-mini-flash-tiny": "d655df2c744808e3"}
+    "phi4-mini-flash-tiny": "d655df2c744808e3",
+    "keye-vl2-tiny": "1c9f9836af20be88"}  # the last as commit e34b81f (PR 48) traced it
 
 
 def _scoped(jaxpr, under=""):

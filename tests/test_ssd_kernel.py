@@ -120,6 +120,67 @@ def test_kernel_gives_the_xla_forms_and_the_recurrences_output_and_cotangents(ca
             assert rel(g, w) < (1e-5 if name == "y" else 1e-4), name
 
 
+# -- groups of heads that share B and C ---------------------------------------
+@functools.cache
+def _grouped_form(interpret, groups, chunk=CHUNK):
+    return _weighted(lambda u, dt, a_log, b, c, segs: ssd.ssd_scan(
+        u, dt, -jnp.exp(a_log), b, c, segs, chunk=chunk, groups=groups, interpret=interpret))
+
+
+@functools.cache
+def _grouped_recurrence(groups):
+    from predictionio_tpu.testing import nemotronh_reference as grouped
+
+    def rows(u, dt, a_log, b, c, segs):
+        split = lambda t, r: t[r].reshape(t.shape[1], groups, -1)  # noqa: E731
+        return jnp.stack([grouped.ssd_recurrence(
+            u[r].astype(jnp.float32), split(b, r), split(c, r), dt[r], a_log, segs[r], block=8)
+            for r in range(u.shape[0])])
+
+    return _weighted(rows)
+
+
+@pytest.mark.parametrize("case", ["on a chunk's first slot", "on a chunk's last slot",
+                                  "several inside one chunk", "a length that is no whole chunk"])
+@pytest.mark.parametrize("heads,width,groups", [
+    pytest.param(8, P, 4, id="a grid step a group"),
+    pytest.param(8, P, 2, id="two grid steps a group"),
+    pytest.param(4, 16, 4, id="a head a group, a grid step of one head"),
+    pytest.param(4, P, 1, id="one group, said")])
+def test_grouped_heads_read_their_groups_b_and_c_in_both_forms(case, heads, width, groups):
+    """Head i on ``B``, ``C`` of group ``i // (H / G)``: the kernel
+    (interpreted; a grid step's heads share a group, whose columns it is
+    handed by block index, and whose cotangents it sums over the group's grid
+    steps alone) and XLA's batch products (a ``vmap`` over the groups) against
+    the slot-by-slot recurrence, ``y`` and every cotangent, with boundaries on
+    a chunk's first, last and inner slots."""
+    other = BOUNDARIES["several inside one chunk"][:len(BOUNDARIES[case])]
+    segs = np.stack([BOUNDARIES[case], other])
+    assert ssd.scan_kind(heads, width, N, segs.shape[1], CHUNK, interpret=True,
+                         groups=groups) == "pallas"
+    assert ssd._tile(heads, width, groups) == min(2, heads // groups)
+    inputs, weight = _inputs(len(case) + groups, segs, heads=heads, width=width, state=groups * N)
+    with jax.default_matmul_precision("highest"):
+        want = _grouped_recurrence(groups)(inputs, weight)
+    for interpret in (True, False):
+        got = _grouped_form(interpret, groups)(inputs, weight)
+        for name, g, w in zip(NAMES, got, want):
+            assert g.shape == w.shape and np.isfinite(np.asarray(g)).all(), name
+            assert rel(g, w) < (1e-5 if name == "y" else 1e-4), (name, interpret)
+    if groups > 1:  # and not what one group's B and C for all heads gives
+        inputs_one = inputs[:3] + tuple(jnp.tile(t[..., :N], (1, 1, groups)) for t in inputs[3:5]) + inputs[5:]
+        assert rel(_grouped_form(False, groups)(inputs_one, weight)[0], want[0]) > 0.3
+
+
+def test_groups_that_split_no_grid_step_take_the_xla_form():
+    """Three heads a group and lane tiles of two: no grid step's heads would
+    share a group, so the call is XLA's."""
+    assert ssd._tile(6, P, 2) == 0 and ssd._tile(6, P, 3) == 2
+    assert ssd.scan_kind(6, 16, N, L, CHUNK, interpret=True, groups=2) == "pallas"  # a head a tile
+    assert ssd.scan_kind(6, P, N, L, CHUNK, interpret=True, groups=2) == "xla"  # half a lane tile
+    assert ssd.scan_kind(6, P, N, L, CHUNK, interpret=True, groups=4) == "xla"  # no equal groups
+
+
 @pytest.mark.parametrize("heads,width,a_step", [
     pytest.param(8, 8, 2, id="four grid steps of two heads"),
     pytest.param(4, 8, 4, id="four heads in one grid step, two lane tiles"),
